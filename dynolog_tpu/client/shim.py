@@ -329,7 +329,10 @@ class CaptureRing:
         self.last_path: str | None = None
         self.last_error: str | None = None
         self._pending = False
-        self._last_capture_t = 0.0
+        # -inf, not 0.0: time.monotonic() counts from boot, so on a host
+        # whose uptime is below min_interval_s a zero start would read as
+        # "captured just now" and the first ring capture would never arm.
+        self._last_capture_t = float("-inf")
         self._last_step_seen = 0
 
     # -- sampling decision (called from step(), must stay trivial) ------
@@ -701,20 +704,21 @@ class JaxProfiler:
         try:
             from jax._src.lib import _profiler
 
-            # Backend (and on TPU, libtpu) must be initialized before the
-            # tracer is created, as jax.profiler.start_trace itself
-            # ensures.
-            jax.devices()
-            opts = jax.profiler.ProfileOptions()
-            for attr, value in self.tracer_levels.items():
-                setattr(opts, attr, value)
-            self._sess = _profiler.ProfilerSession(opts)
-        except Exception:  # noqa: BLE001 - the session type, its ctor
-            # signature, and ProfileOptions are all private jax API: ANY
-            # refactor of them must degrade to the slow public path, never
-            # to broken captures.
+            session_type = _profiler.ProfilerSession
+        except (ImportError, AttributeError):
+            # A jax whose private session type moved: the public API
+            # still captures, without the collect/write decomposition.
             self._sess = None
             jax.profiler.start_trace(trace_dir)
+            return
+        # Backend (and on TPU, libtpu) must be initialized before the
+        # tracer is created, as jax.profiler.start_trace itself ensures.
+        # A failure from here on is a failed capture and surfaces as one.
+        jax.devices()
+        opts = jax.profiler.ProfileOptions()
+        for attr, value in self.tracer_levels.items():
+            setattr(opts, attr, value)
+        self._sess = session_type(opts)
 
     def stop(self) -> None:
         import jax
@@ -761,10 +765,9 @@ class JaxProfiler:
             pending.queue.fail(e)
             raise
         # Decomposition for the capture manifest: collection is the
-        # runtime's trace drain (on remote-dispatch platforms, tunnel
-        # RTT-bound — environmental); feed is this thread's hand-off
-        # into the queue (backpressure-bounded); write_ms arrives from
-        # the writer via the finisher's pending.wait().
+        # runtime's trace drain; feed is this thread's hand-off into the
+        # queue (backpressure-bounded); write_ms arrives from the writer
+        # via the finisher's pending.wait().
         self.last_stop_decomposition = {
             "collect_ms": int((t_collect - t0) * 1000),
             "feed_ms": int((time.time() - t_collect) * 1000),

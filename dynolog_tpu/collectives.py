@@ -38,15 +38,14 @@ def _mesh_and_ops():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from dynolog_tpu.parallel._compat import shard_map_compat
-
     devices = jax.devices()
     n = len(devices)
     mesh = Mesh(np.asarray(devices), ("x",))
 
     def wrap(f, out_spec):
-        sm = shard_map_compat(
-            f, mesh=mesh, in_specs=P("x"), out_specs=out_spec)
+        sm = jax.shard_map(
+            f, mesh=mesh, in_specs=P("x"), out_specs=out_spec,
+            check_vma=False)
         return jax.jit(sm)
 
     import jax.numpy as jnp

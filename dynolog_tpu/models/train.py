@@ -11,13 +11,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from dynolog_tpu.models.transformer import TransformerConfig, init_params, loss_fn
-from dynolog_tpu.parallel.sharding import (
-    batch_sharding,
-    partition_invariant_rng,
-    shard_params,
-)
+from dynolog_tpu.parallel.sharding import batch_sharding, shard_params
 
 
 def make_optimizer(lr: float = 3e-4):
@@ -27,37 +24,47 @@ def make_optimizer(lr: float = 3e-4):
 def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     """(params, opt_state), placed on the mesh when one is given.
 
-    Both branches draw under partition_invariant_rng so the sharded and
-    unsharded inits of the same seed produce the SAME weights — legacy
-    threefry draws change value when jit partitions a dim-0-sharded
-    output (see sharding.partition_invariant_rng), which made the
-    sharded-vs-single-device equivalence tests diverge by ~0.02 loss.
+    The init is one jitted program either way: each matrix's float32 draw
+    is scaled, cast and freed inside it (eagerly, the embedding alone holds
+    2.1 GB of float32 at published widths), and with a mesh the outputs are
+    born sharded, so a large model is never materialized on one device.
+    Threefry is partitionable, so the sharded and unsharded inits of one
+    seed produce the same weights. The optimizer state is given the
+    parameters' layout explicitly: its zeros depend on no input, so
+    propagation leaves them whole on device 0 (seen on four v5e chips: 6 GB
+    of Adam state on chip 0 and a 15.9 GB peak there in the first step).
     """
     optimizer = make_optimizer(lr)
-    if mesh is None:
-        with partition_invariant_rng():
-            params = init_params(rng, cfg)
-        return params, optimizer.init(params)
-
-    # Initialize sharded: jit init with output shardings so large models are
-    # never materialized on one device. Optimizer state inherits the
-    # parameter layout through jit's sharding propagation.
-    abstract = jax.eval_shape(lambda r: init_params(r, cfg), rng)
-    param_shardings = shard_params(abstract, mesh)
-    with partition_invariant_rng():
-        params = jax.jit(
-            lambda r: init_params(r, cfg), out_shardings=param_shardings)(rng)
-    opt_state = jax.jit(optimizer.init)(params)
+    param_shardings = opt_shardings = None
+    if mesh is not None:
+        abstract = jax.eval_shape(lambda r: init_params(r, cfg), rng)
+        param_shardings = shard_params(abstract, mesh)
+        replicated = NamedSharding(mesh, PartitionSpec())
+        opt_shardings = optax.tree_utils.tree_map_params(
+            optimizer, lambda _, sharding: sharding,
+            jax.eval_shape(optimizer.init, abstract), param_shardings,
+            transform_non_params=lambda _: replicated)
+    params = jax.jit(
+        lambda r: init_params(r, cfg), out_shardings=param_shardings)(rng)
+    opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
     return params, opt_state
 
 
 def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     """Returns a jitted (params, opt_state, tokens) -> (params, opt_state,
-    loss) step; sharded over `mesh` when given."""
+    loss) step; sharded over `mesh` when given.
+
+    params and opt_state are donated: the update writes into the buffers it
+    read, so the resident state is held once, not twice. A caller must
+    rebind both from the step's outputs; the arrays it passed in are gone.
+    """
     optimizer = make_optimizer(lr)
 
-    # ring attention and MoE sharding constraints need the mesh at trace time
-    fwd_mesh = mesh if (cfg.attn_impl == "ring" or cfg.n_experts > 0) else None
+    # ring/flash attention (shard_map) and MoE sharding constraints need the
+    # mesh at trace time
+    fwd_mesh = (
+        mesh if cfg.attn_impl in ("ring", "flash") or cfg.n_experts > 0
+        else None)
 
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, fwd_mesh)
@@ -66,10 +73,12 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
         return params, opt_state, loss
 
     if mesh is None:
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=(0, 1))
 
     data_sharding = batch_sharding(mesh)
-    return jax.jit(step, in_shardings=(None, None, data_sharding))
+    return jax.jit(
+        step, in_shardings=(None, None, data_sharding),
+        donate_argnums=(0, 1))
 
 
 def make_batch(rng, cfg: TransformerConfig, batch_size: int, seq_len: int):

@@ -132,7 +132,20 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
     if cfg.attn_impl == "flash":
         from dynolog_tpu.ops.flash_attention import flash_attention
 
-        out = flash_attention(q, k, v, True).reshape(b, s, d)
+        def attn(q, k, v):
+            return flash_attention(q, k, v, True)
+
+        if mesh is not None:
+            # A Mosaic kernel is opaque to the SPMD partitioner ("cannot be
+            # automatically partitioned"), so under a mesh each device runs
+            # it on its own batch rows and heads.
+            from jax.sharding import PartitionSpec as P
+
+            spec = P("data", None, "model", None)
+            attn = jax.shard_map(
+                attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False)
+        out = attn(q, k, v).reshape(b, s, d)
     elif cfg.attn_impl == "ring":
         from dynolog_tpu.parallel.ring_attention import ring_attention
 

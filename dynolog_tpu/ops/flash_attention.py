@@ -16,8 +16,10 @@ Design (TPU-first, not a port — the reference does no model computation):
   so training at long context keeps the O(S) memory profile — materializing
   the score matrix in the VJP would reintroduce exactly the OOM the
   forward kernel avoids.
-- Off-TPU the kernels run in Pallas interpret mode (numerics-identical),
-  so CPU CI exercises the same code paths the TPU compiles.
+- The kernels carry no interpret switch: on a TPU Mosaic compiles them, and
+  anywhere else the call fails. The CPU tests run the same code under
+  `jax.experimental.pallas.tpu.force_tpu_interpret_mode()`, chosen in the
+  test where it can be seen.
 """
 
 from __future__ import annotations
@@ -50,10 +52,6 @@ def reference_attention(q, k, v, *, causal: bool = True):
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() not in ("tpu",)
 
 
 # ---------------------------------------------------------------- forward
@@ -110,7 +108,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
     lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l_safe)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
+def _flash_forward(q, k, v, causal, block_q, block_k):
     """[B*H, S, D] inputs -> (out [B*H, S, D], lse [B*H, 1, S] f32)."""
     bh, s, d = q.shape
     bq = _pick_block(s, block_q)
@@ -133,7 +131,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
-        interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -232,8 +230,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                    interpret):
+def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
     """[B*H, S, D] residuals + cotangent g -> (dq, dk, dv)."""
     bh, s, d = q.shape
     bq = _pick_block(s, block_q)
@@ -259,7 +256,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -282,7 +279,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
-        interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -304,27 +301,23 @@ def _from_bh(x, b, h):
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512):
     """Flash attention; q,k,v: [B, S, H, D] -> [B, S, H, D].
 
-    Forward and backward both run as Pallas kernels (interpret mode
-    off-TPU); only O(S) residuals (q, k, v, out, lse) are saved.
+    Forward and backward both run as Pallas kernels; only O(S) residuals
+    (q, k, v, out, lse) are saved.
 
-    Default 512x512 blocks measured best across seq 512-8192 on v5e
-    (interleaved A/B sweep, benchmarks/flash_attention_bench.py): larger
-    blocks halve each program's full-K/V re-reads, closing the short-seq
-    backward gap (fwd+bwd at 1024 now at parity with XLA; 1.5x ahead at
-    8192 vs the old 256x256 blocks).
+    Default 512x512 blocks: larger blocks halve each program's full-K/V
+    re-reads. The sweep that chose them (benchmarks/flash_attention_bench
+    .py) predates the current machine and has not been repeated on it.
     """
     b, _, h, _ = q.shape
     out, _ = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k,
-        _use_interpret())
+        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k)
     return _from_bh(out, b, h)
 
 
 def _vjp_fwd(q, k, v, causal, block_q, block_k):
     b, _, h, _ = q.shape
     out, lse = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k,
-        _use_interpret())
+        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k)
     return _from_bh(out, b, h), (q, k, v, out, lse)
 
 
@@ -333,7 +326,7 @@ def _vjp_bwd(causal, block_q, block_k, res, g):
     b, _, h, _ = q.shape
     dq, dk, dv = _flash_backward(
         _to_bh(q), _to_bh(k), _to_bh(v), out_bh, lse, _to_bh(g),
-        causal, block_q, block_k, _use_interpret())
+        causal, block_q, block_k)
     return _from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h)
 
 

@@ -40,7 +40,6 @@ from dynolog_tpu.models.transformer import (
     _mlp,
     _rmsnorm,
 )
-from dynolog_tpu.parallel._compat import shard_map_compat
 
 
 def init_pipeline_params(rng, cfg: TransformerConfig, mesh):
@@ -153,7 +152,7 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
         loss = jax.lax.pmean(loss, "data")
         return loss
 
-    return shard_map_compat(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -164,6 +163,7 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
             P("data", None),  # tokens: DP over batch
         ),
         out_specs=P(),
+        check_vma=False,
     )(
         params["layers"],
         params["embedding"],

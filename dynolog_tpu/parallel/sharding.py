@@ -14,37 +14,11 @@ the sharding annotations — no hand-written comms.
 
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass, field
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-@contextlib.contextmanager
-def partition_invariant_rng():
-    """Scope partitionable threefry over parameter initialization.
-
-    Legacy threefry (``jax_threefry_partitionable=False``, the default on
-    the pinned jax) is NOT partition-invariant: jitting an init with an
-    ``out_shardings`` that splits dimension 0 (the ``P("model", None)``
-    rows of PARAM_RULES — ``wo``/``w_down``) compiles a partitioned RNG
-    whose draws DIFFER from the unsharded program's, so a mesh-sharded
-    init silently produced different weights than the single-device init
-    for exactly those tensors (measured ~O(1) elementwise — different
-    draws, not rounding). Partitionable threefry generates the same bits
-    however the output is sharded, which is why upstream jax later made
-    it the default. Every init path (sharded AND unsharded, so the two
-    agree with each other) runs under this scope; the flag is restored
-    on exit so the rest of the process keeps its configured behavior.
-    """
-    old = jax.config.jax_threefry_partitionable
-    jax.config.update("jax_threefry_partitionable", True)
-    try:
-        yield
-    finally:
-        jax.config.update("jax_threefry_partitionable", old)
 
 
 @dataclass(frozen=True)

@@ -17,11 +17,16 @@ if [[ $# -lt 1 ]]; then
   exit 1
 fi
 
+# The daemon starts before the job, so the TPU backend is named: `grpc`
+# binds the job's runtime metric service (localhost:8431) when it comes
+# up, where the default `auto` would resolve now, find no runtime yet, and
+# stay without TPU metrics.
 "$DYNOLOGD" \
   --port="$DYNOLOG_PORT" \
   --enable_ipc_monitor \
   --ipc_endpoint_name="$DYNOLOG_ENDPOINT" \
   --enable_tpu_monitor \
+  --tpu_metric_backend=grpc \
   --json_log_file="$LOG_FILE" \
   --nouse_JSON &
 DAEMON_PID=$!

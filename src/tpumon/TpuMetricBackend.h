@@ -4,9 +4,9 @@
 // one sample map per TPU device per tick. Three backends:
 //   - FakeTpuBackend: deterministic synthetic metrics; the unit-test backend
 //     the reference never had for gpumon (SURVEY §4 note).
-//   - FileTpuBackend: reads a JSON snapshot exported by a sidecar (the
-//     dynolog_tpu Python exporter publishes libtpu/JAX device metrics there);
-//     covers TPU-VM runtimes where metrics only surface in-process.
+//   - FileTpuBackend: reads a JSON snapshot the job itself writes
+//     (dynolog_tpu.exporter.write_snapshot, called in-process: a chip
+//     belongs to one process); covers metrics that only surface in-process.
 //   - LibtpuBackend: dlopen'd libtpu monitoring API with graceful
 //     degradation when the library or symbols are absent — the
 //     DcgmApiStub.cpp:121-186 soft-fail pattern.

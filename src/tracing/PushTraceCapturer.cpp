@@ -249,10 +249,9 @@ json::Value capturePushTrace(
   // cost (outside this codebase), write = our local finalize tail.
   // first_data splits the server side from the transfer: request → first
   // DATA byte covers the window + the server's session + device-trace
-  // collection + serialize (on remote-dispatch platforms the device
-  // drain rides the tunnel HERE), while stream − first_data is the
-  // localhost copy of the serialized XSpace to the daemon — overlapped
-  // with the disk write by the streaming sink.
+  // collection + serialize, while stream − first_data is the localhost
+  // copy of the serialized XSpace to the daemon — overlapped with the
+  // disk write by the streaming sink.
   manifest["rpc_ms"] = rpcMs;
   manifest["server_overhead_ms"] = rpcMs - durationMs;
   manifest["rpc_first_data_ms"] = rpcStats.firstDataMs;

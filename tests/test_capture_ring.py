@@ -2,6 +2,7 @@
 promotion, K-retention, TTL sweep, env opt-in — all with a fake profiler
 that emits the deterministic synthetic XSpace (no jax, no daemon)."""
 
+import itertools
 import json
 import os
 import pathlib
@@ -11,7 +12,10 @@ import time
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 from xspace_fixture import build_xspace  # noqa: E402
 
+import pytest  # noqa: E402
+
 from dynolog_tpu import diagnose  # noqa: E402
+from dynolog_tpu.client import shim as shim_mod  # noqa: E402
 from dynolog_tpu.client.shim import (  # noqa: E402
     CaptureRing,
     RingConfig,
@@ -78,7 +82,14 @@ def test_ring_samples_on_step_boundary_and_promotes(tmp_path):
     assert not [p for p in (tmp_path / "ring").rglob("*.xplane.pb")]
 
 
-def test_ring_burst_arms_once_and_rate_cap_holds(tmp_path):
+@pytest.mark.parametrize("uptime_s", [42.0, 1e6])
+def test_ring_burst_arms_once_and_rate_cap_holds(
+        tmp_path, monkeypatch, uptime_s):
+    # time.monotonic() counts from boot: a host up for less than
+    # min_interval_s (a freshly booted TPU VM) must still arm the first
+    # capture, so the clock is pinned on both sides of the interval.
+    clock = itertools.count(uptime_s, 0.001)
+    monkeypatch.setattr(shim_mod.time, "monotonic", lambda: next(clock))
     ring = _ring(tmp_path, min_interval_s=3600.0)
     prof = FakeXplaneProfiler()
     # A burst crossing several boundaries between polls arms exactly once.

@@ -1,13 +1,16 @@
 """Numerics tests for the TPU compute kernels (flash + ring attention).
 
-Run on the virtual 8-device CPU mesh (conftest): the Pallas kernel runs in
-interpret mode (numerics-identical to the compiled TPU path), ring
-attention runs over a real shard_map ring with ppermute.
+Run on the virtual 8-device CPU mesh (conftest). The Pallas kernels have no
+interpret switch of their own (on a TPU Mosaic compiles them, elsewhere the
+call fails), so every flash test asks for interpret mode itself through the
+`pallas_interpret` fixture; ring attention runs over a real shard_map ring
+with ppermute.
 """
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from conftest import slow_lane
 from dynolog_tpu.models.train import make_batch, make_train_state, make_train_step
@@ -15,6 +18,12 @@ from dynolog_tpu.models.transformer import TransformerConfig, forward, init_para
 from dynolog_tpu.ops.flash_attention import flash_attention, reference_attention
 from dynolog_tpu.parallel.ring_attention import ring_attention
 from dynolog_tpu.parallel.sharding import MeshSpec, batch_sharding, make_mesh
+
+
+@pytest.fixture
+def pallas_interpret():
+    with pltpu.force_tpu_interpret_mode():
+        yield
 
 
 def _qkv(rng, b=2, s=64, h=4, d=16, dtype=jnp.float32):
@@ -27,21 +36,21 @@ def _qkv(rng, b=2, s=64, h=4, d=16, dtype=jnp.float32):
     )
 
 
-def test_flash_matches_reference_causal():
+def test_flash_matches_reference_causal(pallas_interpret):
     q, k, v = _qkv(jax.random.PRNGKey(0))
     out = flash_attention(q, k, v, True, 32, 16)
     ref = reference_attention(q, k, v, causal=True)
     assert jnp.allclose(out, ref, atol=1e-5), float(jnp.abs(out - ref).max())
 
 
-def test_flash_matches_reference_noncausal():
+def test_flash_matches_reference_noncausal(pallas_interpret):
     q, k, v = _qkv(jax.random.PRNGKey(1), s=48)
     out = flash_attention(q, k, v, False, 16, 16)
     ref = reference_attention(q, k, v, causal=False)
     assert jnp.allclose(out, ref, atol=1e-5)
 
 
-def test_flash_odd_block_sizes():
+def test_flash_odd_block_sizes(pallas_interpret):
     """Requested blocks that don't divide S fall back to valid divisors."""
     q, k, v = _qkv(jax.random.PRNGKey(2), s=40)
     out = flash_attention(q, k, v, True, 256, 256)
@@ -49,7 +58,7 @@ def test_flash_odd_block_sizes():
     assert jnp.allclose(out, ref, atol=1e-5)
 
 
-def test_flash_grad_matches_reference():
+def test_flash_grad_matches_reference(pallas_interpret):
     q, k, v = _qkv(jax.random.PRNGKey(3), s=32)
 
     def loss_flash(q, k, v):
@@ -64,7 +73,15 @@ def test_flash_grad_matches_reference():
         assert jnp.allclose(a, b, atol=1e-4), float(jnp.abs(a - b).max())
 
 
-def test_flash_bf16():
+def test_flash_without_interpret_fails_off_tpu():
+    """No backend-name test picks interpret mode behind the caller's back:
+    off a TPU the compiled path is the only path, and it refuses."""
+    q, k, v = _qkv(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="interpret"):
+        flash_attention(q, k, v, True, 32, 16)
+
+
+def test_flash_bf16(pallas_interpret):
     q, k, v = _qkv(jax.random.PRNGKey(4), dtype=jnp.bfloat16)
     out = flash_attention(q, k, v, True, 32, 32)
     ref = reference_attention(q, k, v, causal=True)
@@ -108,7 +125,7 @@ def test_ring_attention_grads():
         assert jnp.allclose(a, b, atol=1e-4), float(jnp.abs(a - b).max())
 
 
-def test_forward_flash_impl_matches_reference():
+def test_forward_flash_impl_matches_reference(pallas_interpret):
     cfg_ref = TransformerConfig(
         vocab_size=128, d_model=32, n_layers=2, n_heads=4, d_ff=64
     )
@@ -148,8 +165,8 @@ def test_sharded_ring_train_step_matches_single_device():
     ref_params, ref_opt = make_train_state(jax.random.PRNGKey(0), cfg_ref)
     ref_step = make_train_step(cfg_ref)
     _, _, ref_loss = ref_step(ref_params, ref_opt, batch)
-    # Inits are now exactly equal (partition_invariant_rng in
-    # make_train_state); the residual is ring attention's chunked
+    # Inits are exactly equal (threefry is partitionable, so a sharded
+    # draw equals the unsharded one); the residual is ring attention's chunked
     # online-softmax accumulating softmax·V in a different order than the
     # dense reference on a bf16 model (~1e-3 observed, same class of noise
     # the flash/MoE equivalence tests above tolerate at 0.2/2e-2). 1e-2
@@ -157,3 +174,27 @@ def test_sharded_ring_train_step_matches_single_device():
     # 2.3e-2.
     assert abs(float(ring_loss) - float(ref_loss)) < 1e-2, (
         float(ring_loss), float(ref_loss))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
+def test_sharded_flash_train_step_matches_single_device(pallas_interpret):
+    """A Mosaic kernel cannot be partitioned by XLA (lowering refuses it
+    under a mesh), so the model runs it per device under shard_map: batch
+    rows over `data`, heads over `model`. Same loss as one device."""
+    mesh = make_mesh(MeshSpec(data=2, model=2))
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+        attn_impl="flash",
+    )
+    batch = make_batch(jax.random.PRNGKey(1), cfg, 4, 32)
+
+    with mesh:
+        params, opt_state = make_train_state(jax.random.PRNGKey(0), cfg, mesh)
+        step = make_train_step(cfg, mesh)
+        sharded_batch = jax.device_put(batch, batch_sharding(mesh))
+        _, _, sharded_loss = step(params, opt_state, sharded_batch)
+
+    ref_params, ref_opt = make_train_state(jax.random.PRNGKey(0), cfg)
+    _, _, ref_loss = make_train_step(cfg)(ref_params, ref_opt, batch)
+    assert abs(float(sharded_loss) - float(ref_loss)) < 1e-2, (
+        float(sharded_loss), float(ref_loss))

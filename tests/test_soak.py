@@ -138,7 +138,7 @@ def _piecewise_rss(samples, soak_seconds):
 
 
 def _start_grpc_metric_fake(holder):
-    """grpcio runtime fake whose duty_cycle_pct reads a mutable holder —
+    """grpcio runtime fake whose duty-cycle gauge reads a mutable holder —
     the gRPC-leg analog of oscillating write_snapshot()."""
     import pytest as _pytest
 
@@ -147,7 +147,7 @@ def _start_grpc_metric_fake(holder):
     from concurrent import futures
 
     from test_grpc_backend import (
-        SERVICE, device_attr, gauge_double, pb_msg, pb_str, tpu_metric)
+        DUTY, SERVICE, device_attr, gauge_double, pb_msg, pb_str, tpu_metric)
 
     class OscillatingService(grpc.GenericRpcHandler):
         def service(self, handler_call_details):
@@ -156,12 +156,11 @@ def _start_grpc_metric_fake(holder):
                 return None
             if method == "ListSupportedMetrics":
                 def handler(request, ctx):
-                    return pb_msg(1, pb_str(1, "duty_cycle_pct"))
+                    return pb_msg(1, pb_str(1, DUTY))
             elif method == "GetRuntimeMetric":
                 def handler(request, ctx):
                     return tpu_metric(
-                        "duty_cycle_pct",
-                        [device_attr(0) + gauge_double(holder["v"])])
+                        DUTY, [device_attr(0) + gauge_double(holder["v"])])
             else:
                 return None
             return grpc.unary_unary_rpc_method_handler(
@@ -217,7 +216,10 @@ def test_soak_flat_rss_fd_threads(bin_dir, tmp_path, monkeypatch):
         # retention pruner run on every fire past the second.
         result = run_dyno(
             bin_dir, daemon.port, "autotrigger", "add",
-            "--metric=tpu0.tpu_duty_cycle_pct", "--below=50",
+            # The runtime service's duty-cycle gauge is the tensorcore one.
+            "--metric=tpu0." + ("tensorcore_duty_cycle_pct"
+                                if grpc_server is not None
+                                else "tpu_duty_cycle_pct"), "--below=50",
             "--for_ticks=1", "--cooldown_s=2", "--keep_last=2",
             "--job_id=77", "--duration_ms=100",
             f"--log_file={tmp_path / 'soak.json'}",
