@@ -1,0 +1,98 @@
+"""BENCHMARK.json resolves to files that exist, by name alone; a four-chip
+configuration is already a legal value."""
+
+import json
+
+import pytest
+
+import cells
+import gen_benchmark
+import rehearsal
+
+
+def test_every_workload_resolves_to_files_that_exist():
+    bench = cells.load_benchmark()
+    assert bench["workloads"]
+    for entry in bench["workloads"]:
+        cell = cells.load_cell(entry["name"])
+        assert cell.chips == entry["chips"] == cell.config["deployment"]["chips"]
+        assert cell.kind in cells.TRAFFIC_KINDS
+        assert cell.job["step_module"]
+        assert cells.metric_names(bench, cell, "end_to_end")
+        assert cells.metric_names(bench, cell, "per_layer")
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for entry in bench["workloads"]:
+        assert (cells.ROOT / files[entry["config"]]).is_file()
+
+
+def test_every_cell_reports_setup_and_one_more_and_a_layer():
+    bench = cells.load_benchmark()
+    for entry in bench["workloads"]:
+        cell = cells.load_cell(entry["name"])
+        e2e = cells.metric_names(bench, cell, "end_to_end")
+        assert "setup_s" in e2e and len(e2e) >= 2
+        by_name = {m["name"]: m for m in bench["per_layer"]}
+        for name in cells.metric_names(bench, cell, "per_layer"):
+            assert by_name[name]["moves"] in e2e, (cell.name, name)
+
+
+def test_per_layer_table_is_what_the_reader_files_generate():
+    bench = cells.load_benchmark()
+    assert bench["per_layer"] == gen_benchmark.per_layer(bench)
+    assert set(cells.load_readers()) == {m["name"] for m in bench["per_layer"]}
+
+
+def test_unknown_names_are_errors_not_defaults():
+    with pytest.raises(cells.BenchmarkError, match="no workload"):
+        cells.load_cell("no-such-cell")
+    with pytest.raises(cells.BenchmarkError, match="not in perfbench/peaks"):
+        cells.load_peaks("TPU v9 imaginary")
+    assert cells.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+
+
+def test_traffic_push_is_data_and_two_clients_are_refused(tmp_path):
+    (tmp_path / "traffic").mkdir()
+    base = {"kind": "capture", "mode": "push", "window_ms": 500,
+            "think_ms": 0, "clients": 1, "trigger": "cli"}
+    (tmp_path / "traffic" / "push.json").write_text(json.dumps(base))
+    assert cells.load_traffic("push", tmp_path)["mode"] == "push"
+    (tmp_path / "traffic" / "two.json").write_text(
+        json.dumps(dict(base, clients=2)))
+    with pytest.raises(cells.BenchmarkError, match="one client"):
+        cells.load_traffic("two", tmp_path)
+    (tmp_path / "traffic" / "odd.json").write_text(json.dumps({"kind": "x"}))
+    with pytest.raises(cells.BenchmarkError, match="kind"):
+        cells.load_traffic("odd", tmp_path)
+
+
+def test_four_chip_configuration_parses_into_a_mesh():
+    import jax
+
+    cell = rehearsal.toy_cell("capture-pull", config="toy-cpu4")
+    assert cell.chips == 4
+    mesh = cells.build_mesh(cell.config["deployment"], jax.devices())
+    assert dict(mesh.shape)["data"] == 2 and dict(mesh.shape)["model"] == 2
+    assert mesh.devices.size == 4
+    one = rehearsal.toy_cell("steady")
+    assert cells.build_mesh(one.config["deployment"], jax.devices()) is None
+
+
+def test_mesh_that_does_not_multiply_to_the_chips_is_refused(tmp_path):
+    (tmp_path / "configs").mkdir()
+    conf = rehearsal.toy_cell("steady", config="toy-cpu4").config
+    conf["deployment"]["mesh"] = {"data": 2}
+    (tmp_path / "configs" / "bad.json").write_text(json.dumps(conf))
+    with pytest.raises(cells.BenchmarkError, match="multiply"):
+        cells.load_config("bad", tmp_path)
+
+
+def test_s1_counts_only_slots_in_which_the_job_stepped():
+    import checks
+
+    ends = [100.0 + 0.134 * i for i in range(1, 299)]  # 40 s of steps
+    assert checks.samples_needed(ends, 100.0, 40.0, 1.0) == 37
+    stalled = [t for t in ends if not 110.2 < t < 118.7]  # an 8.5 s stall
+    assert checks.samples_needed(stalled, 100.0, 40.0, 1.0) == 30  # 7 dead slots
+    # capture traffic: slots as long as interval + longest capture
+    assert checks.samples_needed(ends, 100.0, 40.0, 1.0 + 1.4) == 13
+    assert checks.samples_needed([], 100.0, 40.0, 1.0) == 1
