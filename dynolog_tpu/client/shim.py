@@ -41,11 +41,13 @@ Usage::
 
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import math
 import os
 import shutil
+import statistics
 import tempfile
 import threading
 import time
@@ -576,28 +578,33 @@ class PendingWrite:
     capture's write with the next one's window.
     """
 
-    def __init__(self, path: str, on_complete=None, max_chunks: int = 8):
+    def __init__(self, path: str, on_complete=None, max_chunks: int = 8,
+                 ctx: "obs.TraceContext | None" = None):
         self.path = path
         self.queue = stream_mod.BoundedChunkQueue(max_chunks)
         self.result: dict | None = None
+        self.span: obs.Span | None = None  # shim.xplane_write, once done
         self.error: str | None = None
         self._done = threading.Event()
         # unsupervised by design: one writer per capture, joined (via
         # wait()) by whoever needs the artifact — the trace finisher,
         # the ring, or TraceClient.stop().
         self._thread = threading.Thread(
-            target=self._run, args=(on_complete,),
+            target=self._run, args=(on_complete, ctx),
             name="dynolog_tpu_xplane_write", daemon=True)
         self._thread.start()
 
-    def _run(self, on_complete) -> None:
+    def _run(self, on_complete, ctx) -> None:
         from dynolog_tpu import trace as trace_mod
 
-        t0 = time.time()
         try:
-            written = trace_mod.stream_write(self.path, self.queue)
+            # ctx is the capture's request context, handed in because this
+            # thread has no ambient one; the span may outlive shim.capture.
+            with obs.span("shim.xplane_write", ctx=ctx) as write:
+                written = trace_mod.stream_write(self.path, self.queue)
+            self.span = write
             self.result = {
-                "write_ms": int((time.time() - t0) * 1000),
+                "write_ms": write.dur_us // 1000,
                 "write_bytes": written,
             }
             if on_complete is not None:
@@ -660,6 +667,11 @@ class JaxProfiler:
         self._dir: str | None = None
         self._export_thread: threading.Thread | None = None
         self._pending_write: PendingWrite | None = None
+        # The request context of the capture in progress, set and cleared
+        # by the shim: the clock marks exist inside a capture only, and
+        # the writer thread's span parents to the request. None (ring
+        # samples, warmup): no marks, and the ambient context instead.
+        self.obs_ctx: obs.TraceContext | None = None
 
     # Config key -> the converter budget env var the export child reads
     # (trace.ConvertBudget.from_env).
@@ -710,6 +722,7 @@ class JaxProfiler:
             # still captures, without the collect/write decomposition.
             self._sess = None
             jax.profiler.start_trace(trace_dir)
+            self._clock_sync()
             return
         # Backend (and on TPU, libtpu) must be initialized before the
         # tracer is created, as jax.profiler.start_trace itself ensures.
@@ -719,17 +732,55 @@ class JaxProfiler:
         for attr, value in self.tracer_levels.items():
             setattr(opts, attr, value)
         self._sess = session_type(opts)
+        self._clock_sync()
+
+    def _clock_sync(self) -> None:
+        """Inside a capture, one host event that carries the wall clock
+        into the open session (stat `unix_ns`), once after the session
+        opens and once before it stops: a reader lays the trace on unix
+        time, the clock of the obs spans, and holds its mapping against
+        these two marks (docs/OBSERVABILITY.md). Needs the host tracer;
+        under PROFILE_HOST_TRACER_LEVEL=0 the trace holds none."""
+        import jax
+
+        if self.obs_ctx is None:
+            return
+        with jax.profiler.TraceAnnotation(
+                "dynolog.clock_sync", unix_ns=time.time_ns()):
+            pass
 
     def stop(self) -> None:
         import jax
 
+        self._clock_sync()
+        # The export child parents to THIS thread's ambient span
+        # (shim.capture), the writer thread's span to the request.
+        export_ctx = obs.current()
+        write_ctx = self.obs_ctx or export_ctx
         if self._sess is None:
-            jax.profiler.stop_trace()
+            with obs.span("shim.collect") as collect:
+                jax.profiler.stop_trace()
+            self.last_stop_decomposition = {"spans": [collect]}
             return
         sess, self._sess = self._sess, None
-        t0 = time.time()
-        xspace = sess.stop()
-        t_collect = time.time()
+        with obs.span("shim.collect") as collect:
+            xspace = sess.stop()
+        with obs.span("shim.feed") as feed:
+            self._feed(xspace, export_ctx, write_ctx)
+        # Decomposition for the capture manifest, each duration from the
+        # span that measured it (`spans`, which the shim lists in the
+        # manifest): collection is the runtime's trace drain; feed is this
+        # thread's hand-off into the queue (backpressure-bounded);
+        # write_ms arrives from the writer via the finisher's
+        # pending.wait().
+        self.last_stop_decomposition = {
+            "collect_ms": collect.dur_us // 1000,
+            "feed_ms": feed.dur_us // 1000,
+            "xspace_bytes": len(xspace),
+            "spans": [collect, feed],
+        }
+
+    def _feed(self, xspace, export_ctx, write_ctx) -> None:
         import socket
 
         run = _unique_run_name()
@@ -747,13 +798,11 @@ class JaxProfiler:
         # waits on take_pending_write(). Chunks are memoryview slices —
         # zero-copy; ProfilerSession.stop() hands us one buffer today,
         # but a future incremental drain feeds the same queue.
-        # The export child inherits THIS thread's ambient span context
-        # (the shim.capture span) — the writer thread has none.
-        ctx = obs.current()
         on_complete = None
         if self.export_trace_json:
-            on_complete = lambda path: self._spawn_export(path, ctx)  # noqa: E731
-        pending = PendingWrite(xplane_path, on_complete=on_complete)
+            on_complete = lambda path: self._spawn_export(path, export_ctx)  # noqa: E731
+        pending = PendingWrite(
+            xplane_path, on_complete=on_complete, ctx=write_ctx)
         self._pending_write = pending
         try:
             for chunk in stream_mod.chunk_views(
@@ -764,15 +813,6 @@ class JaxProfiler:
         except BaseException as e:
             pending.queue.fail(e)
             raise
-        # Decomposition for the capture manifest: collection is the
-        # runtime's trace drain; feed is this thread's hand-off into the
-        # queue (backpressure-bounded); write_ms arrives from the writer
-        # via the finisher's pending.wait().
-        self.last_stop_decomposition = {
-            "collect_ms": int((t_collect - t0) * 1000),
-            "feed_ms": int((time.time() - t_collect) * 1000),
-            "xspace_bytes": len(xspace),
-        }
 
     def take_pending_write(self) -> "PendingWrite | None":
         """Hands the caller the in-flight artifact write of the capture
@@ -886,8 +926,77 @@ class RecordingProfiler:
         self.calls.append(("stop", None))
 
 
+# How many of the job's last steps TraceClient keeps as marks: a 500 ms
+# window with its drain lies over a dozen steps of a 100 ms job; a bounded
+# ring keeps step() free of allocation growth.
+STEP_MARKS = 64
+
+
+@dataclass
+class _Capture:
+    """One capture's own state, from the config in hand to the manifest."""
+
+    cfg: TraceConfig
+    pid: int
+    trace_dir: str
+    ctx: obs.TraceContext
+    timing: dict = field(default_factory=dict)
+    spans: list = field(default_factory=list)  # obs.Span, for the manifest
+    started_ms: int = 0
+    error: str | None = None
+    window_end_us: int | None = None  # end of shim.window: the stop begins
+    stopped_us: int | None = None  # end of shim.capture: shim.finish begins
+
+
+def job_cost(marks: list, spans: list) -> tuple[list, dict]:
+    """What a capture cost the job, from the job's own step marks.
+
+    `marks` are (end_us, dur_us) of the last steps, oldest first, on the
+    clock of `spans` (the manifest's span rows). Returns the marks that
+    overlap the capture — shim.config_fetch's start to shim.feed's end
+    (shim.capture's where the backend recorded no feed) — preceded by the
+    one before them for comparison, and `job_cost_ms`: `baseline_ms` is the
+    median duration over all marks; `total` sums, over the overlapping
+    steps, what each took beyond the baseline (never negative); `start`
+    and `collect` are that sum over the steps that overlap
+    shim.profiler_start, and shim.collect to shim.feed. A step that had
+    not ended when the manifest was written is not in `marks` and is not
+    waited for."""
+    at = {s["name"]: (s["start_us"], s["start_us"] + s["dur_us"])
+          for s in spans}
+    last = at.get("shim.feed") or at.get("shim.capture")
+    first = at.get("shim.config_fetch") or at.get("shim.capture")
+    baseline = statistics.median(d for _, d in marks) if marks else 0
+
+    def over(lo_hi) -> list:
+        if lo_hi is None:
+            return []
+        lo, hi = lo_hi
+        return [i for i, (end, dur) in enumerate(marks)
+                if end > lo and end - dur < hi]
+
+    def excess_ms(indices) -> float:
+        return round(
+            sum(max(marks[i][1] - baseline, 0) for i in indices) / 1e3, 3)
+
+    whole = over((first[0], last[1])) if first and last else []
+    collect = at.get("shim.collect")
+    cost = {
+        "baseline_ms": round(baseline / 1e3, 3),
+        "total": excess_ms(whole),
+        "start": excess_ms(over(at.get("shim.profiler_start"))),
+        "collect": excess_ms(over(collect and (collect[0], last[1]))),
+    }
+    steps = marks[max(whole[0] - 1, 0):whole[-1] + 1] if whole else []
+    return steps, cost
+
+
 class TraceClient:
     """Registers with dynologd and serves on-demand trace requests."""
+
+    # The wall clock of the step marks and of the shim's own spans, one
+    # name so that a test drives both synthetically.
+    _wall = staticmethod(time.time)
 
     def __init__(
         self,
@@ -920,8 +1029,6 @@ class TraceClient:
         # is as fast as later ones.
         self.warmup_profiler = warmup_profiler
         self.profiler = profiler if profiler is not None else JaxProfiler()
-        self._timing: dict = {}
-        self._capture_ctx: obs.TraceContext | None = None
         # Pipelined capture finishers (manifest after the async xplane
         # write): every LIVE one is joined by stop() so shutdown never
         # strands a capture mid-finalize — back-to-back captures can have
@@ -940,6 +1047,12 @@ class TraceClient:
         self.report_interval_s = report_interval_s
         self._step_durations: list[float] = []
         self._last_step_t: float | None = None
+        # The job's last STEP_MARKS steps as (end, duration) on the wall
+        # clock, the clock of the obs spans: a capture's manifest lists
+        # the ones it lay over, and what it cost them (job_cost).
+        self._step_marks: collections.deque = collections.deque(
+            maxlen=STEP_MARKS)
+        self._last_step_wall = 0.0
         self._ever_stepped = False
         self._last_report_t = time.monotonic()
         # Rate comes from the step-count delta per report, NOT from the
@@ -1049,11 +1162,13 @@ class TraceClient:
         """Call once per training iteration to enable iteration-based traces
         and step-rate/latency telemetry."""
         now = time.monotonic()
+        wall = self._wall()
         with self._step_cv:
             self._step_count += 1
             if self._last_step_t is not None:
                 self._step_durations.append(now - self._last_step_t)
                 self._recent_step_s = now - self._last_step_t
+                self._step_marks.append((wall, wall - self._last_step_wall))
             else:
                 # Epoch-opening step (first ever, or first after an idle
                 # reset): it marks the measurement origin — align the
@@ -1065,6 +1180,7 @@ class TraceClient:
                 self._reported_steps = self._step_count
             self._ever_stepped = True
             self._last_step_t = now
+            self._last_step_wall = wall
             self._step_cv.notify_all()
             count = self._step_count
         if self.ring:
@@ -1074,13 +1190,12 @@ class TraceClient:
 
     # -- internals -------------------------------------------------------
 
-    def _poll_loop(self) -> None:
-        if self.warmup_profiler:
-            import shutil
-            import tempfile
-
-            tmp = tempfile.mkdtemp(prefix="dynolog_tpu_warmup_")
-            try:
+    def _warm_profiler(self) -> None:
+        tmp = tempfile.mkdtemp(prefix="dynolog_tpu_warmup_")
+        try:
+            # One parent span, as shim.ring_capture is for a ring sample:
+            # the backend's stop() spans hang under it.
+            with obs.span("shim.warmup"):
                 self.profiler.start(tmp)
                 self.profiler.stop()
                 # Drain the streaming stop's in-flight write before the
@@ -1089,12 +1204,19 @@ class TraceClient:
                 pending = take() if take is not None else None
                 if pending is not None:
                     pending.wait(30.0)
-            except Exception as e:  # noqa: BLE001 - warmup must never kill polling
-                self.last_error = f"profiler warmup failed: {e}"
-            finally:
-                shutil.rmtree(tmp, ignore_errors=True)
+        except Exception as e:  # noqa: BLE001 - warmup must never kill polling
+            self.last_error = f"profiler warmup failed: {e}"
+        finally:
+            shutil.rmtree(tmp, ignore_errors=True)
+
+    def _poll_loop(self) -> None:
+        if self.warmup_profiler:
+            self._warm_profiler()
         self.warmup_done.set()
         while not self._stop.is_set():
+            # The kick or the poll timer woke _wait_for_tick just now: a
+            # config fetched by this poll was on its way from here.
+            polled_us = int(self._wall() * 1e6)
             try:
                 text = self._client.request_config(
                     self.job_id,
@@ -1139,7 +1261,7 @@ class TraceClient:
                 text = self._client.take_late_config()
             if text:
                 try:
-                    self._run_trace(TraceConfig.parse(text))
+                    self._run_trace(TraceConfig.parse(text), polled_us)
                 except Exception as e:  # noqa: BLE001 - never kill the app
                     self.last_error = f"trace failed: {e}"
             try:
@@ -1323,17 +1445,42 @@ class TraceClient:
                 # --profile-start-time trick, unitrace.py:144-148).
                 time.sleep(delay)
 
-    def _run_trace(self, cfg: TraceConfig) -> None:
+    def _run_trace(self, cfg: TraceConfig, polled_us: int | None = None) -> None:
+        """One on-demand capture. `polled_us` is when the poll that fetched
+        `cfg` set out (None: the config was handed over directly)."""
         # Fault drill: shim.run_trace=throw proves the poll loop contains
         # a capture-path crash (last_error set, polling continues).
         failpoints.fire("shim.run_trace")
+        # Control-plane identity for this capture: the TRACE_CONTEXT the
+        # daemon (or unitrace) put in the config, minted locally when
+        # absent (auto-trigger fires, pre-tracing CLIs). Every span this
+        # capture records — and the export child's trace.convert span —
+        # shares it, so `dyno selftrace --trace_id=...` reconstructs the
+        # request across both languages.
+        ctx = obs.TraceContext.parse(cfg.trace_ctx) or obs.TraceContext.mint()
+        try:
+            self._capture(cfg, ctx, polled_us)
+        finally:
+            # A capture that failed before the profiler's stop() must not
+            # leave its context for the next ring sample to parent to.
+            self.profiler.obs_ctx = None
+
+    def _capture(self, cfg: TraceConfig, ctx: obs.TraceContext,
+                 polled_us: int | None) -> None:
+        # The fetch is known for a capture's only now, with the context in
+        # hand, so its span opens in the past.
+        with obs.span("shim.config_fetch", ctx=ctx, now=self._wall,
+                      start_us=polled_us) as fetch:
+            pass
         pid = os.getpid()
-        trace_dir = cfg.trace_dir(pid)
+        cap = _Capture(
+            cfg=cfg, pid=pid, trace_dir=cfg.trace_dir(pid), ctx=ctx,
+            spans=[fetch])
         # First capture against this trace base: reclaim expired debris
         # (a SIGKILL'd export child's *.tmp files, dead-pid session dirs —
         # all carrying THIS base's name prefix) before writing new
         # artifacts next to it.
-        base = os.path.abspath(trace_dir)[: -len(f"_{pid}")]
+        base = os.path.abspath(cap.trace_dir)[: -len(f"_{pid}")]
         if base not in self._swept_dirs:
             self._swept_dirs.add(base)
             try:
@@ -1341,78 +1488,69 @@ class TraceClient:
             except Exception as e:  # noqa: BLE001 - sweep must never cost
                 # the capture
                 _log.warning("artifact sweep of %s failed: %s", base, e)
-        os.makedirs(trace_dir, exist_ok=True)
+        os.makedirs(cap.trace_dir, exist_ok=True)
         if hasattr(self.profiler, "configure"):
             # Per-capture knobs from the config text (tracer levels,
             # TRACE_JSON) — unknown keys are ignored, so an old shim and a
             # new CLI stay compatible in both directions.
             self.profiler.configure(cfg.raw)
-        # Control-plane identity for this capture: the TRACE_CONTEXT the
-        # daemon (or unitrace) put in the config, minted locally when
-        # absent (auto-trigger fires, pre-tracing CLIs). Every span this
-        # capture records — and the export child's trace.convert span —
-        # shares it, so `dyno selftrace --trace_id=...` reconstructs the
-        # request across both languages.
-        self._capture_ctx = obs.TraceContext.parse(
-            cfg.trace_ctx) or obs.TraceContext.mint()
-        # The export child flushes its spans back to THIS daemon.
+        # The export child flushes its spans back to THIS daemon; the
+        # writer thread's span parents to this request.
         self.profiler.obs_endpoint = self.endpoint
-        # Timing decomposition for the manifest: where capture latency goes
-        # (config pickup is daemon→shim poll alignment; profiler start/stop
-        # is jax.profiler's own cost — seconds on some backends).
-        self._timing = {"received_ms": int(time.time() * 1000)}
+        self.profiler.obs_ctx = ctx
+        # Timing decomposition for the manifest: where capture latency goes,
+        # in whole milliseconds. received_ms is a mark (config in hand,
+        # profiler configured); every duration is taken from the span that
+        # measured it (profiler start/stop is jax.profiler's own cost —
+        # seconds on some backends).
+        cap.timing["received_ms"] = int(self._wall() * 1000)
         self._wait_for_start(cfg)
 
-        started_ms = int(time.time() * 1000)
+        cap.started_ms = int(self._wall() * 1000)
         # The capture span closes BEFORE _finish_trace runs, so the
         # manifest-write flush ships it to the daemon with this capture,
         # not the next one.
-        with obs.span("shim.capture", ctx=self._capture_ctx):
-            error = self._capture_window(cfg, trace_dir)
+        with obs.span("shim.capture", ctx=ctx, now=self._wall) as capture:
+            cap.error = self._capture_window(cap)
+        cap.spans.append(capture)
+        cap.stopped_us = capture.end_us
+        if cap.window_end_us is not None:
+            # After the window the capture span holds the profiler's
+            # stop() and nothing else.
+            cap.timing["profiler_stop_ms"] = (
+                capture.end_us - cap.window_end_us) // 1000
         # Streaming pipeline: a profiler with an in-flight artifact write
         # (JaxProfiler's PendingWrite) hands the capture to a finisher
         # thread — the poll loop returns to serving configs immediately,
         # so back-to-back captures overlap one capture's write/manifest
-        # with the next one's window. Snapshot the per-capture state the
-        # finisher needs: the NEXT capture may start before it runs.
+        # with the next one's window. `cap` is this capture's own state:
+        # the NEXT capture may start before the finisher runs.
         take = getattr(self.profiler, "take_pending_write", None)
         pending = take() if take is not None else None
-        timing, ctx = self._timing, self._capture_ctx
         if pending is None:
-            self._finish_trace(
-                cfg, pid, trace_dir, started_ms, error, timing, ctx)
+            self._finish_trace(cap)
             return
         finisher = threading.Thread(
-            target=self._finish_pipelined,
-            args=(pending, cfg, pid, trace_dir, started_ms, error, timing,
-                  ctx),
+            target=self._finish_pipelined, args=(cap, pending),
             name="dynolog_tpu_trace_finish", daemon=True)
         finisher.start()
         self._finishers = [
             t for t in self._finishers if t.is_alive()] + [finisher]
 
-    def _finish_pipelined(
-        self, pending, cfg, pid, trace_dir, started_ms, error, timing, ctx
-    ) -> None:
-        """Finisher-thread tail of one capture: wait out the streaming
-        xplane write, fold its decomposition into the manifest timing,
-        and finalize. A write failure fails the capture loudly (status
-        error in the manifest) — stream_write's tmp discipline already
-        guaranteed no torn artifact was left behind."""
+    def _finish_pipelined(self, cap: "_Capture", pending) -> None:
+        """Finisher-thread tail of one capture. A write failure fails the
+        capture loudly (status error in the manifest) — stream_write's tmp
+        discipline already guaranteed no torn artifact was left behind."""
         try:
-            decomp = pending.wait()
-            write_error = decomp.pop("write_error", None)
-            timing.update(decomp)
-            self._finish_trace(
-                cfg, pid, trace_dir, started_ms, error or write_error,
-                timing, ctx)
+            self._finish_trace(cap, pending)
         except Exception as e:  # noqa: BLE001 - the finisher must never
             # die silently: the manifest is the completion signal.
             self.last_error = f"trace finalize failed: {e}"
 
-    def _capture_window(self, cfg: TraceConfig, trace_dir: str) -> str | None:
+    def _capture_window(self, cap: "_Capture") -> str | None:
         """The profiler start/wait/stop body of one capture; returns the
         error string (None = clean capture)."""
+        cfg = cap.cfg
         if cfg.iterations > 0:
             with self._step_cv:
                 base = self._step_count
@@ -1436,13 +1574,14 @@ class TraceClient:
                     f"{start_at} within {self.step_start_timeout_s:g}s "
                     f"(at {self._step_count})"
                 )
-            self._timed_profiler_start(trace_dir)
-            with self._step_cv:
-                elapsed = self._step_cv.wait_for(
-                    lambda: self._step_count >= end_at,
-                    timeout=self.step_trace_timeout_s,
-                )
-            self._timed_profiler_stop()
+            self._profiler_start(cap)
+            with obs.span("shim.window", now=self._wall) as window:
+                with self._step_cv:
+                    elapsed = self._step_cv.wait_for(
+                        lambda: self._step_count >= end_at,
+                        timeout=self.step_trace_timeout_s,
+                    )
+            self._profiler_stop(cap, window)
             if not elapsed:
                 return (
                     f"iteration trace timed out: {cfg.iterations} steps did "
@@ -1450,90 +1589,114 @@ class TraceClient:
                     f"(at {self._step_count}, wanted {end_at})"
                 )
             return None
-        self._timed_profiler_start(trace_dir)
-        time.sleep(cfg.duration_ms / 1000.0)
-        self._timed_profiler_stop()
+        self._profiler_start(cap)
+        with obs.span("shim.window", now=self._wall) as window:
+            time.sleep(cfg.duration_ms / 1000.0)
+        self._profiler_stop(cap, window)
         return None
 
-    def _timed_profiler_start(self, trace_dir: str) -> None:
-        t0 = time.time()
-        self.profiler.start(trace_dir)
-        self._timing["profiler_start_ms"] = int((time.time() - t0) * 1000)
+    def _profiler_start(self, cap: "_Capture") -> None:
+        with obs.span("shim.profiler_start", now=self._wall) as start:
+            self.profiler.start(cap.trace_dir)
+        cap.spans.append(start)
+        cap.timing["profiler_start_ms"] = start.dur_us // 1000
 
-    def _timed_profiler_stop(self) -> None:
-        t0 = time.time()
+    def _profiler_stop(self, cap: "_Capture", window: obs.Span) -> None:
+        cap.spans.append(window)
+        cap.window_end_us = window.end_us
         self.profiler.stop()
-        self._timing["profiler_stop_ms"] = int((time.time() - t0) * 1000)
-        decomp = getattr(self.profiler, "last_stop_decomposition", None)
-        if decomp:
-            self._timing.update(decomp)
+        # The backend's own spans (shim.collect, shim.feed), and their
+        # lengths as whole milliseconds with its counters.
+        decomp = dict(
+            getattr(self.profiler, "last_stop_decomposition", None) or {})
+        cap.spans += decomp.pop("spans", [])
+        cap.timing.update(decomp)
 
-    def _finish_trace(
-        self,
-        cfg: TraceConfig,
-        pid: int,
-        trace_dir: str,
-        started_ms: int,
-        error: str | None,
-        timing: dict,
-        capture_ctx: obs.TraceContext | None,
-    ) -> None:
-        # Manifest at the path the CLI prints (log_file_<pid>.json) pointing
-        # at the XLA trace directory; status records capture failures so the
-        # operator sees them instead of a silently-wrong trace window.
-        # timing/ctx arrive as arguments (not read off self): the finisher
-        # thread may run this while the poll thread is already inside the
-        # NEXT capture.
-        manifest = {
-            "pid": pid,
-            "job_id": self.job_id,
-            "trace_dir": trace_dir,
-            "started_ms": started_ms,
-            "ended_ms": int(time.time() * 1000),
-            "mode": "iterations" if cfg.iterations > 0 else "duration",
-            "config": cfg.raw,
-            "status": "error" if error else "ok",
-            "timing": timing,
-        }
-        if capture_ctx is not None:
-            # The id `dyno selftrace --trace_id=...` filters on: recorded
-            # in the artifact so a trace on disk names its control-plane
-            # request.
-            manifest["trace_ctx"] = capture_ctx.header()
-        if error:
-            manifest["error"] = error
-            self.last_error = error
-        # Atomic (tmp + rename): the manifest's existence IS the
-        # completion signal operators and the bench poll for; a reader
-        # must never catch a half-written JSON. A REFUSED write (ENOSPC,
-        # quota — or the trace.artifact.write errno: drill) aborts
-        # cleanly: tmp unlinked, nothing renamed, and the refusal lands
-        # in last_error so the shim reports it alongside the daemon's
-        # own pressure surface instead of dying in the finisher thread.
-        path = cfg.manifest_path(pid)
-        tmp = f"{path}.tmp"
-        wrote = False
-        with obs.span("shim.artifact_write", ctx=capture_ctx):
-            try:
-                failpoints.fire("trace.artifact.write")
-                with open(tmp, "w") as f:
-                    json.dump(manifest, f, indent=2)
-                os.replace(tmp, path)
-                wrote = True
-            except OSError as e:
+    def _finish_trace(self, cap: "_Capture", pending=None) -> None:
+        """Waits out the streaming xplane write (`pending`), folds its
+        decomposition into the timing, and writes the manifest at the path
+        the CLI prints (log_file_<pid>.json), pointing at the XLA trace
+        directory; status records capture failures so the operator sees
+        them instead of a silently-wrong trace window. All per-capture
+        state arrives in `cap` (not read off self): the finisher thread may
+        run this while the poll thread is already inside the NEXT capture.
+        """
+        cfg = cap.cfg
+        # shim.finish runs from the profiler's stop() returning, on the
+        # poll thread, to the manifest's rename, here.
+        with obs.span("shim.finish", ctx=cap.ctx, now=self._wall,
+                      start_us=cap.stopped_us):
+            if pending is not None:
+                decomp = pending.wait()
+                cap.error = cap.error or decomp.pop("write_error", None)
+                cap.timing.update(decomp)
+                if pending.span is not None:
+                    cap.spans.append(pending.span)
+            # This capture's completed spans. shim.finish and
+            # shim.artifact_write are still open; they reach
+            # `dyno selftrace` only.
+            span_rows = [
+                {"name": s.name, "span_id": f"{s.span_id:016x}",
+                 "parent_id": f"{s.parent_id:016x}",
+                 "start_us": s.start_us, "dur_us": s.dur_us}
+                for s in sorted(cap.spans, key=lambda s: s.start_us)]
+            with self._step_cv:
+                marks = [(round(end * 1e6), round(dur * 1e6))
+                         for end, dur in self._step_marks]
+            steps, cost = job_cost(marks, span_rows)
+            manifest = {
+                "pid": cap.pid,
+                "job_id": self.job_id,
+                "trace_dir": cap.trace_dir,
+                "started_ms": cap.started_ms,
+                "ended_ms": int(self._wall() * 1000),
+                "mode": "iterations" if cfg.iterations > 0 else "duration",
+                "config": cfg.raw,
+                "status": "error" if cap.error else "ok",
+                "timing": cap.timing,
+                # The id `dyno selftrace --trace_id=...` filters on:
+                # recorded in the artifact so a trace on disk names its
+                # control-plane request.
+                "trace_ctx": cap.ctx.header(),
+                "spans": span_rows,
+                "steps": [list(m) for m in steps],
+                "job_cost_ms": cost,
+            }
+            if cap.error:
+                manifest["error"] = cap.error
+                self.last_error = cap.error
+            # Atomic (tmp + rename): the manifest's existence IS the
+            # completion signal operators and the bench poll for; a reader
+            # must never catch a half-written JSON. A REFUSED write (ENOSPC,
+            # quota — or the trace.artifact.write errno: drill) aborts
+            # cleanly: tmp unlinked, nothing renamed, and the refusal lands
+            # in last_error so the shim reports it alongside the daemon's
+            # own pressure surface instead of dying in the finisher thread.
+            path = cfg.manifest_path(cap.pid)
+            tmp = f"{path}.tmp"
+            wrote = False
+            with obs.span("shim.artifact_write", now=self._wall):
                 try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
-                self.last_error = f"manifest write refused: {e}"
-        if wrote and not error:
+                    failpoints.fire("trace.artifact.write")
+                    with open(tmp, "w") as f:
+                        json.dump(manifest, f, indent=2)
+                    os.replace(tmp, path)
+                    wrote = True
+                except OSError as e:
+                    try:
+                        os.unlink(tmp)
+                    except OSError:
+                        pass
+                    self.last_error = f"manifest write refused: {e}"
+        if wrote and not cap.error:
             self.traces_completed += 1
         # Ship this capture's spans to the daemon (fire-and-forget, same
-        # posture as pstat): the selftrace merge is what turns per-process
-        # timing into one cross-language request trace. The export
-        # child's trace.convert span flushes itself on exit. Optional
-        # capability: an IPC double without span support (tests, old
-        # clients) just skips the flush.
+        # posture as pstat), after the rename: the selftrace merge is what
+        # turns per-process timing into one cross-language request trace
+        # (by trace id, so the next capture's first spans going with this
+        # flush do no harm). The export child's trace.convert span
+        # flushes itself on exit. Optional capability: an IPC double
+        # without span support (tests, old clients) just skips the flush.
         send_spans = getattr(self._client, "send_spans", None)
         if send_spans is not None:
             try:

@@ -113,6 +113,10 @@ class Span:
     dur_us: int
     pid: int = field(default_factory=os.getpid)
 
+    @property
+    def end_us(self) -> int:
+        return self.start_us + self.dur_us
+
     def chrome_event(self) -> dict:
         return {
             "name": self.name,
@@ -195,20 +199,23 @@ def span(
     ctx: TraceContext | None = None,
     journal: SpanJournal | None = None,
     now=time.time,
+    start_us: int | None = None,
 ):
     """Times a section and records it on exit (exceptions included — a
     failing capture's span is exactly the interesting one). The section
     runs with the ambient context set to THIS span (same trace, this
     span-id as parent), so nested spans parent correctly. Yields the
     recorded-on-exit Span (ids valid inside the block; timing filled at
-    exit)."""
+    exit). `start_us` opens the span in the past: for a phase that began
+    before its context was known (the shim's config fetch) or on another
+    thread (the capture's finish)."""
     parent = ctx if ctx is not None else current()
     rec = Span(
         name=name[: NAME_BYTES - 1],
         trace_id=parent.trace_id if parent else mint_id(),
         span_id=mint_id(),
         parent_id=parent.span_id if parent else 0,
-        start_us=int(now() * 1e6),
+        start_us=int(now() * 1e6) if start_us is None else start_us,
         dur_us=0,
     )
     token = _current.set(TraceContext(rec.trace_id, rec.span_id))

@@ -1,0 +1,17 @@
+"""Median of the manifests' `job_cost_ms.collect`: the excess of the job's steps
+that overlap `shim.collect` and `shim.feed`, the drain of the trace."""
+
+import spans
+
+NAME = "xspan.capture_job_cost_ms.collect"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_counter"
+LAYER = "shim capture"
+MOVES = "step_ms_p50"
+CELLS = ('capture',)
+
+
+def read(run: dict):
+    return spans.median_of(
+        run, lambda c: c["manifest"]["job_cost_ms"]["collect"])

@@ -412,25 +412,28 @@ def test_shim_manifest_write_refusal_cleans_tmp_and_reports(tmp_path):
     # ENOSPC'd manifest write aborts cleanly — tmp unlinked, nothing
     # renamed, the refusal in last_error, traces_completed NOT bumped —
     # and the retried capture publishes normally.
-    from dynolog_tpu.client.shim import TraceClient, TraceConfig
+    from dynolog_tpu import obs
+    from dynolog_tpu.client.shim import TraceClient, TraceConfig, _Capture
 
-    client = TraceClient.__new__(TraceClient)
-    client.job_id = 7
+    client = TraceClient(job_id=7)
     client.last_error = ""
-    client.traces_completed = 0
+    client._client.close()
     client._client = object()  # no send_spans capability: flush skipped
     cfg = TraceConfig(log_file=str(tmp_path / "cap.json"))
+
+    def capture():
+        return _Capture(cfg=cfg, pid=1234, trace_dir=str(tmp_path / "cap_1234"),
+                        ctx=obs.TraceContext.mint(), started_ms=1)
+
     failpoints.arm("trace.artifact.write", "errno:ENOSPC*1")
-    client._finish_trace(cfg, 1234, str(tmp_path / "cap_1234"), 1, None,
-                         {}, None)
+    client._finish_trace(capture())
     manifest = tmp_path / "cap_1234.json"
     assert not manifest.exists()
     assert not pathlib.Path(str(manifest) + ".tmp").exists()
     assert "refused" in client.last_error
     assert client.traces_completed == 0
     # Space returns: the next capture's manifest publishes atomically.
-    client._finish_trace(cfg, 1234, str(tmp_path / "cap_1234"), 1, None,
-                         {}, None)
+    client._finish_trace(capture())
     assert manifest.exists()
     assert client.traces_completed == 1
     assert json.loads(manifest.read_text())["status"] == "ok"
