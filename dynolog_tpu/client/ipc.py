@@ -198,8 +198,8 @@ class IpcClient:
                 if left <= 0:
                     return None
                 # select, not a sleep loop: wakes the instant the reply
-                # lands (the daemon answers within its 10ms IPC tick) and
-                # burns no CPU while waiting.
+                # lands (the daemon's IPC thread wakes on the request and
+                # answers at once) and burns no CPU while waiting.
                 try:
                     select.select([sock], [], [], left)
                 except (OSError, ValueError):

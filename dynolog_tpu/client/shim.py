@@ -6,8 +6,9 @@ for on-demand configs; when the operator runs `dyno gputrace/tpurace`, the
 received key=value config is parsed and an XLA trace is captured with
 `jax.profiler.start_trace` / `stop_trace`. Beyond the reference protocol,
 the shim also subscribes to config "kick" datagrams: the daemon wakes it
-the moment a capture is triggered, so pickup costs the daemon's 10ms IPC
-tick instead of ~poll_interval/2 (polling remains the delivery
+the moment a capture is triggered (its IPC thread blocks in poll(2) and
+wakes on the posted config and on the request), so pickup costs two
+thread wake-ups instead of ~poll_interval/2 (polling remains the delivery
 mechanism — kicks are purely a latency optimization). Beyond the reference: if the app
 calls step(), the shim also reports step rate + step-time percentiles to
 the daemon every report_interval_s (fire-and-forget "pstat" datagram),
@@ -180,8 +181,30 @@ def sweep_stale_artifacts(
     except OSError:
         return []
     for name in entries:
+        # The name decides first, with no stat: only `<prefix>_<pid>` and
+        # `<prefix>_<pid>.json.tmp` can be this base's, so a directory
+        # full of other captures (an operator who names each one) costs
+        # this listing and nothing more.
+        is_manifest_tmp = name.endswith(".json.tmp")
+        stem = name[: -len(".json.tmp")] if is_manifest_tmp else name
+        head, sep, pid_part = stem.rpartition("_")
+        if not sep or head != prefix or not pid_part.isdigit():
+            continue
         path = os.path.join(root, name)
-        if os.path.isdir(path):
+        if is_manifest_tmp:
+            # Manifest atomic-write leftover: `<base>_<pid>.json.tmp`
+            # (a directory of that name fails the unlink and stays).
+            if _pid_alive(int(pid_part)):
+                continue
+            try:
+                if os.path.getmtime(path) >= cutoff:
+                    continue
+                os.unlink(path)
+            except OSError:
+                continue
+            _log.info("reclaimed stale artifact: %s", path)
+            reclaimed.append(path)
+        elif os.path.isdir(path):
             pid = _trace_session_dir(path, prefix)
             if pid is None:
                 continue
@@ -200,22 +223,6 @@ def sweep_stale_artifacts(
             _log.info(
                 "reclaimed stale trace-session dir (pid %d gone): %s",
                 pid, path)
-            reclaimed.append(path)
-        elif name.endswith(".json.tmp"):
-            # Manifest atomic-write leftover: `<base>_<pid>.json.tmp`.
-            stem = name[: -len(".json.tmp")]
-            head, sep, pid_part = stem.rpartition("_")
-            if not sep or head != prefix or not pid_part.isdigit():
-                continue
-            if _pid_alive(int(pid_part)):
-                continue
-            try:
-                if os.path.getmtime(path) >= cutoff:
-                    continue
-                os.unlink(path)
-            except OSError:
-                continue
-            _log.info("reclaimed stale artifact: %s", path)
             reclaimed.append(path)
     return reclaimed
 
@@ -1129,7 +1136,7 @@ class TraceClient:
             )
             # Opt in to config kicks: the daemon wakes this shim the
             # moment a capture is triggered, so pickup latency is the
-            # daemon's 10ms IPC tick instead of ~poll_interval/2.
+            # daemon IPC thread's wake-up instead of ~poll_interval/2.
             # Fire-and-forget; polling remains the delivery mechanism.
             self._client.subscribe_kicks(self.job_id, dest=self.endpoint)
             self._last_subscribe = time.monotonic()
@@ -1291,7 +1298,7 @@ class TraceClient:
         Waits on the client's DEDICATED kick socket, so the inter-poll
         sleep is wakeup-capable: a "kick" datagram (config just installed
         for this job) triggers an immediate poll and on-demand pickup
-        costs the daemon's 10ms IPC tick instead of ~poll_interval/2.
+        costs the daemon IPC thread's wake-up instead of ~poll_interval/2.
         The request/reply socket is never read here — an earlier design
         that select()ed on the shared socket stole "req" replies from
         any concurrent exchange (bench.py measured the fallout as a 20x
@@ -1476,18 +1483,6 @@ class TraceClient:
         cap = _Capture(
             cfg=cfg, pid=pid, trace_dir=cfg.trace_dir(pid), ctx=ctx,
             spans=[fetch])
-        # First capture against this trace base: reclaim expired debris
-        # (a SIGKILL'd export child's *.tmp files, dead-pid session dirs —
-        # all carrying THIS base's name prefix) before writing new
-        # artifacts next to it.
-        base = os.path.abspath(cap.trace_dir)[: -len(f"_{pid}")]
-        if base not in self._swept_dirs:
-            self._swept_dirs.add(base)
-            try:
-                sweep_stale_artifacts(base, self.sweep_ttl_s)
-            except Exception as e:  # noqa: BLE001 - sweep must never cost
-                # the capture
-                _log.warning("artifact sweep of %s failed: %s", base, e)
         os.makedirs(cap.trace_dir, exist_ok=True)
         if hasattr(self.profiler, "configure"):
             # Per-capture knobs from the config text (tracer levels,
@@ -1703,3 +1698,22 @@ class TraceClient:
                 send_spans(obs.JOURNAL.drain(), dest=self.endpoint)
             except OSError as e:
                 self.last_error = f"span flush failed: {e}"
+        self._sweep_once(cap)
+
+    def _sweep_once(self, cap: "_Capture") -> None:
+        """First capture against this trace base: reclaim expired debris
+        (a SIGKILL'd export child's *.tmp files, dead-pid session dirs —
+        all carrying THIS base's name prefix). Called with the manifest
+        written and the spans flushed, on whichever thread finished the
+        capture: fault recovery, so none of it lies between the
+        operator's request and the artifact. Two finishers racing on one
+        base may both sweep it; the sweep is idempotent (ENOENT ignored)."""
+        base = os.path.abspath(cap.trace_dir)[: -len(f"_{cap.pid}")]
+        if base in self._swept_dirs:
+            return
+        self._swept_dirs.add(base)
+        try:
+            sweep_stale_artifacts(base, self.sweep_ttl_s)
+        except Exception as e:  # noqa: BLE001 - sweep must never cost
+            # the capture
+            _log.warning("artifact sweep of %s failed: %s", base, e)

@@ -480,6 +480,9 @@ int runSelfTrace() {
   doc["otherData"]["clock"] = response.at("clock").asString("unix_us");
   doc["otherData"]["spans_recorded"] = response.at("spans_recorded").asInt();
   doc["otherData"]["ring_capacity"] = response.at("ring_capacity").asInt();
+  if (response.at("ipc_wakeups").isObject()) { // absent from older daemons
+    doc["otherData"]["ipc_wakeups"] = response.at("ipc_wakeups");
+  }
   doc["traceEvents"] = response.at("traceEvents");
   const std::string out = doc.dump();
   if (!FLAGS_log_file.empty()) {

@@ -722,7 +722,8 @@ int main(int argc, char** argv) {
             return [monitor] {
               failpoints::maybeFail("collector.ipc.poll");
               // ~1s slices: one health heartbeat per slice, exceptions
-              // contained per slice, 10ms message cadence inside.
+              // contained per slice; inside, the thread blocks in
+              // poll(2) and wakes on the message or the posted config.
               monitor->runSlice(1000);
             };
           });
@@ -937,7 +938,7 @@ int main(int argc, char** argv) {
   {
     std::lock_guard<std::mutex> lock(ipcMonitorMutex);
     if (ipcMonitor) {
-      ipcMonitor->stop(); // cut the in-flight slice short (<= 10ms tick)
+      ipcMonitor->stop(); // wakes the thread out of its poll(2)
     }
   }
   server.stop();

@@ -114,7 +114,7 @@ class FabricManager {
 
   // Polls once: peeks the metadata, then reads metadata+payload in one
   // datagram. Returns true when a message was enqueued.
-  // hot-path: runs every 10ms monitor tick; must never block.
+  // hot-path: runs every pass of the monitor thread; must never block.
   bool recv() {
     Metadata metadata;
     std::vector<Payload> peekIov{{&metadata, sizeof(Metadata)}};
@@ -165,6 +165,12 @@ class FabricManager {
       std::this_thread::sleep_for(std::chrono::microseconds(sleepTimeUs));
     }
     return false;
+  }
+
+  // The bound socket, for a caller that waits in poll(2) for the next
+  // datagram instead of retrying recv() on a timer.
+  int fd() const {
+    return endpoint_.fd();
   }
 
   std::unique_ptr<Message> retrieve_msg() {

@@ -27,6 +27,7 @@
 #include "src/tracing/CaptureUtils.h"
 #include "src/tracing/CpuTraceCapturer.h"
 #include "src/tracing/Diagnoser.h"
+#include "src/tracing/IPCMonitor.h"
 #include "src/tracing/PushTraceCapturer.h"
 
 DYN_DEFINE_string(
@@ -521,6 +522,14 @@ json::Value ServiceHandler::selftrace(const json::Value& request) {
   response["clock"] = "unix_us";
   response["spans_recorded"] = static_cast<int64_t>(journal.recorded());
   response["ring_capacity"] = static_cast<int64_t>(journal.capacity());
+  // The IPC thread's wake-ups by cause, beside the ipc.config_handoff
+  // spans they serve: `timeout` alone on an idle daemon.
+  const auto wakes = tracing::IPCMonitor::wakeCounts();
+  auto wakeups = json::Value::object();
+  wakeups["message"] = static_cast<int64_t>(wakes.message);
+  wakeups["posted"] = static_cast<int64_t>(wakes.posted);
+  wakeups["timeout"] = static_cast<int64_t>(wakes.timeout);
+  response["ipc_wakeups"] = std::move(wakeups);
   response["traceEvents"] = std::move(events);
   return response;
 }

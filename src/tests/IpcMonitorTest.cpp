@@ -11,8 +11,12 @@
 #include <sys/wait.h>
 #include <unistd.h>
 
+#include <poll.h>
+
+#include <chrono>
 #include <cstddef>
 #include <cstring>
+#include <thread>
 
 #include "src/core/Histograms.h"
 #include "src/core/SpanJournal.h"
@@ -46,6 +50,51 @@ std::unique_ptr<ipc::Message> makeRequestMsg(
       sizeof(int32_t) * pids.size());
   return ipc::Message::create(buf.data(), size, kMsgTypeRequest);
 }
+
+// Waits for one datagram at `client`; returns the milliseconds it took,
+// or -1 after `limitMs`. The blocking loop answers at its wake-up, so a
+// reply that needs the loop's own poll timeout (250 ms) is a failure of
+// the wake, which the callers' limits tell apart.
+double recvWithinMs(ipc::FabricManager& client, int limitMs) {
+  auto start = std::chrono::steady_clock::now();
+  auto elapsedMs = [&start] {
+    return std::chrono::duration<double, std::milli>(
+               std::chrono::steady_clock::now() - start)
+        .count();
+  };
+  while (!client.recv()) {
+    double left = limitMs - elapsedMs();
+    if (left <= 0) {
+      return -1;
+    }
+    pollfd pfd{client.fd(), POLLIN, 0};
+    ::poll(&pfd, 1, static_cast<int>(left) + 1);
+  }
+  return elapsedMs();
+}
+
+// A monitor served by its own thread, as the daemon runs it: loop()
+// blocks in poll(2) between messages. Joined by stop().
+struct ServedMonitor {
+  explicit ServedMonitor(IPCMonitor& monitor)
+      : monitor_(monitor), thread_([&monitor] { monitor.loop(); }) {}
+  ~ServedMonitor() {
+    if (thread_.joinable()) {
+      stop(); // a failed ASSERT unwinds past the test's own stop()
+    }
+  }
+  // Milliseconds stop() needed to get the blocked thread out and joined.
+  double stop() {
+    auto start = std::chrono::steady_clock::now();
+    monitor_.stop();
+    thread_.join();
+    return std::chrono::duration<double, std::milli>(
+               std::chrono::steady_clock::now() - start)
+        .count();
+  }
+  IPCMonitor& monitor_;
+  std::thread thread_;
+};
 
 } // namespace
 
@@ -218,6 +267,32 @@ TEST(IpcMonitor, OnDemandConfigRoundTrip) {
   EXPECT_EQ(
       client->retrieve_msg()->payloadString(),
       std::string("ACTIVITIES_DURATION_MSECS=750\n"));
+
+  // The same exchange against the blocking loop on its own thread: the
+  // request itself wakes it, with no tick to wait out. Let the thread
+  // reach its poll first, so the exchange meets a BLOCKED thread.
+  auto before = IPCMonitor::wakeCounts();
+  ServedMonitor served(monitor);
+  std::this_thread::sleep_for(std::chrono::milliseconds(20));
+  mgr->setOnDemandConfig(55, {}, "ACTIVITIES_DURATION_MSECS=751", kActivities, 3);
+  // The post alone wakes it (nobody subscribed, so nothing is sent);
+  // wait for that wake, or the request's would count for both.
+  for (int i = 0; i < 2000 && IPCMonitor::wakeCounts().posted == before.posted;
+       ++i) {
+    std::this_thread::sleep_for(std::chrono::milliseconds(1));
+  }
+  EXPECT_TRUE(IPCMonitor::wakeCounts().posted > before.posted);
+  ASSERT_TRUE(client->sync_send(*poll, daemonName));
+  double tookMs = recvWithinMs(*client, 2000);
+  EXPECT_TRUE(tookMs >= 0 && tookMs < 200);
+  EXPECT_EQ(
+      client->retrieve_msg()->payloadString(),
+      std::string("ACTIVITIES_DURATION_MSECS=751\n"));
+  EXPECT_TRUE(IPCMonitor::wakeCounts().message > before.message);
+  // stop() reaches a thread blocked in poll(2) through the wake
+  // descriptor, not at the poll's 250 ms timeout.
+  std::this_thread::sleep_for(std::chrono::milliseconds(20));
+  EXPECT_TRUE(served.stop() < 200);
 }
 
 TEST(IpcMonitor, PerfStatsLandInMetricStore) {
@@ -467,6 +542,41 @@ TEST(IpcMonitor, KickSubscriberNotifiedOnConfigPost) {
   auto badResMsg = ipc::Message::createFromPod(badRes, kMsgTypeSubscribe);
   ASSERT_TRUE(client->sync_send(*badResMsg, daemonName));
   ASSERT_TRUE(monitor.pollOnce());
+
+  // The wake descriptor: readable from a post until the drain, and the
+  // blocking loop, parked in poll(2) on it, kicks at once.
+  pollfd posted{mgr->postedFd(), POLLIN, 0};
+  ASSERT_TRUE(posted.fd >= 0);
+  monitor.sendPendingKicks(); // drains what the refused post left
+  EXPECT_EQ(::poll(&posted, 1, 0), 0);
+  // Take the first config, so that the next post is not "busy".
+  ASSERT_TRUE(client->sync_send(*poll, daemonName));
+  ASSERT_TRUE(monitor.pollOnce());
+  ASSERT_TRUE(client->poll_recv(100));
+  client->retrieve_msg();
+  auto before = IPCMonitor::wakeCounts();
+  ServedMonitor served(monitor);
+  std::this_thread::sleep_for(std::chrono::milliseconds(20));
+  mgr->setOnDemandConfig(88, {}, "ACTIVITIES_DURATION_MSECS=11", kActivities, 3);
+  double tookMs = recvWithinMs(*client, 2000);
+  EXPECT_TRUE(tookMs >= 0 && tookMs < 200);
+  auto blockingKick = client->retrieve_msg();
+  ASSERT_TRUE(blockingKick != nullptr);
+  EXPECT_EQ(std::string(blockingKick->metadata.type), std::string("kick"));
+  EXPECT_TRUE(IPCMonitor::wakeCounts().posted > before.posted);
+  // An idle blocked loop leaves by its timeout only: nothing is due, so
+  // a 600 ms wait sees two or three timeouts and no other cause.
+  before = IPCMonitor::wakeCounts();
+  std::this_thread::sleep_for(std::chrono::milliseconds(600));
+  auto idle = IPCMonitor::wakeCounts();
+  EXPECT_EQ(idle.message, before.message);
+  EXPECT_EQ(idle.posted, before.posted);
+  EXPECT_TRUE(idle.timeout - before.timeout >= 1);
+  EXPECT_TRUE(idle.timeout - before.timeout <= 4);
+  EXPECT_TRUE(served.stop() < 200);
+  // stop() leaves the descriptor set; the next drain clears it.
+  monitor.sendPendingKicks();
+  EXPECT_EQ(::poll(&posted, 1, 0), 0);
 }
 
 TEST(IpcMonitor, PerfStatsNonzeroReservedRejected) {

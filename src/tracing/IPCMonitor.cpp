@@ -1,11 +1,12 @@
 #include "src/tracing/IPCMonitor.h"
 
+#include <poll.h>
+
 #include <algorithm>
 #include <chrono>
 #include <cmath>
 #include <cstring>
 #include <map>
-#include <thread>
 #include <utility>
 #include <vector>
 
@@ -18,7 +19,13 @@
 namespace dynotpu {
 namespace tracing {
 
-constexpr int kPollSleepUs = 10000; // 10ms, as in reference IPCMonitor.cpp:22
+// Longest the monitor thread blocks with nothing to do. Nothing on the
+// hand-off path waits for it (a message or a posted job ends the poll at
+// once); it only bounds how late the thread notices stop_, the end of
+// its supervised slice and the kick-subscriber TTL sweep. The reference
+// sleeps 10 ms between polls instead (IPCMonitor.cpp:22): a deliberate
+// departure, docs/PARITY.md.
+constexpr int64_t kMaxWaitMs = 250;
 // Kick-subscription hygiene: entries refresh on each "sub" and die after
 // the TTL (shims re-subscribe about every 30s); the global address cap
 // bounds what hostile local datagrams can make the daemon remember.
@@ -38,24 +45,56 @@ IPCMonitor::IPCMonitor(
   }
 }
 
+std::atomic<uint64_t> IPCMonitor::wakeMessage_{0};
+std::atomic<uint64_t> IPCMonitor::wakePosted_{0};
+std::atomic<uint64_t> IPCMonitor::wakeTimeout_{0};
+
+IPCMonitor::WakeCounts IPCMonitor::wakeCounts() {
+  return {wakeMessage_.load(), wakePosted_.load(), wakeTimeout_.load()};
+}
+
 void IPCMonitor::loop() {
+  serve(/*deadlineMs=*/-1);
+}
+
+void IPCMonitor::runSlice(int64_t maxMs) {
+  serve(nowUnixMillis() + maxMs);
+}
+
+void IPCMonitor::serve(int64_t deadlineMs) {
   while (fabric_ && !stop_.load()) {
+    const int64_t leftMs =
+        deadlineMs < 0 ? kMaxWaitMs : deadlineMs - nowUnixMillis();
+    if (leftMs <= 0) {
+      return;
+    }
     bool handled = pollOnce();
     sendPendingKicks();
+    // Everything queued is drained before the thread blocks again: a
+    // handled message goes straight to the next one.
     if (!handled) {
-      std::this_thread::sleep_for(std::chrono::microseconds(kPollSleepUs));
+      waitForWork(static_cast<int>(std::min(leftMs, kMaxWaitMs)));
     }
   }
 }
 
-void IPCMonitor::runSlice(int64_t maxMs) {
-  const int64_t deadline = nowUnixMillis() + maxMs;
-  while (fabric_ && !stop_.load() && nowUnixMillis() < deadline) {
-    bool handled = pollOnce();
-    sendPendingKicks();
-    if (!handled) {
-      std::this_thread::sleep_for(std::chrono::microseconds(kPollSleepUs));
-    }
+void IPCMonitor::waitForWork(int timeoutMs) {
+  // Level-triggered on both descriptors: a datagram or a post that lands
+  // between the pass above and this poll ends it at once.
+  pollfd fds[2] = {
+      {fabric_->fd(), POLLIN, 0},
+      {configManager_->postedFd(), POLLIN, 0},
+  };
+  int ready = ::poll(fds, 2, timeoutMs);
+  if (stop_.load()) {
+    return; // stop() woke us: no cause of the thread's own
+  }
+  if (ready <= 0) {
+    wakeTimeout_++; // EINTR counts as one: the pass runs either way
+  } else if (fds[0].revents) {
+    wakeMessage_++;
+  } else {
+    wakePosted_++;
   }
 }
 
@@ -150,10 +189,11 @@ void IPCMonitor::handleSubscribe(std::unique_ptr<ipc::Message> msg) {
   kickSubCount_++;
 }
 
-// hot-path: the monitor thread's 10ms tick body — the dispatch itself
-// never blocks (recv is non-blocking). Replies inside the handlers are
-// the known, bounded exception: sync_send's retry backoff can stall the
-// tick against a peer with a full socket buffer. The interprocedural
+// hot-path: the monitor thread's pass body — the dispatch itself never
+// blocks (recv is non-blocking; the thread blocks in waitForWork only,
+// with nothing queued). Replies inside the handlers are the known,
+// bounded exception: sync_send's retry backoff can stall the pass
+// against a peer with a full socket buffer. The interprocedural
 // reach pass sees those chains now; each reply site carries its audited
 // // blocking-ok waiver (docs/STATIC_ANALYSIS.md).
 bool IPCMonitor::pollOnce() {

@@ -1,6 +1,8 @@
 // dynolog_tpu: daemon-side IPC monitor for profiler-client handshakes.
-// Behavioral parity: reference dynolog/src/tracing/IPCMonitor.{h,cpp} — 10ms
-// poll loop over FabricManager (IPCMonitor.cpp:33-41), dispatch on the
+// Behavioral parity: reference dynolog/src/tracing/IPCMonitor.{h,cpp} —
+// one thread over FabricManager (IPCMonitor.cpp:33-41; the reference
+// sleeps 10ms between polls, this one blocks in poll(2) until a message
+// or a posted config arrives, docs/PARITY.md), dispatch on the
 // 4-byte message type: "ctxt" registers a client process (replying with the
 // per-device instance count, :90-113), "req" hands out the pending on-demand
 // config (replying with the config string, :58-88). Wire structs match
@@ -62,7 +64,7 @@ static_assert(sizeof(ClientPerfStats) == 56, "wire layout");
 // learns about configs except by polling). A shim that sends "sub"
 // after registering gets a "kick" datagram (payload: int64 jobId) the
 // moment a config is installed for its job, collapsing pickup latency
-// from ~poll_interval/2 to the monitor's 10ms loop tick. Purely an
+// from ~poll_interval/2 to the monitor thread's wake-up. Purely an
 // optimization: delivery is still the poll, a lost kick costs nothing,
 // and clients that never subscribe (stock libkineto) are never sent
 // unsolicited messages.
@@ -108,7 +110,8 @@ class IPCMonitor {
       const std::string& endpointName = kDaemonEndpointName,
       std::shared_ptr<MetricStore> metricStore = nullptr);
 
-  // Runs until stop(); polls every 10ms.
+  // Runs until stop(): handles every queued message, kicks every posted
+  // job, then blocks until the next of either.
   void loop();
 
   // Supervised slice: like loop(), but returns after ~maxMs so the
@@ -117,16 +120,30 @@ class IPCMonitor {
   // monitor instead of losing the thread.
   void runSlice(int64_t maxMs);
 
+  // Also ends a blocked wait, through the manager's wake descriptor.
   void stop() {
     stop_.store(true);
+    configManager_->wakeDrainer();
   }
+
+  // Why the monitor thread left its blocking wait, counted over the
+  // process's life (all incarnations: the supervisor rebuilds the
+  // monitor, the counts go on): a datagram on the socket, a posted
+  // config, or the wait's own timeout. An idle daemon shows timeouts
+  // only, a handful a second; the `selftrace` verb reports all three.
+  struct WakeCounts {
+    uint64_t message;
+    uint64_t posted;
+    uint64_t timeout;
+  };
+  static WakeCounts wakeCounts();
 
   // Processes at most one pending message; returns whether one was handled
   // (deterministic entry point for tests).
   bool pollOnce();
 
   // Drains freshly-posted configs and kicks their subscribers
-  // (deterministic entry point for tests; loop() calls it every tick).
+  // (deterministic entry point for tests; loop() calls it every pass).
   void sendPendingKicks();
 
   bool active() const {
@@ -134,6 +151,11 @@ class IPCMonitor {
   }
 
  private:
+  // deadlineMs: unix ms to return at, negative for never.
+  void serve(int64_t deadlineMs);
+  // Blocks until the socket or the manager's postedFd() is readable, or
+  // timeoutMs pass; counts the cause.
+  void waitForWork(int timeoutMs);
   void processMsg(std::unique_ptr<ipc::Message> msg);
   void handleRequest(std::unique_ptr<ipc::Message> msg);
   void handleContext(std::unique_ptr<ipc::Message> msg);
@@ -161,6 +183,9 @@ class IPCMonitor {
   // prefixed names. Monitor thread only, bounded by kMaxTelemetryJobs.
   std::map<int64_t, std::array<uint32_t, 4>> telemetryIds_;
   std::atomic<bool> stop_{false};
+  static std::atomic<uint64_t> wakeMessage_;
+  static std::atomic<uint64_t> wakePosted_;
+  static std::atomic<uint64_t> wakeTimeout_;
 };
 
 } // namespace tracing

@@ -1,5 +1,8 @@
 #include "src/tracing/TraceConfigManager.h"
 
+#include <sys/eventfd.h>
+#include <unistd.h>
+
 #include <fstream>
 
 #include "src/common/Defs.h"
@@ -26,7 +29,15 @@ json::Value TraceTriggerResult::toJson() const {
 TraceConfigManager::TraceConfigManager(
     std::chrono::seconds keepAlive,
     std::string baseConfigPath)
-    : keepAlive_(keepAlive), baseConfigPath_(std::move(baseConfigPath)) {
+    : keepAlive_(keepAlive),
+      baseConfigPath_(std::move(baseConfigPath)),
+      postedFd_(::eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC)) {
+  if (postedFd_ < 0) {
+    // poll(2) skips a negative descriptor: kicks then leave at the IPC
+    // thread's poll timeout instead of at once, and nothing else changes.
+    DLOG_ERROR << "TraceConfigManager: eventfd failed (" << errno
+               << "); config kicks will wait for the IPC poll timeout";
+  }
   // unsupervised-thread: lifecycle bound to this singleton's ctor/dtor;
   // managerLoop only expires registry entries under its own lock.
   managerThread_ = std::thread([this] { managerLoop(); });
@@ -39,6 +50,17 @@ TraceConfigManager::~TraceConfigManager() {
   }
   cv_.notify_all();
   managerThread_.join();
+  if (postedFd_ >= 0) {
+    ::close(postedFd_);
+  }
+}
+
+void TraceConfigManager::wakeDrainer() {
+  if (postedFd_ >= 0) {
+    // An eventfd write fails only at counter overflow (EAGAIN): the
+    // descriptor is readable already, which is all a wake asks for.
+    (void)::eventfd_write(postedFd_, 1);
+  }
 }
 
 std::shared_ptr<TraceConfigManager> TraceConfigManager::getInstance() {
@@ -218,9 +240,10 @@ TraceTriggerResult TraceConfigManager::setOnDemandConfig(
     // the queue stays bounded even with NO drainer attached (IPC
     // monitor disabled or its endpoint bind failed — the daemon keeps
     // serving RPC either way, and auto-triggers can fire for days);
-    // with a live drainer the 10ms drain never lets it near the cap.
+    // a live drainer wakes on every post and never lets it near the cap.
     if (postedJobs_.size() < 1024) {
       postedJobs_.push_back(jobId);
+      wakeDrainer();
     }
   }
   if (!res.activityProfilersTriggered.empty()) {
@@ -235,6 +258,12 @@ TraceTriggerResult TraceConfigManager::setOnDemandConfig(
 
 std::vector<int64_t> TraceConfigManager::drainPostedJobs() {
   std::lock_guard<std::mutex> lock(mutex_);
+  if (postedFd_ >= 0) {
+    // Under the lock, so a post after this read is in the next drain
+    // AND leaves the descriptor readable for it.
+    eventfd_t count;
+    (void)::eventfd_read(postedFd_, &count);
+  }
   std::vector<int64_t> out;
   out.swap(postedJobs_);
   return out;

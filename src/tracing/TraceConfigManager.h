@@ -83,11 +83,24 @@ class TraceConfigManager {
   int processCount(int64_t jobId) const;
 
   // Jobs that had a config installed since the last drain (at least one
-  // process matched). The IPC monitor drains this on its 10ms loop and
-  // sends "kick" datagrams to subscribed shims, collapsing config
-  // pickup latency from ~poll_interval/2 to the loop tick. Kicks are an
-  // optimization only — polling remains the delivery mechanism.
+  // process matched). The IPC monitor drains this whenever postedFd()
+  // wakes it and sends "kick" datagrams to subscribed shims, collapsing
+  // config pickup latency from ~poll_interval/2 to the thread's wake-up.
+  // Kicks are an optimization only — polling remains the delivery
+  // mechanism.
   std::vector<int64_t> drainPostedJobs();
+
+  // Wake descriptor of the drainer (an eventfd): readable from a post to
+  // postedJobs_ until the next drainPostedJobs(). The IPC monitor's
+  // thread blocks in poll(2) on it beside the fabric's socket, so a
+  // posted job is kicked without waiting out a timer.
+  int postedFd() const {
+    return postedFd_;
+  }
+
+  // Makes postedFd() readable with nothing posted: IPCMonitor::stop()
+  // uses it to cut a blocked poll short.
+  void wakeDrainer();
 
   // Unix ms of the last setOnDemandConfig that triggered at least one
   // profiler for `jobId` (0 = never). Lets the auto-trigger engine
@@ -146,6 +159,9 @@ class TraceConfigManager {
 
   // Jobs with a freshly-installed config, pending kick fan-out.
   std::vector<int64_t> postedJobs_; // guarded_by(mutex_)
+  // eventfd behind postedFd(); written on every post, cleared by the
+  // drain. Opened in the constructor, closed in the destructor.
+  const int postedFd_;
 
   // jobId → pid-ancestry-set → process state
   std::map<int64_t, std::map<std::set<int32_t>, ClientProcess>>
