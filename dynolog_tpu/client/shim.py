@@ -586,11 +586,17 @@ class PendingWrite:
     """
 
     def __init__(self, path: str, on_complete=None, max_chunks: int = 8,
-                 ctx: "obs.TraceContext | None" = None):
+                 ctx: "obs.TraceContext | None" = None, xspace=None):
         self.path = path
         self.queue = stream_mod.BoundedChunkQueue(max_chunks)
         self.result: dict | None = None
         self.span: obs.Span | None = None  # shim.xplane_write, once done
+        # `xspace`: the whole serialized XSpace the chunks are views of,
+        # where the feeder holds it; the writer then lists its planes
+        # ({"name", "bytes"} each, trace.plane_index) for the manifest.
+        self._xspace = xspace
+        self.planes: list | None = None
+        self.index_span: obs.Span | None = None  # shim.plane_index
         self.error: str | None = None
         self._done = threading.Event()
         # unsupervised by design: one writer per capture, joined (via
@@ -614,6 +620,7 @@ class PendingWrite:
                 "write_ms": write.dur_us // 1000,
                 "write_bytes": written,
             }
+            self._index_planes(ctx)
             if on_complete is not None:
                 on_complete(self.path)
         except Exception as e:  # noqa: BLE001 - the writer is its own
@@ -623,6 +630,23 @@ class PendingWrite:
             self.queue.abandon()
         finally:
             self._done.set()
+
+    def _index_planes(self, ctx) -> None:
+        """One row a plane of the XSpace just written: its top level only,
+        a few fields a plane, so four device planes of megabytes cost what
+        one does. An XSpace that does not parse costs the capture nothing
+        but the rows."""
+        from dynolog_tpu import trace as trace_mod
+
+        xspace, self._xspace = self._xspace, None
+        if xspace is None:
+            return
+        with obs.span("shim.plane_index", ctx=ctx) as index:
+            try:
+                self.planes = trace_mod.plane_index(xspace)
+            except ValueError:
+                pass
+        self.index_span = index
 
     def wait(self, timeout_s: float = 120.0) -> dict:
         """Blocks until the write finished; returns its decomposition
@@ -634,6 +658,35 @@ class PendingWrite:
         if self.error is not None:
             return {"write_error": self.error}
         return dict(self.result or {})
+
+
+def _new_xplane_path(trace_dir: str | None) -> str:
+    """<trace_dir>/plugins/profile/<run>/<host>.xplane.pb, its directory
+    made: where TensorBoard/XProf and dynolog_tpu.trace look."""
+    import socket
+
+    host = socket.gethostname().split(".")[0] or "host"
+    run_dir = os.path.join(
+        trace_dir or ".", "plugins", "profile", _unique_run_name())
+    os.makedirs(run_dir, exist_ok=True)
+    return os.path.join(run_dir, f"{host}.xplane.pb")
+
+
+def _feed_write(xplane_path: str, xspace, chunk_bytes: int, on_complete,
+                ctx) -> PendingWrite:
+    """Opens the artifact's PendingWrite and feeds it the serialized XSpace
+    in zero-copy chunks; returns at the end of the feed, not of the write."""
+    pending = PendingWrite(
+        xplane_path, on_complete=on_complete, ctx=ctx, xspace=xspace)
+    try:
+        for chunk in stream_mod.chunk_views(xspace, chunk_bytes):
+            if not pending.queue.put(chunk):
+                break  # writer died; pending.wait() reports why
+        pending.queue.close()
+    except BaseException as e:
+        pending.queue.fail(e)
+        raise
+    return pending
 
 
 class JaxProfiler:
@@ -671,6 +724,7 @@ class JaxProfiler:
         # (TRACE_CONVERT_* config keys -> DYNO_TRACE_CONVERT_* env).
         self.convert_env: dict[str, str] = {}
         self._sess = None
+        self._local_devices: int | None = None
         self._dir: str | None = None
         self._export_thread: threading.Thread | None = None
         self._pending_write: PendingWrite | None = None
@@ -735,6 +789,7 @@ class JaxProfiler:
         # tracer is created, as jax.profiler.start_trace itself ensures.
         # A failure from here on is a failed capture and surfaces as one.
         jax.devices()
+        self._local_devices = jax.local_device_count()
         opts = jax.profiler.ProfileOptions()
         for attr, value in self.tracer_levels.items():
             setattr(opts, attr, value)
@@ -784,17 +839,12 @@ class JaxProfiler:
             "collect_ms": collect.dur_us // 1000,
             "feed_ms": feed.dur_us // 1000,
             "xspace_bytes": len(xspace),
+            "local_devices": self._local_devices,
             "spans": [collect, feed],
         }
 
     def _feed(self, xspace, export_ctx, write_ctx) -> None:
-        import socket
-
-        run = _unique_run_name()
-        host = socket.gethostname().split(".")[0] or "host"
-        run_dir = os.path.join(self._dir or ".", "plugins", "profile", run)
-        os.makedirs(run_dir, exist_ok=True)
-        xplane_path = os.path.join(run_dir, f"{host}.xplane.pb")
+        xplane_path = _new_xplane_path(self._dir)
         # Streaming pipeline hand-off: this (collect) thread feeds the
         # bounded chunk queue of a PendingWrite; its writer thread drains
         # the chunks through trace.stream_write (atomic tmp + rename)
@@ -808,18 +858,9 @@ class JaxProfiler:
         on_complete = None
         if self.export_trace_json:
             on_complete = lambda path: self._spawn_export(path, export_ctx)  # noqa: E731
-        pending = PendingWrite(
-            xplane_path, on_complete=on_complete, ctx=write_ctx)
-        self._pending_write = pending
-        try:
-            for chunk in stream_mod.chunk_views(
-                    xspace, self.WRITE_CHUNK_BYTES):
-                if not pending.queue.put(chunk):
-                    break  # writer died; pending.wait() reports why
-            pending.queue.close()
-        except BaseException as e:
-            pending.queue.fail(e)
-            raise
+        self._pending_write = _feed_write(
+            xplane_path, xspace, self.WRITE_CHUNK_BYTES, on_complete,
+            write_ctx)
 
     def take_pending_write(self) -> "PendingWrite | None":
         """Hands the caller the in-flight artifact write of the capture
@@ -921,16 +962,41 @@ class JaxProfiler:
 
 
 class RecordingProfiler:
-    """Test backend: records calls instead of tracing."""
+    """Test backend: records calls instead of tracing. Given an `xspace`
+    (serialized bytes), stop() hands it to the artifact pipeline as
+    JaxProfiler hands the runtime's: a capture of a session over
+    `local_devices` devices without one."""
 
-    def __init__(self):
+    def __init__(self, xspace: bytes | None = None, local_devices: int = 1):
         self.calls: list[tuple[str, str | None]] = []
+        self.xspace, self.local_devices = xspace, local_devices
+        self.obs_ctx: obs.TraceContext | None = None
+        self._dir: str | None = None
+        self._pending_write: PendingWrite | None = None
 
     def start(self, trace_dir: str) -> None:
         self.calls.append(("start", trace_dir))
+        self._dir = trace_dir
 
     def stop(self) -> None:
         self.calls.append(("stop", None))
+        self.last_stop_decomposition = None
+        if self.xspace is None:
+            return
+        with obs.span("shim.feed") as feed:
+            self._pending_write = _feed_write(
+                _new_xplane_path(self._dir), self.xspace,
+                JaxProfiler.WRITE_CHUNK_BYTES, None,
+                self.obs_ctx or obs.current())
+        self.last_stop_decomposition = {
+            "xspace_bytes": len(self.xspace),
+            "local_devices": self.local_devices,
+            "spans": [feed],
+        }
+
+    def take_pending_write(self) -> "PendingWrite | None":
+        pending, self._pending_write = self._pending_write, None
+        return pending
 
 
 # How many of the job's last steps TraceClient keeps as marks: a 500 ms
@@ -951,6 +1017,7 @@ class _Capture:
     spans: list = field(default_factory=list)  # obs.Span, for the manifest
     started_ms: int = 0
     error: str | None = None
+    local_devices: int | None = None  # devices the profiler session covered
     window_end_us: int | None = None  # end of shim.window: the stop begins
     stopped_us: int | None = None  # end of shim.capture: shim.finish begins
 
@@ -1605,6 +1672,7 @@ class TraceClient:
         decomp = dict(
             getattr(self.profiler, "last_stop_decomposition", None) or {})
         cap.spans += decomp.pop("spans", [])
+        cap.local_devices = decomp.pop("local_devices", None)
         cap.timing.update(decomp)
 
     def _finish_trace(self, cap: "_Capture", pending=None) -> None:
@@ -1625,8 +1693,8 @@ class TraceClient:
                 decomp = pending.wait()
                 cap.error = cap.error or decomp.pop("write_error", None)
                 cap.timing.update(decomp)
-                if pending.span is not None:
-                    cap.spans.append(pending.span)
+                cap.spans += [s for s in (pending.span, pending.index_span)
+                              if s is not None]
             # This capture's completed spans. shim.finish and
             # shim.artifact_write are still open; they reach
             # `dyno selftrace` only.
@@ -1657,6 +1725,11 @@ class TraceClient:
                 "steps": [list(m) for m in steps],
                 "job_cost_ms": cost,
             }
+            if cap.local_devices is not None:
+                manifest["local_devices"] = cap.local_devices
+            if pending is not None and pending.planes is not None:
+                # one row a plane of the artifact's XSpace, in file order
+                manifest["planes"] = pending.planes
             if cap.error:
                 manifest["error"] = cap.error
                 self.last_error = cap.error

@@ -60,17 +60,11 @@ SCHEMA_VERSION = 1
 NOISE_PCT = 5.0
 NOISE_IMPACT_MS = 0.05
 
-# Op-name fragments identifying collective-communication ops (XLA HLO
-# naming): growth here means the pod is waiting on a peer, not computing.
-_COLLECTIVE_TOKENS = (
-    "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
-    "collective", "send", "recv",
-)
-
-
 def classify_op(name: str) -> str:
+    # collective (trace.COLLECTIVE_TOKENS): growth here means the pod is
+    # waiting on a peer, not computing.
     low = name.lower()
-    if any(tok in low for tok in _COLLECTIVE_TOKENS):
+    if trace.is_collective(low):
         return "collective"
     if "fusion" in low:
         return "fusion"

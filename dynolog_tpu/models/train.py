@@ -21,6 +21,21 @@ def make_optimizer(lr: float = 3e-4):
     return optax.adamw(lr, weight_decay=0.01)
 
 
+def state_shardings(optimizer, params, mesh):
+    """(parameter shardings, optimizer-state shardings) over `mesh` for a
+    pytree of parameters (arrays, tracers or shapes: only the paths are
+    read): PARAM_RULES for the parameters, the same layout for each moment
+    of them, everything else in the optimizer state (the step count)
+    replicated."""
+    param_shardings = shard_params(params, mesh)
+    replicated = NamedSharding(mesh, PartitionSpec())
+    opt_shardings = optax.tree_utils.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, params), param_shardings,
+        transform_non_params=lambda _: replicated)
+    return param_shardings, opt_shardings
+
+
 def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     """(params, opt_state), placed on the mesh when one is given.
 
@@ -37,13 +52,9 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     optimizer = make_optimizer(lr)
     param_shardings = opt_shardings = None
     if mesh is not None:
-        abstract = jax.eval_shape(lambda r: init_params(r, cfg), rng)
-        param_shardings = shard_params(abstract, mesh)
-        replicated = NamedSharding(mesh, PartitionSpec())
-        opt_shardings = optax.tree_utils.tree_map_params(
-            optimizer, lambda _, sharding: sharding,
-            jax.eval_shape(optimizer.init, abstract), param_shardings,
-            transform_non_params=lambda _: replicated)
+        param_shardings, opt_shardings = state_shardings(
+            optimizer, jax.eval_shape(lambda r: init_params(r, cfg), rng),
+            mesh)
     params = jax.jit(
         lambda r: init_params(r, cfg), out_shardings=param_shardings)(rng)
     opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
@@ -57,6 +68,13 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     params and opt_state are donated: the update writes into the buffers it
     read, so the resident state is held once, not twice. A caller must
     rebind both from the step's outputs; the arrays it passed in are gone.
+
+    Under a mesh the state leaves the step in the layout it is born in
+    (`state_shardings`). Left to propagation, XLA hands the replicated norm
+    scales back sharded on `model`: a second call of `jax.jit` then
+    compiles the step again for the new layout, and an executable compiled
+    ahead of time (`.lower().compile()`) refuses its own outputs as inputs
+    ("compiled for input shardings that disagree").
     """
     optimizer = make_optimizer(lr)
 
@@ -70,6 +88,9 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, fwd_mesh)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
+        if mesh is not None:
+            params, opt_state = jax.lax.with_sharding_constraint(
+                (params, opt_state), state_shardings(optimizer, params, mesh))
         return params, opt_state, loss
 
     if mesh is None:

@@ -207,6 +207,19 @@ def _parse_event_metadata_entry(buf: bytes) -> tuple[int, str, str, list]:
     return mid, name, disp, stats
 
 
+# Op-name fragments identifying collective-communication ops (XLA HLO
+# naming). dynolog_tpu.diagnose classes ops by the same test.
+COLLECTIVE_TOKENS = (
+    "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+    "collective", "send", "recv",
+)
+
+
+def is_collective(op_name: str) -> bool:
+    low = op_name.lower()
+    return any(tok in low for tok in COLLECTIVE_TOKENS)
+
+
 @dataclass
 class OpAggregate:
     name: str
@@ -415,6 +428,74 @@ def iter_plane_bufs(data: bytes):
     for num, wt, plane_buf in _walk(data):
         if num == 1 and wt == 2:
             yield plane_buf
+
+
+def _read_varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if not b & 0x80:
+            return value, i
+        shift += 7
+
+
+def plane_index(data) -> list[dict]:
+    """[{"name", "bytes"}] for every plane of a serialized XSpace, in file
+    order: the top level only. A plane's bytes are its payload's (the
+    XSpace's framing, a tag and a length a plane, is not counted); its name
+    is read from the plane's leading fields (XPlane{id=1, name=2}; the
+    lines, metadata and events behind them are skipped unread), so the
+    cost is a few fields a plane whatever the trace's size. Raises
+    ValueError (IndexError folded in) on malformed input."""
+    view = memoryview(data)
+    planes = []
+    i, n = 0, len(view)
+    try:
+        while i < n:
+            tag, i = _read_varint(view, i)
+            wt = tag & 7
+            if wt == 0:
+                _, i = _read_varint(view, i)
+                continue
+            if wt in (1, 5):
+                i += 8 if wt == 1 else 4
+                continue
+            if wt != 2:
+                raise ValueError(f"unsupported wire type {wt}")
+            size, i = _read_varint(view, i)
+            end = i + size
+            if end > n:
+                raise ValueError("truncated plane")
+            if tag >> 3 == 1:
+                planes.append(
+                    {"name": _plane_name(view, i, end), "bytes": size})
+            i = end
+    except IndexError as e:
+        raise ValueError("truncated xspace") from e
+    return planes
+
+
+def _plane_name(view, i: int, end: int) -> str:
+    """The name of the plane at view[i:end]: its first field 2, looked for
+    among the scalar fields that lead the message and no further than the
+    first line or metadata entry (field >= 3)."""
+    while i < end:
+        tag, i = _read_varint(view, i)
+        num, wt = tag >> 3, tag & 7
+        if wt == 0:
+            _, i = _read_varint(view, i)
+        elif wt == 2:
+            size, i = _read_varint(view, i)
+            if num == 2:
+                return bytes(view[i:i + size]).decode(errors="replace")
+            if num > 2:
+                break
+            i += size
+        else:
+            break
+    return ""
 
 
 def _plane_events(pid: int, plane_buf: bytes) -> list[dict]:
@@ -889,12 +970,18 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
     device_planes = [p for p in planes if "device" in p.name.lower()
                      or "tpu" in p.name.lower() or "gpu" in p.name.lower()]
     for p in planes:
+        op_ps = sum(a.total_ps for a in p.ops.values())
+        collective_ps = sum(
+            a.total_ps for name, a in p.ops.items() if is_collective(name))
         out["planes"].append(
             {
                 "name": p.name,
                 "lines": p.lines,
                 "events": p.events,
                 "duration_ms": round(p.duration_ps / 1e9, 3),
+                # time of the plane's collective ops over all its op time
+                "collective_pct": round(
+                    100.0 * collective_ps / op_ps, 2) if op_ps else 0.0,
             }
         )
         # Op table from device planes when present (the question operators
@@ -1102,10 +1189,11 @@ def main(argv: list[str] | None = None) -> int:
         if ok is False:
             for m in mismatches:
                 print(f"warning: SCHEMA MISMATCH: {m}", file=sys.stderr)
-    print(f"{'plane':<40} {'lines':>6} {'events':>8} {'span ms':>9}")
+    print(f"{'plane':<40} {'lines':>6} {'events':>8} {'span ms':>9} "
+          f"{'coll %':>7}")
     for p in summary["planes"]:
         print(f"{p['name']:<40.40} {p['lines']:>6} {p['events']:>8} "
-              f"{p['duration_ms']:>9.3f}")
+              f"{p['duration_ms']:>9.3f} {p['collective_pct']:>7.2f}")
     if "steps" in summary:
         s = summary["steps"]
         print(f"\nsteps: {s['count']}  mean {s['mean_ms']:.3f} ms  "

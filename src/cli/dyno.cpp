@@ -483,6 +483,9 @@ int runSelfTrace() {
   if (response.at("ipc_wakeups").isObject()) { // absent from older daemons
     doc["otherData"]["ipc_wakeups"] = response.at("ipc_wakeups");
   }
+  if (response.at("tpu_rows").isInt()) { // absent from older daemons
+    doc["otherData"]["tpu_rows"] = response.at("tpu_rows");
+  }
   doc["traceEvents"] = response.at("traceEvents");
   const std::string out = doc.dump();
   if (!FLAGS_log_file.empty()) {

@@ -29,6 +29,7 @@
 #include "src/tracing/Diagnoser.h"
 #include "src/tracing/IPCMonitor.h"
 #include "src/tracing/PushTraceCapturer.h"
+#include "src/tpumon/TpuMonitor.h"
 
 DYN_DEFINE_string(
     trace_output_root,
@@ -530,6 +531,9 @@ json::Value ServiceHandler::selftrace(const json::Value& request) {
   wakeups["posted"] = static_cast<int64_t>(wakes.posted);
   wakeups["timeout"] = static_cast<int64_t>(wakes.timeout);
   response["ipc_wakeups"] = std::move(wakeups);
+  // Device rows of the TPU monitor's last tick: one a chip the backend
+  // reported (4 on a four-chip host), 0 before the first tick.
+  response["tpu_rows"] = tpumon::TpuMonitor::lastTickRows();
   response["traceEvents"] = std::move(events);
   return response;
 }

@@ -179,13 +179,23 @@ std::unique_ptr<TpuMonitor> TpuMonitor::factoryWithBackend(
       new TpuMonitor(std::move(backend), std::move(fields)));
 }
 
+std::atomic<int64_t> TpuMonitor::lastTickRows_{0};
+
+int64_t TpuMonitor::lastTickRows() {
+  return lastTickRows_.load();
+}
+
 void TpuMonitor::update() {
   samples_ = backend_->sample();
+  int64_t rows = 0;
   for (const auto& s : samples_) {
-    if (!s.valid) {
+    if (s.valid) {
+      rows++;
+    } else {
       errorCount_++;
     }
   }
+  lastTickRows_.store(rows);
 }
 
 void TpuMonitor::log(Logger& logger) {

@@ -10,6 +10,7 @@
 // popen("nvidia-smi pmon")).
 #pragma once
 
+#include <atomic>
 #include <memory>
 #include <string>
 #include <vector>
@@ -59,6 +60,13 @@ class TpuMonitor {
     return backend_->name();
   }
 
+  // Valid device rows of the process's last update(), whichever monitor
+  // made it (the supervisor may rebuild the monitor; the count goes on):
+  // 0 before the first tick. The `selftrace` verb reports it as
+  // `tpu_rows`, so a chip the metric source stops reporting is a counter
+  // that falls, not only a series that ends.
+  static int64_t lastTickRows();
+
  private:
   TpuMonitor(
       std::unique_ptr<TpuMetricBackend> backend,
@@ -69,6 +77,7 @@ class TpuMonitor {
   std::vector<int32_t> fields_;
   std::vector<TpuDeviceSample> samples_;
   int64_t errorCount_ = 0;
+  static std::atomic<int64_t> lastTickRows_;
 };
 
 } // namespace tpumon

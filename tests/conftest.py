@@ -24,23 +24,36 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 BUILD_DIR = REPO_ROOT / "build"
 
 
-def _build_cpp() -> None:
-    # DYNO_PREBUILT=1: trust existing build/src binaries instead of
-    # requiring cmake/ninja — for containers that build the C++ tree by
-    # other means (manual g++, a cached image layer). Explicitly opt-in:
-    # stale binaries silently passing for new code would be worse than a
-    # missing-toolchain error.
-    import os
+def _forget_foreign_cache(build_dir: pathlib.Path, source_dir: pathlib.Path) -> bool:
+    """A build/ configured for another checkout (a tree that was copied with
+    its build directory) makes cmake refuse to configure: "CMakeCache.txt
+    directory ... is different". Drop the cache, keep nothing of it: the
+    objects were compiled from the other tree's paths. True when dropped."""
+    import shutil
 
-    if os.environ.get("DYNO_PREBUILT") and (BUILD_DIR / "src" / "dynologd").exists():
-        return
+    cache = build_dir / "CMakeCache.txt"
+    try:
+        text = cache.read_text(errors="replace")
+    except OSError:
+        return False
+    home = next((line.split("=", 1)[1] for line in text.splitlines()
+                 if line.startswith("CMAKE_HOME_DIRECTORY:")), None)
+    if home is None or pathlib.Path(home).resolve() == source_dir.resolve():
+        return False
+    cache.unlink()
+    shutil.rmtree(build_dir / "CMakeFiles", ignore_errors=True)
+    return True
+
+
+def _configure_cpp(source_dir: pathlib.Path, build_dir: pathlib.Path) -> None:
+    _forget_foreign_cache(build_dir, source_dir)
     subprocess.run(
         [
             "cmake",
             "-S",
-            str(REPO_ROOT),
+            str(source_dir),
             "-B",
-            str(BUILD_DIR),
+            str(build_dir),
             "-G",
             "Ninja",
             "-DCMAKE_BUILD_TYPE=Release",
@@ -48,9 +61,28 @@ def _build_cpp() -> None:
         check=True,
         capture_output=True,
     )
-    subprocess.run(
-        ["cmake", "--build", str(BUILD_DIR)], check=True, capture_output=True
-    )
+
+
+def _build_cpp() -> None:
+    # DYNO_PREBUILT=1: trust existing build/src binaries instead of
+    # requiring cmake/ninja — for containers that build the C++ tree by
+    # other means (manual g++, a cached image layer). Explicitly opt-in:
+    # stale binaries silently passing for new code would be worse than a
+    # missing-toolchain error.
+    import fcntl
+    import os
+
+    if os.environ.get("DYNO_PREBUILT") and (BUILD_DIR / "src" / "dynologd").exists():
+        return
+    BUILD_DIR.mkdir(exist_ok=True)
+    # One build at a time: every xdist worker has a session of its own, and
+    # two ninjas in one directory overwrite each other's objects.
+    with open(BUILD_DIR / ".pytest_build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        _configure_cpp(REPO_ROOT, BUILD_DIR)
+        subprocess.run(
+            ["cmake", "--build", str(BUILD_DIR)], check=True, capture_output=True
+        )
 
 
 @pytest.fixture(scope="session")
