@@ -47,6 +47,7 @@ import json
 import logging
 import math
 import os
+import resource
 import shutil
 import statistics
 import tempfile
@@ -689,6 +690,31 @@ def _feed_write(xplane_path: str, xspace, chunk_bytes: int, on_complete,
     return pending
 
 
+def _usage() -> dict:
+    """The calling thread's and the process's counters, for the account of
+    a library call the shim cannot see into: CPU time in whole
+    microseconds (`cpu_us` the thread's, `proc_cpu_us` every thread's;
+    both from the CPU clocks, which getrusage's times are coarser than),
+    and the thread's voluntary and involuntary context switches and minor
+    faults, from one getrusage. Where the kernel refuses RUSAGE_THREAD the
+    counts are left out, not zeroed."""
+    out = {"cpu_us": time.thread_time_ns() // 1000,
+           "proc_cpu_us": time.process_time_ns() // 1000}
+    try:
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+    except (AttributeError, OSError, ValueError):
+        return out
+    out.update(nvcsw=ru.ru_nvcsw, nivcsw=ru.ru_nivcsw, minflt=ru.ru_minflt)
+    return out
+
+
+def _account(prefix: str, before: dict, after: dict) -> dict:
+    """`<prefix>_<counter>`: what a call added to each counter of
+    `_usage()` taken before and after it."""
+    return {f"{prefix}_{key}": after[key] - before[key]
+            for key in before if key in after}
+
+
 class JaxProfiler:
     """Default profiler backend: jax.profiler XLA trace capture.
 
@@ -772,8 +798,10 @@ class JaxProfiler:
 
         self._dir = trace_dir
         # Per-capture: a fallback-path stop() must not inherit the
-        # previous capture's collect/write decomposition.
+        # previous capture's collect/write decomposition, nor a failed
+        # start the previous start's account.
         self.last_stop_decomposition = None
+        self.last_start_account = None
         try:
             from jax._src.lib import _profiler
 
@@ -782,7 +810,10 @@ class JaxProfiler:
             # A jax whose private session type moved: the public API
             # still captures, without the collect/write decomposition.
             self._sess = None
+            before = _usage()
             jax.profiler.start_trace(trace_dir)
+            self.last_start_account = _account(
+                "profiler_start", before, _usage())
             self._clock_sync()
             return
         # Backend (and on TPU, libtpu) must be initialized before the
@@ -793,7 +824,12 @@ class JaxProfiler:
         opts = jax.profiler.ProfileOptions()
         for attr, value in self.tracer_levels.items():
             setattr(opts, attr, value)
+        # The session's opening is the library's: the account around it
+        # (CPU against the wall of shim.profiler_start, which the caller
+        # holds) says whether it computes, waits, or works elsewhere.
+        before = _usage()
         self._sess = session_type(opts)
+        self.last_start_account = _account("profiler_start", before, _usage())
         self._clock_sync()
 
     def _clock_sync(self) -> None:
@@ -821,12 +857,16 @@ class JaxProfiler:
         write_ctx = self.obs_ctx or export_ctx
         if self._sess is None:
             with obs.span("shim.collect") as collect:
+                before = _usage()
                 jax.profiler.stop_trace()
-            self.last_stop_decomposition = {"spans": [collect]}
+                account = _account("collect", before, _usage())
+            self.last_stop_decomposition = {**account, "spans": [collect]}
             return
         sess, self._sess = self._sess, None
         with obs.span("shim.collect") as collect:
+            before = _usage()
             xspace = sess.stop()
+            account = _account("collect", before, _usage())
         with obs.span("shim.feed") as feed:
             self._feed(xspace, export_ctx, write_ctx)
         # Decomposition for the capture manifest, each duration from the
@@ -837,6 +877,7 @@ class JaxProfiler:
         # pending.wait().
         self.last_stop_decomposition = {
             "collect_ms": collect.dur_us // 1000,
+            **account,
             "feed_ms": feed.dur_us // 1000,
             "xspace_bytes": len(xspace),
             "local_devices": self._local_devices,
@@ -1662,6 +1703,9 @@ class TraceClient:
             self.profiler.start(cap.trace_dir)
         cap.spans.append(start)
         cap.timing["profiler_start_ms"] = start.dur_us // 1000
+        # the backend's account of the library call inside that span
+        cap.timing.update(
+            getattr(self.profiler, "last_start_account", None) or {})
 
     def _profiler_stop(self, cap: "_Capture", window: obs.Span) -> None:
         cap.spans.append(window)

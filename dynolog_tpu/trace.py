@@ -40,7 +40,8 @@ FLOAT64 = struct.Struct("<d")
 # (verify_schema_pins() — a jax upgrade that renumbers a field fails
 # loudly instead of silently mis-summarizing):
 #   XSpace.planes = 1
-#   XPlane: name=2, lines=3, event_metadata=4 (map), stat_metadata=5 (map)
+#   XPlane: name=2, lines=3, event_metadata=4 (map), stat_metadata=5 (map),
+#           stats=6
 #   XLine: id=1, name=2, timestamp_ns=3, events=4
 #   XEvent: metadata_id=1, offset_ps=2, duration_ps=3, stats=4
 #   XEventMetadata: id=1, name=2, display_name=4, stats=5
@@ -52,6 +53,7 @@ _SCHEMA_PINS = {
     "XSpace": {"planes": 1},
     "XPlane": {
         "name": 2, "lines": 3, "event_metadata": 4, "stat_metadata": 5,
+        "stats": 6,
     },
     "XLine": {"id": 1, "name": 2, "timestamp_ns": 3, "events": 4},
     "XEvent": {
@@ -254,11 +256,40 @@ def _op_shape(name: str) -> str:
     return token.split("{", 1)[0]
 
 
+# What a plane's bytes are made of, by the XPlane field that holds them
+# (the account an operator asks "why is my trace 13 MB" of): the events
+# live in `lines`; everything else is said once a plane, whatever the
+# window's length.
+CONTENT_FIELDS = {3: "lines", 4: "event_metadata", 5: "stat_metadata",
+                  6: "stats"}
+
+
+def _varint_len(n: int) -> int:
+    return max(1, (n.bit_length() + 6) // 7)
+
+
+def _field_size(num: int, wt: int, value) -> int:
+    """The encoded size of one field as `_walk` yields it: its tag, its
+    length prefix where it has one, its payload."""
+    tag = _varint_len(num << 3)
+    if wt == 0:
+        return tag + _varint_len(value)
+    if wt == 2:
+        return tag + _varint_len(len(value)) + len(value)
+    return tag + len(value)  # fixed 64 / 32
+
+
 @dataclass
 class PlaneSummary:
     name: str
     lines: int = 0
     events: int = 0
+    bytes: int = 0  # the plane's payload, as plane_index counts it
+    # bytes by CONTENT_FIELDS name, "other" (id, name) besides: they add
+    # up to `bytes`
+    content: dict = field(default_factory=lambda: dict.fromkeys(
+        (*CONTENT_FIELDS.values(), "other"), 0))
+    event_metadata: int = 0  # entries of the event-metadata map
     duration_ps: int = 0  # max event end across lines
     ops: dict = field(default_factory=dict)  # name -> OpAggregate
     line_names: list = field(default_factory=list)
@@ -286,18 +317,21 @@ def summarize_xplane_bytes(
     for num, wt, plane_buf in _walk(data):
         if num != 1 or wt != 2:
             continue
-        plane = PlaneSummary(name="")
+        plane = PlaneSummary(name="", bytes=len(plane_buf))
         metadata_names: dict[int, str] = {}
         metadata_shapes: dict[int, str] = {}
         metadata_stats: dict[int, list] = {}
         stat_names: dict[int, str] = {}
         lines = []
         for pn, pw, pv in _walk(plane_buf):
+            plane.content[CONTENT_FIELDS.get(pn, "other")] += _field_size(
+                pn, pw, pv)
             if pn == 2 and pw == 2:
                 plane.name = pv.decode(errors="replace")
             elif pn == 3 and pw == 2:
                 lines.append(pv)
             elif pn == 4 and pw == 2:  # event_metadata map entry
+                plane.event_metadata += 1
                 meta_id, meta_name, _disp, meta_stats = (
                     _parse_event_metadata_entry(pv))
                 metadata_names[meta_id] = meta_name
@@ -982,6 +1016,11 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
                 # time of the plane's collective ops over all its op time
                 "collective_pct": round(
                     100.0 * collective_ps / op_ps, 2) if op_ps else 0.0,
+                # what the plane's bytes are made of: `<field>_bytes` add
+                # up to `bytes`; entries of the event-metadata map
+                "bytes": p.bytes,
+                **{f"{name}_bytes": n for name, n in p.content.items()},
+                "event_metadata": p.event_metadata,
             }
         )
         # Op table from device planes when present (the question operators
@@ -1110,6 +1149,25 @@ def _print_diff(diff: dict, baseline: str, top: int) -> None:
             f"{row['impact_ms']:>+10.3f}")
 
 
+def _print_content(planes: list[dict]) -> None:
+    """The second table of a summary: each plane's bytes by what holds
+    them. The events are in `lines`; the rest is metadata and stats, the
+    same for a window of any length."""
+    names = (*CONTENT_FIELDS.values(), "other")
+    print(f"\n{'plane':<40} {'bytes':>10} " + " ".join(
+        f"{name.replace('_metadata', ' meta'):>10}" for name in names)
+        + f" {'meta rows':>9}")
+    for p in planes:
+        print(f"{p['name']:<40.40} {p['bytes']:>10} " + " ".join(
+            f"{p[name + '_bytes']:>10}" for name in names)
+            + f" {p['event_metadata']:>9}")
+    total = sum(p["bytes"] for p in planes)
+    in_lines = sum(p["lines_bytes"] for p in planes)
+    if total:
+        print(f"not in lines (metadata and stats): "
+              f"{100.0 * (total - in_lines) / total:.1f} % of {total} bytes")
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
@@ -1194,6 +1252,7 @@ def main(argv: list[str] | None = None) -> int:
     for p in summary["planes"]:
         print(f"{p['name']:<40.40} {p['lines']:>6} {p['events']:>8} "
               f"{p['duration_ms']:>9.3f} {p['collective_pct']:>7.2f}")
+    _print_content(summary["planes"])
     if "steps" in summary:
         s = summary["steps"]
         print(f"\nsteps: {s['count']}  mean {s['mean_ms']:.3f} ms  "

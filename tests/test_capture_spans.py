@@ -445,3 +445,151 @@ def test_jax_profiler_puts_two_clock_marks_into_a_capture(tmp_path):
     for start_ns, carried in marks:
         origin = opened if start_ns < 1e17 else 0
         assert abs(origin + start_ns - carried) < 1e6, (start_ns, carried)
+
+
+# ------------------------------------------------- the two library calls
+# JaxProfiler around a ProfilerSession that the test supplies: what the
+# shim's account (CPU against wall, switches, faults) says of a call that
+# sleeps and of one that computes.
+
+ACCOUNT = ("cpu_us", "proc_cpu_us", "nvcsw", "nivcsw", "minflt")
+CALL_MS = 60
+
+
+def sleep_call():
+    time.sleep(CALL_MS / 1e3)
+
+
+def spin_call():
+    """CALL_MS of this thread's own CPU time, however long that takes."""
+    until = time.thread_time_ns() + CALL_MS * 1_000_000
+    while time.thread_time_ns() < until:
+        pass
+
+
+def fake_session(monkeypatch, opening=None, stopping=None):
+    """jaxlib's ProfilerSession replaced: its constructor and its stop()
+    do what the test says and nothing else; stop() returns an XSpace."""
+    from jax._src.lib import _profiler
+
+    import xspace_fixture
+
+    xspace = xspace_fixture.build_xspace(planes=1, events_per_line=50)
+
+    class Session:
+        def __init__(self, options):
+            if opening:
+                opening()
+
+        def stop(self):
+            if stopping:
+                stopping()
+            return xspace
+
+    monkeypatch.setattr(_profiler, "ProfilerSession", Session)
+
+
+def jax_capture(tmp_path, stem="lib", client=None) -> dict:
+    """One capture through the real JaxProfiler; the manifest's timing."""
+    own = client is None
+    if own:
+        client, _ = make_client(shim.JaxProfiler(export_trace_json=False))
+    cfg = config(tmp_path, stem, obs.TraceContext.mint())
+    try:
+        client._run_trace(cfg, None)
+        manifest = wait_manifest(cfg)
+    finally:
+        if own:
+            client.stop()
+    assert manifest["status"] == "ok"
+    return manifest["timing"]
+
+
+@pytest.mark.parametrize("call", ["profiler_start", "collect"])
+@pytest.mark.parametrize("work, low, high", [
+    (sleep_call, 0.0, 0.3), (spin_call, 0.6, 1.05)],
+    ids=["sleeps", "spins"])
+def test_cpu_against_wall_tells_waiting_from_work(
+        tmp_path, monkeypatch, call, work, low, high):
+    fake_session(monkeypatch, **{
+        "opening" if call == "profiler_start" else "stopping": work})
+    shares = []
+    for attempt in range(3):  # a preempted spin reads low: best of three
+        timing = jax_capture(tmp_path, f"{call}{attempt}")
+        wall_us = timing[f"{call}_ms"] * 1000
+        assert wall_us >= CALL_MS * 1000
+        shares.append(timing[f"{call}_cpu_us"] / wall_us)
+        if low <= shares[-1] <= high:
+            break
+    assert low <= shares[-1] <= high, shares
+    # other threads idle here: the process's CPU is this thread's, nearly
+    assert timing[f"{call}_proc_cpu_us"] >= timing[f"{call}_cpu_us"] * 0.9
+
+
+def test_the_account_is_ten_whole_numbers_beside_the_two_durations(
+        tmp_path, monkeypatch):
+    fake_session(monkeypatch, stopping=sleep_call)
+    timing = jax_capture(tmp_path)
+    for prefix in ("profiler_start", "collect"):
+        for counter in ACCOUNT:
+            value = timing[f"{prefix}_{counter}"]
+            assert type(value) is int and value >= 0, (prefix, counter)
+    # a sleep is left by a voluntary switch, at least the one
+    assert timing["collect_nvcsw"] >= 1
+    assert all(type(v) is int for v in timing.values()), timing
+
+
+def test_the_public_api_fallback_takes_the_same_account(
+        tmp_path, monkeypatch):
+    """A jax whose private session type moved: start_trace / stop_trace
+    are the two library calls, and the manifest holds their account."""
+    import jax
+    from jax._src.lib import _profiler
+
+    monkeypatch.delattr(_profiler, "ProfilerSession")
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: spin_call())
+    monkeypatch.setattr(jax.profiler, "stop_trace", sleep_call)
+    timing = jax_capture(tmp_path)
+    for prefix in ("profiler_start", "collect"):
+        assert {f"{prefix}_{c}" for c in ACCOUNT} <= set(timing)
+    assert timing["profiler_start_cpu_us"] >= CALL_MS * 1000 * 0.75
+    assert timing["collect_cpu_us"] < CALL_MS * 1000 * 0.3
+    assert "collect_ms" not in timing  # the fallback's span only, as before
+
+
+def test_one_captures_account_does_not_leak_into_the_next(
+        tmp_path, monkeypatch):
+    fake_session(monkeypatch, opening=spin_call, stopping=spin_call)
+    profiler = shim.JaxProfiler(export_trace_json=False)
+    client, _ = make_client(profiler)
+    try:
+        first = jax_capture(tmp_path, "first", client)
+        fake_session(monkeypatch)  # both calls return at once
+        second = jax_capture(tmp_path, "second", client)
+        # a start that fails leaves no account of the one before it
+        fake_session(monkeypatch, opening=lambda: 1 / 0)
+        with pytest.raises(ZeroDivisionError):
+            client._run_trace(config(tmp_path, "third"), None)
+        assert profiler.last_start_account is None
+        assert profiler.last_stop_decomposition is None
+    finally:
+        client.stop()
+    for prefix in ("profiler_start", "collect"):
+        assert first[f"{prefix}_cpu_us"] >= CALL_MS * 1000 * 0.75
+        assert second[f"{prefix}_cpu_us"] < CALL_MS * 1000 * 0.3
+
+
+def test_where_the_kernel_refuses_rusage_thread(tmp_path, monkeypatch):
+    """gVisor may: the CPU fields then come from the thread's clock, and
+    the switch and fault counts are absent, not zero."""
+    def refused(who):
+        raise OSError(22, "Invalid argument")
+
+    monkeypatch.setattr(shim.resource, "getrusage", refused)
+    fake_session(monkeypatch, stopping=spin_call)
+    timing = jax_capture(tmp_path)
+    for prefix in ("profiler_start", "collect"):
+        assert {k for k in timing if k.startswith(prefix)} == {
+            f"{prefix}_ms", f"{prefix}_cpu_us", f"{prefix}_proc_cpu_us"}
+    assert timing["collect_cpu_us"] >= CALL_MS * 1000 * 0.75
+    assert timing["profiler_start_cpu_us"] < CALL_MS * 1000 * 0.3

@@ -116,6 +116,72 @@ def test_collective_share_is_in_json_and_in_the_printed_table(tmp_path, capsys):
     assert table[1].split()[-1] == "40.00"
 
 
+def environment_plane() -> bytes:
+    """A plane of stats and stat metadata and no line, as the session's
+    `Task Environment` is; a fixed64 double among the stats."""
+    stat_meta = xf._field_varint(1, 1) + xf._field_bytes(
+        2, xf._field_varint(1, 1) + xf._field_str(2, "profile_start_time"))
+    stat = xf._field_varint(1, 1) + xf._field_varint(3, 1_790_000_000 * 10**9)
+    double = xf._field_varint(1, 2) + b"\x11" + trace.FLOAT64.pack(0.5)
+    return (xf._field_varint(1, 9) + xf._field_str(2, "Task Environment")
+            + xf._field_bytes(5, stat_meta) + xf._field_bytes(6, stat)
+            + xf._field_bytes(6, double))
+
+
+CONTENT = ("lines", "event_metadata", "stat_metadata", "stats", "other")
+
+
+@pytest.mark.parametrize("build", [
+    xf.build_xspace,
+    lambda: four_chip_xspace()[0] + xf._field_bytes(1, environment_plane()),
+], ids=["fixture", "four_chip_and_environment"])
+def test_what_a_planes_bytes_are_made_of_adds_up(build):
+    data = build()
+    summary = trace._summarize_planes(trace.summarize_xplane_bytes(data))
+    index = trace.plane_index(data)
+    assert [(p["name"], p["bytes"]) for p in summary["planes"]] == [
+        (row["name"], row["bytes"]) for row in index]
+    for p in summary["planes"]:
+        assert sum(p[f"{part}_bytes"] for part in CONTENT) == p["bytes"], p
+        assert p["lines_bytes"] > 0 or p["lines"] == 0
+
+
+def test_the_content_columns_are_hand_computable(tmp_path, capsys):
+    data, planes = four_chip_xspace()
+    data += xf._field_bytes(1, environment_plane())
+    summary = trace._summarize_planes(trace.summarize_xplane_bytes(data))
+    by_name = {p["name"]: p for p in summary["planes"]}
+    device = by_name["/device:TPU:0"]
+    # id and name; three metadata entries, each under its tag and length
+    assert device["other_bytes"] == 2 + 2 + len("/device:TPU:0")
+    assert device["event_metadata"] == len(DEVICE_OPS)
+    assert device["event_metadata_bytes"] == sum(
+        2 + len(xf._event_metadata(i, op, ""))
+        for i, (op, _) in enumerate(DEVICE_OPS, start=1))
+    assert device["lines_bytes"] == len(planes[1]) - (
+        device["other_bytes"] + device["event_metadata_bytes"])
+    assert device["stat_metadata_bytes"] == device["stats_bytes"] == 0
+    environment = by_name["Task Environment"]
+    assert (environment["lines"], environment["lines_bytes"]) == (0, 0)
+    # a uint64 stat (2 + 1 + a 9-byte varint) and a double (2 + 1 + 8),
+    # each under a tag and a length; one stat-metadata entry
+    assert environment["stats_bytes"] == (2 + 12) + (2 + 11)
+    assert environment["stat_metadata_bytes"] == 2 + 2 + 2 + (
+        2 + 2 + len("profile_start_time"))
+    # the printed summary: a second table, and the share outside `lines`
+    path = tmp_path / "host.xplane.pb"
+    path.write_bytes(data)
+    assert trace.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    header = next(line for line in out.splitlines() if "meta rows" in line)
+    assert header.split()[:3] == ["plane", "bytes", "lines"]
+    total = sum(p["bytes"] for p in summary["planes"])
+    in_lines = sum(p["lines_bytes"] for p in summary["planes"])
+    assert (f"not in lines (metadata and stats): "
+            f"{100.0 * (total - in_lines) / total:.1f} % of {total} bytes"
+            ) in out
+
+
 def test_diagnose_classes_collectives_by_the_same_tokens():
     for op, _ in DEVICE_OPS[1:]:
         assert trace.is_collective(op)
