@@ -1,8 +1,8 @@
 """Flash-attention kernel benchmark: Pallas MXU kernel vs plain-XLA
 attention on the attached TPU chip (forward and forward+backward), across
-sequence lengths. Complements bench.py (the daemon overhead/latency
-benchmark the driver tracks) with kernel-level evidence. Runs on a TPU or
-not at all: the kernels have no interpret mode of their own.
+sequence lengths. Kernel-level evidence beside the benchmark the driver
+runs (perfbench/), whose cells time the daemon and the shim. Runs on a
+TPU or not at all: the kernels have no interpret mode of their own.
 
 Usage: python benchmarks/flash_attention_bench.py [--seqs 1024,2048,4096]
 """

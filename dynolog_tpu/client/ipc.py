@@ -110,7 +110,7 @@ class IpcClient:
         # concurrent exchange on the main socket is blocked on. Sharing
         # one socket made the tick-wait steal replies from any second
         # thread calling request_config, which then span its full timeout
-        # (~20x the CPU) — measured live by bench.py's shim-cost probe.
+        # (tests/test_e2e_trace.py pins the dedicated socket).
         self.kick_name = self.name + "_k"
         try:
             self.kick_sock = self._bind(self.kick_name)

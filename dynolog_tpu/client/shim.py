@@ -718,10 +718,11 @@ def _account(prefix: str, before: dict, after: dict) -> dict:
 class JaxProfiler:
     """Default profiler backend: jax.profiler XLA trace capture.
 
-    Fast-stop design. `jax.profiler.stop_trace()` spends only ~0.7-1.1s
-    collecting the XSpace from the runtime but then ~2s more converting
-    it to trace.json.gz inside `stop_and_export` (measured on a v5e chip,
-    BENCH_r03 decomposition) — all of it on the capture's critical path.
+    Fast-stop design. `jax.profiler.stop_trace()` collects the XSpace
+    from the runtime and then converts it to trace.json.gz inside
+    `stop_and_export`, all of it on the capture's critical path (the
+    collect alone on the chip: `collect_ms`, PERF.md section 5; the
+    conversion is not measured there).
     This backend drives the underlying ProfilerSession directly: stop()
     collects the raw XSpace and streams the canonical TensorBoard artifact
     (plugins/profile/<run>/<host>.xplane.pb — what TensorBoard/XProf and
@@ -947,7 +948,7 @@ class JaxProfiler:
         # fork-deadlock-prone in a process full of XLA threads and blocks
         # posix_spawn): the conversion is pure-CPU gzip/json churn that
         # would otherwise inflate the next capture's write and the
-        # training loop itself (measured in BENCH_r03 decompositions).
+        # training loop itself.
         code = (
             "import os; os.nice(19); "
             "from dynolog_tpu.trace import write_derived_artifacts; "
@@ -1409,8 +1410,8 @@ class TraceClient:
         costs the daemon IPC thread's wake-up instead of ~poll_interval/2.
         The request/reply socket is never read here — an earlier design
         that select()ed on the shared socket stole "req" replies from
-        any concurrent exchange (bench.py measured the fallout as a 20x
-        shim-CPU inflation). Sliced at 200ms to keep stop() prompt.
+        any concurrent exchange, and the requester then spun out its
+        timeout. Sliced at 200ms to keep stop() prompt.
         """
         interval = self.poll_interval_s
         if self._absent_polls >= self._absent_threshold:
@@ -1778,7 +1779,7 @@ class TraceClient:
                 manifest["error"] = cap.error
                 self.last_error = cap.error
             # Atomic (tmp + rename): the manifest's existence IS the
-            # completion signal operators and the bench poll for; a reader
+            # completion signal operators and the benchmark poll for; a reader
             # must never catch a half-written JSON. A REFUSED write (ENOSPC,
             # quota — or the trace.artifact.write errno: drill) aborts
             # cleanly: tmp unlinked, nothing renamed, and the refusal lands

@@ -288,8 +288,8 @@ def _op_findings(diff: dict, base_shapes: dict, cur_shapes: dict,
 def diagnose(base_summary: dict, cur_summary: dict, top: int = 10) -> dict:
     """The diagnosis pass: diff two summaries, mine the op-level
     patterns, rank findings by estimated total impact. Pure function —
-    the CLI, the ring, the daemon's Diagnoser and the bench all call
-    this one entry point."""
+    the CLI, the ring and the daemon's Diagnoser all call this one
+    entry point."""
     with obs.span("diagnose.diff"):
         diff = trace.diff_summaries(base_summary, cur_summary)
     base_shapes = {o["op"]: o.get("shapes") for o in

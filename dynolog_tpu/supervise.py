@@ -898,8 +898,8 @@ class AckedTcpSender:
     connection, wait (bounded) for ``ACK <seq>`` covering it, return the
     highest seq confirmed (0 = failed; the sink's breaker backs off and
     the WAL keeps the backlog). One definition for every mirror harness
-    (upstream relay legs, bench, smokes) so the sender half cannot
-    drift between them."""
+    (upstream relay legs, smokes) so the sender half cannot drift
+    between them."""
 
     def __init__(self, host: str, port: int, *, timeout_s: float = 2.0):
         self.host = host
@@ -955,12 +955,11 @@ class AckingRelay:
     and replies ``ACK <seq>`` per burst — the ``--sink_relay_ack``
     protocol RelayLogger speaks.
 
-    The ONE implementation behind every durability harness (bench.py's
-    measure_durability arm, tests/test_durability.py, and the
-    scripts/chaos_smoke.py CI gate), so the ack protocol the gates
-    measure cannot drift between them. ``sever()`` closes the listener
-    and stops serving (the outage of the chaos scenario); a new instance
-    on the same port restores service.
+    The ONE implementation behind every durability harness
+    (tests/test_durability.py and the scripts/chaos_smoke.py CI gate),
+    so the ack protocol the gates check cannot drift between them.
+    ``sever()`` closes the listener and stops serving (the outage of the
+    chaos scenario); a new instance on the same port restores service.
 
     ``drop_acks=N`` drills the duplicate-delivery hole: the first N
     bursts are received and recorded, but the connection dies before the
@@ -1194,8 +1193,8 @@ class FleetView:
     (host, boot epoch, wal_seq) dedup watermarks, live/stale/lost
     liveness machine with flap damping, per-host rollups, durable-ack
     discipline and snapshot-section schema — so the chaos drills
-    (scripts/fleet_smoke.py), bench.py's measure_fleet arm and the
-    tier-1 tests pin the relay semantics without a C++ toolchain."""
+    (scripts/fleet_smoke.py) and the tier-1 tests pin the relay
+    semantics without a C++ toolchain."""
 
     def __init__(self, *, stale_after_ms: int = 15000,
                  lost_after_ms: int = 60000, flap_threshold: int = 3,

@@ -600,9 +600,9 @@ def xplane_to_chrome_trace(data: bytes) -> dict:
 
     Exists so the shim's fast-stop path (shim.JaxProfiler) can write the
     raw XSpace on the capture's critical path (milliseconds) and produce
-    this derived view in the background: the conversion is exactly the
-    ~2s the reference-style `jax.profiler.stop_trace()` export spends
-    AFTER collection (measured in BENCH_r03; see docs/PARITY.md).
+    this derived view in the background: the conversion is exactly what
+    the reference-style `jax.profiler.stop_trace()` export spends AFTER
+    collection (not measured on the chip; docs/TRACE_PIPELINE.md).
 
     This is the single-shot in-memory form (everything in one dict); the
     production writer is the streamed, budgeted `write_chrome_trace_gz`,
@@ -619,9 +619,9 @@ def xplane_to_chrome_trace(data: bytes) -> dict:
 class ConvertBudget:
     """Explicit CPU budget for the background converter stage.
 
-    Post-processing must stay bounded and off the capture path (the
-    BENCH_r05 lesson: unbudgeted converters contaminated every later
-    benchmark phase). Knobs:
+    Post-processing must stay bounded and off the capture path:
+    unbudgeted converters pile up across back-to-back captures and
+    take CPU from every later one and from the job. Knobs:
 
     - max_workers: plane-conversion parallelism. >1 fans planes out over
       a process pool (the work is pure-Python and GIL-bound, so threads
@@ -798,7 +798,7 @@ def write_chrome_trace_gz(
     through a chunked `zlib.compressobj` at the budget's gzip level as it
     arrives — the full event list is never materialized, and the CPU cost
     is a fraction of the old monolithic level-9 `gzip.open` + `json.dump`
-    (kept as `write_chrome_trace_gz_single` for the bench's A/B arm).
+    (kept as `write_chrome_trace_gz_single`, the tests' reference).
     Write-then-rename, tmp unlinked on failure: a reader (TensorBoard, an
     operator's scp) must never see a torn gzip, and a converter crash
     must not orphan a .tmp next to the trace dir."""
@@ -838,9 +838,9 @@ def write_chrome_trace_gz_single(
     xplane_path: str, data: bytes | None = None
 ) -> str:
     """The pre-streaming converter: one in-memory dict, one monolithic
-    default-level `gzip.open` + `json.dump`. Kept as the measured
-    reference arm for bench.py's conversion phase and the parity test's
-    ground truth — not used on any production path."""
+    default-level `gzip.open` + `json.dump`. Kept as the reference that
+    tests/test_trace_convert.py compares the streamed converter against
+    — not used on any production path."""
     import gzip
 
     trace = xplane_to_chrome_trace(_read_xplane(xplane_path, data))

@@ -5,7 +5,7 @@ XSpace fixture into a valid trace.json.gz inside a wall-clock budget.
 A pure-stdlib end-to-end check of the post-capture pipeline's hot stage —
 no jax, no C++ build — so a converter regression (a parse slowdown, a
 pool that hangs, an output that stops gunzipping) fails CI in seconds,
-not at the next hardware bench round.
+not on the next machine with a chip.
 
 Usage: python scripts/convert_smoke.py [fixture] [--budget-s=N | --budget-s N]
 Exit 0 on success; 1 with a reason on any failure.

@@ -6,8 +6,8 @@ against a pure-Python reference peer, inside a wall-clock budget.
 Pre-build by design (no C++, no jax): it pins the Python side of the
 int32-length-prefixed wire protocol — framing, connection reuse, the
 reconnect-once retry, and deadline-bounded failure — so a cluster-plane
-regression (unitrace polling, the bench RPC arm) fails CI in seconds,
-not at the next hardware bench round. The daemon side of the same
+regression (unitrace polling) fails CI in seconds, not on the next
+machine with a chip. The daemon side of the same
 protocol is covered by src/tests/RpcTest.cpp and
 tests/test_rpc_eventloop.py once the tree is built.
 

@@ -1,7 +1,7 @@
 // dynolog_tpu: the daemon's own resource footprint as store series —
 // "monitor the monitor". The <1% overhead budget (BASELINE.md) is a
 // production property; these series make it observable in production
-// instead of only in bench runs: dyno watch --metrics=daemon_cpu_pct, or a
+// instead of only in benchmark runs: dyno watch --metrics=daemon_cpu_pct, or a
 // Prometheus alert on daemon_rss_kb. No reference analog (the reference
 // daemon never reports its own cost).
 #pragma once

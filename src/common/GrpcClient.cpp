@@ -306,9 +306,9 @@ bool GrpcClient::connect(std::string* error, int timeoutMs,
   // responses never stall on flow control; window and frame-size stay
   // modest ON PURPOSE — frequent WINDOW_UPDATE credit keeps the peer's
   // sends in steady small bursts that interleave with the streamed
-  // disk write, and advertising 1MB frames or a 4MB window measurably
-  // SLOWED the push arm ~2x on the bench host) + a connection-window
-  // grant.
+  // disk write; advertising 1MB frames or a 4MB window slowed a push
+  // capture when it was tried, not re-measured on the chip) + a
+  // connection-window grant.
   std::string settings;
   settings.push_back(0x00);
   settings.push_back(0x04); // SETTINGS_INITIAL_WINDOW_SIZE

@@ -45,9 +45,8 @@
 //
 // The pure-Python mirror (dynolog_tpu/supervise.py ResourceGovernor,
 // same class/priority/pressure semantics and snapshot keys) backs the
-// pre-build pressure smoke (scripts/pressure_smoke.py), the tier-1
-// pressure tests (tests/test_pressure.py), and bench.py's
-// measure_pressure arm.
+// pre-build pressure smoke (scripts/pressure_smoke.py) and the tier-1
+// pressure tests (tests/test_pressure.py).
 #pragma once
 
 #include <cstdint>

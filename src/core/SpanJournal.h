@@ -84,8 +84,7 @@ struct Span {
 // concurrent writers and readers.
 class SpanJournal {
  public:
-  // capacity 0 disables recording entirely (the bench's A/B toggle,
-  // --selftrace_capacity=0).
+  // capacity 0 disables recording entirely (--selftrace_capacity=0).
   explicit SpanJournal(size_t capacity = kDefaultCapacity);
 
   // Process-wide journal; capacity from --selftrace_capacity at first

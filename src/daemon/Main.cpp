@@ -342,7 +342,7 @@ static void superviseKernelMonitor(
         auto collector = std::make_shared<KernelCollector>();
         // The daemon's own footprint rides the kernel tick (same logger
         // row): the <1% overhead budget stays observable in production,
-        // not just in bench runs.
+        // not just in benchmark runs.
         auto selfStats = std::make_shared<SelfStatsCollector>();
         auto logger = makeLogger(store, health);
         return [collector, selfStats, logger] {

@@ -238,8 +238,8 @@ std::string ServiceHandler::processRequest(
   if (fn == "getStatus") {
     response["status"] = getStatus();
     // Build identity on the cheapest verb every prober already calls —
-    // fleet tooling (and the bench compact line) correlates behavior
-    // against version without a second RPC.
+    // fleet tooling correlates behavior against version without a
+    // second RPC.
     response["version"] = kVersion;
     response["proto"] = kWireProtoVersion;
   } else if (fn == "getVersion") {
@@ -358,8 +358,8 @@ std::string ServiceHandler::processRequest(
     std::string profilerHost =
         request.at("profiler_host").asString("localhost");
     std::string logFile = request.at("log_file").asString();
-    // Optional per-capture tracer levels (absent = jax profile defaults);
-    // the bench's lighter-tracer A/B rides these. Range-validated at the
+    // Optional per-capture tracer levels (absent = jax profile defaults).
+    // Range-validated at the
     // RPC boundary: the CLI filters negatives, but the JSON RPC is the
     // public surface and a stray -1 would serialize as a 2^64-1 varint
     // in ProfileOptions.

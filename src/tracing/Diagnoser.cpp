@@ -452,7 +452,7 @@ int64_t Diagnoser::diagnoseCapture(
       {
         // The wait for the shim to finish writing the capture is its
         // own span: config hand-off to manifest is exactly the capture
-        // latency the bench decomposes.
+        // latency the benchmark reads as capture_ms_p50.
         SpanScope waitSpan(
             "diagnose.capture_wait", childCtx.traceId, childCtx.spanId);
         int64_t deadline = nowUnixMillis() + waitDeadlineMs;

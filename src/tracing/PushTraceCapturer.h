@@ -21,8 +21,8 @@ namespace tracing {
 
 // tensorflow.ProfileOptions tracer levels for a push capture. Defaults
 // match jax's own profile defaults (host "info", device on, python off —
-// python tracing costs seconds of server-side stop time). The bench's
-// tracer-level A/B drives these through the pushtrace RPC.
+// python tracing costs seconds of server-side stop time). `dyno
+// pushtrace --host_tracer_level/...` sets them through the pushtrace RPC.
 struct PushProfileOptions {
   int hostTracerLevel = 2;
   int deviceTracerLevel = 1;

@@ -9,7 +9,7 @@ import pathlib
 import subprocess
 
 # Force the virtual 8-device CPU mesh before any backend initializes (fast +
-# deterministic; the real chip is for chip_smoke.py and bench.py).
+# deterministic; the real chip is for chip_smoke.py and perfbench/).
 import sys
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
@@ -98,11 +98,11 @@ def bin_dir(cpp_build: pathlib.Path) -> pathlib.Path:
 
 
 # Opt-in slow lane (DYNO_SLOW_TESTS=1): multi-minute tests whose coverage
-# is redundant with a cheaper default-lane test or with the driver's own
-# round checks (the multichip dryrun runs separately every round and its
-# result is recorded in MULTICHIP_r*.json). Keeps the default suite's
-# wall time bounded on the 1-core CI host without deleting coverage —
-# CI's slow job (and any dev with the env var) still runs them.
+# is redundant with a cheaper default-lane test (for the multichip dryrun:
+# test_sharded_train_step_matches_single_device, tests/test_sharded_job.py).
+# Keeps the default suite's wall time bounded on the 1-core CI host
+# without deleting coverage — CI's slow job (and any dev with the env
+# var) still runs them.
 import os  # noqa: E402
 
 slow_lane = pytest.mark.skipif(

@@ -34,6 +34,14 @@ class Daemon:
             return json.loads(data) if data is not None else None
 
 
+def assert_status_ok(reply: dict | None) -> None:
+    """getStatus answered by a live daemon: status 1 plus the build
+    identity the verb carries (version, wire proto)."""
+    assert reply is not None, "no reply to getStatus"
+    assert reply["status"] == 1, reply
+    assert "version" in reply and "proto" in reply, reply
+
+
 def _read_exact(sock: socket.socket, n: int) -> bytes | None:
     buf = b""
     while len(buf) < n:

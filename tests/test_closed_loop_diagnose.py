@@ -15,6 +15,7 @@ from test_capture_ring import FakeXplaneProfiler  # noqa: E402
 from xspace_fixture import build_xspace  # noqa: E402
 
 from daemon_utils import (  # noqa: E402
+    assert_status_ok,
     run_dyno,
     start_daemon,
     stop_daemon,
@@ -215,7 +216,7 @@ def test_diagnosis_failure_is_recorded_not_fatal(bin_dir, tmp_path):
         assert listed["failures_total"] >= 1
         # The capture itself still completed; the daemon still serves.
         assert client.traces_completed >= 1
-        assert daemon.rpc({"fn": "getStatus"}) == {"status": 1}
+        assert_status_ok(daemon.rpc({"fn": "getStatus"}))
     finally:
         client.stop()
         stop_daemon(daemon)

@@ -8,11 +8,13 @@ config through RPC → registry → IPC poll → profiler trigger.
 
 import json
 import os
+import pathlib
+import re
 import time
 
 import pytest
 
-from daemon_utils import run_dyno, start_daemon, stop_daemon
+from daemon_utils import assert_status_ok, run_dyno, start_daemon, stop_daemon
 from dynolog_tpu.client import IpcClient, TraceClient
 from dynolog_tpu.client.shim import RecordingProfiler, TraceConfig
 
@@ -31,11 +33,13 @@ def test_status_and_version(daemon, bin_dir):
 
     result = run_dyno(bin_dir, daemon.port, "version")
     assert result.returncode == 0
-    assert "0.6.0" in result.stdout
+    header = pathlib.Path(__file__).resolve().parent.parent / "src/common/Version.h"
+    version = re.search(r'kVersion = "([^"]+)"', header.read_text()).group(1)
+    assert version in result.stdout
 
 
 def test_rpc_direct(daemon):
-    assert daemon.rpc({"fn": "getStatus"}) == {"status": 1}
+    assert_status_ok(daemon.rpc({"fn": "getStatus"}))
     # unknown fn: server closes without reply
     assert daemon.rpc({"fn": "noSuchVerb"}) is None
 
@@ -127,10 +131,9 @@ def test_concurrent_request_config_replies_not_stolen(daemon):
     """A second thread's request/reply exchange must not lose its reply to
     the poll thread's inter-poll wait. An earlier kick design select()ed
     on the SHARED socket between polls and consumed concurrent "req"
-    replies; the requester then span its full timeout per call (bench.py
-    measured it as a 20x shim-CPU inflation). Kicks now ride a dedicated
-    socket and exchanges serialize on a lock, so every out-of-band
-    request_config gets its reply at daemon-tick speed."""
+    replies; the requester then span its full timeout per call. Kicks
+    now ride a dedicated socket and exchanges serialize on a lock, so
+    every out-of-band request_config gets its reply at daemon-tick speed."""
     client = TraceClient(
         job_id=96, endpoint=daemon.endpoint, poll_interval_s=0.1,
         profiler=RecordingProfiler())
@@ -327,7 +330,7 @@ def test_on_demand_trace_iteration_mode(daemon, bin_dir, tmp_path):
 def test_iteration_trace_timeout_fails_loudly(daemon, bin_dir, tmp_path):
     # App never calls step(): the capture must abort WITHOUT starting the
     # profiler, record the failure in last_error, and write an error
-    # manifest — not silently trace the wrong window (VERDICT r1 weak #6).
+    # manifest — not silently trace the wrong window.
     profiler = RecordingProfiler()
     client = TraceClient(
         job_id=78,
@@ -429,8 +432,12 @@ def test_busy_detection_via_rpc(daemon):
         )
         assert r2["activityProfilersTriggered"] == []
         assert r2["activityProfilersBusy"] == 1
-        # Client consumes pending config; gets A only.
-        assert ipc_client.request_config(55, [4242], dest=daemon.endpoint) == "A=1\n"
+        # Client consumes pending config; gets A only (plus the trace
+        # identity the verb mints for a caller that sent none).
+        config = ipc_client.request_config(55, [4242], dest=daemon.endpoint)
+        lines = config.splitlines()
+        assert lines[0] == "A=1", config
+        assert all(ln.startswith("TRACE_CONTEXT=") for ln in lines[1:]), config
 
 
 def test_daemon_restart_clients_reregister(bin_dir, tmp_path):

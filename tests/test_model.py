@@ -224,9 +224,9 @@ def test_graft_entry_compiles():
 def test_graft_entry_dryrun():
     """Slow lane: the full 8-device dryrun (~3.5 min on the 1-core CI
     host: three mesh configs x (compile + monitoring leg) + the push
-    capture). The driver runs exactly this entry point separately every
-    round and records MULTICHIP_r*.json, so the default lane carries no
-    coverage gap."""
+    capture). The default lane holds the sharded step at a smaller size
+    (test_sharded_train_step_matches_single_device above,
+    tests/test_sharded_job.py); MoE and pipeline are slow lane too."""
     import __graft_entry__ as graft
 
     graft.dryrun_multichip(8)

@@ -56,9 +56,14 @@ def test_perfsample_verb(cpp_build):
         assert weights == sorted(weights, reverse=True)
         total_pct = sum(t["weight_pct"] for t in threads)
         assert total_pct <= 100.0 + 1e-6
-        # The busy loop must dominate the profile.
         assert threads[0]["name"], threads[0]
-        assert threads[0]["weight_pct"] > 30.0, threads
+        # The busy loop must hold most of a core. The profile is
+        # system-wide and weight_pct is a share of every CPU's samples,
+        # so on a many-core host the bar is the thread's own CPU time
+        # (task-clock weight is ns) against the window.
+        busy = [th for th in threads if th["tid"] == t.native_id]
+        assert busy, threads
+        assert busy[0]["weight"] > 0.3 * result["window_ms"] * 1e6, threads
 
         # Unknown events fail soft with a parse error, not a hang.
         bad = daemon.rpc(

@@ -19,7 +19,7 @@ import time
 import urllib.request
 from pathlib import Path
 
-from daemon_utils import start_daemon, stop_daemon
+from daemon_utils import assert_status_ok, start_daemon, stop_daemon
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
@@ -44,7 +44,7 @@ def test_stalled_client_does_not_delay_status_rpc(bin_dir):
             t0 = time.monotonic()
             for _ in range(5):
                 response = client.call({"fn": "getStatus"})
-                assert response == {"status": 1}
+                assert_status_ok(response)
             elapsed = time.monotonic() - t0
         # The serial transport parked every caller behind the stalled
         # clients' 5s IO timeout; the event loop serves them in their own
@@ -84,7 +84,7 @@ def test_persistent_connection_many_requests(bin_dir):
     try:
         with FramedRpcClient("localhost", daemon.port) as client:
             for _ in range(50):
-                assert client.call({"fn": "getStatus"}) == {"status": 1}
+                assert_status_ok(client.call({"fn": "getStatus"}))
             listed = client.call({"fn": "listMetrics"})
             assert isinstance(listed.get("metrics"), list)
     finally:
@@ -102,7 +102,7 @@ def test_connection_cap_evicts_oldest_idle(bin_dir):
             idle.append(s)
             time.sleep(0.05)  # deterministic idle-age ordering
         # The 5th caller gets in and is served (oldest idle evicted).
-        assert daemon.rpc({"fn": "getStatus"}) == {"status": 1}
+        assert_status_ok(daemon.rpc({"fn": "getStatus"}))
         # The stalest idle connection saw EOF.
         idle[0].settimeout(5)
         assert idle[0].recv(4) == b""
@@ -124,7 +124,7 @@ def test_slowloris_reaped_by_request_deadline(bin_dir):
         assert time.monotonic() - t0 < 5.0
         s.close()
         # The daemon itself is unaffected.
-        assert daemon.rpc({"fn": "getStatus"}) == {"status": 1}
+        assert_status_ok(daemon.rpc({"fn": "getStatus"}))
     finally:
         stop_daemon(daemon)
 
@@ -140,13 +140,13 @@ def test_backlog_and_tuning_flags_accepted(bin_dir):
         kernel_interval_s=60,
     )
     try:
-        assert daemon.rpc({"fn": "getStatus"}) == {"status": 1}
+        assert_status_ok(daemon.rpc({"fn": "getStatus"}))
         # An idle persistent connection is reaped after the idle timeout;
         # the client transparently reconnects on its next call.
         with FramedRpcClient("localhost", daemon.port) as client:
-            assert client.call({"fn": "getStatus"}) == {"status": 1}
+            assert_status_ok(client.call({"fn": "getStatus"}))
             time.sleep(3.0)
-            assert client.call({"fn": "getStatus"}) == {"status": 1}
+            assert_status_ok(client.call({"fn": "getStatus"}))
     finally:
         stop_daemon(daemon)
 
@@ -270,7 +270,7 @@ def test_fetch_trace_streams_artifact_end_to_end(bin_dir, tmp_path):
             assert header is not None and header["status"] == "ok"
             assert header["streamed_bytes"] == len(payload)
             # The stream left the connection reusable.
-            assert client.call({"fn": "getStatus"}) == {"status": 1}
+            assert_status_ok(client.call({"fn": "getStatus"}))
         assert dest.read_bytes() == payload
         assert not (tmp_path / "fetched.xplane.pb.tmp").exists()
     finally:
@@ -341,7 +341,7 @@ def test_fetch_client_disconnect_mid_stream_daemon_survives(bin_dir, tmp_path):
         assert s.recv(4096)  # some of the header/stream arrived
         s.close()  # vanish mid-stream
         with FramedRpcClient("localhost", daemon.port) as client:
-            assert client.call({"fn": "getStatus"}) == {"status": 1}
+            assert_status_ok(client.call({"fn": "getStatus"}))
         daemon.proc.send_signal(signal.SIGTERM)
         rc = daemon.proc.wait(timeout=10)
         assert rc == 0, f"daemon exited {rc}"

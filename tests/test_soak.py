@@ -11,9 +11,9 @@ its own SelfStats series. A leak of one fd or a few KB per capture would
 pass every functional test and still kill a fleet deployment; this test
 asserts the slopes are flat.
 
-Default runtime is CI-sized (~75s). DYNO_SOAK_SECONDS=900 runs the long
-soak that produces the PARITY artifact (benchmarks/soak_r4.json written
-when DYNO_SOAK_ARTIFACT is set to the output path).
+Default runtime is CI-sized (~75s). DYNO_SOAK_SECONDS=900 runs a long
+soak (its record is written to the path in DYNO_SOAK_ARTIFACT when that
+is set).
 """
 
 import json

@@ -46,8 +46,8 @@ def _read_gz(path: str) -> str:
 
 def test_fixture_regenerates_identically():
     # The checked-in fixture IS its generator's output — a drifted
-    # generator (or a hand-edited fixture) fails here, keeping the three
-    # consumers (this test, CI smoke, bench conversion arm) in sync.
+    # generator (or a hand-edited fixture) fails here, keeping its
+    # consumers (this test, the CI smokes, the diagnosis tests) in sync.
     from xspace_fixture import build_xspace
 
     assert build_xspace() == FIXTURE.read_bytes()
@@ -310,7 +310,7 @@ def test_shim_convert_budget_plumbing():
 
 def test_summarizer_reads_fixture():
     # The fixture is schema-faithful: the summarizer parses it and sees
-    # the synthetic ops (shared sanity for bench's conversion arm).
+    # the synthetic ops.
     summary = trace._summarize_planes(
         trace.summarize_xplane_bytes(FIXTURE.read_bytes()))
     assert len(summary["planes"]) == 4

@@ -9,9 +9,10 @@ tests/fixtures/bench.xplane.pb can be regenerated and diffed:
     python tests/xspace_fixture.py tests/fixtures/bench.xplane.pb
 
 The fixture is the shared workload for the converter parity test
-(tests/test_trace_convert.py), the CI conversion-smoke step, and
-bench.py's conversion arm — one artifact, three consumers, so a
-converter regression shows up identically in all of them.
+(tests/test_trace_convert.py), the CI conversion and stream smokes
+(scripts/convert_smoke.py, scripts/stream_smoke.py) and the diagnosis
+tests — one artifact, so a converter regression shows up identically
+in all of them.
 """
 
 from __future__ import annotations
@@ -84,7 +85,7 @@ def build_xspace(
 
     `op_duration_scale` ({meta_id: factor}) scales chosen ops' durations
     and `op_shapes` ({meta_id: "bf16[64,64]"}) overrides result shapes —
-    the synthetic-regression knobs the diagnosis smoke/bench/tests use to
+    the synthetic-regression knobs the diagnosis smoke and tests use to
     build a "current" capture that regressed vs the pristine default
     (which stays bit-identical to the checked-in fixture)."""
     scale = op_duration_scale or {}
