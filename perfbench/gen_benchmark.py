@@ -7,8 +7,17 @@ A per-layer metric is one reader file; its NAME, UNIT, BETTER, SOURCE,
 LAYER, MOVES and CELLS (the traffic kinds in which it finds something to
 read) become its entry. A metric whose CELLS cover every traffic kind gets
 no `workloads` key, so it is due in every cell, later ones too; any other
-lists the cells of its kinds. Everything else in BENCHMARK.json is left as
-it stands. A later PR adds a metric by adding a file and running this.
+lists the cells of its kinds, less those its file names in EXCEPT: cells of
+a kind it reads in which it still finds nothing, with the sentence that
+says why beside them. A due metric that prints nothing refuses a PR, so a
+reader lists only cells in which it always has a value.
+
+An entry the table already holds keeps its place and its content; a cell
+that no entry's list names yet is new, and joins the list of every reader
+of its kind. Readers the table does not hold are appended after the
+others, in the order of their names. Everything else in BENCHMARK.json is
+left as it stands. A later PR adds a metric by adding a file, or a cell by
+adding an entry under `workloads`, and running this.
 """
 
 from __future__ import annotations
@@ -19,19 +28,42 @@ import sys
 import cells
 
 
-def per_layer(bench: dict) -> list:
-    kinds = {w["name"]: cells.load_traffic(w["traffic"])["kind"]
-             for w in bench["workloads"]}
+def entry_of(reader, kinds: dict) -> dict:
+    """The table entry that one reader file generates; `kinds` is cell
+    name -> traffic kind, in the order of `workloads`."""
+    entry = {"name": reader.NAME, "unit": reader.UNIT, "better": reader.BETTER,
+             "source": reader.SOURCE, "layer": reader.LAYER,
+             "moves": reader.MOVES}
+    excepted = getattr(reader, "EXCEPT", ())
+    if set(reader.CELLS) != set(cells.TRAFFIC_KINDS) or excepted:
+        entry["workloads"] = [
+            w for w, kind in kinds.items()
+            if kind in reader.CELLS and w not in excepted]
+    return entry
+
+
+def cell_kinds(bench: dict) -> dict:
+    return {w["name"]: cells.load_traffic(w["traffic"])["kind"]
+            for w in bench["workloads"]}
+
+
+def per_layer(bench: dict, readers: dict | None = None) -> list:
+    readers = cells.load_readers() if readers is None else readers
+    kinds = cell_kinds(bench)
+    listed = {w for m in bench["per_layer"] for w in m.get("workloads", ())}
     table = []
-    for name, reader in cells.load_readers().items():
-        entry = {"name": name, "unit": reader.UNIT, "better": reader.BETTER,
-                 "source": reader.SOURCE, "layer": reader.LAYER,
-                 "moves": reader.MOVES}
-        if set(reader.CELLS) != set(cells.TRAFFIC_KINDS):
-            entry["workloads"] = [
-                w for w, kind in kinds.items() if kind in reader.CELLS]
+    for held in bench["per_layer"]:
+        if held["name"] not in readers:
+            continue  # its file went; so does its entry
+        entry = dict(held)
+        if "workloads" in entry:
+            entry["workloads"] = entry["workloads"] + [
+                w for w in entry_of(readers[held["name"]], kinds).get(
+                    "workloads", ())
+                if w not in listed]
         table.append(entry)
-    return table
+    new = sorted(set(readers) - {m["name"] for m in bench["per_layer"]})
+    return table + [entry_of(readers[name], kinds) for name in new]
 
 
 def main() -> int:

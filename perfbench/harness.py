@@ -8,12 +8,14 @@ are copied from chip_smoke.py, which ran on the chip in PR 21.
     preflight -> build -> dynologd (before JAX) -> JAX on the chip ->
     weights from the seed -> check J's reference -> optimizer state ->
     step compiled or loaded -> shim registered -> warm steps (and one warm
-    capture) -> THE WINDOW -> drain -> checks S1 S2 C1 C2 C3 -> teardown
-    (check C4) -> one JSON line.
+    capture) -> THE WINDOW -> drain -> checks S1 S2 C1 C2 C3
+    -> in traced runs the journals (dyno selftrace, the shim's counters)
+    -> teardown (check C4) -> one JSON line.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -27,6 +29,7 @@ from pathlib import Path
 
 import cells
 import checks
+import selftrace
 import stats
 import xplane
 
@@ -143,6 +146,17 @@ class Daemon:
             return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
         except (OSError, IndexError, ValueError):
             return None
+
+    def rss_kb(self) -> int | None:
+        """VmRSS of /proc/<pid>/status; None where it cannot be read."""
+        try:
+            with open(f"/proc/{self.proc.pid}/status") as f:
+                for line in f:
+                    if line.startswith("VmRSS:"):
+                        return int(line.split()[1])
+        except (OSError, IndexError, ValueError):
+            pass
+        return None
 
     def stop(self, timeout_s: float = 10.0) -> bool:
         """SIGTERM; True when the daemon exited by itself within timeout_s."""
@@ -462,9 +476,10 @@ class Run:
         n = len(rec["step_ms"])
         ends = zip(self.steps[-n:], self.parts[-n:])
         rec["longest_passes"] = [
-            [round(ms, 1), round(t - t0, 2), parts]
+            [round(ms, 1), round(t - t0, 4), parts]
             for (t, ms), parts in sorted(ends, key=lambda s: -s[0][1])[:3]]
         cpu1 = self.daemon.cpu_seconds()
+        rec["daemon_rss_kb"] = self.daemon.rss_kb()
         rec["window_s"] = rec["window_end"] - t0
         if cpu0 is not None and cpu1 is not None:
             rec["daemon_cpu_s"] = cpu1 - cpu0
@@ -547,6 +562,31 @@ class Run:
                 self.average_captures(good)
             elif self.cell.kind == "steady":
                 self.reduce_trace(self.own_trace())
+
+    def read_journals(self) -> None:
+        """What the daemon and the shim recorded by themselves, for the
+        readers of traced runs: after the window and after every check, so
+        it costs the run nothing but those readers' values where the daemon
+        does not answer."""
+        rec = self.record
+        client = self.client
+        rec["shim_counters"] = {
+            "traces_completed": client.traces_completed,
+            "daemon_reconnects": client.daemon_reconnects,
+            "last_error": client.last_error,
+            "steps": getattr(client, "_step_count", None)}
+        t0 = time.time()
+        try:
+            proc = self.daemon.dyno("selftrace", timeout=30)
+            if proc.returncode != 0:
+                raise ValueError(f"dyno selftrace exit {proc.returncode}: "
+                                 + (proc.stdout + proc.stderr)[-300:])
+            rec["selftrace"] = selftrace.parse(proc.stdout)
+        except (OSError, subprocess.SubprocessError, ValueError, KeyError,
+                TypeError) as e:
+            rec["selftrace"] = {"error": f"{type(e).__name__}: {e}"}
+        rec["selftrace_oldest_ms"] = selftrace.oldest_ms(rec["selftrace"])
+        self.phase("journals_s", t0)
 
     def teardown(self) -> None:
         """Stops the shim, waits for its convert children, stops the daemon;
@@ -634,6 +674,8 @@ def measure(run: Run) -> None:
         run.warm_up()
         run.window()
         run.drain_and_check()
+        if run.trace:
+            run.read_journals()
     finally:
         run.teardown()
     run.record["phases"]["total_s"] = time.time() - t0
@@ -664,6 +706,28 @@ def main(argv=None, t_process: float | None = None) -> int:
     return 0
 
 
+def report_journals(rec: dict) -> None:
+    """The journal's census (the ring's headroom is spans_recorded against
+    ring_capacity), and the three longest passes with every daemon span
+    (tick, verb, hand-off) that lies over each."""
+    journal = rec["selftrace"]
+    if "error" in journal:
+        say(f"dyno selftrace gave nothing: {journal['error']}")
+        return
+    counts = collections.Counter(s["name"] for s in journal["spans"])
+    say(f"selftrace: {journal['spans_recorded']} spans recorded, ring of "
+        f"{journal['ring_capacity']}; the oldest began "
+        f"{rec['window_start'] - (rec['selftrace_oldest_ms'] or 0) / 1e3:.1f}"
+        f" s before the window; ipc_wakeups {journal['ipc_wakeups']}, "
+        f"tpu_rows {journal['tpu_rows']}; shim {rec['shim_counters']}; "
+        f"by name {json.dumps(dict(sorted(counts.items())))}")
+    for longest in rec.get("longest_passes", []):
+        say(f"pass of {longest[0]} ms, ended {longest[1]} s into the window, "
+            f"[dispatch, device wait, shim] {longest[2]}; daemon spans over "
+            "it [name, began ms after the pass did, ms]: "
+            f"{selftrace.spans_over(rec, longest)}")
+
+
 def report(run: Run, line: dict) -> None:
     """Everything that is not the last line: to earlier lines and to
     perfbench/out/<workload>-<seed>.json. The captures' artifacts go."""
@@ -675,6 +739,8 @@ def report(run: Run, line: dict) -> None:
     say(f"warm steps ms: {[round(x, 1) for x in rec['warm_step_ms']]}; daemon "
         f"CPU in the window {rec.get('daemon_cpu_s')} s; longest passes "
         f"[ms, ended s into the window, [dispatch, device wait, shim] ms]: {rec.get('longest_passes')}")
+    if "selftrace" in rec:
+        report_journals(rec)
     say("phases: " + json.dumps(
         {k: round(v, 2) for k, v in rec["phases"].items()}))
     say(f"compile cache: {run.cache['hits']} hits, {run.cache['misses']} "

@@ -8,9 +8,10 @@ and what the capture cost them. A program that lacks them (the parent of
 the PR that added them) writes manifests without `spans`: every reader here
 then returns None.
 
-Their reader files are named `xspan.<metric>`: `gen_benchmark.py` emits
-the `per_layer` table in file order and an accepted entry keeps its place,
-so a new reader needs a name that sorts after those the table has.
+Their reader files are named `xspan.<metric>` because `gen_benchmark.py`
+emitted the `per_layer` table in file order until PR 31 and an accepted
+entry keeps its place; accepted names stay, a new reader is named for what
+it reads.
 
 The device trace's clock is not assumed. `ProfileData` hands event starts
 either as nanoseconds since the epoch or as nanoseconds since the session

@@ -6,7 +6,6 @@ import json
 import pytest
 
 import cells
-import gen_benchmark
 import rehearsal
 
 
@@ -34,12 +33,6 @@ def test_every_cell_reports_setup_and_one_more_and_a_layer():
         by_name = {m["name"]: m for m in bench["per_layer"]}
         for name in cells.metric_names(bench, cell, "per_layer"):
             assert by_name[name]["moves"] in e2e, (cell.name, name)
-
-
-def test_per_layer_table_is_what_the_reader_files_generate():
-    bench = cells.load_benchmark()
-    assert bench["per_layer"] == gen_benchmark.per_layer(bench)
-    assert set(cells.load_readers()) == {m["name"] for m in bench["per_layer"]}
 
 
 def test_unknown_names_are_errors_not_defaults():

@@ -3,7 +3,9 @@ sound, `correct` is true; with the timed path broken underneath, false."""
 
 import jax.numpy as jnp
 
+import cells
 import rehearsal
+import selftrace
 
 
 def failed(run) -> list:
@@ -17,6 +19,9 @@ def test_sound_steady_run_is_correct(monkeypatch, tmp_path):
     assert line["failed"] == 0 and line["attempted"] == len(run.record["step_ms"])
     assert run.record["setup_s"] > 0 and sum(run.record["step_ms"]) > 3000
     assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    # the journals are asked for in traced runs alone; the RSS in every run
+    assert "selftrace" not in run.record and "shim_counters" not in run.record
+    assert run.record["daemon_rss_kb"] > 1000
 
 
 def test_step_telemetry_lost_is_not_correct(monkeypatch, tmp_path):
@@ -50,3 +55,25 @@ def test_capture_run_drives_every_check(monkeypatch, tmp_path):
     assert len(run.record["captures"]) >= 2
     assert all(c["ok"] for c in run.record["captures"])
     assert line["correct"] is False
+    # the hook, against the real daemon and CLI: a tick a second, one verb
+    # and one hand-off a capture (the warm one too), the counters
+    rec = run.record
+    journal, captures = rec["selftrace"], len(rec["captures"]) + 1
+    count = {}
+    for span in journal["spans"]:
+        count[span["name"]] = count.get(span["name"], 0) + 1
+    assert count[selftrace.CAPTURE_VERB] == count[selftrace.HANDOFF] == captures
+    assert count["shim.capture"] == captures
+    in_window = selftrace.window_ms(rec, selftrace.TPU_TICK)
+    assert 2 <= len(in_window) <= 4  # 3 s of window, one tick a second
+    assert journal["tpu_rows"] == 4 and journal["ipc_wakeups"]["message"] > 0
+    assert rec["selftrace_oldest_ms"] < rec["window_start"] * 1e3
+    assert rec["shim_counters"]["traces_completed"] == captures
+    assert rec["shim_counters"]["steps"] == len(run.steps)
+    readers = cells.load_readers()
+    for name in ("tpu_tick_ms_p50", "kernel_tick_ms_p50", "rpc_verb_ms",
+                 "ipc_handoff_ms", "ipc_timeout_wakeup_pct", "daemon_rss_mb",
+                 "first_capture_ms", "longest_pass_tick_overlap_ms"):
+        assert readers[name].read(rec) is not None, name
+    assert readers["first_capture_ms"].read(rec) == (
+        rec["warm_capture"][0]["capture_ms"])

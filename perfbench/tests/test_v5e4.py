@@ -69,35 +69,27 @@ def test_source_and_reduced_differ_from_every_other_configuration():
     assert four == [CELL] and bench["workloads"][-1]["name"] == CELL
 
 
-def test_new_readers_come_last_and_list_every_capture_cell():
-    bench = cells.load_benchmark()
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-3:] == ["xspan.trace_clock_skew_us",
-                          "xspan.xla_collective_pct",
-                          "xspan.xplane_plane_skew_pct"]
-    capture_cells = [w["name"] for w in bench["workloads"]
-                     if cells.load_traffic(w["traffic"])["kind"] == "capture"]
-    assert capture_cells[-1] == CELL
-    for entry in bench["per_layer"][-2:]:
-        assert entry["workloads"] == capture_cells
-    e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["capture_ms_p50"]["workloads"] == capture_cells
-
-
-def test_every_per_layer_metric_listed_for_the_cell_has_something_to_read():
-    """Every capture reader lists the cell but `step_ms_p95.capture`: a window
-    of `run_seconds` holds 109 steps of 366 ms, five beyond their p95, and
+def test_every_capture_reader_lists_the_cell_but_the_one_that_excepts_it():
+    """`step_ms_p95.capture` names the cell in its own EXCEPT: a window of
+    `run_seconds` holds 109 steps of 366 ms, five beyond their p95, and
     `stats.tail` prints no tail with fewer than ten beyond it. The driver's
-    first check of this PR refused the cell for that one name."""
+    first check of PR 28 refused the cell for that one name, and until PR 31
+    the table left it out by a hand edit."""
     import stats
 
     bench = cells.load_benchmark()
     listed = {m["name"]: CELL in m.get("workloads", [CELL])
               for m in bench["per_layer"]}
-    assert not listed.pop("step_ms_p95.capture")
+    excepting = []
     for name, reader in cells.load_readers().items():
-        if "capture" in reader.CELLS and name != "step_ms_p95.capture":
+        if "capture" not in reader.CELLS:
+            continue
+        if CELL in getattr(reader, "EXCEPT", ()):
+            excepting.append(name)
+            assert not listed[name], name
+        else:
             assert listed[name], name
+    assert excepting == ["step_ms_p95.capture"]
     steps = [366.4] * 109
     with pytest.raises(stats.TooFewSamples):
         stats.tail(steps, 0.95, bench["run_seconds"] * 1e3)

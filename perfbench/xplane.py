@@ -15,9 +15,12 @@ check C3 holds it against this one. What is read:
   program on the core;
 - host spans (jax.profiler.TraceAnnotation) by name, from the host plane.
 
-ProfileData gives `start_ns` as a float64 of nanoseconds since the epoch,
-so a start is exact only to 256 ns; durations are exact. Sums and counts
-do not depend on starts; the union and the gaps do, to that resolution.
+ProfileData gives `start_ns` as a float64 of nanoseconds. On this jaxlib it
+counts from the session's opening, whose unix time the artifact's `Task
+Environment` plane carries (`spans.trace_origin_ns` lays it on unix time);
+a jaxlib that counts from the epoch holds a start only to 256 ns. Durations
+are exact. Sums and counts do not depend on starts; the union and the gaps
+do, to that resolution.
 """
 
 from __future__ import annotations
