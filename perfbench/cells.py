@@ -3,7 +3,8 @@
 A cell is one `workloads` entry: a configuration
 (`perfbench/configs/<config>.json`) under a traffic mix
 (`perfbench/traffic/<traffic>.json`). Per-layer metrics are the readers in
-`perfbench/metrics/`, found by listing the directory. Nothing here or in
+`perfbench/metrics/`, found by listing the directory, and so is an end-to-end
+metric added since PR 24, in `perfbench/end_to_end/`. Nothing here or in
 the harness branches on a cell's, configuration's or metric's name: a
 later PR adds a cell by adding data files and an entry.
 """
@@ -134,23 +135,39 @@ def build_mesh(deployment: dict, devices):
     return make_mesh(MeshSpec(**mesh), list(devices)[:deployment["chips"]])
 
 
-def load_readers(here: Path = HERE) -> dict:
-    """name -> module, for every perfbench/metrics/*.py. A file's name may
-    hold dots (`step_ms_p95.capture.py`), so it is loaded by path."""
-    readers = {}
-    for path in sorted((here / "metrics").glob("*.py")):
+READER_ATTRS = ("NAME", "UNIT", "LAYER", "MOVES", "CELLS", "SOURCE", "BETTER",
+                "read")
+END_TO_END_ATTRS = ("NAME", "UNIT", "BOUND", "CELLS", "SOURCE", "BETTER",
+                    "read")
+
+
+def load_modules(folder: Path, attrs: tuple) -> dict:
+    """name -> module, for every <folder>/*.py. A file's name may hold dots
+    (`step_ms_p95.capture.py`), so it is loaded by path."""
+    modules = {}
+    for path in sorted(folder.glob("*.py")):
         spec = importlib.util.spec_from_file_location(
-            "perfbench_metric_" + path.stem.replace(".", "_"), path)
+            f"perfbench_{folder.name}_" + path.stem.replace(".", "_"), path)
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
-        for attr in ("NAME", "UNIT", "LAYER", "MOVES", "CELLS", "SOURCE",
-                     "BETTER", "read"):
+        for attr in attrs:
             if not hasattr(module, attr):
                 raise BenchmarkError(f"{path} exports no {attr}")
         if module.NAME != path.stem:
             raise BenchmarkError(f"{path} names its metric {module.NAME!r}")
-        readers[module.NAME] = module
-    return readers
+        modules[module.NAME] = module
+    return modules
+
+
+def load_readers(here: Path = HERE) -> dict:
+    """The per-layer metrics: one reader file each in perfbench/metrics/."""
+    return load_modules(here / "metrics", READER_ATTRS)
+
+
+def load_end_to_end(here: Path = HERE) -> dict:
+    """The end-to-end metrics that have a file of their own in
+    perfbench/end_to_end/ (those of PR 24 are `harness.end_to_end`'s)."""
+    return load_modules(here / "end_to_end", END_TO_END_ATTRS)
 
 
 def load_peaks(device_kind: str, here: Path = HERE) -> dict:

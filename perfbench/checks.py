@@ -1,4 +1,4 @@
-"""What decides `correct`: seven checks, none of which can race.
+"""What decides `correct`: eight checks, none of which can race.
 
 Every check is a count or a range test made after the window has closed
 (behind a bounded wait where a series has to arrive), or a comparison of
@@ -12,6 +12,8 @@ asks whether a gauge moved, or compares a rate with a rate.
     C2  every capture holds the steps of its window
     C3  the product's summary of a capture equals the plain reducer's
     C4  nothing is left running
+    C5  what the export child wrote beside every capture is there, whole,
+        and says what the bytes say
 
 A check is {"name", "ok", "compared": [{"what", "value", "limit", "ok"}]}:
 every number compared is printed beside its limit, in every run.
@@ -19,9 +21,13 @@ every number compared is printed beside its limit, in every run.
 
 from __future__ import annotations
 
+import glob
+import gzip
+import json
 import math
 import os
 import time
+import zlib
 
 import xplane
 
@@ -49,6 +55,14 @@ S1_MARGIN = 0.05
 # C1's bytes and readability, and to no more than the tolerance on its plane.
 C2_TOLERANCE = 2
 CHILDREN_GONE_S = 120.0
+# C5 (c): the summary rounds an op's time to a microsecond (half of one
+# either way) and sums picoseconds where ProfileData hands whole nanoseconds
+# rounded down (up to one nanosecond an event above the plain sum, C3's
+# bound). So a sound gap less 1 ns an event lies in [-0.0005, 0.0005] ms; the
+# chip's largest readings are in PERF.md section 2. A time one microsecond
+# off in a row of the table fails.
+C5_SUMMARY_MS_LIMIT = 0.001
+DERIVED = (".summary.json", ".trace.json.gz")
 
 
 def part(what: str, value, limit, ok: bool) -> dict:
@@ -121,20 +135,35 @@ def wait_for_telemetry(run) -> dict:
 
 
 def check_s1(run, store: dict) -> dict:
+    """A rate is finite and positive, or it is 0 and true: the shim reports
+    0 once the job has completed no step for two report intervals (a
+    stalled job is what a step-rate trigger has to see), so a 0 passes
+    where the job's own passes show no step completed in the report
+    interval before the sample's stamp, and nowhere else. The chip stalled
+    a steady job for 3.0 s once in 61 runs (PR 32) and for 2.65 and 3.56 s
+    in PR 26 and 29; each failed this part for the product telling the
+    truth."""
     rec = run.record
     rate, p50 = telemetry_names(run.job_id)
-    rates = store.get(rate, {}).get("values", [])
+    series = store.get(rate, {})
+    rates, stamps = series.get("values", []), series.get("timestamps", [])
+    interval = run.cell.config["shim"]["report_interval_s"]
+    true_zeros = [
+        t for v, t in zip(rates, stamps)
+        if v == 0 and not steps_between(run.steps, t / 1e3 - interval, t / 1e3)]
     good = [v for v in rates if math.isfinite(v) and v > 0]
     step_ms = [ms for _, ms in run.steps]
     lo, hi = min(step_ms) * (1 - S1_MARGIN), max(step_ms) * (1 + S1_MARGIN)
     p50s = store.get(p50, {}).get("values", [])
     outside = [v for v in p50s if not lo <= v <= hi]
-    rec["telemetry_stamps_ms"] = store.get(rate, {}).get("timestamps", [])
+    rec["telemetry_stamps_ms"] = stamps
     return check("S1", [
         part(f"samples of {rate} stamped since the window opened", len(rates),
              f">= {rec['s1_needed']}", len(rates) >= rec["s1_needed"]),
-        part("of them finite and positive", len(good), f"== {len(rates)}",
-             len(good) == len(rates)),
+        part(f"of them finite and positive, or 0 with no step of the job in "
+             f"the {interval:g} s before the stamp ({len(true_zeros)})",
+             len(good) + len(true_zeros), f"== {len(rates)}",
+             len(good) + len(true_zeros) == len(rates)),
         part(f"samples of {p50} outside the job's own step times "
              f"[{lo:.2f}, {hi:.2f}] ms", len(outside) if p50s else "no samples",
              "== 0", bool(p50s) and not outside),
@@ -221,7 +250,7 @@ def check_captures(run) -> list:
     c1, c2, c3 = [], [], []
     module = run.cell.job["step_module"]
     acked = [c for c in run.record["captures"] if c["cli_rc"] == 0]
-    last_bytes = None
+    last_bytes = last_whole = summarized = None
     for cap in acked:
         label = f"capture {cap['k']}"
         if not cap["ok"]:
@@ -244,6 +273,8 @@ def check_captures(run) -> list:
             c1.append(part(f"{label} readable by ProfileData", repr(e),
                            "readable", False))
             continue
+        cap["xplane_path"] = path  # whole and readable: C5 owes its files
+        last_whole = (cap, profile)
         start_t, stop_t = capture_window(manifest)
         inside = steps_between(run.steps, start_t, stop_t)
         cap["steps_in_window"] = inside
@@ -272,6 +303,7 @@ def check_captures(run) -> list:
                 and abs(seen - inside) <= C2_TOLERANCE))
         if len(cap.get("device_ns", [])) == run.cell.chips:
             last_bytes = (label, data, profile)
+            summarized = last_whole
     if last_bytes is None:
         c3.append(part("a capture to summarize", None,
                        "one whole capture with device events", False))
@@ -286,11 +318,177 @@ def check_captures(run) -> list:
             f"dynolog_tpu.trace and the plain reducer, of {len(plain_ops)}",
             differ[:3] if differ else 0, "== 0, of > 0",
             bool(plain_ops) and not differ))
+    # where C3 had no capture (and fails), C5 reads the last whole one
+    run.summarized = summarized or last_whole
     if not acked:
         c1.append(part("captures acknowledged by the CLI", 0, "> 0", False))
     return [check("C1", c1), check("C2", c2 or [
         part("captures whose window could be read", 0, "> 0", False)]),
         check("C3", c3)]
+
+
+# ------------------------------------------------------------------ C5
+
+
+def read_derived(cap: dict) -> None:
+    """One look, after the convert children are gone, at what the export
+    child wrote beside a capture's .xplane.pb: `cap["derived"]` (mtime and
+    size of each file, or None; what ends in .tmp beside them) and, where
+    both are there, `cap["derived_ms"]`: the later mtime - the spawn of the
+    capture's `dyno gputrace`, the origin of `capture_ms`."""
+    path = cap.get("xplane_path")
+    if path is None and cap["ok"]:
+        path = xplane.find_xplane(cap["manifest"]["trace_dir"])
+    if path is None:
+        return
+    stem = path[:-len(".xplane.pb")]
+    found = {}
+    for ext in DERIVED:
+        try:
+            st = os.stat(stem + ext)
+            found[ext] = {"path": stem + ext, "mtime": st.st_mtime,
+                          "bytes": st.st_size}
+        except OSError:
+            found[ext] = None
+    cap["derived"] = dict(found, tmp=sorted(
+        os.path.basename(p)
+        for p in glob.glob(os.path.join(os.path.dirname(path), "*.tmp"))))
+    if cap["ok"] and all(found.values()):
+        cap["derived_ms"] = (max(f["mtime"] for f in found.values())
+                             - cap["spawn_t"]) * 1e3
+
+
+def gunzip_to_end(path: str) -> int | str:
+    """Streams the file through gzip to its end, where Python holds the
+    trailer's CRC and length against what it read: the bytes it held, or
+    the sentence that says where it broke."""
+    total = 0
+    try:
+        with gzip.open(path, "rb") as f:
+            while chunk := f.read(1 << 20):
+                total += len(chunk)
+    except (OSError, EOFError, zlib.error) as e:
+        return f"{type(e).__name__}: {e}"
+    return total
+
+
+def chrome_events(doc: dict) -> dict:
+    """(process name, thread name) -> complete events, of a Chrome trace:
+    a plane is a process, a line a thread, both named by metadata events."""
+    process, thread, count = {}, {}, {}
+    for ev in doc["traceEvents"]:
+        if ev.get("ph") == "M" and ev.get("name") == "process_name":
+            process[ev["pid"]] = ev["args"]["name"]
+        elif ev.get("ph") == "M" and ev.get("name") == "thread_name":
+            thread[ev["pid"], ev["tid"]] = ev["args"]["name"]
+        elif ev.get("ph") == "X":
+            key = (ev["pid"], ev["tid"])
+            count[key] = count.get(key, 0) + 1
+    out: dict = {}
+    for (pid, tid), n in count.items():
+        key = (process.get(pid), thread.get((pid, tid)))
+        out[key] = out.get(key, 0) + n
+    return out
+
+
+def compare_top_ops(rows: list, plain_ops: dict) -> tuple:
+    """C5 (c). rows: the summary's `top_ops`; plain_ops: group ->
+    [total_ns, count] from the plain reducer over the same planes. Returns
+    (the groups that differ, with both readings; the largest
+    |total_ms - plain| less 1 ns an event, in ms, over the groups both
+    have)."""
+    product = {row["op"]: (row["total_ms"], row["count"]) for row in rows}
+    differ, worst = [], 0.0
+    for op in sorted(set(product) | set(plain_ops)):
+        ms, n = product.get(op, (None, None))
+        ns, m = plain_ops.get(op, (None, None))
+        if ms is None or ns is None:
+            differ.append((op, (ms, n), (ns, m)))
+            continue
+        gap = abs(ms - ns / 1e6) - 1e-6 * m
+        worst = max(worst, gap)
+        if n != m or gap > C5_SUMMARY_MS_LIMIT:
+            differ.append((op, (ms, n), (ns, m)))
+    return differ, worst
+
+
+def check_c5(run) -> dict:
+    """After the convert children are gone. (a) every capture whose
+    artifact C1 found whole and readable has both derived files and no
+    .tmp beside them; (b) every .trace.json.gz gunzips to its end, and the
+    one of the capture C3 summarized holds, for each device plane, as many
+    events on "XLA Ops" as the plain reducer counts there; (c) that
+    capture's .summary.json, `top_ops`, against the plain reducer."""
+    owed = [c for c in run.record["captures"] if "xplane_path" in c]
+    lacking, broken, gz_bytes = [], [], 0
+    for cap in owed:
+        derived = cap.get("derived") or {}
+        missing = [ext for ext in DERIVED if not derived.get(ext)]
+        if missing or derived.get("tmp"):
+            lacking.append((cap["k"], missing, derived.get("tmp")))
+        trace = derived.get(DERIVED[1])
+        if trace:
+            held = gunzip_to_end(trace["path"])
+            if isinstance(held, int) and held > 0:
+                gz_bytes += held
+                trace["json_bytes"] = held
+            else:
+                broken.append((cap["k"], held))
+    parts = [
+        part(f"captures with a whole artifact, of {len(owed)}, that lack a "
+             "derived file (.summary.json, .trace.json.gz) or hold a .tmp",
+             lacking[:3] if lacking else 0, "== 0, of > 0",
+             bool(owed) and not lacking),
+        part(".trace.json.gz files that do not gunzip to their end (CRC, "
+             f"length); the others hold {gz_bytes} bytes of JSON",
+             broken[:3] if broken else 0, "== 0", not broken)]
+    if run.summarized is None:
+        parts.append(part("a capture whose derived files to read", None,
+                          "one whole capture", False))
+        return check("C5", parts)
+    cap, profile = run.summarized
+    label = f"capture {cap['k']}"
+    derived = cap.get("derived") or {}
+    # the device planes C1 found events on, each with its plain count
+    planes = {}
+    for i in range(run.cell.chips):
+        plane = xplane.find_plane(profile, xplane.device_plane_name(i))
+        reduced = xplane.reduce_plane(plane)
+        if reduced is not None:
+            planes[plane] = reduced.events
+    try:
+        with gzip.open(derived[DERIVED[1]]["path"], "rt") as f:
+            events = chrome_events(json.load(f))
+        for plane, want in planes.items():
+            got = events.get((plane.name, xplane.XLA_OPS), 0)
+            parts.append(part(
+                f"{label} .trace.json.gz events of {plane.name} "
+                f'"{xplane.XLA_OPS}"', got, f"== {want}", got == want))
+        parts.append(part(
+            f"{label} .trace.json.gz parses; complete events in all",
+            sum(events.values()), "> 0", sum(events.values()) > 0))
+    except Exception as e:  # noqa: BLE001 - unreadable IS the finding
+        parts.append(part(f"{label} .trace.json.gz parses as a Chrome trace",
+                          repr(e), "parses", False))
+    try:
+        with open(derived[DERIVED[0]]["path"]) as f:
+            rows = json.load(f)["top_ops"]
+        plain_ops = xplane.reduce_groups(profile, list(planes))
+        differ, worst = compare_top_ops(rows, plain_ops)
+        parts.append(part(
+            f"{label} .summary.json top_ops rows whose (total_ms, count) "
+            f"differ from the plain reducer's, of {len(plain_ops)} over "
+            f"{len(planes) or 'all'} planes",
+            differ[:3] if differ else 0, "== 0, of > 0",
+            bool(plain_ops) and not differ))
+        parts.append(part(
+            f"{label} largest |total_ms - plain| less 1 ns an event, ms",
+            worst, f"<= {C5_SUMMARY_MS_LIMIT}",
+            worst <= C5_SUMMARY_MS_LIMIT))
+    except Exception as e:  # noqa: BLE001 - unreadable IS the finding
+        parts.append(part(f"{label} .summary.json parses and has top_ops",
+                          repr(e), "parses", False))
+    return check("C5", parts)
 
 
 # ------------------------------------------------------------------ C4

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Regenerates BENCHMARK.json's `per_layer` table from perfbench/metrics/*.py.
+"""Regenerates BENCHMARK.json's `per_layer` table from perfbench/metrics/*.py
+and appends to `end_to_end` what perfbench/end_to_end/*.py adds.
 
     python3 perfbench/gen_benchmark.py
 
@@ -15,7 +16,10 @@ reader lists only cells in which it always has a value.
 An entry the table already holds keeps its place and its content; a cell
 that no entry's list names yet is new, and joins the list of every reader
 of its kind. Readers the table does not hold are appended after the
-others, in the order of their names. Everything else in BENCHMARK.json is
+others, in the order of their names. An end-to-end metric added since PR 24
+is a file under perfbench/end_to_end/ (NAME, UNIT, BETTER, BOUND, SOURCE,
+CELLS, `read`): its entry is appended the same way, and every entry
+`end_to_end` holds stays as it stands. Everything else in BENCHMARK.json is
 left as it stands. A later PR adds a metric by adding a file, or a cell by
 adding an entry under `workloads`, and running this.
 """
@@ -28,18 +32,39 @@ import sys
 import cells
 
 
+def with_workloads(entry: dict, module, kinds: dict) -> dict:
+    """`entry` with the cells of the module's kinds, less those it excepts,
+    unless that is every cell there is or will be."""
+    excepted = getattr(module, "EXCEPT", ())
+    if set(module.CELLS) != set(cells.TRAFFIC_KINDS) or excepted:
+        entry["workloads"] = [
+            w for w, kind in kinds.items()
+            if kind in module.CELLS and w not in excepted]
+    return entry
+
+
 def entry_of(reader, kinds: dict) -> dict:
     """The table entry that one reader file generates; `kinds` is cell
     name -> traffic kind, in the order of `workloads`."""
-    entry = {"name": reader.NAME, "unit": reader.UNIT, "better": reader.BETTER,
-             "source": reader.SOURCE, "layer": reader.LAYER,
-             "moves": reader.MOVES}
-    excepted = getattr(reader, "EXCEPT", ())
-    if set(reader.CELLS) != set(cells.TRAFFIC_KINDS) or excepted:
-        entry["workloads"] = [
-            w for w, kind in kinds.items()
-            if kind in reader.CELLS and w not in excepted]
-    return entry
+    return with_workloads(
+        {"name": reader.NAME, "unit": reader.UNIT, "better": reader.BETTER,
+         "source": reader.SOURCE, "layer": reader.LAYER,
+         "moves": reader.MOVES}, reader, kinds)
+
+
+def end_to_end_entry_of(metric, kinds: dict) -> dict:
+    return with_workloads(
+        {"name": metric.NAME, "unit": metric.UNIT, "better": metric.BETTER,
+         "bound": metric.BOUND, "source": metric.SOURCE}, metric, kinds)
+
+
+def end_to_end(bench: dict, metrics: dict | None = None) -> list:
+    """The held entries as they stand, then the files' that it lacks."""
+    metrics = cells.load_end_to_end() if metrics is None else metrics
+    kinds = cell_kinds(bench)
+    new = sorted(set(metrics) - {m["name"] for m in bench["end_to_end"]})
+    return bench["end_to_end"] + [
+        end_to_end_entry_of(metrics[name], kinds) for name in new]
 
 
 def cell_kinds(bench: dict) -> dict:
@@ -69,11 +94,13 @@ def per_layer(bench: dict, readers: dict | None = None) -> list:
 def main() -> int:
     path = cells.ROOT / "BENCHMARK.json"
     bench = cells.load_benchmark()
+    bench["end_to_end"] = end_to_end(bench)
     bench["per_layer"] = per_layer(bench)
     with open(path, "w") as f:
         json.dump(bench, f, indent=2)
         f.write("\n")
-    print(f"{path}: {len(bench['per_layer'])} per-layer metrics")
+    print(f"{path}: {len(bench['end_to_end'])} end-to-end and "
+          f"{len(bench['per_layer'])} per-layer metrics")
     return 0
 
 

@@ -9,8 +9,10 @@ are copied from chip_smoke.py, which ran on the chip in PR 21.
     weights from the seed -> check J's reference -> optimizer state ->
     step compiled or loaded -> shim registered -> warm steps (and one warm
     capture) -> THE WINDOW -> drain -> checks S1 S2 C1 C2 C3
+    -> the shim stops and its convert children are waited for; what they
+    wrote is read (derived_ms, check C5)
     -> in traced runs the journals (dyno selftrace, the shim's counters)
-    -> teardown (check C4) -> one JSON line.
+    -> teardown: the daemon stops (check C4) -> one JSON line.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import json
+import math
 import os
 import select
 import shutil
@@ -276,6 +279,10 @@ class Run:
         self.steps: list = []  # (end wall time, ms) of every step of the run
         self.parts: list = []  # [dispatch, device wait, shim] ms, alongside
         self.cache = {"hits": 0, "misses": 0}
+        self.settled = False
+        self.children_gone_s: float | None = None
+        self.summarized = None  # (capture, profile) check C3 used
+        self.c5: dict | None = None
 
     # ------------------------------------------------------------ set-up
 
@@ -588,16 +595,41 @@ class Run:
         rec["selftrace_oldest_ms"] = selftrace.oldest_ms(rec["selftrace"])
         self.phase("journals_s", t0)
 
-    def teardown(self) -> None:
-        """Stops the shim, waits for its convert children, stops the daemon;
-        check C4 is whether each went by itself in its time."""
+    def settle(self) -> None:
+        """Stops the shim and waits for its convert children, once, with
+        the daemon still up: a child hands its `trace.convert` span to the
+        daemon as it exits, so the journal is read after this. Then, in
+        capture cells, one look at what the children wrote: the files'
+        mtimes and sizes (`derived_ms`) and check C5."""
+        if self.settled:
+            return
+        self.settled = True
         t0 = time.time()
         if self.client is not None:
             self.client.stop()
-        gone = checks.wait_children_gone(
+        self.children_gone_s = checks.wait_children_gone(
             skip={self.daemon.proc.pid} if self.daemon else set())
+        self.phase("children_s", t0)
+        if self.cell.kind == "capture" and "window_end" in self.record:
+            t0 = time.time()
+            rec = self.record
+            for cap in rec["captures"]:
+                checks.read_derived(cap)
+            rec["derived_ms"] = [
+                c["derived_ms"] for c in rec["captures"] if "derived_ms" in c]
+            self.c5 = checks.check_c5(self)
+            self.phase("derived_s", t0)
+
+    def teardown(self) -> None:
+        """Stops the daemon, after the shim and its convert children;
+        check C4 is whether each went by itself in its time."""
+        t0 = time.time()
+        self.settle()
         daemon_clean = self.daemon.stop() if self.daemon else True
-        self.record["checks"].append(checks.check_c4(gone, daemon_clean))
+        self.record["checks"].append(
+            checks.check_c4(self.children_gone_s, daemon_clean))
+        if self.c5 is not None:
+            self.record["checks"].append(self.c5)
         self.phase("teardown_s", t0)
 
 
@@ -637,16 +669,35 @@ def result_line(run: Run, bench: dict, readers: dict) -> dict:
         device["window_s"] = rec["trace"]["window_s"]
         line["breakdown"] = {"device_ops": rec["trace"]["groups"],
                              "idle_gaps": rec["trace"]["idle_gaps"]}
+    line["compared"] = compared(rec)
     return line
+
+
+def compared(rec: dict) -> list:
+    """Every number `correct` compared, `[check, what, number, limit, ok]`,
+    those that failed last: what is kept of a run that is not correct is the
+    end of its last line and of its standard error."""
+    def plain(value):
+        # NaN and Infinity are not JSON: a reading that is one goes as text
+        if isinstance(value, float) and not math.isfinite(value):
+            return repr(value)
+        return value
+
+    rows = [[c["name"], p["what"], plain(p["value"]), p["limit"], p["ok"]]
+            for c in rec["checks"] for p in c["compared"]]
+    return sorted(rows, key=lambda row: not row[4])
 
 
 def end_to_end(rec: dict) -> dict:
     """The end-to-end metrics, from all the steps and all the captures of
     the window. A tail is not printed where a window of this length holds,
-    at the median step, fewer than ten samples beyond it."""
+    at the median step, fewer than ten samples beyond it. A metric added
+    since PR 24 is its own file under perfbench/end_to_end/."""
     out = {"setup_s": rec["setup_s"],
            "step_ms_p50": stats.median(rec["step_ms"]),
            "step_ms_p95": None, "capture_ms_p50": None}
+    for name, metric in cells.load_end_to_end().items():
+        out[name] = metric.read(rec)
     try:
         out["step_ms_p95"] = stats.tail(
             rec["step_ms"], 0.95, rec["window_s"] * 1e3)
@@ -674,6 +725,7 @@ def measure(run: Run) -> None:
         run.warm_up()
         run.window()
         run.drain_and_check()
+        run.settle()
         if run.trace:
             run.read_journals()
     finally:
@@ -702,7 +754,11 @@ def main(argv=None, t_process: float | None = None) -> int:
         print(f"perfbench: no result: {e}", file=sys.stderr, flush=True)
         return 1
     report(run, line)
-    print(json.dumps(line), flush=True)
+    print(json.dumps(line, default=str), flush=True)
+    for name, what, value, limit, ok in line["compared"]:
+        print(f"check {name} {'ok  ' if ok else 'FAIL'} {what}: {value} "
+              f"(limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 
@@ -729,13 +785,10 @@ def report_journals(rec: dict) -> None:
 
 
 def report(run: Run, line: dict) -> None:
-    """Everything that is not the last line: to earlier lines and to
-    perfbench/out/<workload>-<seed>.json. The captures' artifacts go."""
+    """Everything that is not the last line or a compared number (those go
+    into the line and, after it, to standard error): to earlier lines and
+    to perfbench/out/<workload>-<seed>.json. The captures' artifacts go."""
     rec = run.record
-    for check in rec["checks"]:
-        for part in check["compared"]:
-            say(f"check {check['name']} {'ok  ' if part['ok'] else 'FAIL'} "
-                f"{part['what']}: {part['value']} (limit {part['limit']})")
     say(f"warm steps ms: {[round(x, 1) for x in rec['warm_step_ms']]}; daemon "
         f"CPU in the window {rec.get('daemon_cpu_s')} s; longest passes "
         f"[ms, ended s into the window, [dispatch, device wait, shim] ms]: {rec.get('longest_passes')}")
@@ -755,6 +808,10 @@ def report(run: Run, line: dict) -> None:
         say("collect_ms: " + " ".join(
             str(c["manifest"]["timing"].get("collect_ms"))
             for c in rec["captures"] if c["ok"]))
+        say(f"derived_ms ({len(rec.get('derived_ms', []))} of "
+            f"{len(rec['capture_ms'])} ok captures have both derived "
+            "files): " + " ".join(
+                f"{x:.0f}" for x in rec.get("derived_ms", [])))
     slim = dict(rec, result=line)
     path = OUT / f"{run.cell.name}-{run.seed}-t{int(run.trace)}.json"
     with open(path, "w") as f:

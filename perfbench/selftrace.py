@@ -25,6 +25,7 @@ TPU_TICK = "collector.tpu_monitor.tick"
 KERNEL_TICK = "collector.kernel_monitor.tick"
 HANDOFF = "ipc.config_handoff"
 CAPTURE_VERB = "rpc.setKinetOnDemandRequest"  # what `dyno gputrace` sends
+CONVERT = "trace.convert"  # the export child's, handed over as it exits
 DAEMON_PREFIXES = ("collector.", "rpc.", "ipc.")
 # The IPC thread's "tick" is a one-second slice of blocking in poll(2), back
 # to back: it lies over every pass and says nothing about any.
@@ -78,6 +79,17 @@ def window_ms(run: dict, name: str) -> list | None:
 def window_median_ms(run: dict, name: str) -> float | None:
     durations = window_ms(run, name)
     return stats.median(durations) if durations else None
+
+
+def convert_starts_us(run: dict) -> dict | None:
+    """The request's trace id -> where its export child's `trace.convert`
+    span began, unix microseconds; None where the journal does not reach
+    back to the window's opening."""
+    found = journal(run)
+    if found is None:
+        return None
+    return {s["args"].get("trace_id"): s["ts"] for s in found["spans"]
+            if s["name"] == CONVERT}
 
 
 def pass_bounds_us(run: dict, longest: list) -> tuple:

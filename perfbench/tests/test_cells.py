@@ -89,3 +89,36 @@ def test_s1_counts_only_slots_in_which_the_job_stepped():
     # capture traffic: slots as long as interval + longest capture
     assert checks.samples_needed(ends, 100.0, 40.0, 1.0 + 1.4) == 13
     assert checks.samples_needed([], 100.0, 40.0, 1.0) == 1
+
+
+def test_s1_passes_a_zero_rate_only_under_a_stall_of_the_job_itself():
+    """The shim reports 0 once the job has completed no step for two report
+    intervals. The job below steps every 134 ms but for 3.0 s from t =
+    119.14 (the chip's, PR 32); the sample stamped at 121.16 is 0."""
+    import types
+
+    import checks
+
+    ends = [100.0 + 0.134 * i for i in range(1, 299)]
+    steps = [(t, 134.0) for t in ends if not 119.14 < t < 122.15]
+    stamps = [int((100.0 + k + 0.16) * 1e3) for k in range(1, 41)]
+    rates = [7.46] * 40
+    rates[20] = 0.0  # the sample at 121.16 s
+    run = types.SimpleNamespace(
+        record={"s1_needed": 35}, job_id=7, steps=steps,
+        cell=types.SimpleNamespace(config={"shim": {"report_interval_s": 1.0}}))
+    store = {"job7.steps_per_sec": {"values": rates, "timestamps": stamps},
+             "job7.step_time_p50_ms": {"values": [134.0] * 39,
+                                       "timestamps": stamps[:39]}}
+    check = checks.check_s1(run, store)
+    assert check["ok"], check
+    assert check["compared"][1]["value"] == 40
+    assert "(1)" in check["compared"][1]["what"]
+    # the same 0 while the job was stepping is a lie, and fails
+    rates[20], rates[30] = 7.46, 0.0
+    check = checks.check_s1(run, store)
+    assert not check["ok"] and check["compared"][1]["value"] == 39
+    # a negative or a NaN rate fails wherever it falls
+    for bad in (-1.0, float("nan")):
+        rates[30], rates[20] = 7.46, bad
+        assert not checks.check_s1(run, store)["ok"]

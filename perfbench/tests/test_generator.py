@@ -13,8 +13,22 @@ import cells
 import gen_benchmark
 
 READERS = cells.load_readers()
-# The table as PR 30 left it (the ledger's accepted benchmark before PR 31),
-# kept here and not asked of git: the driver's checkout is no repository.
+END_TO_END = cells.load_end_to_end()
+# The tables as PR 31 left them (the ledger's accepted benchmark before
+# PR 32), kept here and not asked of git: the driver's checkout is no
+# repository. PR 30's 24 in the order of their files, then PR 31's nine by
+# name.
+ACCEPTED_END_TO_END = (
+    {"name": "step_ms_p50", "unit": "ms", "better": "lower", "bound": 0.01,
+     "source": "host_clock"},
+    {"name": "step_ms_p95", "unit": "ms", "better": "lower", "bound": 0.01,
+     "source": "host_clock", "workloads": ["olmo2-1b.steady"]},
+    {"name": "capture_ms_p50", "unit": "ms", "better": "lower", "bound": 0.06,
+     "source": "host_clock", "workloads": [
+         "olmo2-1b.capture", "olmo2-7b-2l.capture",
+         "olmo2-13b-v5e4.capture"]},
+    {"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.25,
+     "source": "host_clock"})
 ACCEPTED = (
     "capture_ms_max", "collect_ms", "daemon_cpu_pct", "device_idle_pct",
     "pickup_ms", "profiler_start_ms", "step_ms_p95.capture", "top_op_share",
@@ -25,7 +39,11 @@ ACCEPTED = (
     "xspan.trace_clock_skew_us", "xspan.xla_collective_pct",
     "xspan.xplane_plane_skew_pct", "xspan.xspace_metadata_pct",
     "xspan.xstart_cpu_pct", "xspan.xstop_cpu_pct",
-    "xspan.xstop_others_cpu_ms")
+    "xspan.xstop_others_cpu_ms",
+    "daemon_rss_mb", "first_capture_ms", "ipc_handoff_ms",
+    "ipc_timeout_wakeup_pct", "kernel_tick_ms_p50",
+    "longest_pass_tick_overlap_ms", "rpc_verb_ms", "tpu_tick_ms_max",
+    "tpu_tick_ms_p50")
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +116,63 @@ def test_a_reader_whose_file_went_loses_its_entry(bench):
     fewer = {n: r for n, r in READERS.items() if n != "write_ms"}
     names = [m["name"] for m in gen_benchmark.per_layer(bench, fewer)]
     assert "write_ms" not in names and len(names) == len(READERS) - 1
+
+
+# ------------------------------------------------------------ end to end
+
+
+def test_the_accepted_end_to_end_entries_stand_as_they_were(bench):
+    held = bench["end_to_end"][:len(ACCEPTED_END_TO_END)]
+    assert held == list(ACCEPTED_END_TO_END)
+    # key for key in the same order: byte for byte once dumped
+    assert [list(m) for m in held] == [list(m) for m in ACCEPTED_END_TO_END]
+
+
+@pytest.mark.parametrize("name", sorted(END_TO_END))
+def test_each_later_end_to_end_entry_is_what_its_file_generates(bench, name):
+    table = {m["name"]: m for m in bench["end_to_end"]}
+    metric = END_TO_END[name]
+    assert table[name] == gen_benchmark.end_to_end_entry_of(
+        metric, gen_benchmark.cell_kinds(bench))
+    assert list(table[name])[:5] == ["name", "unit", "better", "bound", "source"]
+    assert 0.01 <= metric.BOUND <= 0.25
+    assert metric.SOURCE in ("host_clock", "device_trace")
+    # every cell that reports it is of a kind the file reads
+    kinds = gen_benchmark.cell_kinds(bench)
+    assert all(kinds[w] in metric.CELLS
+               for w in table[name].get("workloads", kinds))
+
+
+def test_the_generator_appends_end_to_end_files_and_keeps_what_is_held(bench):
+    names = [m["name"] for m in bench["end_to_end"]]
+    later = names[len(ACCEPTED_END_TO_END):]
+    assert later == sorted(END_TO_END) and "derived_ms_p50" in later
+    assert gen_benchmark.end_to_end(bench) == bench["end_to_end"]
+    before = dict(bench, end_to_end=list(ACCEPTED_END_TO_END))
+    assert gen_benchmark.end_to_end(before) == bench["end_to_end"]
+    assert gen_benchmark.end_to_end(before, {}) == list(ACCEPTED_END_TO_END)
+    # a held entry stays as it stands, whatever its file would say today
+    class Tighter:
+        NAME, UNIT, BETTER, SOURCE = "derived_ms_p50", "s", "lower", "host_clock"
+        BOUND, CELLS = 0.01, ("capture",)
+
+    assert gen_benchmark.end_to_end(
+        bench, {"derived_ms_p50": Tighter}) == bench["end_to_end"]
+
+
+def test_every_per_layer_entry_moves_a_metric_its_cells_report(bench):
+    kinds = gen_benchmark.cell_kinds(bench)
+    reports = {m["name"]: set(m.get("workloads", kinds))
+               for m in bench["end_to_end"]}
+    for entry in bench["per_layer"]:
+        assert set(entry.get("workloads", kinds)) <= reports[entry["moves"]], (
+            entry["name"])
+
+
+def test_the_steady_cell_reports_nothing_of_the_derive_layer(bench):
+    steady = cells.load_cell("olmo2-1b.steady")
+    assert "derived_ms_p50" not in cells.metric_names(
+        bench, steady, "end_to_end")
+    due = cells.metric_names(bench, steady, "per_layer")
+    table = {m["name"]: m for m in bench["per_layer"]}
+    assert not [n for n in due if table[n]["layer"] == "derive"]

@@ -4,6 +4,7 @@ sound, `correct` is true; with the timed path broken underneath, false."""
 import jax.numpy as jnp
 
 import cells
+import harness
 import rehearsal
 import selftrace
 
@@ -18,7 +19,11 @@ def test_sound_steady_run_is_correct(monkeypatch, tmp_path):
     assert [c["name"] for c in run.record["checks"]] == ["J", "S1", "S2", "C4"]
     assert line["failed"] == 0 and line["attempted"] == len(run.record["step_ms"])
     assert run.record["setup_s"] > 0 and sum(run.record["step_ms"]) > 3000
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device",
+                          "compared"]
+    # every number compared, beside its limit, in the line itself
+    assert [row[0] for row in line["compared"]].count("J") == 2
+    assert all(row[4] for row in line["compared"])
     # the journals are asked for in traced runs alone; the RSS in every run
     assert "selftrace" not in run.record and "shim_counters" not in run.record
     assert run.record["daemon_rss_kb"] > 1000
@@ -31,6 +36,8 @@ def test_step_telemetry_lost_is_not_correct(monkeypatch, tmp_path):
 
     run, line = rehearsal.rehearse(monkeypatch, tmp_path, "steady", broken=broken)
     assert failed(run) == ["S1"] and line["correct"] is False
+    # what failed comes last: the end of the line is what a record keeps
+    assert line["compared"][-1][0] == "S1" and not line["compared"][-1][4]
 
 
 def test_step_that_leaves_out_half_the_batch_is_not_correct(monkeypatch, tmp_path):
@@ -46,12 +53,14 @@ def test_step_that_leaves_out_half_the_batch_is_not_correct(monkeypatch, tmp_pat
 
 def test_capture_run_drives_every_check(monkeypatch, tmp_path):
     """A CPU has no /device:TPU:0 plane, so C1-C3 read false here; the run
-    still has to reach its end, count its captures and leave nothing."""
+    still has to reach its end, count its captures and leave nothing. C5
+    holds: every capture has both derived files, whole, and the summary of
+    the last says what the plain reducer says of its host planes."""
     run, line = rehearsal.rehearse(
         monkeypatch, tmp_path, "capture-pull", seconds=3.0, trace=True)
     names = [c["name"] for c in run.record["checks"]]
-    assert names == ["J", "S1", "S2", "C1", "C2", "C3", "C4"]
-    assert "C4" not in failed(run) and "J" not in failed(run)
+    assert names == ["J", "S1", "S2", "C1", "C2", "C3", "C4", "C5"]
+    assert failed(run) == ["C1", "C2", "C3"]
     assert len(run.record["captures"]) >= 2
     assert all(c["ok"] for c in run.record["captures"])
     assert line["correct"] is False
@@ -73,7 +82,42 @@ def test_capture_run_drives_every_check(monkeypatch, tmp_path):
     readers = cells.load_readers()
     for name in ("tpu_tick_ms_p50", "kernel_tick_ms_p50", "rpc_verb_ms",
                  "ipc_handoff_ms", "ipc_timeout_wakeup_pct", "daemon_rss_mb",
-                 "first_capture_ms", "longest_pass_tick_overlap_ms"):
+                 "first_capture_ms", "longest_pass_tick_overlap_ms",
+                 "convert_ms", "convert_lag_ms", "convert_alive_max",
+                 "derived_bytes"):
         assert readers[name].read(rec) is not None, name
+    # the children were waited for before the journal was read: every
+    # capture's conversion is in it, the warm one's too, under its trace id
+    assert count[selftrace.CONVERT] == captures
+    begun = selftrace.convert_starts_us(rec)
+    for cap in rec["captures"]:
+        assert cap["manifest"]["trace_ctx"].split("/")[0] in begun
+        assert cap["derived_ms"] > cap["capture_ms"]
+        assert cap["derived"]["tmp"] == []
+    assert len(rec["derived_ms"]) == len(rec["captures"])
+    assert harness.end_to_end(rec)["derived_ms_p50"] > (
+        harness.end_to_end(rec)["capture_ms_p50"])
+    assert 0 < readers["convert_lag_ms"].read(rec) < 5000
     assert readers["first_capture_ms"].read(rec) == (
         rec["warm_capture"][0]["capture_ms"])
+
+
+def test_an_export_child_that_fails_is_not_correct(monkeypatch, tmp_path):
+    """The control of C5: `DYNO_FAILPOINTS` names `trace.convert`, so every
+    export child dies as a killed one does, before it writes anything. The
+    captures themselves are what they were (C1-C3 read as in the sound run
+    above, C4 holds: the children are gone); C5 alone is added."""
+    monkeypatch.setenv("DYNO_FAILPOINTS", "trace.convert=throw")
+    run, line = rehearsal.rehearse(
+        monkeypatch, tmp_path, "capture-pull", seconds=3.0)
+    assert failed(run) == ["C1", "C2", "C3", "C5"]
+    assert line["correct"] is False
+    captures = run.record["captures"]
+    assert len(captures) >= 2 and all(c["ok"] for c in captures)
+    assert all("derived_ms" not in c for c in captures)
+    assert all(c["derived"][".summary.json"] is None for c in captures)
+    metrics = harness.end_to_end(run.record)
+    assert metrics["derived_ms_p50"] is None and metrics["capture_ms_p50"] > 0
+    c5 = next(c for c in run.record["checks"] if c["name"] == "C5")
+    assert not c5["compared"][0]["ok"]
+    assert line["compared"][-1][0] == "C5"

@@ -5,7 +5,9 @@ dynolog_tpu.trace is imported, because that summarizer is the product and
 check C3 holds it against this one. What is read:
 
 - per-op total device time and event count on a device plane's "XLA Ops"
-  line (the synchronous ops; "Async XLA Ops" would double count);
+  line (the synchronous ops; "Async XLA Ops" would double count), and the
+  same by kind of op over several planes (check C5 holds the summary the
+  export child writes against it);
 - busy time as the union of those events' intervals, the span from the
   first op's start to the last op's end, and the idle share 1 - busy/span;
 - the gaps between ops, longest first;
@@ -146,6 +148,23 @@ def reduce_plane(plane, keep_gaps: int = 5) -> PlaneReduction | None:
     out.busy_ns += cur_end - cur_start
     out.span_ns = cur_end - out.first_ns
     out.gaps = sorted(gaps, reverse=True)[:keep_gaps]
+    return out
+
+
+def reduce_groups(profile, planes: list) -> dict:
+    """op group -> [total_ns, count] over the "XLA Ops" lines of `planes`,
+    instances of a kind together: the op table an operator ranks from.
+    Where `planes` is empty (a trace without a device plane: the CPU
+    rehearsal), over every line of every plane, as the summary's own
+    docstring has it: "device planes when present, host planes otherwise"."""
+    lines = [find_line(p, XLA_OPS) for p in planes] or [
+        line for p in profile.planes for line in p.lines]
+    out: dict = {}
+    for line in lines:
+        for ev in _events(line):
+            entry = out.setdefault(group_key(op_key(ev.name)), [0.0, 0])
+            entry[0] += ev.duration_ns
+            entry[1] += 1
     return out
 
 
