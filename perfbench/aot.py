@@ -35,13 +35,15 @@ FIT_BYTES = 14.0e9
 CANDIDATES = ((1, 4096), (2, 2048), (1, 2048))
 
 
-def compile_step(job: dict, batch: int, seq: int, sharding):
+def compile_step(config: dict, batch: int, seq: int, sharding):
     import jax
     import jax.numpy as jnp
 
+    import cells
     import harness
-    import reference
 
+    job = config["job"]
+    reference = cells.load_reference(config)
     cfg = harness.transformer_config(job)
     from dynolog_tpu.models.train import make_optimizer, make_train_step
 
@@ -75,7 +77,7 @@ def main(argv) -> int:
     for batch, seq in candidates or CANDIDATES:
         t0 = time.time()
         try:
-            mem = compile_step(config["job"], batch, seq, one_chip).memory_analysis()
+            mem = compile_step(config, batch, seq, one_chip).memory_analysis()
         except Exception as e:  # noqa: BLE001 - the compiler's refusal IS the reading
             print(f"{argv[1]} batch {batch} x seq {seq}: refused: "
                   f"{str(e).splitlines()[0][:300]}", flush=True)

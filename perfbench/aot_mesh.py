@@ -17,10 +17,15 @@ line `aot.py` uses and for its reasons. The deepest candidate that fits is
 the configuration's depth; the lines go into its `aot` key and PERF.md.
 
 It also compiles what check J runs on the same devices BEFORE the optimizer
-state exists (the plain float32 reference's layer and head on the sharded
-bf16 weights, and the program's forward), because the reference keeps
-[batch, heads, seq, seq] float32 scores and has to fit beside the weights.
-A compile that passes is not a chip run.
+state exists (the program's forward, and the float32 reference of the
+module the configuration names, on the sharded weights), because a plain
+reference keeps [batch, heads, seq, seq] float32 scores and has to fit
+beside the weights. The reference is compiled as ONE program through the
+module's `forward`, its one export that computes, whatever smaller programs
+the module makes of it in a run: its arguments are all the weights, its
+temporaries those of its largest piece (13B widths over 2x2: 3.25 + 3.29 GB,
+where the dense module's head alone read 0.68 + 3.29). A compile that
+passes is not a chip run.
 """
 
 from __future__ import annotations
@@ -33,12 +38,11 @@ import aot  # sets TPU_LOG_DIR / TPU_SKIP_MDS_QUERY and the import paths
 DEPTHS = (8, 7, 6, 5, 4)
 
 
-def shardings(job: dict, mesh):
+def shardings(reference, job: dict, mesh):
     """(abstract params, abstract optimizer state), placed as make_job
     places them: the program's own layout of its training state."""
     import jax
 
-    import reference
     from dynolog_tpu.models.train import make_optimizer, state_shardings
 
     optimizer = make_optimizer()
@@ -62,7 +66,7 @@ def sizes(compiled) -> tuple:
 
 def compile_candidate(config: dict, devices, layers: int, batch: int,
                       seq: int) -> dict:
-    """GB per device of the step, and of check J's three programs."""
+    """GB per device of the step, and of check J's two sides."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
@@ -70,15 +74,15 @@ def compile_candidate(config: dict, devices, layers: int, batch: int,
     import cells
     import checks
     import harness
-    import reference
     from dynolog_tpu.models.train import make_train_step
     from dynolog_tpu.models.transformer import forward
     from dynolog_tpu.parallel.sharding import batch_sharding
 
     job = dict(config["job"], n_layers=layers, batch=batch, seq=seq)
+    reference = cells.load_reference(config)
     cfg = harness.transformer_config(job)
     mesh = cells.build_mesh(config["deployment"], devices)
-    params, opt_state = shardings(job, mesh)
+    params, opt_state = shardings(reference, job, mesh)
     replicated = NamedSharding(mesh, PartitionSpec())
     tokens = jax.ShapeDtypeStruct(
         (batch, seq), jnp.int32, sharding=batch_sharding(mesh))
@@ -91,14 +95,9 @@ def compile_candidate(config: dict, devices, layers: int, batch: int,
     out["job_forward"] = sizes(jax.jit(
         lambda p, t: forward(p, t, cfg, mesh)[:, -last:]).lower(
             params, whole).compile())
-    x = jax.ShapeDtypeStruct(
-        (batch, seq, job["d_model"]), jnp.float32, sharding=replicated)
-    with jax.default_matmul_precision("highest"):
-        out["reference_layer"] = sizes(reference._layer.lower(
-            params["layers"][0], x, job["n_heads"],
-            float(job["rope_theta"]), None).compile())
-        out["reference_head"] = sizes(reference._head.lower(
-            params, x, whole, last, None).compile())
+    out["reference_forward"] = sizes(jax.jit(
+        lambda p, t: reference.forward(p, t, job, last)).lower(
+            params, whole).compile())
     return out
 
 

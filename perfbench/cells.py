@@ -4,9 +4,11 @@ A cell is one `workloads` entry: a configuration
 (`perfbench/configs/<config>.json`) under a traffic mix
 (`perfbench/traffic/<traffic>.json`). Per-layer metrics are the readers in
 `perfbench/metrics/`, found by listing the directory, and so is an end-to-end
-metric added since PR 24, in `perfbench/end_to_end/`. Nothing here or in
-the harness branches on a cell's, configuration's or metric's name: a
-later PR adds a cell by adding data files and an entry.
+metric added since PR 24, in `perfbench/end_to_end/`. The module that makes a
+job's weights and says what they should compute is the file its
+configuration names under `reference`. Nothing here or in the harness
+branches on a cell's, configuration's, module's or metric's name: a later PR
+adds a cell by adding data files and an entry.
 """
 
 from __future__ import annotations
@@ -59,7 +61,7 @@ class Cell:
 def load_config(name: str, here: Path = HERE) -> dict:
     config = _load_json(here / "configs" / f"{name}.json")
     for key in ("source", "assumed", "reduced", "departures", "deployment",
-                "job", "daemon_flags", "shim"):
+                "job", "reference", "daemon_flags", "shim"):
         if key not in config:
             raise BenchmarkError(f"configs/{name}.json lacks '{key}'")
     deployment = config["deployment"]
@@ -135,24 +137,51 @@ def build_mesh(deployment: dict, devices):
     return make_mesh(MeshSpec(**mesh), list(devices)[:deployment["chips"]])
 
 
+REFERENCE_ATTRS = ("init_weights", "forward", "lower", "rel_rms",
+                   "J_LOGIT_REL_RMS_LIMIT", "J_LOSS_ABS_LIMIT")
 READER_ATTRS = ("NAME", "UNIT", "LAYER", "MOVES", "CELLS", "SOURCE", "BETTER",
                 "read")
 END_TO_END_ATTRS = ("NAME", "UNIT", "BOUND", "CELLS", "SOURCE", "BETTER",
                     "read")
 
 
+def load_module(path: Path, attrs: tuple):
+    """The module in the file `path`, which has to export every one of
+    `attrs`. A file's name may hold dots (`step_ms_p95.capture.py`), so it
+    is loaded by path."""
+    if not path.is_file():
+        raise BenchmarkError(f"{path} is not a file")
+    spec = importlib.util.spec_from_file_location(
+        f"perfbench_{path.parent.name}_" + path.stem.replace(".", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    for attr in attrs:
+        if not hasattr(module, attr):
+            raise BenchmarkError(f"{path} exports no {attr}")
+    return module
+
+
+def load_reference(config: dict, here: Path = HERE):
+    """The module a configuration names under `reference`, a path under
+    perfbench/: it makes the job's weights from the seed
+    (`init_weights(key, job)`), says what they should compute
+    (`forward(params, tokens, job, last, rounding=None)` -> the logits of
+    the last positions and the loss the first step is held to; `lower`, the
+    rounding of its lower-precision control; `rel_rms`) and states check J's
+    two limits, which were measured for it."""
+    path = (here / config["reference"]).resolve()
+    if here.resolve() not in path.parents:
+        raise BenchmarkError(
+            f"reference {config['reference']!r} leads out of {here}: the "
+            "yardstick lives with the benchmark")
+    return load_module(path, REFERENCE_ATTRS)
+
+
 def load_modules(folder: Path, attrs: tuple) -> dict:
-    """name -> module, for every <folder>/*.py. A file's name may hold dots
-    (`step_ms_p95.capture.py`), so it is loaded by path."""
+    """name -> module, for every <folder>/*.py."""
     modules = {}
     for path in sorted(folder.glob("*.py")):
-        spec = importlib.util.spec_from_file_location(
-            f"perfbench_{folder.name}_" + path.stem.replace(".", "_"), path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        for attr in attrs:
-            if not hasattr(module, attr):
-                raise BenchmarkError(f"{path} exports no {attr}")
+        module = load_module(path, attrs)
         if module.NAME != path.stem:
             raise BenchmarkError(f"{path} names its metric {module.NAME!r}")
         modules[module.NAME] = module

@@ -31,15 +31,10 @@ import zlib
 
 import xplane
 
+# Check J's form; its two limits are stated by the module that a
+# configuration names under `reference`, beside the readings of that block
+# they were set from.
 J_POSITIONS = 256
-# Limits of check J, set from readings on the chip (PERF.md section 2 gives
-# the readings): above the largest a sound run gave over a dozen seeds and
-# below the smallest the float8 control gave.
-J_LOGIT_REL_RMS_LIMIT = 0.05
-# The loss hardly moves with precision (the control's smallest gap was
-# 3.4e-5); it is held against a part of the batch left out or a token
-# altered, at about three times the sound runs' largest gap (9.2e-4).
-J_LOSS_ABS_LIMIT = 0.003
 S1_MARGIN = 0.05
 # The device plane's first execution starts with the session and its last is
 # cut at the stop (the chip showed 132, 132, 132, 98 ms in one window), so
@@ -76,16 +71,30 @@ def check(name: str, parts: list) -> dict:
 # ------------------------------------------------------------------- J
 
 
-def check_j(j: dict) -> dict:
+def check_j(j: dict, logit_limit: float, loss_limit: float) -> dict:
+    """`j` is the run's three readings; the limits are those of the module
+    that made the weights and the reference (`cells.load_reference`)."""
     gap = abs(j["step_loss"] - j["ref_loss"])
     return check("J", [
         part("||job logits - reference|| / ||reference||, last "
              f"{J_POSITIONS} positions", j["logit_rel_rms"],
-             f"<= {J_LOGIT_REL_RMS_LIMIT}",
-             j["logit_rel_rms"] <= J_LOGIT_REL_RMS_LIMIT),
+             f"<= {logit_limit}", j["logit_rel_rms"] <= logit_limit),
         part("|first step's loss - reference loss|", gap,
-             f"<= {J_LOSS_ABS_LIMIT}", gap <= J_LOSS_ABS_LIMIT),
+             f"<= {loss_limit}", gap <= loss_limit),
     ])
+
+
+def __getattr__(name: str):
+    """`tests/test_sharded_job.py` (tier-1, not a `benchmark` PR's to edit)
+    reads the dense block's limits as `checks.J_*`: handed through from that
+    block's module, nothing kept here. Goes with that test's next edit
+    (PERF.md Open 0)."""
+    if name in ("J_LOGIT_REL_RMS_LIMIT", "J_LOSS_ABS_LIMIT"):
+        import cells
+
+        return getattr(cells.load_reference({"reference": "reference.py"}),
+                       name)
+    raise AttributeError(f"module 'checks' has no attribute {name!r}")
 
 
 # ------------------------------------------------------------------ S1

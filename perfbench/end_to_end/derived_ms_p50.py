@@ -15,7 +15,7 @@ UNIT = "ms"
 BETTER = "lower"
 SOURCE = "host_clock"
 # about five times the widest spread of two sets of six runs a cell (1.27 %
-# at olmo2-1b.capture; PERF.md section 2), floor 0.01, ceiling 0.25
+# at the 1B job's capture cell; PERF.md section 2), floor 0.01, ceiling 0.25
 BOUND = 0.06
 CELLS = ('capture',)
 

@@ -8,10 +8,10 @@ A per-layer metric is one reader file; its NAME, UNIT, BETTER, SOURCE,
 LAYER, MOVES and CELLS (the traffic kinds in which it finds something to
 read) become its entry. A metric whose CELLS cover every traffic kind gets
 no `workloads` key, so it is due in every cell, later ones too; any other
-lists the cells of its kinds, less those its file names in EXCEPT: cells of
-a kind it reads in which it still finds nothing, with the sentence that
-says why beside them. A due metric that prints nothing refuses a PR, so a
-reader lists only cells in which it always has a value.
+lists the cells of its kinds, less those whose configuration names it under
+`no_reading`: a deployment in which the reader still finds nothing, with the
+sentence that says why beside the name. A due metric that prints nothing
+refuses a PR, so a reader lists only cells in which it always has a value.
 
 An entry the table already holds keeps its place and its content; a cell
 that no entry's list names yet is new, and joins the list of every reader
@@ -32,30 +32,35 @@ import sys
 import cells
 
 
-def with_workloads(entry: dict, module, kinds: dict) -> dict:
-    """`entry` with the cells of the module's kinds, less those it excepts,
-    unless that is every cell there is or will be."""
-    excepted = getattr(module, "EXCEPT", ())
-    if set(module.CELLS) != set(cells.TRAFFIC_KINDS) or excepted:
+def due_everywhere(module) -> bool:
+    return set(module.CELLS) == set(cells.TRAFFIC_KINDS)
+
+
+def with_workloads(entry: dict, module, kinds: dict, unread: dict) -> dict:
+    """`entry` with the cells of the module's kinds, less those in which it
+    has no reading, unless it is due in every cell there is or will be."""
+    if not due_everywhere(module):
         entry["workloads"] = [
             w for w, kind in kinds.items()
-            if kind in module.CELLS and w not in excepted]
+            if kind in module.CELLS
+            and module.NAME not in unread.get(w, ())]
     return entry
 
 
-def entry_of(reader, kinds: dict) -> dict:
+def entry_of(reader, kinds: dict, unread: dict) -> dict:
     """The table entry that one reader file generates; `kinds` is cell
-    name -> traffic kind, in the order of `workloads`."""
+    name -> traffic kind, in the order of `workloads`, and `unread` cell
+    name -> the readers its configuration says have no reading there."""
     return with_workloads(
         {"name": reader.NAME, "unit": reader.UNIT, "better": reader.BETTER,
          "source": reader.SOURCE, "layer": reader.LAYER,
-         "moves": reader.MOVES}, reader, kinds)
+         "moves": reader.MOVES}, reader, kinds, unread)
 
 
 def end_to_end_entry_of(metric, kinds: dict) -> dict:
     return with_workloads(
         {"name": metric.NAME, "unit": metric.UNIT, "better": metric.BETTER,
-         "bound": metric.BOUND, "source": metric.SOURCE}, metric, kinds)
+         "bound": metric.BOUND, "source": metric.SOURCE}, metric, kinds, {})
 
 
 def end_to_end(bench: dict, metrics: dict | None = None) -> list:
@@ -72,9 +77,30 @@ def cell_kinds(bench: dict) -> dict:
             for w in bench["workloads"]}
 
 
+def no_reading(bench: dict, readers: dict) -> dict:
+    """Cell name -> the readers its configuration names under `no_reading`.
+    A name there that is no reader's, or that of a reader due in every cell
+    (it has no list to leave a cell off), is an error and not silence."""
+    unread = {}
+    for w in bench["workloads"]:
+        named = cells.load_config(w["config"]).get("no_reading", {})
+        for name in named:
+            if name not in readers:
+                raise cells.BenchmarkError(
+                    f"configs/{w['config']}.json: no_reading names {name!r}, "
+                    "which is no reader in perfbench/metrics/")
+            if due_everywhere(readers[name]):
+                raise cells.BenchmarkError(
+                    f"configs/{w['config']}.json: no_reading names {name!r}, "
+                    "which is due in every cell and has no list of cells")
+        unread[w["name"]] = tuple(named)
+    return unread
+
+
 def per_layer(bench: dict, readers: dict | None = None) -> list:
     readers = cells.load_readers() if readers is None else readers
     kinds = cell_kinds(bench)
+    unread = no_reading(bench, readers)
     listed = {w for m in bench["per_layer"] for w in m.get("workloads", ())}
     table = []
     for held in bench["per_layer"]:
@@ -83,12 +109,12 @@ def per_layer(bench: dict, readers: dict | None = None) -> list:
         entry = dict(held)
         if "workloads" in entry:
             entry["workloads"] = entry["workloads"] + [
-                w for w in entry_of(readers[held["name"]], kinds).get(
+                w for w in entry_of(readers[held["name"]], kinds, unread).get(
                     "workloads", ())
                 if w not in listed]
         table.append(entry)
     new = sorted(set(readers) - {m["name"] for m in bench["per_layer"]})
-    return table + [entry_of(readers[name], kinds) for name in new]
+    return table + [entry_of(readers[name], kinds, unread) for name in new]
 
 
 def main() -> int:

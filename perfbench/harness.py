@@ -328,12 +328,12 @@ class Run:
         """Weights, check J, optimizer state, the step, the shim."""
         import jax
 
-        import reference
         from dynolog_tpu.client import TraceClient
         from dynolog_tpu.models.train import make_optimizer, make_train_step
         from dynolog_tpu.models.transformer import forward
 
         job = self.cell.job
+        reference = cells.load_reference(self.cell.config)
         cfg = transformer_config(job)
         mesh = cells.build_mesh(self.cell.config["deployment"], self.devices)
         param_shardings = opt_shardings = None
@@ -371,6 +371,8 @@ class Run:
             params, tokens)
         self.j = {"logit_rel_rms": reference.rel_rms(got, want),
                   "ref_loss": float(want_loss)}
+        self.j_limits = (reference.J_LOGIT_REL_RMS_LIMIT,
+                         reference.J_LOSS_ABS_LIMIT)
         del want, got
         self.phase("reference_s", t0)
 
@@ -556,7 +558,7 @@ class Run:
     def drain_and_check(self) -> None:
         rec = self.record
         store = checks.wait_for_telemetry(self)
-        rec["checks"].append(checks.check_j(self.j))
+        rec["checks"].append(checks.check_j(self.j, *self.j_limits))
         rec["checks"].append(checks.check_s1(self, store))
         rec["checks"].append(checks.check_s2(self))
         if self.cell.kind == "capture":

@@ -11,9 +11,6 @@ SOURCE = "host_clock"
 LAYER = "shim capture"
 MOVES = "step_ms_p50"
 CELLS = ('capture',)
-# 40 s hold 109 steps of 366 ms at four chips, five beyond their p95, and
-# `stats.tail` prints no tail with fewer than ten beyond it.
-EXCEPT = ("olmo2-13b-v5e4.capture",)
 
 
 def read(run: dict):

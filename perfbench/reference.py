@@ -1,4 +1,7 @@
-"""The observed job's weights and its plain float32 reference (check J).
+"""The dense block's module: the observed job's weights, its plain float32
+reference and check J's limits for it. A configuration names the file under
+`reference` (`cells.load_reference`); another block's module is another file
+with the same exports.
 
 Nothing of dynolog_tpu is imported here. The benchmark makes the weights
 itself, from the seed, on the device, in the type the job trains in, and
@@ -28,6 +31,15 @@ import jax
 import jax.numpy as jnp
 
 EPS = 1e-6
+# Limits of check J for this block, set from readings on the chip (PERF.md
+# section 2 gives the readings): above the largest a sound run gave over a
+# dozen seeds (0.0202) and below the smallest the float8 control gave
+# (0.1273).
+J_LOGIT_REL_RMS_LIMIT = 0.05
+# The loss hardly moves with precision (the control's smallest gap was
+# 3.4e-5); it is held against a part of the batch left out or a token
+# altered, at about three times the sound runs' largest gap (9.2e-4).
+J_LOSS_ABS_LIMIT = 0.003
 
 
 def init_weights(key, job: dict):

@@ -31,7 +31,8 @@ def toy_cell(traffic: str, config: str = "toy-cpu") -> cells.Cell:
 
 
 def rehearse(monkeypatch, tmp_path, traffic: str, seed: int = 7,
-             seconds: float = 4.0, trace: bool = False, broken=None):
+             seconds: float = 4.0, trace: bool = False, broken=None,
+             config: str = "toy-cpu"):
     """Returns (run, result line). `broken(run)` may break the timed path
     after the job is made and before it is warmed up."""
     import jax
@@ -45,7 +46,8 @@ def rehearse(monkeypatch, tmp_path, traffic: str, seed: int = 7,
         lambda kind: {"hbm_total_bytes_range": [14e9, 18e9]})
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(harness, "CACHE_DIR", tmp_path / "jax_cache")
-    run = harness.Run(toy_cell(traffic), seed, seconds, trace, time.time())
+    run = harness.Run(
+        toy_cell(traffic, config), seed, seconds, trace, time.time())
     if broken is not None:
         make_job = run.make_job
 

@@ -43,6 +43,51 @@ def test_unknown_names_are_errors_not_defaults():
     assert cells.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
 
 
+def test_a_reference_module_is_found_by_the_path_its_configuration_gives(
+        tmp_path):
+    """The loader's errors name the file and the export; a configuration
+    without the key is no configuration."""
+    dense = cells.load_reference(cells.load_config("olmo2-1b-v5e1"))
+    assert dense.J_LOGIT_REL_RMS_LIMIT == 0.05 and callable(dense.forward)
+    toy = cells.load_reference(rehearsal.toy_cell("steady", "toy-moe").config)
+    assert toy.__file__.endswith("tests/data/moe_block.py")
+    assert toy.J_LOGIT_REL_RMS_LIMIT != dense.J_LOGIT_REL_RMS_LIMIT
+    with pytest.raises(cells.BenchmarkError, match="nowhere.py is not a file"):
+        cells.load_reference({"reference": "nowhere.py"}, tmp_path)
+    (tmp_path / "half.py").write_text(
+        "init_weights = forward = lower = rel_rms = print\n"
+        "J_LOGIT_REL_RMS_LIMIT = 0.05\n")
+    with pytest.raises(cells.BenchmarkError,
+                       match="half.py exports no J_LOSS_ABS_LIMIT"):
+        cells.load_reference({"reference": "half.py"}, tmp_path)
+    with pytest.raises(cells.BenchmarkError, match="leads out"):
+        cells.load_reference({"reference": "../checks.py"}, cells.HERE / "tests")
+    (tmp_path / "configs").mkdir()
+    conf = dict(rehearsal.toy_cell("steady").config)
+    del conf["reference"]
+    (tmp_path / "configs" / "old.json").write_text(json.dumps(conf))
+    with pytest.raises(cells.BenchmarkError, match="lacks 'reference'"):
+        cells.load_config("old", tmp_path)
+
+
+def test_no_reader_and_no_harness_file_names_a_cell_or_a_configuration():
+    """What belongs to one deployment is said in its file: a reader that
+    named a cell had to be edited for the next cell of that kind. Nor does
+    the harness import a block's module by name."""
+    bench = cells.load_benchmark()
+    names = ([w["name"] for w in bench["workloads"]]
+             + [c["name"] for c in bench["configs"]])
+    for folder in ("metrics", "end_to_end"):
+        for path in sorted((cells.HERE / folder).glob("*.py")):
+            text = path.read_text()
+            assert not [n for n in names if n in text], path.name
+            assert "EXCEPT" not in text, path.name
+    for path in sorted(cells.HERE.glob("*.py")):
+        text = path.read_text()
+        assert "import reference" not in text, path.name
+        assert not [n for n in names if n in text], path.name
+
+
 def test_traffic_push_is_data_and_two_clients_are_refused(tmp_path):
     (tmp_path / "traffic").mkdir()
     base = {"kind": "capture", "mode": "push", "window_ms": 500,

@@ -1,7 +1,8 @@
 """BENCHMARK.json's `per_layer` table is what `gen_benchmark.py` makes of the
 reader files: every entry is its file's, accepted entries stay where they
 were, new ones follow in the order of their names, and a new cell reaches
-the list of every reader of its kind. No test here or elsewhere pins the
+the list of every reader of its kind but those its configuration says it
+cannot read. No test here or elsewhere pins the
 table's tail: the next reader lengthens it.
 """
 
@@ -53,11 +54,24 @@ def bench():
 
 @pytest.mark.parametrize("name", sorted(READERS))
 def test_each_table_entry_is_what_its_file_generates(bench, name):
-    """The cells a reader excepts included: `step_ms_p95.capture`'s entry
-    was a hand edit of the table until its file said why."""
+    """From the readers and the configurations alone, the cell a
+    configuration says a reader cannot read included:
+    `step_ms_p95.capture`'s entry was a hand edit of the table until PR 31,
+    and the reader named the cell until PR 33."""
     table = {m["name"]: m for m in bench["per_layer"]}
     assert table[name] == gen_benchmark.entry_of(
-        READERS[name], gen_benchmark.cell_kinds(bench))
+        READERS[name], gen_benchmark.cell_kinds(bench),
+        gen_benchmark.no_reading(bench, READERS))
+
+
+def test_the_tables_generated_from_nothing_held_are_benchmark_json(bench):
+    """Every entry of both tables, from the readers, the end-to-end files
+    and the configurations: the order of a table is its history (held
+    entries keep their place), so it is compared by name."""
+    bare = dict(bench, per_layer=[], end_to_end=list(ACCEPTED_END_TO_END))
+    made = {m["name"]: m for m in gen_benchmark.per_layer(bare)}
+    assert made == {m["name"]: m for m in bench["per_layer"]}
+    assert gen_benchmark.end_to_end(bare) == bench["end_to_end"]
 
 
 def test_the_generator_leaves_the_accepted_prefix_where_it_was(bench):
@@ -77,7 +91,8 @@ def test_the_generator_leaves_the_accepted_prefix_where_it_was(bench):
 
 def test_a_held_entry_is_kept_as_it_stands_and_a_new_cell_joins_its_kind(bench):
     """A cell that no list names yet is new: it joins every reader of its
-    kind that does not except it, at the end of the list; nothing else of
+    kind that its configuration does not name under `no_reading`, at the end
+    of the list; nothing else of
     a held entry moves, whatever its file would generate today."""
     grown = copy.deepcopy(bench)
     grown["workloads"].append(dict(
@@ -99,17 +114,54 @@ def test_a_held_entry_is_kept_as_it_stands_and_a_new_cell_joins_its_kind(bench):
         "olmo2-1b.capture-again")
     assert "olmo2-13b-v5e4.capture" not in (
         table["step_ms_p95.capture"]["workloads"])
+    # a second cell of the configuration that cannot read it stays off too
+    grown["workloads"].append(dict(
+        bench["workloads"][-1], name="olmo2-13b-v5e4.capture-again"))
+    table = {m["name"]: m for m in gen_benchmark.per_layer(grown)}
+    assert "olmo2-13b-v5e4.capture-again" not in (
+        table["step_ms_p95.capture"]["workloads"])
+    assert table["collect_ms"]["workloads"][-1] == (
+        "olmo2-13b-v5e4.capture-again")
 
 
-def test_an_excepted_cell_is_left_off_a_reader_of_every_kind():
-    class Reader:
-        NAME, UNIT, BETTER, SOURCE = "x_ms", "ms", "lower", "host_clock"
-        LAYER, MOVES, CELLS = "device", "step_ms_p50", ('steady', 'capture')
+def named_unread(monkeypatch, bench, names: dict) -> dict:
+    """`bench`'s first configuration, naming `names` under `no_reading`."""
+    first = bench["workloads"][0]["config"]
+    load_config = cells.load_config
+    monkeypatch.setattr(cells, "load_config", lambda name, *a: dict(
+        load_config(name, *a), **({"no_reading": names}
+                                  if name == first else {})))
+    return gen_benchmark.no_reading(bench, READERS)
 
-    kinds = {"a.capture": "capture", "a.steady": "steady"}
-    assert "workloads" not in gen_benchmark.entry_of(Reader, kinds)
-    Reader.EXCEPT = ("a.steady",)
-    assert gen_benchmark.entry_of(Reader, kinds)["workloads"] == ["a.capture"]
+
+def test_a_configuration_takes_its_cells_off_the_readers_it_names(
+        monkeypatch, bench):
+    """Every cell of the configuration, and no cell of another."""
+    unread = named_unread(monkeypatch, bench, {"collect_ms": "why not"})
+    kinds = gen_benchmark.cell_kinds(bench)
+    mine = [w["name"] for w in bench["workloads"]
+            if w["config"] == bench["workloads"][0]["config"]]
+    entry = gen_benchmark.entry_of(READERS["collect_ms"], kinds, unread)
+    assert entry["workloads"] == [
+        w for w, kind in kinds.items() if kind == "capture" and w not in mine]
+    other = gen_benchmark.entry_of(READERS["write_ms"], kinds, unread)
+    assert other["workloads"] == [
+        w for w, kind in kinds.items() if kind == "capture"]
+
+
+def test_a_no_reading_name_that_is_no_readers_is_an_error(monkeypatch, bench):
+    with pytest.raises(cells.BenchmarkError, match="no reader"):
+        named_unread(monkeypatch, bench, {"step_ms_p95.captur": "a typo"})
+
+
+def test_a_reader_due_everywhere_cannot_be_named(monkeypatch, bench):
+    """It has no list to leave a cell off: later cells owe it too."""
+    everywhere = next(n for n, r in READERS.items()
+                      if gen_benchmark.due_everywhere(r))
+    assert "workloads" not in gen_benchmark.entry_of(
+        READERS[everywhere], gen_benchmark.cell_kinds(bench), {})
+    with pytest.raises(cells.BenchmarkError, match="due in every cell"):
+        named_unread(monkeypatch, bench, {everywhere: "nothing to read"})
 
 
 def test_a_reader_whose_file_went_loses_its_entry(bench):

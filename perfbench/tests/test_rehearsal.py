@@ -1,12 +1,40 @@
 """A whole run on the CPU at a toy size, the look for a chip stubbed here:
 sound, `correct` is true; with the timed path broken underneath, false."""
 
+import os
+import re
+import sys
+from pathlib import Path
+
+import jax
 import jax.numpy as jnp
+import pytest
 
 import cells
 import harness
 import rehearsal
 import selftrace
+
+@pytest.fixture(scope="module")
+def audit():
+    """One audit hook a process (a hook cannot be taken off again); it
+    records a file opened into `into[0]` while a test has a list there."""
+    into: list = []
+
+    def hook(event, args):
+        if into and event == "open" and isinstance(
+                args[0], (str, bytes, os.PathLike)):
+            into[0].append(os.fsdecode(args[0]))
+
+    sys.addaudithook(hook)
+    return into
+
+
+@pytest.fixture
+def opened(audit):
+    audit.append([])
+    yield audit[0]
+    audit.clear()
 
 
 def failed(run) -> list:
@@ -27,6 +55,66 @@ def test_sound_steady_run_is_correct(monkeypatch, tmp_path):
     # the journals are asked for in traced runs alone; the RSS in every run
     assert "selftrace" not in run.record and "shim_counters" not in run.record
     assert run.record["daemon_rss_kb"] > 1000
+
+
+def test_a_job_of_another_block_runs_from_test_data_alone(
+        monkeypatch, tmp_path, opened):
+    """The seam: `toy-moe.json` names `tests/data/moe_block.py`, whose
+    weights (a router, stacked experts) `perfbench/reference.py` cannot
+    make. The whole run passes J on that module's own limits, and of
+    perfbench/ it opened its test data and the harness's own files: no
+    configuration of a cell and not the dense block's module, which
+    `import reference` would find no more."""
+    monkeypatch.setitem(sys.modules, "reference", None)
+    run, line = rehearsal.rehearse(
+        monkeypatch, tmp_path, "steady", config="toy-moe")
+    assert failed(run) == [] and line["correct"] is True
+    assert [c["name"] for c in run.record["checks"]] == ["J", "S1", "S2", "C4"]
+    assert sorted(run.state[0]["layers"][0]) == [
+        "attn_scale", "experts_down", "experts_gate", "experts_up",
+        "mlp_scale", "router", "wk", "wo", "wq", "wv"]
+    rows = [row for row in line["compared"] if row[0] == "J"]
+    assert [row[3] for row in rows] == ["<= 0.001", "<= 0.001"]
+    assert rows[0][2] < 1e-5 and rows[1][2] < 1e-5  # float32 on both sides
+    inside = set()
+    for name in opened:
+        path = Path(name).resolve()
+        if cells.HERE in path.parents:
+            source = re.sub(r"\.cpython-\d+\.pyc$", ".py", path.name)
+            inside.add(str((path.parent / source).relative_to(
+                cells.HERE)).replace("__pycache__/", ""))
+    assert {"tests/data/moe_block.py", "tests/data/toy-moe.json",
+            "traffic/steady.json"} <= inside
+    own = {p.name for p in cells.HERE.glob("*.py")} - {"reference.py"}
+    for name in inside:
+        first = name.split("/")[0]
+        assert (first in ("tests", "metrics", "end_to_end", "traffic")
+                or name in own or name == "peaks.json"), name
+
+
+def test_the_other_blocks_own_control_is_not_correct(monkeypatch, tmp_path):
+    """The control of that module, in the program's place: the program's
+    forward on the toy weights rounded through the module's own `lower`
+    (bfloat16 under the float32 the configuration states; the router with
+    them) fails J's first number on the module's limit, and nothing else."""
+    import dynolog_tpu.models.transformer as program
+
+    module = cells.load_reference(
+        rehearsal.toy_cell("steady", "toy-moe").config)
+    forward = program.forward
+
+    def lowered(params, tokens, cfg, mesh=None):
+        params = jax.tree_util.tree_map(
+            lambda w: module.lower(w).astype(w.dtype), params)
+        return forward(params, tokens, cfg, mesh)
+
+    monkeypatch.setattr(program, "forward", lowered)
+    run, line = rehearsal.rehearse(
+        monkeypatch, tmp_path, "steady", config="toy-moe")
+    assert failed(run) == ["J"] and line["correct"] is False
+    j = run.record["checks"][0]["compared"]
+    assert [p["ok"] for p in j] == [False, True]
+    assert j[0]["value"] > 3 * module.J_LOGIT_REL_RMS_LIMIT
 
 
 def test_step_telemetry_lost_is_not_correct(monkeypatch, tmp_path):

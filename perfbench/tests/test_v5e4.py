@@ -69,27 +69,24 @@ def test_source_and_reduced_differ_from_every_other_configuration():
     assert four == [CELL] and bench["workloads"][-1]["name"] == CELL
 
 
-def test_every_capture_reader_lists_the_cell_but_the_one_that_excepts_it():
-    """`step_ms_p95.capture` names the cell in its own EXCEPT: a window of
-    `run_seconds` holds 109 steps of 366 ms, five beyond their p95, and
-    `stats.tail` prints no tail with fewer than ten beyond it. The driver's
-    first check of PR 28 refused the cell for that one name, and until PR 31
-    the table left it out by a hand edit."""
+def test_every_capture_reader_lists_the_cell_but_the_one_it_cannot_read():
+    """The configuration names `step_ms_p95.capture` under `no_reading`: a
+    window of `run_seconds` holds 109 steps of 366 ms, five beyond their
+    p95, and `stats.tail` prints no tail with fewer than ten beyond it. The
+    driver's first check of PR 28 refused the cell for that one name; until
+    PR 31 the table left it out by a hand edit, and until PR 33 the reader
+    named the cell."""
     import stats
 
     bench = cells.load_benchmark()
-    listed = {m["name"]: CELL in m.get("workloads", [CELL])
-              for m in bench["per_layer"]}
-    excepting = []
-    for name, reader in cells.load_readers().items():
-        if "capture" not in reader.CELLS:
-            continue
-        if CELL in getattr(reader, "EXCEPT", ()):
-            excepting.append(name)
-            assert not listed[name], name
-        else:
-            assert listed[name], name
-    assert excepting == ["step_ms_p95.capture"]
+    unread = cells.load_config(NAME)["no_reading"]
+    assert list(unread) == ["step_ms_p95.capture"] and "109" in (
+        unread["step_ms_p95.capture"])
+    readers = cells.load_readers()
+    for entry in bench["per_layer"]:
+        if "capture" in readers[entry["name"]].CELLS:
+            listed = CELL in entry.get("workloads", [CELL])
+            assert listed == (entry["name"] not in unread), entry["name"]
     steps = [366.4] * 109
     with pytest.raises(stats.TooFewSamples):
         stats.tail(steps, 0.95, bench["run_seconds"] * 1e3)
@@ -199,13 +196,9 @@ def test_whole_run_over_a_mesh_reaches_its_end(monkeypatch, tmp_path):
     forward(..., mesh), the ahead-of-time compiled step fed its own outputs,
     one profiler session over four local devices. A CPU writes no
     /device:TPU plane, so C1-C3 read false and both readers nothing."""
-    toy = rehearsal.toy_cell
-    monkeypatch.setattr(
-        rehearsal, "toy_cell",
-        lambda traffic, config="toy-cpu4": toy(traffic, config))
     run, line = rehearsal.rehearse(
         monkeypatch, tmp_path, "capture-pull", seed=2**31 + 28, seconds=3.0,
-        trace=True)
+        trace=True, config="toy-cpu4")
     failed = [c["name"] for c in run.record["checks"] if not c["ok"]]
     assert failed == ["C1", "C2", "C3"] and line["failed"] == 0
     s2 = next(c for c in run.record["checks"] if c["name"] == "S2")
