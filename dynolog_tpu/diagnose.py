@@ -61,7 +61,7 @@ NOISE_PCT = 5.0
 NOISE_IMPACT_MS = 0.05
 
 def classify_op(name: str) -> str:
-    # collective (trace.COLLECTIVE_TOKENS): growth here means the pod is
+    # collective (trace.COLLECTIVE_KINDS): growth here means the pod is
     # waiting on a peer, not computing.
     low = name.lower()
     if trace.is_collective(low):
@@ -206,14 +206,15 @@ def _step_findings(diff: dict, findings: list) -> None:
 
 def _op_findings(diff: dict, base_shapes: dict, cur_shapes: dict,
                  findings: list) -> None:
-    collective_growth_ms = 0.0
+    growth_by_kind: dict[str, float] = {}  # kind of collective -> ms grown
     for row in diff["ops"]:
         name = row["op"]
         category = classify_op(name)
         bpc, cpc = row["base_ms_per_call"], row["ms_per_call"]
         impact = row["impact_ms"]
         if category == "collective" and impact > 0:
-            collective_growth_ms += impact
+            kind = trace.collective_kind(name)
+            growth_by_kind[kind] = growth_by_kind.get(kind, 0.0) + impact
         bs, cs = base_shapes.get(name), cur_shapes.get(name)
         if bs and cs and bs != cs:
             findings.append({
@@ -272,16 +273,21 @@ def _op_findings(diff: dict, base_shapes: dict, cur_shapes: dict,
                     f"{name} improved {-pct:.0f}% per call "
                     f"({impact:.3f} ms)"),
             })
+    collective_growth_ms = sum(growth_by_kind.values())
     if collective_growth_ms > NOISE_IMPACT_MS:
+        most = max(growth_by_kind, key=growth_by_kind.get)
         findings.append({
             "kind": "collective_wait_growth",
             "op": None,
             "severity_pct": None,
             "impact_ms": round(collective_growth_ms, 3),
+            # gradient reduction or expert exchange: which kind grew most
+            "collective": most,
             "message": (
                 f"collective/communication time grew "
-                f"{collective_growth_ms:+.3f} ms overall — the job is "
-                "waiting on a peer (check per-pod skew)"),
+                f"{collective_growth_ms:+.3f} ms overall, "
+                f"{growth_by_kind[most]:+.3f} ms of it in {most} — the job "
+                "is waiting on a peer (check per-pod skew)"),
         })
 
 

@@ -78,8 +78,8 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     """
     optimizer = make_optimizer(lr)
 
-    # ring/flash attention (shard_map) and MoE sharding constraints need the
-    # mesh at trace time
+    # ring/flash attention and the expert layer (shard_map) need the mesh at
+    # trace time
     fwd_mesh = (
         mesh if cfg.attn_impl in ("ring", "flash") or cfg.n_experts > 0
         else None)

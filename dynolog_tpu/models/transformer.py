@@ -35,13 +35,23 @@ class TransformerConfig:
     # (dynolog_tpu.ops.flash_attention); "ring": sequence-parallel ring
     # attention over the mesh's seq axis (requires a mesh at call time).
     attn_impl: str = "reference"
-    # MoE: n_experts > 0 replaces every dense MLP with a top-k-routed
-    # mixture of SwiGLU experts (dynolog_tpu.models.moe), expert-parallel
-    # over the mesh's `expert` axis.
+    # RMSNorm's epsilon, and whether q and k are normalised (a learned
+    # scale over the whole d_model of each, before the heads are split and
+    # RoPE applied), as published configs state them.
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    # MoE: n_experts > 0 replaces every dense MLP with a dropless
+    # top-k-routed mixture of SwiGLU experts (dynolog_tpu.models.moe),
+    # expert-parallel over the mesh's `expert` axis. The loss gains
+    # moe_aux_weight x the balancing term (over first choices, or over all
+    # k where moe_balance_all_k) + moe_z_weight x the router z-loss, each a
+    # mean over layers; moe_norm_topk renormalises a token's k gates.
     n_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
+    moe_balance_all_k: bool = False
+    moe_z_weight: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -86,6 +96,9 @@ def init_params(rng, cfg: TransformerConfig):
             "wo": dense(k[3], (d, d), d),
             "mlp_scale": jnp.ones((d,), dtype),
         }
+        if cfg.qk_norm:
+            layer.update(q_scale=jnp.ones((d,), dtype),
+                         k_scale=jnp.ones((d,), dtype))
         if cfg.n_experts > 0:
             from dynolog_tpu.models.moe import init_moe_layer
 
@@ -102,9 +115,9 @@ def init_params(rng, cfg: TransformerConfig):
     return params
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6).astype(x.dtype)) * scale
+    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale
 
 
 def _rope(x, positions, theta):
@@ -123,8 +136,11 @@ def _rope(x, positions, theta):
 def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
-    q = (x @ layer["wq"]).reshape(b, s, h, hd)
-    k = (x @ layer["wk"]).reshape(b, s, h, hd)
+    q, k = x @ layer["wq"], x @ layer["wk"]
+    if cfg.qk_norm:
+        q = _rmsnorm(q, layer["q_scale"], cfg.norm_eps)
+        k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
+    q, k = q.reshape(b, s, h, hd), k.reshape(b, s, h, hd)
     v = (x @ layer["wv"]).reshape(b, s, h, hd)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
@@ -141,7 +157,9 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
             # it on its own batch rows and heads.
             from jax.sharding import PartitionSpec as P
 
-            spec = P("data", None, "model", None)
+            from dynolog_tpu.parallel.sharding import BATCH_AXES
+
+            spec = P(BATCH_AXES, None, "model", None)
             attn = jax.shard_map(
                 attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
@@ -167,7 +185,8 @@ def _mlp(layer, x):
 
 
 def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
-    """tokens [B, S] int32 → (logits [B, S, vocab] f32, moe aux-loss scalar)."""
+    """tokens [B, S] int32 → (logits [B, S, vocab] f32, the expert layers'
+    weighted loss terms, a mean over layers: 0 for a dense model)."""
     x = params["embedding"][tokens]
     positions = jnp.broadcast_to(
         jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
@@ -175,18 +194,20 @@ def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
     aux = jnp.zeros((), jnp.float32)
     for layer in params["layers"]:
         x = x + _attention(
-            layer, _rmsnorm(x, layer["attn_scale"]), positions, cfg, mesh
+            layer, _rmsnorm(x, layer["attn_scale"], cfg.norm_eps), positions,
+            cfg, mesh
         )
-        h = _rmsnorm(x, layer["mlp_scale"])
+        h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
         if cfg.n_experts > 0:
             from dynolog_tpu.models.moe import moe_mlp
 
-            y, layer_aux = moe_mlp(layer, h, cfg, mesh)
-            aux = aux + layer_aux
+            y, balance, z = moe_mlp(layer, h, cfg, mesh)
+            aux = aux + (cfg.moe_aux_weight * balance
+                         + cfg.moe_z_weight * z) / cfg.n_layers
         else:
             y = _mlp(layer, h)
         x = x + y
-    x = _rmsnorm(x, params["final_scale"])
+    x = _rmsnorm(x, params["final_scale"], cfg.norm_eps)
     return (x @ params["w_out"]).astype(jnp.float32), aux
 
 
@@ -201,17 +222,14 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None):
     The full [B, S] sequence is forwarded and the last-position logits
     dropped afterwards — keeping S intact through the model so the
     sequence axis stays evenly shardable (ring attention / sp mesh). With
-    MoE enabled the Switch load-balancing aux loss is added, scaled by
-    cfg.moe_aux_weight."""
+    MoE enabled the expert layers' balancing and z terms are added, under
+    cfg.moe_aux_weight and cfg.moe_z_weight."""
     logits, aux = _forward_with_aux(params, tokens, cfg, mesh)
     logits = logits[:, :-1]
     targets = tokens[:, 1:]
     logprobs = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)
-    loss = jnp.mean(nll)
-    if cfg.n_experts > 0:
-        loss = loss + cfg.moe_aux_weight * aux / cfg.n_layers
-    return loss
+    return jnp.mean(nll) + aux
 
 
 @partial(jax.jit, static_argnames=("cfg",))

@@ -74,8 +74,10 @@ def _stage_forward(stage_layers, x, positions, cfg: TransformerConfig):
     [n_local_layers, ...]; x: [mb, S, D]."""
 
     def body(h, layer):
-        h = h + _attention(layer, _rmsnorm(h, layer["attn_scale"]), positions, cfg)
-        h = h + _mlp(layer, _rmsnorm(h, layer["mlp_scale"]))
+        h = h + _attention(
+            layer, _rmsnorm(h, layer["attn_scale"], cfg.norm_eps), positions,
+            cfg)
+        h = h + _mlp(layer, _rmsnorm(h, layer["mlp_scale"], cfg.norm_eps))
         return h, None
 
     x, _ = jax.lax.scan(body, x, stage_layers)
@@ -136,7 +138,7 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
         # skips it on every other stage rather than masking afterwards.
         def head_loss():
             out = ys[n_stages - 1 :]  # [n_micro, mb, S, D]
-            x = _rmsnorm(out, final_scale)
+            x = _rmsnorm(out, final_scale, cfg.norm_eps)
             logits = (x @ w_out).astype(jnp.float32)[..., :-1, :]
             targets = micro[..., 1:]
             logprobs = jax.nn.log_softmax(logits, axis=-1)

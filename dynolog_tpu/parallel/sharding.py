@@ -9,7 +9,10 @@ Design: a named `jax.sharding.Mesh` with axes (data, seq, model); parameters
 are sharded tensor-parallel on the `model` axis, the batch dimension
 data-parallel on `data`, and long-sequence activations sequence-parallel on
 `seq`. XLA inserts the collectives (all-gather/reduce-scatter over ICI) from
-the sharding annotations — no hand-written comms.
+the sharding annotations — no hand-written comms. The one exception is the
+expert layer (dynolog_tpu.models.moe): the chips of the `expert` axis share a
+layer's experts AND the batch (expert parallel inside data parallel), and
+exchange token copies by an all-to-all the layer writes itself.
 """
 
 from __future__ import annotations
@@ -77,6 +80,10 @@ def named_sharding(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
 
 
+# The batch goes over the chips that share a layer's experts as well as over
+# `data`: each routes its own tokens. With `expert` 1 this is `data` alone.
+BATCH_AXES = ("data", "expert")
+
 # Parameter partition rules, keyed by parameter-name suffix. Attention and
 # MLP matrices are tensor-parallel on `model`; embeddings are replicated on
 # seq/data and sharded on model along the vocab/hidden dim.
@@ -91,8 +98,9 @@ PARAM_RULES = {
     "w_down": P("model", None),
     "w_out": P(None, "model"),
     "scale": P(None),
-    # MoE: router replicated; stacked expert weights [E, d, f] sharded on
-    # `expert` (EP) with the hidden dim tensor-parallel on `model` (EP x TP).
+    # MoE: router (and everything outside the experts) replicated over
+    # `expert`; stacked expert weights [E, d, f] sharded on `expert` (EP)
+    # with the hidden dim tensor-parallel on `model` (EP x TP).
     "router": P(),
     "experts_gate": P("expert", None, "model"),
     "experts_up": P("expert", None, "model"),
@@ -118,5 +126,5 @@ def shard_params(params, mesh: Mesh):
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Tokens [batch, seq]: batch over `data`, sequence over `seq`."""
-    return NamedSharding(mesh, P(("data",), ("seq",)))
+    """Tokens [batch, seq]: batch over BATCH_AXES, sequence over `seq`."""
+    return NamedSharding(mesh, P(BATCH_AXES, ("seq",)))

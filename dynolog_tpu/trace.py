@@ -209,17 +209,28 @@ def _parse_event_metadata_entry(buf: bytes) -> tuple[int, str, str, list]:
     return mid, name, disp, stats
 
 
-# Op-name fragments identifying collective-communication ops (XLA HLO
-# naming). dynolog_tpu.diagnose classes ops by the same test.
-COLLECTIVE_TOKENS = (
-    "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
-    "collective", "send", "recv",
+# (op-name fragment, kind of collective), first match wins, `_` read as `-`:
+# XLA names an op it inserts itself after its opcode (`all-reduce.3`) and one
+# the program wrote after the JAX primitive (`psum.1`, `ragged_all_to_all.85`).
+# dynolog_tpu.diagnose classes ops by the same test.
+COLLECTIVE_KINDS = (
+    ("all-reduce", "all-reduce"), ("psum-scatter", "reduce-scatter"),
+    ("psum", "all-reduce"), ("all-gather", "all-gather"),
+    ("reduce-scatter", "reduce-scatter"), ("all-to-all", "all-to-all"),
+    ("collective-permute", "collective-permute"),
+    ("ppermute", "collective-permute"), ("collective", "collective"),
+    ("send", "send"), ("recv", "recv"),
 )
 
 
+def collective_kind(op_name: str) -> str | None:
+    """The kind of collective an op is by its name, None for any other op."""
+    low = op_name.lower().replace("_", "-")
+    return next((kind for tok, kind in COLLECTIVE_KINDS if tok in low), None)
+
+
 def is_collective(op_name: str) -> bool:
-    low = op_name.lower()
-    return any(tok in low for tok in COLLECTIVE_TOKENS)
+    return collective_kind(op_name) is not None
 
 
 @dataclass
@@ -1005,8 +1016,14 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
                      or "tpu" in p.name.lower() or "gpu" in p.name.lower()]
     for p in planes:
         op_ps = sum(a.total_ps for a in p.ops.values())
-        collective_ps = sum(
-            a.total_ps for name, a in p.ops.items() if is_collective(name))
+        kinds: dict[str, list] = {}  # kind of collective -> [ps, count]
+        for name, agg in p.ops.items():
+            kind = collective_kind(name)
+            if kind is not None:
+                row = kinds.setdefault(kind, [0, 0])
+                row[0] += agg.total_ps
+                row[1] += agg.count
+        collective_ps = sum(ps for ps, _ in kinds.values())
         out["planes"].append(
             {
                 "name": p.name,
@@ -1016,6 +1033,13 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
                 # time of the plane's collective ops over all its op time
                 "collective_pct": round(
                     100.0 * collective_ps / op_ps, 2) if op_ps else 0.0,
+                # the same time by kind: gradient reduction (all-reduce)
+                # or expert exchange (all-to-all) is the first question
+                # of a slow sparse job
+                "collectives": {
+                    kind: {"total_ms": round(ps / 1e9, 3), "count": count}
+                    for kind, (ps, count) in sorted(
+                        kinds.items(), key=lambda kv: -kv[1][0])},
                 # what the plane's bytes are made of: `<field>_bytes` add
                 # up to `bytes`; entries of the event-metadata map
                 "bytes": p.bytes,
@@ -1252,6 +1276,9 @@ def main(argv: list[str] | None = None) -> int:
     for p in summary["planes"]:
         print(f"{p['name']:<40.40} {p['lines']:>6} {p['events']:>8} "
               f"{p['duration_ms']:>9.3f} {p['collective_pct']:>7.2f}")
+        for kind, row in p["collectives"].items():
+            print(f"    {kind:<36} {row['count']:>8} events "
+                  f"{row['total_ms']:>9.3f} ms")
     _print_content(summary["planes"])
     if "steps" in summary:
         s = summary["steps"]

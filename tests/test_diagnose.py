@@ -137,6 +137,25 @@ def test_collective_wait_growth_aggregates():
     assert "waiting on a peer" in growth[0]["message"]
 
 
+def test_collective_wait_growth_names_the_kind_that_grew_most():
+    """Gradient reduction or expert exchange: a sparse job's first
+    question. The exchange carries the JAX primitive's name on the TPU."""
+    base = _summary([_op("all-reduce.1", 2.0, 10),
+                     _op("ragged_all_to_all.85", 4.0, 10),
+                     _op("all_to_all.82", 0.1, 10)])
+    cur = _summary([_op("all-reduce.1", 2.5, 10),
+                    _op("ragged_all_to_all.85", 7.0, 10),
+                    _op("all_to_all.82", 0.3, 10)])
+    report = diagnose.diagnose(base, cur)
+    growth, = [f for f in report["findings"]
+               if f["kind"] == "collective_wait_growth"]
+    assert growth["collective"] == "all-to-all"
+    assert growth["impact_ms"] == pytest.approx(3.7)
+    assert "+3.200 ms of it in all-to-all" in growth["message"]
+    assert diagnose.classify_op("ragged_all_to_all.85") == "collective"
+    assert diagnose.classify_op("psum.3") == "collective"
+
+
 def test_step_regression_and_skew_findings():
     steps_base = {"count": 10, "mean_ms": 10.0, "p50_ms": 10.0,
                   "p95_ms": 11.0, "max_ms": 12.0}

@@ -86,17 +86,20 @@ def test_sharded_train_step_matches_single_device():
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
 @slow_lane
-def test_moe_expert_parallel_matches_single_device():
-    """dp x ep x tp MoE step computes the same loss as unsharded (up to
-    bf16 reduction-order noise across shardings).
+@pytest.mark.parametrize("spec", [
+    MeshSpec(expert=4), MeshSpec(data=2, expert=2, model=2),
+], ids=["expert-4", "data-2-expert-2-model-2"])
+def test_moe_expert_parallel_matches_single_device(spec):
+    """The ep and the dp x ep x tp MoE step compute the unsharded step's
+    loss: in float32 a dropless layer agrees to rounding (2e-2 was what
+    token dropping over a capacity needed).
 
-    Slow lane (~40s compile): the default lane keeps only the
-    UNSHARDED test_moe_train_step_reduces_loss; the sharded dp x ep
-    execution path runs in the driver's dryrun every round and this
-    equivalence check runs in CI's slow job."""
+    Slow lane: tests/test_moe.py holds the expert mesh to the one-device
+    step in the default lane, weights and all; this keeps the composition
+    with `data` and `model`."""
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
-        n_experts=4,
+        n_experts=4, dtype="float32",
     )
     batch = make_batch(jax.random.PRNGKey(1), cfg, 4, 32)
 
@@ -104,15 +107,14 @@ def test_moe_expert_parallel_matches_single_device():
     ref_step = make_train_step(cfg)
     _, _, ref_loss = ref_step(ref_params, ref_opt, batch)
 
-    mesh = make_mesh(MeshSpec(data=2, expert=2, model=2))
+    mesh = make_mesh(spec)
     with mesh:
         params, opt_state = make_train_state(jax.random.PRNGKey(0), cfg, mesh)
         step = make_train_step(cfg, mesh)
         sharded_batch = jax.device_put(batch, batch_sharding(mesh))
         _, _, moe_loss = step(params, opt_state, sharded_batch)
 
-    assert jnp.isfinite(moe_loss)
-    assert abs(float(moe_loss) - float(ref_loss)) < 2e-2
+    assert abs(float(moe_loss) - float(ref_loss)) < 1e-5
 
 
 def test_moe_train_step_reduces_loss():

@@ -4,6 +4,7 @@ not against a fixture we also wrote."""
 
 import glob
 import json
+import pathlib
 import subprocess
 import sys
 
@@ -207,3 +208,74 @@ def test_verify_schema_cli():
     )
     assert out.returncode == 0, out.stderr
     assert "schema" in out.stdout
+
+
+# -- collectives by kind ------------------------------------------------------
+
+US = 1_000_000  # picoseconds in a microsecond
+# (op, microseconds an event) on "XLA Ops": XLA names an op it inserts itself
+# after its opcode, and one the program wrote (the expert layer's exchange, a
+# psum in a shard_map) after the JAX primitive, underscores and all.
+SPARSE_JOB_OPS = (
+    ("%fusion.1 = bf16[8,8]{1,0} fusion(%p0)", 50),
+    ("%all-reduce.2 = bf16[8,8]{1,0} all-reduce(%p1)", 20),
+    ("%ragged_all_to_all.85 = bf16[64,8]{1,0} ragged-all-to-all(%p2)", 24),
+    ("%all_to_all.82 = s32[4,1,1]{2,1,0} all-to-all(%p3)", 1),
+    ("%psum.9 = f32[] all-reduce(%p4)", 5),
+)
+# `top_ops` of the fixture below as the parent of PR 34 wrote it
+TOP_OPS_BEFORE = (
+    '[{"op": "fusion", "total_ms": 0.25, "count": 5, "pct": 50.0, "shapes": '
+    '["bf16[8,8]"]}, {"op": "ragged_all_to_all", "total_ms": 0.12, "count": 5, '
+    '"pct": 24.0, "shapes": ["bf16[64,8]"]}, {"op": "all-reduce", "total_ms": '
+    '0.1, "count": 5, "pct": 20.0, "shapes": ["bf16[8,8]"]}, {"op": "psum", '
+    '"total_ms": 0.025, "count": 5, "pct": 5.0, "shapes": ["f32[]"]}, {"op": '
+    '"all_to_all", "total_ms": 0.005, "count": 5, "pct": 1.0, "shapes": '
+    '["s32[4,1,1]"]}]')
+
+
+def sparse_job_xspace() -> bytes:
+    sys.path.insert(0, str(pathlib.Path(__file__).parent))
+    import xspace_fixture as xf
+
+    def plane(name: str, rounds: int) -> bytes:
+        body = xf._field_varint(1, 7) + xf._field_str(2, name)
+        events, offset = [], 0
+        for _ in range(rounds):
+            for meta_id, (_, us) in enumerate(SPARSE_JOB_OPS, start=1):
+                events.append(xf._event(meta_id, offset, us * US))
+                offset += us * US
+        body += xf._field_bytes(3, xf._line(1, "XLA Ops", 1000, events))
+        for meta_id, (op, _) in enumerate(SPARSE_JOB_OPS, start=1):
+            body += xf._field_bytes(4, xf._event_metadata(meta_id, op, ""))
+        return body
+
+    return b"".join(xf._field_bytes(1, p) for p in (
+        plane("/device:TPU:0", 2), plane("/device:TPU:1", 3)))
+
+
+def test_collectives_by_kind_sum_to_the_planes_collective_time(
+        tmp_path, capsys):
+    from dynolog_tpu import trace
+
+    data = sparse_job_xspace()
+    summary = trace._summarize_planes(trace.summarize_xplane_bytes(data))
+    for plane, rounds in zip(summary["planes"], (2, 3)):
+        kinds = plane["collectives"]
+        # 20 + 5 microseconds a round of reduction, 24 + 1 of exchange
+        assert kinds == {
+            "all-reduce": {"total_ms": 25 * rounds / 1e3, "count": 2 * rounds},
+            "all-to-all": {"total_ms": 25 * rounds / 1e3, "count": 2 * rounds}}
+        assert plane["collective_pct"] == 50.0
+        assert sum(k["total_ms"] for k in kinds.values()) == pytest.approx(
+            plane["collective_pct"] / 100.0 * 0.1 * rounds)
+    # the op table is what it was before the kinds were counted
+    assert json.dumps(summary["top_ops"]) == TOP_OPS_BEFORE
+    path = tmp_path / "host.xplane.pb"
+    path.write_bytes(data)
+    assert trace.main([str(path), "--plane", "TPU:1"]) == 0
+    table = capsys.readouterr().out.splitlines()
+    assert table[1].split()[-1] == "50.00"
+    assert [line.split()[0] for line in table[2:4]] == [
+        "all-reduce", "all-to-all"]
+    assert table[3].split()[1:] == ["6", "events", "0.075", "ms"]
