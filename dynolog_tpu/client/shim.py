@@ -722,7 +722,7 @@ class JaxProfiler:
     from the runtime and then converts it to trace.json.gz inside
     `stop_and_export`, all of it on the capture's critical path (the
     collect alone on the chip: `collect_ms`, PERF.md section 5; the
-    conversion is not measured there).
+    conversion, out of process here, is `convert_ms` there).
     This backend drives the underlying ProfilerSession directly: stop()
     collects the raw XSpace and streams the canonical TensorBoard artifact
     (plugins/profile/<run>/<host>.xplane.pb — what TensorBoard/XProf and
@@ -914,11 +914,13 @@ class JaxProfiler:
         return pending
 
     def _spawn_export(self, xplane_path: str, ctx=None) -> None:
-        """Launches the chrome-trace conversion OUT of process: it is
-        seconds of pure-Python work, and an in-process thread would steal
-        the GIL from the training loop (and from the next capture's
-        stop) for its whole run. Falls back to an in-process thread if
-        the interpreter can't be spawned."""
+        """Launches the conversion (summary and Chrome trace, one decode
+        of each plane: trace.write_derived_artifacts) OUT of process: it is
+        tenths of a second of pure-Python work (`convert_ms`, PERF.md
+        section 5), and an in-process thread would steal the GIL from the
+        training loop (and from the next capture's stop) for its whole
+        run. Falls back to an in-process thread if the interpreter can't
+        be spawned."""
         import subprocess
         import sys
 

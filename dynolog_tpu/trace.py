@@ -2,10 +2,12 @@
 
 The daemon + shim capture traces (`dyno gputrace` → jax.profiler); this
 module answers the operator's next question — *what did the device spend
-its time on* — without TensorBoard: it parses the profiler's XSpace
-protobuf directly (pure-stdlib varint walker, no tensorflow/protobuf
-dependency; field numbers verified against traces captured by this repo's
-own e2e flow) and prints per-plane op aggregates.
+its time on* — without TensorBoard: it decodes the profiler's XSpace
+protobuf directly (pure stdlib, each plane once, by offsets into the one
+buffer: no tensorflow/protobuf dependency; field numbers verified against
+traces captured by this repo's own e2e flow and against the wheel's
+descriptor) and prints per-plane op aggregates; the same decode feeds the
+Chrome trace the shim's export child writes beside the summary.
 
 CLI::
 
@@ -34,21 +36,11 @@ from dataclasses import dataclass, field
 # struct-constant rule.
 FLOAT64 = struct.Struct("<d")
 
-# XSpace schema subset (_SCHEMA_PINS below). Originally pinned empirically
-# against traces this repo's own e2e flow captures; now also verifiable
-# against the xplane FileDescriptor embedded in the installed wheel
-# (verify_schema_pins() — a jax upgrade that renumbers a field fails
-# loudly instead of silently mis-summarizing):
-#   XSpace.planes = 1
-#   XPlane: name=2, lines=3, event_metadata=4 (map), stat_metadata=5 (map),
-#           stats=6
-#   XLine: id=1, name=2, timestamp_ns=3, events=4
-#   XEvent: metadata_id=1, offset_ps=2, duration_ps=3, stats=4
-#   XEventMetadata: id=1, name=2, display_name=4, stats=5
-#   XStat: metadata_id=1, double=2, uint64=3, int64=4, str=5, ref=7
-#   map entries: key=1, value=2 (XEventMetadata also embeds its own id=1)
-
-# message -> {field name: pinned number}; checked against the wheel.
+# The XSpace schema subset the decoder reads, message -> {field name: pinned
+# number}. Originally pinned empirically against traces this repo's own e2e
+# flow captures; now also verifiable against the xplane FileDescriptor
+# embedded in the installed wheel (verify_schema_pins() — a jax upgrade that
+# renumbers a field fails loudly instead of silently mis-summarizing).
 _SCHEMA_PINS = {
     "XSpace": {"planes": 1},
     "XPlane": {
@@ -126,87 +118,67 @@ def verify_schema_pins() -> tuple[bool | None, list[str]]:
     return (not mismatches), mismatches
 
 
-def _walk(buf: bytes):
-    """Yields (field_number, wire_type, value) over one message's fields.
-    Varints yield ints, length-delimited yield bytes; fixed widths yield
-    raw bytes. Raises ValueError on malformed input."""
-    i, n = 0, len(buf)
-    while i < n:
-        tag = 0
-        shift = 0
-        while True:
-            if i >= n:
-                raise ValueError("truncated tag")
-            b = buf[i]
+def _read_varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if not b & 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf, i: int, end: int) -> list[tuple[int, int, int, int]]:
+    """The fields of the message at buf[i:end], decoded in one loop over the
+    one buffer the file was read into: (number, wire type, a, b). A varint's
+    `a` is its value; a length-delimited or fixed field's `a` is where its
+    payload starts, cut out (`buf[a:b]`) only where it is used: a name, never
+    HLO bytes. `b` is where the field ends, so a message's fields tile
+    [i, end). Raises ValueError on truncated or malformed input."""
+    out = []
+    add = out.append
+    try:
+        while i < end:
+            tag = buf[i]
             i += 1
-            tag |= (b & 0x7F) << shift
-            shift += 7
-            if not b & 0x80:
-                break
-        num, wt = tag >> 3, tag & 7
-        if num == 0:
-            raise ValueError("field 0")
-        if wt == 0:
-            v = 0
-            shift = 0
-            while True:
-                if i >= n:
-                    raise ValueError("truncated varint")
-                b = buf[i]
+            if tag > 0x7F:  # a field number above 15
+                tag, i = _read_varint(buf, i - 1)
+            if tag < 8:
+                raise ValueError("field 0")
+            wt = tag & 7
+            if wt == 0:
+                v = buf[i]
                 i += 1
-                v |= (b & 0x7F) << shift
-                shift += 7
-                if not b & 0x80:
-                    break
-            yield num, wt, v
-        elif wt == 2:
-            ln = 0
-            shift = 0
-            while True:
-                if i >= n:
-                    raise ValueError("truncated length")
-                b = buf[i]
+                if v > 0x7F:  # inlined: offsets and durations take this path
+                    v &= 0x7F
+                    shift = 7
+                    while True:
+                        b = buf[i]
+                        i += 1
+                        v |= (b & 0x7F) << shift
+                        if b < 0x80:
+                            break
+                        shift += 7
+                add((tag >> 3, 0, v, i))
+            elif wt == 2:
+                size = buf[i]
                 i += 1
-                ln |= (b & 0x7F) << shift
-                shift += 7
-                if not b & 0x80:
-                    break
-            if i + ln > n:
-                raise ValueError("truncated bytes")
-            yield num, wt, buf[i:i + ln]
-            i += ln
-        elif wt in (1, 5):
-            width = 8 if wt == 1 else 4
-            if i + width > n:
-                raise ValueError("truncated fixed")
-            yield num, wt, buf[i:i + width]
-            i += width
-        else:
-            raise ValueError(f"unsupported wire type {wt}")
-
-
-def _parse_event_metadata_entry(buf: bytes) -> tuple[int, str, str, list]:
-    """One map<id, XEventMetadata> entry -> (id, name, display_name, raw
-    XStat buffers). The id may arrive as the map-entry key (field 1) or as
-    the embedded XEventMetadata.id — producers are free to set either, so
-    both the summarizer and the chrome-trace converter read both through
-    this one parser."""
-    mid, name, disp, stats = 0, "", "", []
-    for mn, mw, mv in _walk(buf):
-        if mn == 1 and mw == 0:
-            mid = mv
-        elif mn == 2 and mw == 2:  # XEventMetadata
-            for en, ew, ev in _walk(mv):
-                if en == 1 and ew == 0:
-                    mid = ev
-                elif en == 2 and ew == 2:
-                    name = ev.decode(errors="replace")
-                elif en == 4 and ew == 2:
-                    # display_name (field 3 is `metadata`: opaque bytes)
-                    disp = ev.decode(errors="replace")
-                elif en == 5 and ew == 2:
-                    stats.append(ev)
-    return mid, name, disp, stats
+                if size > 0x7F:
+                    size, i = _read_varint(buf, i - 1)
+                add((tag >> 3, 2, i, i + size))
+                i += size
+            elif wt == 1 or wt == 5:
+                width = 8 if wt == 1 else 4
+                add((tag >> 3, wt, i, i + width))
+                i += width
+            else:
+                raise ValueError(f"unsupported wire type {wt}")
+    except IndexError as e:
+        raise ValueError("truncated message") from e
+    if i != end:  # the last field runs past the message's end
+        raise ValueError("truncated field")
+    return out
 
 
 # (op-name fragment, kind of collective), first match wins, `_` read as `-`:
@@ -275,21 +247,6 @@ CONTENT_FIELDS = {3: "lines", 4: "event_metadata", 5: "stat_metadata",
                   6: "stats"}
 
 
-def _varint_len(n: int) -> int:
-    return max(1, (n.bit_length() + 6) // 7)
-
-
-def _field_size(num: int, wt: int, value) -> int:
-    """The encoded size of one field as `_walk` yields it: its tag, its
-    length prefix where it has one, its payload."""
-    tag = _varint_len(num << 3)
-    if wt == 0:
-        return tag + _varint_len(value)
-    if wt == 2:
-        return tag + _varint_len(len(value)) + len(value)
-    return tag + len(value)  # fixed 64 / 32
-
-
 @dataclass
 class PlaneSummary:
     name: str
@@ -321,169 +278,230 @@ def _op_key(name: str, group: bool) -> str:
     return name
 
 
+# The stats the op table reads, by XStatMetadata.name: the cost model's two
+# numbers and the XProf category string.
+COST_STATS = ("flops", "bytes_accessed", "hlo_category")
+
+
+@dataclass
+class _Plane:
+    """One plane, decoded once: what its PlaneSummary and its Chrome-trace
+    fragment are both made from."""
+
+    name: str = ""
+    bytes: int = 0
+    content: dict = field(default_factory=dict)  # as PlaneSummary.content
+    event_metadata: int = 0  # entries of the map, as they come
+    names: dict = field(default_factory=dict)  # event metadata id -> name
+    shown: dict = field(default_factory=dict)  # id -> display_name or name
+    costs: dict = field(default_factory=dict)  # id -> {COST_STATS name: value}
+    # a line: (id, name, timestamp_ns, [(metadata id, offset_ps, duration_ps,
+    # the event's own costs or None)])
+    lines: list = field(default_factory=list)
+
+
+def _map_entry(buf, a: int, b: int) -> tuple[int, list]:
+    """One map<int64, message> entry: (id, the value's fields). The id may
+    arrive as the entry's key (field 1) or as the embedded message's own
+    field 1 (XEventMetadata.id, XStatMetadata.id): producers are free to set
+    either, and the one read later stands."""
+    mid, inner = 0, []
+    for num, wt, x, y in _fields(buf, a, b):
+        if num == 1 and wt == 0:
+            mid = x
+        elif num == 2 and wt == 2:
+            inner = _fields(buf, x, y)
+            for en, ew, ex, _ in inner:
+                if en == 1 and ew == 0:
+                    mid = ex
+    return mid, inner
+
+
+def _costs(buf, stat_spans, kinds: dict) -> dict:
+    """{COST_STATS name: value} of the XStats at the spans, by the plane's
+    `kinds` {stat metadata id: COST_STATS name}. The callers hand over the
+    stats worth opening: one whose metadata id leads it in one byte, as
+    producers write it, and is none of `kinds` they step over, unread."""
+    found = {}
+    for a, b in stat_spans:
+        sid, value, text = 0, None, None
+        for num, wt, x, y in _fields(buf, a, b):
+            if num == 1 and wt == 0:
+                sid = x
+            elif num == 2 and wt == 1:
+                value = FLOAT64.unpack_from(buf, x)[0]
+            elif num in (3, 4, 7) and wt == 0:
+                value = float(x)
+            elif num == 5 and wt == 2:
+                text = buf[x:y]
+        kind = kinds.get(sid)
+        if kind == "hlo_category":
+            if text is not None:
+                found[kind] = text.decode(errors="replace")
+        elif kind is not None and value is not None:
+            found[kind] = value
+    return found
+
+
+def _decode_plane(buf, start: int, end: int) -> _Plane:
+    """The one decode of the plane at buf[start:end]: metadata first (the
+    stats' names, then every op's names and cost model), then every line
+    once and every event once. Nothing is copied but the names."""
+    plane = _Plane(bytes=end - start)
+    line_spans, metadata_spans = [], []
+    kinds: dict[int, str] = {}  # stat metadata id -> its COST_STATS name
+    at = start
+    for num, wt, x, y in _fields(buf, start, end):
+        kind = CONTENT_FIELDS.get(num, "other")
+        plane.content[kind] = plane.content.get(kind, 0) + y - at
+        at = y
+        if wt != 2:
+            continue
+        if num == 2:
+            plane.name = buf[x:y].decode(errors="replace")
+        elif num == 3:
+            line_spans.append((x, y))
+        elif num == 4:
+            metadata_spans.append((x, y))
+        elif num == 5:
+            sid, inner = _map_entry(buf, x, y)
+            sname = ""
+            for en, ew, ex, ey in inner:
+                if en == 2 and ew == 2:
+                    sname = buf[ex:ey].decode(errors="replace")
+            kinds.pop(sid, None)  # an id said twice: the later entry stands
+            if sname in COST_STATS:
+                kinds[sid] = sname
+    # Cost-model stats (flops, bytes_accessed) and the hlo_category string
+    # hang off the event METADATA, one set per op instance.
+    plane.event_metadata = len(metadata_spans)
+    for a, b in metadata_spans:
+        mid, inner = _map_entry(buf, a, b)
+        name = disp = ""
+        stat_spans = []
+        for num, wt, x, y in inner:
+            if wt != 2:
+                continue
+            if num == 2:
+                name = buf[x:y].decode(errors="replace")
+            elif num == 4:  # display_name (3 is `metadata`: opaque bytes)
+                disp = buf[x:y].decode(errors="replace")
+            elif num == 5 and kinds and (
+                    y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
+                    or buf[x + 1] in kinds):
+                stat_spans.append((x, y))
+        plane.names[mid] = name
+        plane.shown[mid] = disp or name
+        plane.costs[mid] = _costs(buf, stat_spans, kinds)
+    lines = []
+    for a, b in line_spans:
+        lid, lname, ts_ns, event_spans = 0, "", 0, []
+        for num, wt, x, y in _fields(buf, a, b):
+            if num == 4 and wt == 2:
+                event_spans.append((x, y))
+            elif num == 1 and wt == 0:
+                lid = x
+            elif num == 2 and wt == 2:
+                lname = buf[x:y].decode(errors="replace")
+            elif num == 3 and wt == 0:
+                ts_ns = x
+        lines.append((lid, lname, ts_ns, event_spans))
+    has_xla_ops = any(lname == "XLA Ops" for _, lname, _, _ in lines)
+    for lid, lname, ts_ns, event_spans in lines:
+        # Per-occurrence stats override the metadata's cost model where a
+        # producer emits them per event; only the lines the op table reads
+        # (see _plane_summary) have theirs looked at.
+        own = kinds if not has_xla_ops or lname == "XLA Ops" else None
+        events = []
+        for a, b in event_spans:
+            meta_id = offset_ps = duration_ps = 0
+            stat_spans = []
+            for num, wt, x, y in _fields(buf, a, b):
+                if wt == 0:
+                    if num == 1:
+                        meta_id = x
+                    elif num == 2:
+                        offset_ps = x
+                    elif num == 3:
+                        duration_ps = x
+                elif num == 4 and wt == 2 and own and (
+                        y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
+                        or buf[x + 1] in own):
+                    stat_spans.append((x, y))
+            events.append((
+                meta_id, offset_ps, duration_ps,
+                _costs(buf, stat_spans, own) if stat_spans else None))
+        plane.lines.append((lid, lname, ts_ns, events))
+    return plane
+
+
+def _plane_summary(
+    plane: _Plane, group: bool = True, by_category: bool = False
+) -> PlaneSummary:
+    out = PlaneSummary(
+        name=plane.name, bytes=plane.bytes,
+        event_metadata=plane.event_metadata, lines=len(plane.lines),
+        line_names=[lname for _, lname, _, _ in plane.lines])
+    out.content.update(plane.content)
+    # Device planes carry several views of the same window (Steps, XLA
+    # Modules, XLA Ops, Async XLA Ops); the op table reads the synchronous
+    # "XLA Ops" line when present so step-number and module events don't
+    # pollute it and async copies don't double count compute time.
+    has_xla_ops = "XLA Ops" in out.line_names
+    by_id: dict[int, tuple] = {}  # metadata id -> (its row, flops, bytes)
+    for _, lname, _, events in plane.lines:
+        out.events += len(events)
+        count_ops = not has_xla_ops or lname == "XLA Ops"
+        steps = lname == "Steps"
+        for meta_id, offset_ps, duration_ps, own in events:
+            if offset_ps + duration_ps > out.duration_ps:
+                out.duration_ps = offset_ps + duration_ps
+            if steps and duration_ps > 0:
+                out.step_durations_ps.append(duration_ps)
+            if not count_ops:
+                continue
+            row = by_id.get(meta_id)
+            if row is None:
+                name = plane.names.get(meta_id, f"op#{meta_id}")
+                costs = plane.costs.get(meta_id, {})
+                key = (costs.get("hlo_category", "uncategorized")
+                       if by_category else _op_key(name, group))
+                agg = out.ops.setdefault(key, OpAggregate(key))
+                # an id's shape is the same at every event: offered once
+                shape = _op_shape(name)
+                if shape and len(agg.shapes) < SHAPES_PER_OP:
+                    agg.shapes.add(shape)
+                row = by_id[meta_id] = (
+                    agg, costs.get("flops", 0.0),
+                    costs.get("bytes_accessed", 0.0))
+            agg, flops, nbytes = row
+            if own and (own.get("flops") or own.get("bytes_accessed")):
+                flops = own.get("flops", 0.0)
+                nbytes = own.get("bytes_accessed", 0.0)
+            agg.total_ps += duration_ps
+            agg.count += 1
+            agg.flops += flops
+            agg.bytes_accessed += nbytes
+    return out
+
+
+def _plane_spans(data) -> list[tuple[int, int]]:
+    return [(a, b) for num, wt, a, b in _fields(data, 0, len(data))
+            if num == 1 and wt == 2]
+
+
 def summarize_xplane_bytes(
     data: bytes, group: bool = True, by_category: bool = False
 ) -> list[PlaneSummary]:
-    planes = []
-    for num, wt, plane_buf in _walk(data):
-        if num != 1 or wt != 2:
-            continue
-        plane = PlaneSummary(name="", bytes=len(plane_buf))
-        metadata_names: dict[int, str] = {}
-        metadata_shapes: dict[int, str] = {}
-        metadata_stats: dict[int, list] = {}
-        stat_names: dict[int, str] = {}
-        lines = []
-        for pn, pw, pv in _walk(plane_buf):
-            plane.content[CONTENT_FIELDS.get(pn, "other")] += _field_size(
-                pn, pw, pv)
-            if pn == 2 and pw == 2:
-                plane.name = pv.decode(errors="replace")
-            elif pn == 3 and pw == 2:
-                lines.append(pv)
-            elif pn == 4 and pw == 2:  # event_metadata map entry
-                plane.event_metadata += 1
-                meta_id, meta_name, _disp, meta_stats = (
-                    _parse_event_metadata_entry(pv))
-                metadata_names[meta_id] = meta_name
-                shape = _op_shape(meta_name)
-                if shape:
-                    metadata_shapes[meta_id] = shape
-                metadata_stats[meta_id] = meta_stats
-            elif pn == 5 and pw == 2:  # stat_metadata map entry
-                sid, sname = 0, ""
-                for mn, mw, mv in _walk(pv):
-                    if mn == 1 and mw == 0:
-                        sid = mv
-                    elif mn == 2 and mw == 2:  # XStatMetadata{id=1,name=2}
-                        for en, ew, ev in _walk(mv):
-                            if en == 1 and ew == 0:
-                                sid = ev
-                            elif en == 2 and ew == 2:
-                                sname = ev.decode(errors="replace")
-                stat_names[sid] = sname
-        flop_stat_ids = {i for i, n in stat_names.items() if n == "flops"}
-        bytes_stat_ids = {
-            i for i, n in stat_names.items() if n == "bytes_accessed"
-        }
-        category_stat_ids = {
-            i for i, n in stat_names.items() if n == "hlo_category"
-        }
-
-        def _stat_value(buf) -> tuple[int, float | None]:
-            sid, sval = 0, None
-            for sn, sw, sv in _walk(buf):
-                if sn == 1 and sw == 0:
-                    sid = sv
-                elif sn == 2 and sw == 1:
-                    sval = FLOAT64.unpack(sv)[0]
-                elif sn in (3, 4, 7) and sw == 0:
-                    sval = float(sv)
-            return sid, sval
-
-        # Cost-model stats (flops, bytes_accessed) and the hlo_category
-        # string hang off the event METADATA, one set per op instance.
-        meta_costs: dict[int, tuple[float, float]] = {}
-        meta_category: dict[int, str] = {}
-        for mid, bufs in metadata_stats.items():
-            flops = nbytes = 0.0
-            for buf in bufs:
-                sid, sval = _stat_value(buf)
-                if sid in category_stat_ids:
-                    for sn, sw, sv in _walk(buf):
-                        if sn == 5 and sw == 2:  # str_value
-                            meta_category[mid] = sv.decode(errors="replace")
-                if sval is None:
-                    continue
-                if sid in flop_stat_ids:
-                    flops = sval
-                elif sid in bytes_stat_ids:
-                    nbytes = sval
-            if flops or nbytes:
-                meta_costs[mid] = (flops, nbytes)
-        # Device planes carry several views of the same window (Steps,
-        # XLA Modules, XLA Ops, Async XLA Ops); the op table reads the
-        # synchronous "XLA Ops" line when present so step-number and
-        # module events don't pollute it and async copies don't double
-        # count compute time.
-        line_infos = []
-        for line_buf in lines:
-            lname = ""
-            for ln, lw, lv in _walk(line_buf):
-                if ln == 2 and lw == 2:
-                    lname = lv.decode(errors="replace")
-            line_infos.append((lname, line_buf))
-        plane.line_names = [n for n, _ in line_infos]
-        has_xla_ops = any(n == "XLA Ops" for n, _ in line_infos)
-        for lname, line_buf in line_infos:
-            plane.lines += 1
-            count_ops = not has_xla_ops or lname == "XLA Ops"
-            for ln, lw, lv in _walk(line_buf):
-                if ln != 4 or lw != 2:
-                    continue
-                plane.events += 1
-                meta_id = offset_ps = duration_ps = 0
-                flops = nbytes = 0.0
-                for en, ew, ev in _walk(lv):
-                    if ew == 0:
-                        if en == 1:
-                            meta_id = ev
-                        elif en == 2:
-                            offset_ps = ev
-                        elif en == 3:
-                            duration_ps = ev
-                    elif en == 4 and ew == 2 and count_ops:
-                        # Per-occurrence stats override metadata cost model
-                        # when a producer emits them per event.
-                        sid, sval = _stat_value(ev)
-                        if sval is None:
-                            continue
-                        if sid in flop_stat_ids:
-                            flops = sval
-                        elif sid in bytes_stat_ids:
-                            nbytes = sval
-                plane.duration_ps = max(
-                    plane.duration_ps, offset_ps + duration_ps)
-                if lname == "Steps" and duration_ps > 0:
-                    plane.step_durations_ps.append(duration_ps)
-                if not count_ops:
-                    continue
-                if not (flops or nbytes) and meta_id in meta_costs:
-                    flops, nbytes = meta_costs[meta_id]
-                if by_category:
-                    name = meta_category.get(meta_id, "uncategorized")
-                else:
-                    name = _op_key(
-                        metadata_names.get(meta_id, f"op#{meta_id}"), group)
-                agg = plane.ops.setdefault(name, OpAggregate(name))
-                agg.total_ps += duration_ps
-                agg.count += 1
-                agg.flops += flops
-                agg.bytes_accessed += nbytes
-                shape = metadata_shapes.get(meta_id)
-                if shape and len(agg.shapes) < SHAPES_PER_OP:
-                    agg.shapes.add(shape)
-        planes.append(plane)
-    return planes
+    return [_plane_summary(_decode_plane(data, a, b), group, by_category)
+            for a, b in _plane_spans(data)]
 
 
 def iter_plane_bufs(data: bytes):
     """Yields each plane's raw protobuf buffer from a serialized XSpace —
     the unit of work the parallel converter fans out over."""
-    for num, wt, plane_buf in _walk(data):
-        if num == 1 and wt == 2:
-            yield plane_buf
-
-
-def _read_varint(buf, i: int) -> tuple[int, int]:
-    value = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        value |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return value, i
-        shift += 7
+    for a, b in _plane_spans(data):
+        yield data[a:b]
 
 
 def plane_index(data) -> list[dict]:
@@ -495,31 +513,12 @@ def plane_index(data) -> list[dict]:
     cost is a few fields a plane whatever the trace's size. Raises
     ValueError (IndexError folded in) on malformed input."""
     view = memoryview(data)
-    planes = []
-    i, n = 0, len(view)
     try:
-        while i < n:
-            tag, i = _read_varint(view, i)
-            wt = tag & 7
-            if wt == 0:
-                _, i = _read_varint(view, i)
-                continue
-            if wt in (1, 5):
-                i += 8 if wt == 1 else 4
-                continue
-            if wt != 2:
-                raise ValueError(f"unsupported wire type {wt}")
-            size, i = _read_varint(view, i)
-            end = i + size
-            if end > n:
-                raise ValueError("truncated plane")
-            if tag >> 3 == 1:
-                planes.append(
-                    {"name": _plane_name(view, i, end), "bytes": size})
-            i = end
+        return [{"name": _plane_name(view, a, b), "bytes": b - a}
+                for num, wt, a, b in _fields(view, 0, len(view))
+                if num == 1 and wt == 2]
     except IndexError as e:
         raise ValueError("truncated xspace") from e
-    return planes
 
 
 def _plane_name(view, i: int, end: int) -> str:
@@ -551,56 +550,52 @@ def _plane_events(pid: int, plane_buf: bytes) -> list[dict]:
     complete event ("ph":"X") at ts = line.timestamp_ns + offset_ps,
     named by its XEventMetadata display_name (fallback: name).
     """
-    events: list[dict] = []
-    plane_name = ""
-    meta_names: dict[int, str] = {}
-    lines = []
-    for pn, pw, pv in _walk(plane_buf):
-        if pn == 2 and pw == 2:
-            plane_name = pv.decode(errors="replace")
-        elif pn == 3 and pw == 2:
-            lines.append(pv)
-        elif pn == 4 and pw == 2:  # event_metadata map entry
-            mid, mname, mdisp, _stats = _parse_event_metadata_entry(pv)
-            meta_names[mid] = mdisp or mname
-    events.append({
-        "ph": "M", "pid": pid, "name": "process_name",
-        "args": {"name": plane_name},
-    })
-    for line_buf in lines:
-        lid, lname, ts_ns, evbufs = 0, "", 0, []
-        for ln, lw, lv in _walk(line_buf):
-            if ln == 1 and lw == 0:
-                lid = lv
-            elif ln == 2 and lw == 2:
-                lname = lv.decode(errors="replace")
-            elif ln == 3 and lw == 0:
-                ts_ns = lv
-            elif ln == 4 and lw == 2:
-                evbufs.append(lv)
-        events.append({
-            "ph": "M", "pid": pid, "tid": lid, "name": "thread_name",
-            "args": {"name": lname},
-        })
+    plane = _decode_plane(plane_buf, 0, len(plane_buf))
+    events: list[dict] = [_process_name(pid, plane)]
+    for lid, lname, ts_ns, line_events in plane.lines:
+        events.append(_thread_name(pid, lid, lname))
         base_us = ts_ns / 1e3
-        for ev_buf in evbufs:
-            meta_id = offset_ps = duration_ps = 0
-            for en, ew, ev in _walk(ev_buf):
-                if ew != 0:
-                    continue
-                if en == 1:
-                    meta_id = ev
-                elif en == 2:
-                    offset_ps = ev
-                elif en == 3:
-                    duration_ps = ev
+        for meta_id, offset_ps, duration_ps, _ in line_events:
             events.append({
                 "ph": "X", "pid": pid, "tid": lid,
-                "name": meta_names.get(meta_id, f"op#{meta_id}"),
+                "name": plane.shown.get(meta_id, f"op#{meta_id}"),
                 "ts": base_us + offset_ps / 1e6,
                 "dur": duration_ps / 1e6,
             })
     return events
+
+
+def _process_name(pid: int, plane: _Plane) -> dict:
+    return {"ph": "M", "pid": pid, "name": "process_name",
+            "args": {"name": plane.name}}
+
+
+def _thread_name(pid: int, lid: int, lname: str) -> dict:
+    return {"ph": "M", "pid": pid, "tid": lid, "name": "thread_name",
+            "args": {"name": lname}}
+
+
+def _plane_json(pid: int, plane: _Plane) -> bytes:
+    """`_plane_events` of a decoded plane as a UTF-8 JSON fragment: the
+    events, `", "`-joined, WITHOUT the surrounding array brackets, each byte
+    for byte what `json.dumps` prints (a float by `float.__repr__`: `!r`),
+    so that the planes' fragments joined by `", "` are `json.dump` of the
+    whole list. A name is `json.dumps`-ed once a metadata id, not an event."""
+    parts = [json.dumps(_process_name(pid, plane))]
+    quoted: dict[int, str] = {}
+    for lid, lname, ts_ns, line_events in plane.lines:
+        parts.append(json.dumps(_thread_name(pid, lid, lname)))
+        head = f'{{"ph": "X", "pid": {pid}, "tid": {lid}, "name": '
+        base_us = ts_ns / 1e3
+        for meta_id, offset_ps, duration_ps, _ in line_events:
+            name = quoted.get(meta_id)
+            if name is None:
+                name = quoted[meta_id] = json.dumps(
+                    plane.shown.get(meta_id, f"op#{meta_id}"))
+            parts.append(
+                f'{head}{name}, "ts": {base_us + offset_ps / 1e6!r}, '
+                f'"dur": {duration_ps / 1e6!r}}}')
+    return ", ".join(parts).encode()
 
 
 def xplane_to_chrome_trace(data: bytes) -> dict:
@@ -608,12 +603,6 @@ def xplane_to_chrome_trace(data: bytes) -> dict:
     trace.json.gz artifact jax.profiler's own export writes next to the
     xplane.pb — loadable in chrome://tracing and, minus the metadata
     field, ui.perfetto.dev).
-
-    Exists so the shim's fast-stop path (shim.JaxProfiler) can write the
-    raw XSpace on the capture's critical path (milliseconds) and produce
-    this derived view in the background: the conversion is exactly what
-    the reference-style `jax.profiler.stop_trace()` export spends AFTER
-    collection (not measured on the chip; docs/TRACE_PIPELINE.md).
 
     This is the single-shot in-memory form (everything in one dict); the
     production writer is the streamed, budgeted `write_chrome_trace_gz`,
@@ -699,15 +688,22 @@ def _nice_worker(nice: int) -> None:
 
 
 def _plane_fragment(job: tuple[int, bytes]) -> bytes:
-    """One plane's events as a UTF-8 JSON fragment: the events, already
-    `", "`-joined, WITHOUT the surrounding array brackets. Joining the
-    per-plane fragments with `", "` reproduces `json.dump`'s output for
-    the full event list byte for byte (same default separators), which is
-    what keeps the streamed and single-shot converters event-identical.
-    Top-level so ProcessPoolExecutor can pickle it by reference."""
+    """One plane's events as a UTF-8 JSON fragment (`_plane_json`)."""
+    return _convert_plane(job)[0]
+
+
+def _convert_plane(job: tuple[int, bytes]) -> tuple:
+    """The converter's unit of work: one plane decoded ONCE, its fragment
+    and its PlaneSummary both made from that decode (the summary None where
+    aggregating it raised: the fragment still goes). Top-level so
+    ProcessPoolExecutor can pickle it by reference."""
     pid, plane_buf = job
-    return ", ".join(
-        json.dumps(e) for e in _plane_events(pid, plane_buf)).encode()
+    plane = _decode_plane(plane_buf, 0, len(plane_buf))
+    try:
+        summary = _plane_summary(plane)
+    except Exception:  # noqa: BLE001 - a summarizer bug must not cost
+        summary = None  # the trace.json.gz; the caller finds the None
+    return _plane_json(pid, plane), summary
 
 
 def _fork_safe() -> bool:
@@ -723,7 +719,8 @@ def _fork_safe() -> bool:
 
 
 def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
-    """Per-plane JSON fragments, in plane order, under the budget: a
+    """Per-plane (JSON fragment, PlaneSummary) pairs (`_convert_plane`), in
+    plane order, under the budget: a
     nice'd process pool when the budget allows >1 worker (and there is
     more than one plane to win on), else serial with plane-batch
     yielding. Pool failure — at setup (sandboxes without working fork)
@@ -742,14 +739,14 @@ def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
                 initializer=_nice_worker,
                 initargs=(budget.nice,),
             ) as pool:
-                for fragment in pool.map(_plane_fragment, jobs):
-                    yield fragment
+                for converted in pool.map(_convert_plane, jobs):
+                    yield converted
                     done += 1
             return
         except (OSError, RuntimeError):
             pass  # pool died; planes [done:] convert serially below
     for i, job in enumerate(jobs[done:], start=done + 1):
-        yield _plane_fragment(job)
+        yield _convert_plane(job)
         if (budget.yield_s > 0 and budget.yield_every_planes > 0
                 and i % budget.yield_every_planes == 0 and i < len(jobs)):
             time.sleep(budget.yield_s)
@@ -800,9 +797,13 @@ def write_chrome_trace_gz(
     xplane_path: str,
     data: bytes | None = None,
     budget: ConvertBudget | None = None,
+    summaries: list | None = None,
 ) -> str:
     """Write <base>.trace.json.gz next to an .xplane.pb (the companion
-    artifact jax's own stop_trace export produces); returns its path.
+    artifact jax's own stop_trace export produces); returns its path. Where
+    `summaries` is given, each plane's PlaneSummary (None where aggregating
+    it raised) is appended to it as the plane's fragment goes: the same
+    decode made both (`_convert_plane`).
 
     Streamed and budgeted: planes convert to JSON fragments in a nice'd
     worker pool (or serially, per `budget`), and each fragment goes
@@ -829,8 +830,10 @@ def write_chrome_trace_gz(
         comp = zlib.compressobj(level, zlib.DEFLATED, 16 + zlib.MAX_WBITS)
         yield comp.compress(b'{"displayTimeUnit": "ns", "traceEvents": [')
         first = True
-        for fragment in _iter_fragments(list(iter_plane_bufs(data)),
-                                        budget):
+        for fragment, summary in _iter_fragments(
+                list(iter_plane_bufs(data)), budget):
+            if summaries is not None:
+                summaries.append(summary)
             if not fragment:
                 continue
             if not first:
@@ -869,25 +872,34 @@ def write_chrome_trace_gz_single(
     return out_path
 
 
-def write_summary_json(xplane_path: str, data: bytes | None = None) -> str:
+def write_summary_json(
+    xplane_path: str,
+    data: bytes | None = None,
+    planes: list | None = None,
+) -> str:
     """Write <base>.summary.json next to an .xplane.pb: the summarize()
     output (planes, step stats, top-op table with roofline columns), so
     every capture self-describes without the operator running anything —
-    produced by the shim's background export alongside trace.json.gz."""
-    summary = _summarize_planes(
-        summarize_xplane_bytes(_read_xplane(xplane_path, data)))
+    produced by the shim's background export alongside trace.json.gz, from
+    the `planes` that conversion kept where it kept them all."""
+    if planes is None or None in planes:
+        planes = summarize_xplane_bytes(_read_xplane(xplane_path, data))
     out_path = _derived_path(xplane_path, ".summary.json")
     # stream_write owns the tmp/rename/unlink-on-failure discipline.
-    stream_write(out_path, [json.dumps(summary, indent=1).encode()])
+    stream_write(out_path, [
+        json.dumps(_summarize_planes(planes), indent=1).encode()])
     return out_path
 
 
 def write_derived_artifacts(
     xplane_path: str, budget: ConvertBudget | None = None
 ) -> list[str]:
-    """Background-export entry point: read the xplane ONCE and write each
-    companion artifact in its own failure domain — a summarizer bug must
-    not cost the trace.json.gz (or vice versa). Returns written paths.
+    """Background-export entry point: read the xplane ONCE, decode each of
+    its planes ONCE (the Chrome trace's writer hands on the PlaneSummary
+    each plane's decode also gave) and write each companion artifact in its
+    own failure domain — a summarizer bug must not cost the trace.json.gz,
+    and a converter that breaks off leaves the summary a pass of its own.
+    Returns written paths.
 
     Self-tracing: the whole conversion runs under a trace.convert span —
     parented to the capture's TRACE_CONTEXT when the shim handed one down
@@ -906,15 +918,16 @@ def write_derived_artifacts(
             with open(xplane_path, "rb") as f:
                 data = f.read()
             written = []
-            writers = (
-                lambda: write_summary_json(xplane_path, data),
-                lambda: write_chrome_trace_gz(xplane_path, data, budget),
-            )
-            for writer in writers:
-                try:
-                    written.append(writer())
-                except Exception:  # noqa: BLE001 - derived artifacts are
-                    pass  # best-effort; the canonical xplane.pb is on disk
+            planes: list | None = []
+            try:
+                written.append(
+                    write_chrome_trace_gz(xplane_path, data, budget, planes))
+            except Exception:  # noqa: BLE001 - derived artifacts are
+                planes = None  # best-effort; the xplane.pb is on disk
+            try:
+                written.append(write_summary_json(xplane_path, data, planes))
+            except Exception:  # noqa: BLE001 - as above
+                pass
     finally:
         obs.maybe_flush_env()
     return written
@@ -950,16 +963,6 @@ def summarize(
     return _summarize_planes(planes)
 
 
-def _encode_varint(n: int) -> bytes:
-    out = bytearray()
-    while True:
-        b7 = n & 0x7F
-        n >>= 7
-        out.append(b7 | (0x80 if n else 0))
-        if not n:
-            return bytes(out)
-
-
 def compact_profile(
     data: bytes,
     top: int = 40,
@@ -976,14 +979,11 @@ def compact_profile(
     if budget is None:
         budget = ConvertBudget.from_env()
     planes: list[PlaneSummary] = []
-    for i, plane_buf in enumerate(iter_plane_bufs(data), start=1):
-        # Re-wrap the plane as a one-plane XSpace (field 1, wire type 2)
-        # so the pinned-schema walker summarizes it unchanged.
+    for i, (a, b) in enumerate(_plane_spans(data), start=1):
         # group=False by default: per-op-INSTANCE rows (fusion.116, not
         # fusion) are the diagnosable unit — "which fusion regressed" is
         # the whole question the diff engine answers.
-        wrapped = b"\x0a" + _encode_varint(len(plane_buf)) + plane_buf
-        planes.extend(summarize_xplane_bytes(wrapped, group=group))
+        planes.append(_plane_summary(_decode_plane(data, a, b), group=group))
         if (budget.yield_s > 0 and budget.yield_every_planes > 0
                 and i % budget.yield_every_planes == 0):
             time.sleep(budget.yield_s)

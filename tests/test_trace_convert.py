@@ -1,7 +1,12 @@
 """Streamed, budgeted trace converter (dynolog_tpu.trace) against the
 checked-in XSpace fixture.
 
-Three contracts:
+Four contracts:
+- ONE DECODE: a plane is decoded once, and that decode gives its
+  PlaneSummary and its Chrome-trace fragment: both equal to what the
+  wheel's own xplane_pb2 decodes (field for field; byte for byte what
+  `json.dumps` prints), names that need escaping included, and
+  write_derived_artifacts decodes no plane twice.
 - PARITY: the streamed converter (serial and parallel) produces
   event-identical — in fact byte-identical decompressed — trace.json to
   the old single-shot converter on tests/fixtures/bench.xplane.pb.
@@ -315,3 +320,354 @@ def test_summarizer_reads_fixture():
         trace.summarize_xplane_bytes(FIXTURE.read_bytes()))
     assert len(summary["planes"]) == 4
     assert summary["top_ops"]
+
+
+# -- one decode a plane feeds both derived files ------------------------------
+
+US = 1_000_000  # picoseconds in a microsecond
+
+
+def stats_xspace() -> bytes:
+    """What the checked-in fixture lacks: cost-model stats on the metadata
+    (uint and double, under a one-byte and a two-byte stat id) and on
+    events, `hlo_category`, metadata ids of two and three bytes, an event
+    whose metadata is missing, more result shapes than an op's row keeps, a
+    plane with no "XLA Ops" line, and a `/host:metadata` plane whose bytes
+    are opaque stats."""
+    import xspace_fixture as xf
+
+    flops, nbytes, category, program, source = 1, 200, 3, 4, 150
+    stat_names = ((flops, "flops"), (nbytes, "bytes_accessed"),
+                  (category, "hlo_category"), (program, "program_id"),
+                  (source, "source"))
+
+    def with_stat_names(body: bytes) -> bytes:
+        for sid, name in stat_names:
+            body += xf._field_bytes(5, xf._stat_metadata(sid, name))
+        return body
+
+    device = with_stat_names(
+        xf._field_varint(1, 1) + xf._field_str(2, "/device:TPU:0"))
+    ops = [
+        (1, "%fusion.1 = bf16[8,128]{1,0} fusion(%p0)", "fusion.1", (
+            xf._stat(program, uint=7), xf._stat(flops, uint=4096),
+            xf._stat(nbytes, double=2048.5),
+            xf._stat(category, text="loop fusion"),
+            xf._stat(source, text="models/transformer.py:120"))),
+        (300, "%convolution.2 = f32[4]{0} convolution(%p1)", "", (
+            xf._stat(category, text="convolution"),)),
+        (70000, "%copy.3 = bf16[2,2]{1,0} copy(%p2)", "copy.3", ()),
+    ] + [
+        (10 + i, f"%fusion.{10 + i} = bf16[{i + 1},64]{{1,0}} fusion(%p)",
+         f"fusion.{10 + i}", (xf._stat(flops, double=10.0 * (i + 1)),))
+        for i in range(6)
+    ]
+    for meta_id, name, display, stats in ops:
+        device += xf._field_bytes(
+            4, xf._event_metadata(meta_id, name, display, stats))
+    sync, offset = [], 0
+    for round_ in range(3):
+        for meta_id, _, _, _ in ops:
+            own = ()
+            if meta_id == 300 and round_ == 1:  # its own costs, whole
+                own = (xf._stat(program, uint=9), xf._stat(flops, uint=64),
+                       xf._stat(nbytes, uint=32))
+            elif meta_id == 1 and round_ == 2:  # flops alone: bytes read 0
+                own = (xf._stat(flops, double=1.5),)
+            elif meta_id == 70000:  # nothing the op table reads
+                own = (xf._stat(program, uint=9), xf._stat(source, text="x"))
+            sync.append(
+                xf._event(meta_id, offset, (meta_id % 7 + 1) * US, own))
+            offset += 9 * US
+        sync.append(xf._event(999, offset, 2 * US))  # no such metadata
+        offset += 3 * US
+    for line_id, name, events in (
+        (1, "Steps", [xf._event(1, 0, 40 * US), xf._event(1, 50 * US, 0),
+                      xf._event(1, 60 * US, 45 * US)]),
+        (2, "XLA Modules", [xf._event(300, 0, 100 * US)]),
+        (3, "XLA Ops", sync),
+        (4, "Async XLA Ops", [  # beside "XLA Ops": its costs must not count
+            xf._event(70000, 5 * US, 400 * US,
+                      (xf._stat(flops, uint=10**9),))]),
+    ):
+        device += xf._field_bytes(
+            3, xf._line(line_id, name, 1_700_000_000_000_000_123, events))
+
+    host = with_stat_names(
+        xf._field_varint(1, 2) + xf._field_str(2, "/host:CPU"))
+    host += xf._field_bytes(4, xf._event_metadata(1, "PjitFunction(step)", ""))
+    host += xf._field_bytes(4, xf._event_metadata(
+        2, "TransferToDevice", "", (xf._stat(nbytes, uint=1 << 20),)))
+    for thread in range(2):
+        events = [
+            xf._event(1 + (i + thread) % 2, i * 11 * US, 10 * US,
+                      (xf._stat(flops, double=0.25),) if i == 3 else ())
+            for i in range(6)]
+        host += xf._field_bytes(3, xf._line(
+            100 + thread, f"python3/{100 + thread}", 1_700_000_000_000_000_000,
+            events))
+
+    metadata = xf._field_varint(1, 3) + xf._field_str(2, "/host:metadata")
+    metadata += xf._field_bytes(5, xf._stat_metadata(1, "hlo_proto"))
+    metadata += xf._field_bytes(6, xf._stat(1, raw=bytes(range(256)) * 1200))
+    metadata += xf._field_bytes(4, xf._event_metadata(
+        1, "jit_step", "", (xf._stat(1, raw=b"\x08\x96\x01" * 30000),)))
+    return b"".join(
+        xf._field_bytes(1, plane) for plane in (device, host, metadata))
+
+
+ARTIFACTS = {"fixture": FIXTURE.read_bytes, "stats": stats_xspace}
+
+
+def _wire_fields(buf: bytes):
+    """(number, where the field starts, where its payload starts, where it
+    ends) at one level of a message, by protobuf's own varint reader."""
+    from google.protobuf.internal.decoder import _DecodeVarint
+
+    i = 0
+    while i < len(buf):
+        start = i
+        tag, i = _DecodeVarint(buf, i)
+        payload = i
+        if tag & 7 == 0:
+            _, i = _DecodeVarint(buf, i)
+        elif tag & 7 == 2:
+            size, payload = _DecodeVarint(buf, i)
+            i = payload + size
+        else:
+            i += 8 if tag & 7 == 1 else 4
+        yield tag >> 3, start, payload, i
+
+
+def _oracle(data: bytes, group: bool, by_category: bool):
+    """[(PlaneSummary, Chrome events)] a plane, decoded by the wheel's own
+    xplane_pb2: a decoder that shares nothing with dynolog_tpu.trace."""
+    pb2 = trace._load_xplane_descriptor()
+    if pb2 is None:
+        pytest.skip("no wheel ships an xplane descriptor")
+    payloads = [data[p:e] for num, _, p, e in _wire_fields(data) if num == 1]
+    out = []
+    for pid, (plane, payload) in enumerate(
+            zip(pb2.XSpace.FromString(data).planes, payloads), start=1):
+        want = trace.PlaneSummary(
+            name=plane.name, bytes=len(payload),
+            event_metadata=len(plane.event_metadata), lines=len(plane.lines),
+            line_names=[line.name for line in plane.lines])
+        for num, start, _, end in _wire_fields(payload):
+            want.content[trace.CONTENT_FIELDS.get(num, "other")] += end - start
+
+        def costs(stats) -> dict:
+            found = {}
+            for stat in stats:
+                kind = plane.stat_metadata[stat.metadata_id].name
+                which = stat.WhichOneof("value")
+                if kind == "hlo_category" and which == "str_value":
+                    found[kind] = stat.str_value
+                elif kind in ("flops", "bytes_accessed") and which in (
+                        "double_value", "uint64_value", "int64_value",
+                        "ref_value"):
+                    found[kind] = float(getattr(stat, which))
+            return found
+
+        events = [{"ph": "M", "pid": pid, "name": "process_name",
+                   "args": {"name": plane.name}}]
+        for line in plane.lines:
+            tid = line.id % 2**64  # trace.py reads an int64 as it is written
+            events.append({"ph": "M", "pid": pid, "tid": tid,
+                           "name": "thread_name", "args": {"name": line.name}})
+            counted = ("XLA Ops" not in want.line_names
+                       or line.name == "XLA Ops")
+            for ev in line.events:
+                known = ev.metadata_id in plane.event_metadata
+                md = plane.event_metadata[ev.metadata_id] if known else None
+                name = md.name if known else f"op#{ev.metadata_id}"
+                events.append({
+                    "ph": "X", "pid": pid, "tid": tid,
+                    "name": (md.display_name or name) if known else name,
+                    "ts": line.timestamp_ns / 1e3 + ev.offset_ps / 1e6,
+                    "dur": ev.duration_ps / 1e6})
+                want.events += 1
+                want.duration_ps = max(
+                    want.duration_ps, ev.offset_ps + ev.duration_ps)
+                if line.name == "Steps" and ev.duration_ps > 0:
+                    want.step_durations_ps.append(ev.duration_ps)
+                if not counted:
+                    continue
+                model = costs(md.stats) if known else {}
+                own = costs(ev.stats)
+                if own.get("flops") or own.get("bytes_accessed"):
+                    paid = own
+                else:
+                    paid = model
+                key = (model.get("hlo_category", "uncategorized")
+                       if by_category else trace._op_key(name, group))
+                agg = want.ops.setdefault(key, trace.OpAggregate(key))
+                agg.total_ps += ev.duration_ps
+                agg.count += 1
+                agg.flops += paid.get("flops", 0.0)
+                agg.bytes_accessed += paid.get("bytes_accessed", 0.0)
+                shape = trace._op_shape(name)
+                if shape and len(agg.shapes) < trace.SHAPES_PER_OP:
+                    agg.shapes.add(shape)
+        out.append((want, events))
+    return out
+
+
+@pytest.mark.parametrize("group,by_category", [
+    (True, False), (False, False), (True, True)])
+@pytest.mark.parametrize("artifact", sorted(ARTIFACTS))
+def test_one_decode_equals_the_wheels_decoder(artifact, group, by_category):
+    # The old walker is gone, so the oracle is the protobuf runtime's own
+    # parse: every field of every PlaneSummary, the rows' order too, and
+    # the fragment against json.dumps over the oracle's events.
+    import dataclasses
+
+    data = ARTIFACTS[artifact]()
+    got = trace.summarize_xplane_bytes(
+        data, group=group, by_category=by_category)
+    want = _oracle(data, group, by_category)
+    assert len(got) == len(want)
+    bufs = list(trace.iter_plane_bufs(data))
+    for pid, (plane, (summary, events)) in enumerate(zip(got, want), start=1):
+        assert dataclasses.asdict(plane) == dataclasses.asdict(summary)
+        assert list(plane.ops) == list(summary.ops)
+        assert sum(plane.content.values()) == plane.bytes
+        assert trace._plane_fragment((pid, bufs[pid - 1])) == ", ".join(
+            json.dumps(e) for e in events).encode()
+    if artifact == "stats":  # the fixture holds what it says it holds
+        device = got[0]
+        assert "op#999" in device.ops or by_category
+        assert any(a.flops for a in device.ops.values())
+        assert got[2].content["stats"] > 300_000 and not got[2].events
+
+
+@pytest.mark.parametrize("artifact", sorted(ARTIFACTS))
+def test_fragments_are_json_dumps_of_the_events(artifact):
+    # No wheel needed: the formatted fragment against json.dumps over the
+    # dict form of the same events, byte for byte, a plane at a time.
+    data = ARTIFACTS[artifact]()
+    for job in enumerate(trace.iter_plane_bufs(data), start=1):
+        assert trace._plane_fragment(job) == ", ".join(
+            json.dumps(e) for e in trace._plane_events(*job)).encode()
+
+
+NAMES_TO_ESCAPE = [
+    'say "hello"', "back\\slash", "café 日本", "\U0001f680 lift",
+    "tab\there\nnewline", "bell\x07 nul\x00", "</script>", "  sep",
+]
+
+
+@pytest.mark.parametrize("name", NAMES_TO_ESCAPE)
+def test_a_name_that_needs_escaping_reads_as_json_dumps_prints_it(name):
+    import xspace_fixture as xf
+
+    plane = xf._field_str(2, name) + xf._field_bytes(
+        4, xf._event_metadata(1, "%op.1 = f32[] op()", name))
+    plane += xf._field_bytes(3, xf._line(
+        5, name, 1000, [xf._event(1, i * US, US) for i in range(3)]))
+    fragment = trace._plane_fragment((1, plane)).decode()
+    assert fragment == ", ".join(
+        json.dumps(e) for e in trace._plane_events(1, plane))
+    assert fragment.count(json.dumps(name)) == 5  # plane, line, three events
+    events = json.loads("[" + fragment + "]")
+    assert [e["name"] for e in events if e["ph"] == "X"] == [name] * 3
+    assert events[0]["args"]["name"] == events[1]["args"]["name"] == name
+
+
+def test_bytes_that_are_not_utf8_become_the_replacement_character():
+    import xspace_fixture as xf
+
+    plane = xf._field_bytes(4, xf._field_varint(1, 1) + xf._field_bytes(
+        2, xf._field_varint(1, 1) + xf._field_bytes(2, b"bad\xff\xfename")))
+    plane += xf._field_bytes(3, xf._line(1, "t", 0, [xf._event(1, 0, US)]))
+    fragment = trace._plane_fragment((1, plane))
+    assert b'"name": "bad\\ufffd\\ufffdname"' in fragment
+    assert fragment == ", ".join(
+        json.dumps(e) for e in trace._plane_events(1, plane)).encode()
+
+
+class InProcessPool:
+    """ProcessPoolExecutor's face over the calling process: what the pool
+    would run, run here, so a test can count it."""
+
+    def __init__(self, *a, **k):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def map(self, fn, jobs):
+        return map(fn, jobs)
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_write_derived_artifacts_decodes_each_plane_once(
+        xplane, tmp_path, monkeypatch, workers):
+    import concurrent.futures
+    import shutil
+
+    apart = tmp_path / "apart" / "host.xplane.pb"
+    apart.parent.mkdir()
+    shutil.copy(xplane, apart)
+    budget = trace.ConvertBudget(max_workers=workers)
+    decoded = []
+    decode = trace._decode_plane
+
+    def counting(buf, start, end):
+        decoded.append(end - start)
+        return decode(buf, start, end)
+
+    monkeypatch.setattr(trace, "_decode_plane", counting)
+    pools = []
+    if workers > 1:
+        monkeypatch.setattr(trace, "_fork_safe", lambda: True)
+        monkeypatch.setattr(
+            concurrent.futures, "ProcessPoolExecutor",
+            lambda *a, **k: pools.append(k) or InProcessPool())
+    written = trace.write_derived_artifacts(xplane, budget)
+    planes = [len(b) for b in trace.iter_plane_bufs(FIXTURE.read_bytes())]
+    assert decoded == planes  # once a plane, in order
+    assert len(pools) == workers - 1
+    assert sorted(os.path.basename(p) for p in written) == [
+        "host.summary.json", "host.trace.json.gz"]
+    # called apart, the two writers decode every plane twice between them
+    # and write the same bytes (the gzip's header holds no time)
+    del decoded[:]
+    trace.write_summary_json(str(apart))
+    trace.write_chrome_trace_gz(str(apart), budget=budget)
+    assert sorted(decoded) == sorted(planes * 2)
+    for name in ("host.summary.json", "host.trace.json.gz"):
+        assert (apart.parent / name).read_bytes() == (
+            pathlib.Path(xplane).parent / name).read_bytes()
+
+
+@pytest.mark.parametrize("broken,left", [
+    ("_iter_fragments", "host.summary.json"),
+    ("_plane_json", "host.summary.json"),
+    ("_plane_summary", "host.trace.json.gz"),
+    ("_summarize_planes", "host.trace.json.gz"),
+])
+def test_each_derived_file_survives_the_others_failure(
+        xplane, monkeypatch, broken, left):
+    # Both directions of the failure domains, at the pass and at the writers:
+    # the file whose code did not break is whole, and no .tmp stays.
+    def boom(*a, **k):
+        raise RuntimeError(f"{broken} crash")
+
+    monkeypatch.setattr(trace, broken, boom)
+    written = trace.write_derived_artifacts(
+        xplane, trace.ConvertBudget(max_workers=1))
+    out_dir = os.path.dirname(xplane)
+    assert [os.path.basename(p) for p in written] == [left]
+    assert sorted(os.listdir(out_dir)) == sorted(["host.xplane.pb", left])
+    monkeypatch.undo()
+    if left.endswith(".gz"):
+        assert _read_gz(written[0]) == _read_gz(
+            trace.write_chrome_trace_gz_single(xplane))
+    else:
+        with open(written[0]) as f:
+            assert json.load(f) == trace._summarize_planes(
+                trace.summarize_xplane_bytes(FIXTURE.read_bytes()))

@@ -17,6 +17,7 @@ in all of them.
 
 from __future__ import annotations
 
+import struct
 import sys
 
 # Default shape: big enough that a conversion is tens-of-ms-measurable
@@ -50,17 +51,48 @@ def _field_str(num: int, s: str) -> bytes:
     return _field_bytes(num, s.encode())
 
 
-def _event_metadata(meta_id: int, name: str, display: str) -> bytes:
+def _stat(stat_id: int, *, uint: int | None = None,
+          double: float | None = None, text: str | None = None,
+          raw: bytes | None = None) -> bytes:
+    # XStat: metadata_id=1, then one of double_value=2 (fixed64),
+    # uint64_value=3, str_value=5, bytes_value=6.
+    body = _field_varint(1, stat_id)
+    if uint is not None:
+        body += _field_varint(3, uint)
+    if double is not None:
+        body += _varint((2 << 3) | 1) + struct.pack("<d", double)
+    if text is not None:
+        body += _field_str(5, text)
+    if raw is not None:
+        body += _field_bytes(6, raw)
+    return body
+
+
+def _stat_metadata(stat_id: int, name: str) -> bytes:
+    # map<int64, XStatMetadata> entry: the embedded message carries id=1,
+    # name=2.
+    inner = _field_varint(1, stat_id) + _field_str(2, name)
+    return _field_varint(1, stat_id) + _field_bytes(2, inner)
+
+
+def _event_metadata(meta_id: int, name: str, display: str,
+                    stats: tuple[bytes, ...] = ()) -> bytes:
     # map<int64, XEventMetadata> entry: key=1, value=2; the embedded
-    # XEventMetadata carries id=1, name=2, display_name=4.
+    # XEventMetadata carries id=1, name=2, display_name=4, stats=5.
     inner = (_field_varint(1, meta_id) + _field_str(2, name)
              + _field_str(4, display))
+    for stat in stats:
+        inner += _field_bytes(5, stat)
     return _field_varint(1, meta_id) + _field_bytes(2, inner)
 
 
-def _event(meta_id: int, offset_ps: int, duration_ps: int) -> bytes:
-    return (_field_varint(1, meta_id) + _field_varint(2, offset_ps)
+def _event(meta_id: int, offset_ps: int, duration_ps: int,
+           stats: tuple[bytes, ...] = ()) -> bytes:
+    body = (_field_varint(1, meta_id) + _field_varint(2, offset_ps)
             + _field_varint(3, duration_ps))
+    for stat in stats:
+        body += _field_bytes(4, stat)
+    return body
 
 
 def _line(line_id: int, name: str, ts_ns: int, events: list[bytes]) -> bytes:
