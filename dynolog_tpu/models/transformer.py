@@ -21,6 +21,9 @@ import jax
 import jax.numpy as jnp
 
 
+LAYER_TYPES = ("full_attention", "linear_attention")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 1024
@@ -29,7 +32,9 @@ class TransformerConfig:
     n_heads: int = 8
     d_ff: int = 704  # ~8/3 * d_model, rounded to a multiple of 64 for tiling
     max_seq_len: int = 512
-    rope_theta: float = 10000.0
+    # None: no rotary embedding, as a published config states it
+    # (`rope_parameters.rope_theta: null`)
+    rope_theta: float | None = 10000.0
     dtype: str = "bfloat16"
     # "reference": plain-XLA attention; "flash": Pallas MXU kernel
     # (dynolog_tpu.ops.flash_attention); "ring": sequence-parallel ring
@@ -52,6 +57,35 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     moe_balance_all_k: bool = False
     moe_z_weight: float = 0.0
+    # A hybrid model: each layer's kind, "full_attention" or
+    # "linear_attention" (a gated-delta-net layer,
+    # dynolog_tpu.models.linear_attention, over n_heads heads of
+    # linear_key_head_dim / linear_value_head_dim), as a published config
+    # lists them. None: every layer is full attention.
+    layer_types: tuple | None = None
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_allow_neg_eigval: bool = False
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            return
+        # a list from JSON: the configuration keys a jitted function
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - set(LAYER_TYPES)
+        if unknown or len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types {self.layer_types}: one of {LAYER_TYPES} for "
+                f"each of the {self.n_layers} layers")
+
+    def is_linear(self, i: int) -> bool:
+        return (self.layer_types is not None
+                and self.layer_types[i] == "linear_attention")
+
+    @property
+    def has_linear_layers(self) -> bool:
+        return any(self.is_linear(i) for i in range(self.n_layers))
 
     @property
     def head_dim(self) -> int:
@@ -90,15 +124,19 @@ def init_params(rng, cfg: TransformerConfig):
         d, f = cfg.d_model, cfg.d_ff
         layer = {
             "attn_scale": jnp.ones((d,), dtype),
-            "wq": dense(k[0], (d, d), d),
-            "wk": dense(k[1], (d, d), d),
-            "wv": dense(k[2], (d, d), d),
-            "wo": dense(k[3], (d, d), d),
             "mlp_scale": jnp.ones((d,), dtype),
         }
-        if cfg.qk_norm:
-            layer.update(q_scale=jnp.ones((d,), dtype),
-                         k_scale=jnp.ones((d,), dtype))
+        if cfg.is_linear(i):
+            from dynolog_tpu.models.linear_attention import init_linear_layer
+
+            layer.update(init_linear_layer(k[0], cfg))
+        else:
+            layer.update(
+                wq=dense(k[0], (d, d), d), wk=dense(k[1], (d, d), d),
+                wv=dense(k[2], (d, d), d), wo=dense(k[3], (d, d), d))
+            if cfg.qk_norm:
+                layer.update(q_scale=jnp.ones((d,), dtype),
+                             k_scale=jnp.ones((d,), dtype))
         if cfg.n_experts > 0:
             from dynolog_tpu.models.moe import init_moe_layer
 
@@ -142,8 +180,9 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
         k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
     q, k = q.reshape(b, s, h, hd), k.reshape(b, s, h, hd)
     v = (x @ layer["wv"]).reshape(b, s, h, hd)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta is not None:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
 
     if cfg.attn_impl == "flash":
         from dynolog_tpu.ops.flash_attention import flash_attention
@@ -192,11 +231,14 @@ def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
         jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
     )
     aux = jnp.zeros((), jnp.float32)
-    for layer in params["layers"]:
-        x = x + _attention(
-            layer, _rmsnorm(x, layer["attn_scale"], cfg.norm_eps), positions,
-            cfg, mesh
-        )
+    for i, layer in enumerate(params["layers"]):
+        h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
+        if cfg.is_linear(i):
+            from dynolog_tpu.models.linear_attention import gated_delta_net
+
+            x = x + gated_delta_net(layer, h, cfg)
+        else:
+            x = x + _attention(layer, h, positions, cfg, mesh)
         h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
         if cfg.n_experts > 0:
             from dynolog_tpu.models.moe import moe_mlp

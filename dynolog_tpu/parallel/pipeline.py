@@ -51,8 +51,11 @@ def init_pipeline_params(rng, cfg: TransformerConfig, mesh):
     assert cfg.n_layers % n_stages == 0, (
         f"n_layers={cfg.n_layers} must divide into pipe={n_stages} stages"
     )
-    assert cfg.n_experts == 0 and cfg.attn_impl == "reference", (
-        "pipeline path supports the dense/reference transformer config"
+    assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
+            and not cfg.has_linear_layers), (
+        "pipeline path supports the dense/reference transformer config: no "
+        "experts, no linear_attention layer (a stage would run it as "
+        "attention)"
     )
 
     params = init_params(rng, cfg)
@@ -91,8 +94,11 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
     tokens: global [B, S]; B must divide by data x n_micro.
     """
     n_stages = mesh.shape["pipe"]
-    assert cfg.n_experts == 0 and cfg.attn_impl == "reference", (
-        "pipeline path supports the dense/reference transformer config"
+    assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
+            and not cfg.has_linear_layers), (
+        "pipeline path supports the dense/reference transformer config: no "
+        "experts, no linear_attention layer (a stage would run it as "
+        "attention)"
     )
 
     def local(layers, embedding, w_out, final_scale, tokens_local):

@@ -105,6 +105,25 @@ PARAM_RULES = {
     "experts_gate": P("expert", None, "model"),
     "experts_up": P("expert", None, "model"),
     "experts_down": P("expert", "model", None),
+    # A gated-delta-net layer (dynolog_tpu.models.linear_attention): the
+    # projections by heads on `model` (columns are heads x head size, so
+    # n_heads has to divide by the axis), the output matrix the other way,
+    # as attention's are. Named here because a name no rule ends falls to
+    # replication in silence: 88 M parameters a layer at Olmo-Hybrid-7B's
+    # widths. The small ones (a weight a channel a tap, a number a head, a
+    # head's norm scale) are replicated; gdn_norm_scale by "scale" above.
+    "gdn_q": P(None, "model"),
+    "gdn_k": P(None, "model"),
+    "gdn_v": P(None, "model"),
+    "gdn_g": P(None, "model"),
+    "gdn_o": P("model", None),
+    "gdn_conv_q": P(),
+    "gdn_conv_k": P(),
+    "gdn_conv_v": P(),
+    "gdn_b": P(),
+    "gdn_a": P(),
+    "gdn_a_log": P(),
+    "gdn_dt_bias": P(),
 }
 
 

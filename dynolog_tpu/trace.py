@@ -208,8 +208,14 @@ def is_collective(op_name: str) -> bool:
 @dataclass
 class OpAggregate:
     name: str
+    # total_ps is inclusive, what the bytes say: a `while` event spans its
+    # trips. self_ps is the same less the events that lie directly inside
+    # on the same line (`held` of them): where nothing nests the two are
+    # equal, and self times add up to the time the device was busy.
     total_ps: int = 0
     count: int = 0
+    self_ps: int = 0
+    held: int = 0
     flops: float = 0.0
     bytes_accessed: float = 0.0
     # Result shapes seen for this op ("bf16[128,512]"), parsed from the
@@ -453,9 +459,13 @@ def _plane_summary(
         out.events += len(events)
         count_ops = not has_xla_ops or lname == "XLA Ops"
         steps = lname == "Steps"
+        # whether some event starts before the one written before it
+        # ends: lies inside it, overlaps it, or the line is not in order
+        overlap, last_end_ps = False, 0
         for meta_id, offset_ps, duration_ps, own in events:
-            if offset_ps + duration_ps > out.duration_ps:
-                out.duration_ps = offset_ps + duration_ps
+            end_ps = offset_ps + duration_ps
+            if end_ps > out.duration_ps:
+                out.duration_ps = end_ps
             if steps and duration_ps > 0:
                 out.step_durations_ps.append(duration_ps)
             if not count_ops:
@@ -482,7 +492,33 @@ def _plane_summary(
             agg.count += 1
             agg.flops += flops
             agg.bytes_accessed += nbytes
+            agg.self_ps += duration_ps
+            if offset_ps < last_end_ps:
+                overlap = True
+            last_end_ps = end_ps
+        if overlap and count_ops:
+            _take_held_time(events, by_id)
     return out
+
+
+def _take_held_time(events: list, by_id: dict) -> None:
+    """Takes from each row's `self_ps` the time of the events that lie
+    directly inside its events on this line, and counts them (`held`): the
+    second pass of a line on which events overlap. A line not in start
+    order (an enclosing event before what it holds) is sorted first. An
+    event lies inside the innermost event still open at its start, or in
+    none: one that overlaps it without lying inside is left whole."""
+    events = sorted(events, key=lambda ev: (ev[1], -ev[2]))
+    open_events: list[tuple] = []  # (end, its row), innermost last
+    for meta_id, offset_ps, duration_ps, _ in events:
+        while open_events and open_events[-1][0] <= offset_ps:
+            open_events.pop()
+        end_ps = offset_ps + duration_ps
+        if open_events and end_ps <= open_events[-1][0]:
+            holder = open_events[-1][1]
+            holder.self_ps -= duration_ps
+            holder.held += 1
+        open_events.append((end_ps, by_id[meta_id][0]))
 
 
 def _plane_spans(data) -> list[tuple[int, int]]:
@@ -1015,13 +1051,16 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
     device_planes = [p for p in planes if "device" in p.name.lower()
                      or "tpu" in p.name.lower() or "gpu" in p.name.lower()]
     for p in planes:
-        op_ps = sum(a.total_ps for a in p.ops.values())
+        # Shares are over SELF time: it adds up to the time the plane's ops
+        # kept the device busy, where inclusive time counts the inside of
+        # a loop once for the body's ops and again for the `while`.
+        op_ps = sum(a.self_ps for a in p.ops.values())
         kinds: dict[str, list] = {}  # kind of collective -> [ps, count]
         for name, agg in p.ops.items():
             kind = collective_kind(name)
             if kind is not None:
                 row = kinds.setdefault(kind, [0, 0])
-                row[0] += agg.total_ps
+                row[0] += agg.self_ps
                 row[1] += agg.count
         collective_ps = sum(ps for ps, _ in kinds.values())
         out["planes"].append(
@@ -1040,6 +1079,17 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
                     kind: {"total_ms": round(ps / 1e9, 3), "count": count}
                     for kind, (ps, count) in sorted(
                         kinds.items(), key=lambda kv: -kv[1][0])},
+                # the ops that held others on the device's op line
+                # (`while`, `conditional`, `call`): inclusive time, events,
+                # and the events directly inside them, from which the trip
+                # count reads (inside / count / the body's ops). A host
+                # line's events nest too, as call stacks: not listed
+                "loops": {
+                    name: {"total_ms": round(agg.total_ps / 1e9, 3),
+                           "count": agg.count, "inside": agg.held}
+                    for name, agg in sorted(
+                        p.ops.items(), key=lambda kv: -kv[1].total_ps)
+                    if agg.held and "XLA Ops" in p.line_names},
                 # what the plane's bytes are made of: `<field>_bytes` add
                 # up to `bytes`; entries of the event-metadata map
                 "bytes": p.bytes,
@@ -1053,36 +1103,41 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
             for name, agg in p.ops.items():
                 m = merged.setdefault(name, OpAggregate(name))
                 m.total_ps += agg.total_ps
+                m.self_ps += agg.self_ps
                 m.count += agg.count
                 m.flops += agg.flops
                 m.bytes_accessed += agg.bytes_accessed
                 for shape in agg.shapes:
                     if len(m.shapes) < SHAPES_PER_OP:
                         m.shapes.add(shape)
-    total_ps = sum(a.total_ps for a in merged.values()) or 1
-    for agg in sorted(merged.values(), key=lambda a: -a.total_ps):
+    # Rows rank by self time and `pct` is a share of it; `total_ms` and
+    # `count` stay what the bytes say, inclusive.
+    self_total_ps = sum(a.self_ps for a in merged.values()) or 1
+    for agg in sorted(merged.values(), key=lambda a: (-a.self_ps, -a.total_ps)):
         row = {
             "op": agg.name,
             "total_ms": round(agg.total_ps / 1e9, 3),
             "count": agg.count,
-            "pct": round(agg.total_ps / total_ps * 100.0, 1),
+            "self_ms": round(agg.self_ps / 1e9, 3),
+            "pct": round(agg.self_ps / self_total_ps * 100.0, 1),
         }
         # Roofline view when the profiler recorded cost models: achieved
-        # compute/memory rates over the op's own device time, plus
-        # arithmetic intensity (FLOP per HBM byte). Rates are suppressed
-        # for sub-microsecond marker events (async copy-start/-done
-        # completions), whose durations don't represent the transfer.
-        # Marker heuristic: zero-FLOP ops whose events average < 1µs are
-        # async completion markers, not transfers.
+        # compute/memory rates over the op's own device time (self time:
+        # a `while`'s cost model counts one trip, its time all of them),
+        # plus arithmetic intensity (FLOP per HBM byte). Rates are
+        # suppressed for sub-microsecond marker events (async
+        # copy-start/-done completions), whose durations don't represent
+        # the transfer. Marker heuristic: zero-FLOP ops whose events
+        # average < 1µs are async completion markers, not transfers.
         marker = (
             agg.flops == 0 and agg.count > 0
             and agg.total_ps / agg.count < 1e6
         )
-        if agg.total_ps > 0 and agg.flops > 0:
-            row["gflops_per_s"] = round(agg.flops / (agg.total_ps / 1e3), 1)
-        if agg.total_ps > 0 and agg.bytes_accessed > 0 and not marker:
+        if agg.self_ps > 0 and agg.flops > 0:
+            row["gflops_per_s"] = round(agg.flops / (agg.self_ps / 1e3), 1)
+        if agg.self_ps > 0 and agg.bytes_accessed > 0 and not marker:
             row["gib_per_s"] = round(
-                agg.bytes_accessed / (agg.total_ps / 1e12) / (1 << 30), 1)
+                agg.bytes_accessed / (agg.self_ps / 1e12) / (1 << 30), 1)
         if agg.flops > 0 and agg.bytes_accessed > 0:
             row["flop_per_byte"] = round(agg.flops / agg.bytes_accessed, 2)
         if agg.shapes:
@@ -1119,7 +1174,11 @@ def diff_summaries(base: dict, cur: dict) -> dict:
         b, c = base_ops.get(name), cur_ops.get(name)
 
         def per_call(o):
-            return o["total_ms"] / o["count"] if o and o["count"] else None
+            # self time where the summary has it (PR 36 on): a slower body
+            # op is then its own finding, not the `while`'s around it too
+            if not o or not o["count"]:
+                return None
+            return o.get("self_ms", o["total_ms"]) / o["count"]
 
         bpc, cpc = per_call(b), per_call(c)
         row = {
@@ -1135,9 +1194,9 @@ def diff_summaries(base: dict, cur: dict) -> dict:
             row["delta_ms_per_call"] = round(cpc - bpc, 4)
             impact = (cpc - bpc) * row["count"]
         elif c is not None:  # new op: its whole current total is the impact
-            impact = c["total_ms"]
+            impact = c.get("self_ms", c["total_ms"])
         else:  # op vanished: its baseline total came off the profile
-            impact = -b["total_ms"]
+            impact = -b.get("self_ms", b["total_ms"])
         if row["base_pct"] is not None and row["pct"] is not None:
             row["delta_pp"] = round(row["pct"] - row["base_pct"], 1)
         row["impact_ms"] = round(impact, 3)
@@ -1279,6 +1338,9 @@ def main(argv: list[str] | None = None) -> int:
         for kind, row in p["collectives"].items():
             print(f"    {kind:<36} {row['count']:>8} events "
                   f"{row['total_ms']:>9.3f} ms")
+        for name, row in p["loops"].items():
+            print(f"    {name:<36} {row['count']:>8} events "
+                  f"{row['total_ms']:>9.3f} ms, holding {row['inside']}")
     _print_content(summary["planes"])
     if "steps" in summary:
         s = summary["steps"]
@@ -1288,13 +1350,15 @@ def main(argv: list[str] | None = None) -> int:
     has_roofline = any(
         "gflops_per_s" in op or "gib_per_s" in op
         for op in summary["top_ops"])
-    hdr = f"\n{'op':<40} {'total ms':>9} {'count':>7} {'%':>6}"
+    # `%` is the share of self time; `total ms` is inclusive
+    hdr = (f"\n{'op':<40} {'total ms':>9} {'count':>7} {'self ms':>9} "
+           f"{'%':>6}")
     if has_roofline:
         hdr += f" {'GFLOP/s':>9} {'GiB/s':>8} {'FLOP/B':>7}"
     print(hdr)
     for op in summary["top_ops"]:
         line = (f"{op['op']:<40.40} {op['total_ms']:>9.3f} {op['count']:>7} "
-                f"{op['pct']:>6.1f}")
+                f"{op['self_ms']:>9.3f} {op['pct']:>6.1f}")
         if has_roofline:
             line += (f" {op.get('gflops_per_s', 0):>9.1f}"
                      f" {op.get('gib_per_s', 0):>8.1f}"

@@ -477,7 +477,8 @@ def _oracle(data: bytes, group: bool, by_category: bool):
                            "name": "thread_name", "args": {"name": line.name}})
             counted = ("XLA Ops" not in want.line_names
                        or line.name == "XLA Ops")
-            for ev in line.events:
+            inside = _held(line.events) if counted else {}
+            for at, ev in enumerate(line.events):
                 known = ev.metadata_id in plane.event_metadata
                 md = plane.event_metadata[ev.metadata_id] if known else None
                 name = md.name if known else f"op#{ev.metadata_id}"
@@ -504,6 +505,10 @@ def _oracle(data: bytes, group: bool, by_category: bool):
                 agg = want.ops.setdefault(key, trace.OpAggregate(key))
                 agg.total_ps += ev.duration_ps
                 agg.count += 1
+                held = inside.get(at, ())
+                agg.self_ps += ev.duration_ps - sum(
+                    line.events[i].duration_ps for i in held)
+                agg.held += len(held)
                 agg.flops += paid.get("flops", 0.0)
                 agg.bytes_accessed += paid.get("bytes_accessed", 0.0)
                 shape = trace._op_shape(name)
@@ -511,6 +516,24 @@ def _oracle(data: bytes, group: bool, by_category: bool):
                     agg.shapes.add(shape)
         out.append((want, events))
     return out
+
+
+def _held(events) -> dict:
+    """Index of an event -> the indices of the events directly inside it:
+    for each event, the shortest one that starts no later and ends no
+    earlier (of equals, the one that comes first holds)."""
+    spans = [(ev.offset_ps, ev.offset_ps + ev.duration_ps) for ev in events]
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][0], -spans[i][1], i))
+    inside: dict = {}
+    open_ = []  # candidates still open, outermost first
+    for i in order:
+        start, end = spans[i]
+        open_ = [j for j in open_ if spans[j][1] > start]
+        if open_ and end <= spans[open_[-1]][1]:
+            inside.setdefault(open_[-1], []).append(i)
+        open_.append(i)
+    return inside
 
 
 @pytest.mark.parametrize("group,by_category", [

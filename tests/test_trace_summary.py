@@ -48,9 +48,13 @@ def test_summarize_real_capture(trace_dir):
     # The jitted lambda must show up among the op names somewhere.
     names = " ".join(op["op"] for op in summary["top_ops"])
     assert "jit" in names or "fusion" in names or "dot" in names, names
-    # Aggregates are sane: sorted desc, positive, pct sums to ~100.
-    totals = [op["total_ms"] for op in summary["top_ops"]]
-    assert totals == sorted(totals, reverse=True)
+    # Aggregates are sane: sorted desc by self time (a host line's events
+    # nest), positive, an op's own time within its inclusive time, pct
+    # sums to ~100.
+    own = [op["self_ms"] for op in summary["top_ops"]]
+    assert own == sorted(own, reverse=True)
+    assert all(0 <= op["self_ms"] <= op["total_ms"]
+               for op in summary["top_ops"])
     assert all(op["count"] >= 1 for op in summary["top_ops"])
     assert sum(op["pct"] for op in summary["top_ops"]) == pytest.approx(
         100.0, abs=2.0)
@@ -269,8 +273,13 @@ def test_collectives_by_kind_sum_to_the_planes_collective_time(
         assert plane["collective_pct"] == 50.0
         assert sum(k["total_ms"] for k in kinds.values()) == pytest.approx(
             plane["collective_pct"] / 100.0 * 0.1 * rounds)
-    # the op table is what it was before the kinds were counted
+    # the op table is what it was before the kinds were counted, and
+    # before an op's own time was (PR 36): nothing nests here, so the new
+    # key repeats `total_ms` and every other number stands
+    assert all(row.pop("self_ms") == row["total_ms"]
+               for row in summary["top_ops"])
     assert json.dumps(summary["top_ops"]) == TOP_OPS_BEFORE
+    assert all(p["loops"] == {} for p in summary["planes"])
     path = tmp_path / "host.xplane.pb"
     path.write_bytes(data)
     assert trace.main([str(path), "--plane", "TPU:1"]) == 0

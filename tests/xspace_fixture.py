@@ -150,6 +150,71 @@ def build_xspace(
     return space
 
 
+# One step of a job with a loop on the device, as the TPU draws it on "XLA
+# Ops": a `while` event that spans its trips with the body's events inside it
+# on the same line. (op, microseconds) or (op, [what it holds]); a holder
+# lasts as long as what it holds plus NESTED_SLACK_US of its own.
+NESTED_STEP = (
+    ("%fusion.9 = bf16[64,64]{1,0} fusion(%p0)", 20),
+    ("%while.1 = (s32[], f32[8,8]{1,0}) while(%t)", [
+        ("%fusion.2 = f32[8,8]{1,0} fusion(%a)", 10),
+        ("%fusion.3 = f32[8,8]{1,0} fusion(%b)", 10),
+        ("%all-reduce.4 = f32[8,8]{1,0} all-reduce(%c)", 20),
+        ("%conditional.5 = f32[8,8]{1,0} conditional(%d)", [
+            ("%fusion.6 = f32[8,8]{1,0} fusion(%e)", 10),
+            ("%copy.7 = f32[8,8]{1,0} copy(%f)", 10),
+        ]),
+        ("%fusion.2 = f32[8,8]{1,0} fusion(%a)", 10),
+        ("%dot.8 = f32[8,8]{1,0} dot(%g, %h)", 15),
+    ]),
+)
+NESTED_SLACK_US = 5
+
+
+def build_nested_xspace(steps: int = 3, scale: dict | None = None,
+                        shuffle: bool = False) -> bytes:
+    """One device plane whose "XLA Ops" line holds `steps` times NESTED_STEP.
+    `scale` ({"fusion.3": 2.0}) lengthens chosen ops (and so what holds
+    them); `shuffle` writes the line's events in reverse, which a reader
+    has to sort before it can tell what lies inside what."""
+    scale = scale or {}
+    ids: dict[str, int] = {}
+    events: list[bytes] = []
+
+    def lay(spec, at_ps: int) -> int:
+        for name, what in spec:
+            meta_id = ids.setdefault(name, len(ids) + 1)
+            if isinstance(what, list):
+                slot = len(events)
+                events.append(b"")
+                end_ps = lay(what, at_ps) + NESTED_SLACK_US * 1_000_000
+                events[slot] = _event(meta_id, at_ps, end_ps - at_ps)
+            else:
+                end_ps = at_ps + int(
+                    what * 1_000_000 * scale.get(_shown(name), 1))
+                events.append(_event(meta_id, at_ps, end_ps - at_ps))
+            at_ps = end_ps
+        return at_ps
+
+    at_ps = 0
+    for _ in range(steps):
+        at_ps = lay(NESTED_STEP, at_ps) + 100_000
+    if shuffle:
+        events.reverse()
+    plane = _field_str(2, "/device:TPU:0 (synthetic, nested)")
+    plane += _field_bytes(3, _line(
+        line_id=0, name="XLA Ops", ts_ns=1_700_000_000_000_000_000,
+        events=events))
+    for name, meta_id in ids.items():
+        plane += _field_bytes(4, _event_metadata(meta_id, name, _shown(name)))
+    return _field_bytes(1, plane)
+
+
+def _shown(name: str) -> str:
+    """'%fusion.3 = f32[8,8]{1,0} fusion(%b)' -> 'fusion.3'."""
+    return name[1:].split(" ", 1)[0]
+
+
 def main(argv: list[str]) -> int:
     out = argv[1] if len(argv) > 1 else "tests/fixtures/bench.xplane.pb"
     data = build_xspace()
