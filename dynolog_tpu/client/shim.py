@@ -580,7 +580,9 @@ class PendingWrite:
     bounds memory, not artifact size) and returns to its caller; the
     writer drains the queue through `trace.stream_write` (atomic
     tmp + rename, tmp unlinked on any failure) and then runs
-    `on_complete` (the shim hangs the export-child spawn there). This is
+    `on_complete` with the path, or with None where the write failed (the
+    shim hangs the export child's hand-over there; what it returns joins
+    `result`). This is
     what kills the stop stall: the poll thread's occupancy per capture
     shrinks to the collect itself, and back-to-back captures overlap one
     capture's write with the next one's window.
@@ -623,12 +625,14 @@ class PendingWrite:
             }
             self._index_planes(ctx)
             if on_complete is not None:
-                on_complete(self.path)
+                self.result.update(on_complete(self.path) or {})
         except Exception as e:  # noqa: BLE001 - the writer is its own
             # failure domain; the error surfaces through wait() into the
             # capture manifest, never into the feeding thread.
             self.error = f"xplane write failed: {e}"
             self.queue.abandon()
+            if on_complete is not None:
+                on_complete(None)
         finally:
             self._done.set()
 
@@ -715,6 +719,61 @@ def _account(prefix: str, before: dict, after: dict) -> dict:
             for key in before if key in after}
 
 
+# The export child's whole program: nice 19 before the package is imported
+# (not via preexec_fn, which is fork-deadlock-prone in a process full of
+# XLA threads and blocks posix_spawn), then trace.export_child. The
+# artifact's path is not in it: it arrives on the child's standard input.
+_EXPORT_CHILD_CODE = (
+    "import os; os.nice(19); from dynolog_tpu import trace; "
+    "raise SystemExit(trace.export_child())")
+
+
+class _ExportChild:
+    """One export child (`trace.export_child`) from the shim's side: an
+    interpreter that imports, says `ready <unix seconds>` on its standard
+    output and waits on its standard input for the artifact's path. Its
+    own session (the job's Ctrl-C is not its), reaped by its own thread
+    whenever it goes: wait() parks in waitpid with the GIL released, so
+    the converter can't leave a zombie behind."""
+
+    def __init__(self, env: dict):
+        import subprocess
+        import sys
+
+        self.proc = subprocess.Popen(
+            [sys.executable, "-c", _EXPORT_CHILD_CODE], env=env, bufsize=0,
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            stderr=subprocess.DEVNULL, start_new_session=True)
+        os.set_blocking(self.proc.stdout.fileno(), False)
+        self.reaper = threading.Thread(
+            target=self.proc.wait, name="dynolog_tpu_trace_export_reaper",
+            daemon=True)
+        self.reaper.start()
+
+    def ready_at(self) -> float | None:
+        """The unix time at which the child said it was ready; None where
+        it has not said so yet (or never will)."""
+        try:
+            word, at = os.read(self.proc.stdout.fileno(), 64).split()
+            return float(at) if word == b"ready" else None
+        except (OSError, ValueError):
+            return None
+
+    def hand(self, xplane_path: str | None) -> bool:
+        """Hands the child the artifact's path (None: nothing is owed, it
+        exits 0) and lets go of both pipes. False where the child is not
+        there to take it."""
+        line = ("" if xplane_path is None else json.dumps(xplane_path)) + "\n"
+        try:
+            self.proc.stdin.write(line.encode())
+            return self.proc.poll() is None
+        except (OSError, ValueError):  # died early, or handed already
+            return False
+        finally:
+            self.proc.stdin.close()
+            self.proc.stdout.close()
+
+
 class JaxProfiler:
     """Default profiler backend: jax.profiler XLA trace capture.
 
@@ -730,7 +789,10 @@ class JaxProfiler:
     then produces the same derived trace.json.gz from a deprioritized
     background process (no GIL stolen from the training loop) running the
     streamed, CPU-budgeted converter (trace.ConvertBudget; TRACE_CONVERT_*
-    config keys tune it per capture — see docs/TRACE_PIPELINE.md).
+    config keys tune it per capture — see docs/TRACE_PIPELINE.md). That
+    process is started as the capture's window opens (`warm_export`), so
+    its interpreter and imports are done while the window and the drain
+    last, and it is handed the artifact's path as the write completes.
     Artifact parity with jax's own export, minus ~2s of capture latency.
 
     Falls back to the public start_trace/stop_trace API when the private
@@ -753,7 +815,15 @@ class JaxProfiler:
         self._sess = None
         self._local_devices: int | None = None
         self._dir: str | None = None
+        # The reaper of the newest export child, or the in-process
+        # fallback's thread: alive while that conversion is.
         self._export_thread: threading.Thread | None = None
+        # The child started at this capture's window, until stop() binds
+        # it to the artifact's write or release_export() sends it away;
+        # the lock because TraceClient.stop() may release from another
+        # thread than the poll thread's.
+        self._export_child: _ExportChild | None = None
+        self._export_lock = threading.Lock()
         self._pending_write: PendingWrite | None = None
         # The request context of the capture in progress, set and cleared
         # by the shim: the clock marks exist inside a capture only, and
@@ -899,7 +969,9 @@ class JaxProfiler:
         # but a future incremental drain feeds the same queue.
         on_complete = None
         if self.export_trace_json:
-            on_complete = lambda path: self._spawn_export(path, export_ctx)  # noqa: E731
+            child = self._take_export_child()
+            on_complete = lambda path: self._spawn_export(  # noqa: E731
+                path, export_ctx, child)
         self._pending_write = _feed_write(
             xplane_path, xspace, self.WRITE_CHUNK_BYTES, on_complete,
             write_ctx)
@@ -913,17 +985,37 @@ class JaxProfiler:
         pending, self._pending_write = self._pending_write, None
         return pending
 
-    def _spawn_export(self, xplane_path: str, ctx=None) -> None:
-        """Launches the conversion (summary and Chrome trace, one decode
-        of each plane: trace.write_derived_artifacts) OUT of process: it is
-        tenths of a second of pure-Python work (`convert_ms`, PERF.md
-        section 5), and an in-process thread would steal the GIL from the
-        training loop (and from the next capture's stop) for its whole
-        run. Falls back to an in-process thread if the interpreter can't
-        be spawned."""
-        import subprocess
-        import sys
+    def warm_export(self, ctx=None) -> None:
+        """Called by the shim as a capture's window opens: where this
+        capture will owe derived files (export on, and the session is ours:
+        the public-API fallback writes its own), starts the export child
+        NOW, so its interpreter and imports lie under the window and the
+        drain and not after the artifact. `ctx` is what the child's
+        trace.convert span parents to. A spawn that fails leaves nothing
+        here, and the hand-over starts the child then (`_spawn_export`)."""
+        if self.export_trace_json and self._sess is not None:
+            child = self._start_export_child(ctx)
+            with self._export_lock:
+                self._export_child = child
 
+    def _take_export_child(self) -> "_ExportChild | None":
+        with self._export_lock:
+            child, self._export_child = self._export_child, None
+        return child
+
+    def release_export(self) -> None:
+        """Every way out of a capture that binds no artifact to the child
+        started at its window (stop() raised, the capture was abandoned,
+        the shim stops mid-window) ends here: the child is told that
+        nothing is owed and exits 0, no file written, no span flushed."""
+        child = self._take_export_child()
+        if child is not None:
+            child.hand(None)
+
+    def _start_export_child(self, ctx=None) -> "_ExportChild | None":
+        """One export child, everything but the artifact's path in its
+        environment; None where no interpreter can be spawned (or the
+        shim.export_spawn drill says so)."""
         import dynolog_tpu
 
         pkg_parent = os.path.dirname(
@@ -934,9 +1026,9 @@ class JaxProfiler:
         # Per-capture converter budget (TRACE_CONVERT_* config keys): the
         # child's ConvertBudget.from_env picks these up.
         env.update(self.convert_env)
-        # Self-tracing hand-off: the capture's span context (passed in by
-        # stop(), since this now runs on the writer thread — the ambient
-        # context there is empty) and the daemon endpoint, so the child's
+        # Self-tracing hand-off: the capture's span context (handed in,
+        # since the hand-over runs on the writer thread, whose ambient
+        # context is empty) and the daemon endpoint, so the child's
         # trace.convert span lands under the SAME request trace-id and is
         # flushed back to the daemon on exit
         # (write_derived_artifacts -> obs.maybe_flush_env).
@@ -946,41 +1038,53 @@ class JaxProfiler:
         endpoint = getattr(self, "obs_endpoint", "")
         if endpoint:
             env[obs.ENV_FLUSH_ENDPOINT] = endpoint
-        # nice(19) inside the child (not via preexec_fn, which is
-        # fork-deadlock-prone in a process full of XLA threads and blocks
-        # posix_spawn): the conversion is pure-CPU gzip/json churn that
-        # would otherwise inflate the next capture's write and the
-        # training loop itself.
-        code = (
-            "import os; os.nice(19); "
-            "from dynolog_tpu.trace import write_derived_artifacts; "
-            f"write_derived_artifacts({xplane_path!r})"
-        )
         try:
             if failpoints.fire("shim.export_spawn"):
                 raise OSError("failpoint shim.export_spawn")
-            proc = subprocess.Popen(
-                [sys.executable, "-c", code],
-                env=env,
-                stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL,
-                start_new_session=True,
-            )
+            child = _ExportChild(env)
         except OSError:
-            self._export_thread = threading.Thread(
-                target=self._export_json,
-                args=(xplane_path, dict(self.convert_env)),
-                name="dynolog_tpu_trace_export",
-                daemon=True,
-            )
-            self._export_thread.start()
-            return
-        # Reap without blocking anything: wait() parks in waitpid with the
-        # GIL released, so the converter can't leave a zombie behind.
+            return None
+        self._export_thread = child.reaper
+        return child
+
+    def _spawn_export(self, xplane_path: str | None, ctx=None,
+                      child: "_ExportChild | None" = None) -> dict:
+        """The hand-over, on the writer thread as the artifact's write
+        completes: the conversion (summary and Chrome trace, one decode of
+        each plane: trace.write_derived_artifacts) runs OUT of process,
+        because it is tenths of a second of pure-Python work (`convert_ms`,
+        PERF.md section 5) and an in-process thread would steal the GIL
+        from the training loop (and from the next capture's stop) for its
+        whole run. `child` is the one started at this capture's window: it
+        is handed the path ("warm" where it had said it was ready, and
+        `export_ready_ms` is for how long). Where none is alive to take it
+        (none was started, its spawn failed, it died early) the same child
+        is started now and handed the path at once ("cold"); where no
+        interpreter can be spawned, an in-process thread ("thread"). A
+        path of None (the write failed) sends the child away. Returns what
+        the manifest says of it."""
+        if xplane_path is None:
+            if child is not None:
+                child.hand(None)
+            return {}
+        if child is not None:
+            now, ready_at = time.time(), child.ready_at()
+            if child.hand(xplane_path):
+                if ready_at is None:
+                    return {"export_child": "cold"}
+                return {"export_child": "warm",
+                        "export_ready_ms": int((now - ready_at) * 1000)}
+        child = self._start_export_child(ctx)
+        if child is not None and child.hand(xplane_path):
+            return {"export_child": "cold"}
         self._export_thread = threading.Thread(
-            target=proc.wait, name="dynolog_tpu_trace_export_reaper",
-            daemon=True)
+            target=self._export_json,
+            args=(xplane_path, dict(self.convert_env)),
+            name="dynolog_tpu_trace_export",
+            daemon=True,
+        )
         self._export_thread.start()
+        return {"export_child": "thread"}
 
     @staticmethod
     def _export_json(
@@ -1267,6 +1371,9 @@ class TraceClient:
             # manifest or span flush may be stranded by shutdown.
             finisher.join(timeout=30)
         self._finishers = []
+        # A window that outlasts the join above still holds its export
+        # child waiting for a path: no child of ours waits past stop().
+        self._release_export()
         self._client.close()
 
     def __enter__(self) -> "TraceClient":
@@ -1580,8 +1687,15 @@ class TraceClient:
             self._capture(cfg, ctx, polled_us)
         finally:
             # A capture that failed before the profiler's stop() must not
-            # leave its context for the next ring sample to parent to.
+            # leave its context for the next ring sample to parent to,
+            # nor the export child of its window waiting for an artifact.
             self.profiler.obs_ctx = None
+            self._release_export()
+
+    def _release_export(self) -> None:
+        release = getattr(self.profiler, "release_export", None)
+        if release is not None:
+            release()
 
     def _capture(self, cfg: TraceConfig, ctx: obs.TraceContext,
                  polled_us: int | None) -> None:
@@ -1681,7 +1795,9 @@ class TraceClient:
                     f"(at {self._step_count})"
                 )
             self._profiler_start(cap)
+            export_ctx = obs.current()
             with obs.span("shim.window", now=self._wall) as window:
+                self._warm_export(export_ctx)
                 with self._step_cv:
                     elapsed = self._step_cv.wait_for(
                         lambda: self._step_count >= end_at,
@@ -1696,10 +1812,28 @@ class TraceClient:
                 )
             return None
         self._profiler_start(cap)
+        export_ctx = obs.current()
         with obs.span("shim.window", now=self._wall) as window:
-            time.sleep(cfg.duration_ms / 1000.0)
+            # The window is the operator's: its end is fixed before the
+            # export child's spawn, which this thread would sleep through.
+            deadline = time.monotonic() + cfg.duration_ms / 1000.0
+            self._warm_export(export_ctx)
+            time.sleep(max(0.0, deadline - time.monotonic()))
         self._profiler_stop(cap, window)
         return None
+
+    def _warm_export(self, ctx: obs.TraceContext | None) -> None:
+        """The window has opened and this thread is about to wait it out:
+        the backend starts the export child of this capture now, under the
+        ambient shim.capture span `ctx` (JaxProfiler.warm_export). Never
+        the capture's to pay for: a failure here is a cold start later."""
+        warm = getattr(self.profiler, "warm_export", None)
+        if warm is None:
+            return
+        try:
+            warm(ctx)
+        except Exception as e:  # noqa: BLE001 - derived files only
+            self.last_error = f"export child warm start failed: {e}"
 
     def _profiler_start(self, cap: "_Capture") -> None:
         with obs.span("shim.profiler_start", now=self._wall) as start:
@@ -1736,9 +1870,12 @@ class TraceClient:
         # poll thread, to the manifest's rename, here.
         with obs.span("shim.finish", ctx=cap.ctx, now=self._wall,
                       start_us=cap.stopped_us):
+            export_child = None
             if pending is not None:
                 decomp = pending.wait()
                 cap.error = cap.error or decomp.pop("write_error", None)
+                # a word, so beside `timing`, whose values stay numbers
+                export_child = decomp.pop("export_child", None)
                 cap.timing.update(decomp)
                 cap.spans += [s for s in (pending.span, pending.index_span)
                               if s is not None]
@@ -1777,6 +1914,12 @@ class TraceClient:
             if pending is not None and pending.planes is not None:
                 # one row a plane of the artifact's XSpace, in file order
                 manifest["planes"] = pending.planes
+            if export_child is not None:
+                # how this capture's derived files were begun: by the child
+                # started at its window ("warm"; timing.export_ready_ms is
+                # how long it had been ready), by one started at the
+                # hand-over ("cold"), or in process ("thread")
+                manifest["export_child"] = export_child
             if cap.error:
                 manifest["error"] = cap.error
                 self.last_error = cap.error

@@ -36,7 +36,8 @@ parsed at import), or :func:`arm` / :func:`disarm` from tests.
 Instrumented sites (see docs/RELIABILITY.md for the catalog)::
 
     shim.run_trace       TraceClient capture path (poll-loop containment)
-    shim.export_spawn    JaxProfiler export-child spawn (thread fallback)
+    shim.export_spawn    JaxProfiler export-child spawn, at the window and
+                         at the hand-over (cold child, then thread fallback)
     trace.convert        write_derived_artifacts (a killed export child)
     cluster.rpc_connect  FramedRpcClient connects (fan-out degradation)
 

@@ -679,7 +679,10 @@ class ConvertBudget:
 
     Env overrides (read by `from_env`, and therefore by the shim's export
     subprocess): DYNO_TRACE_CONVERT_WORKERS, DYNO_TRACE_CONVERT_GZIP_LEVEL,
-    DYNO_TRACE_CONVERT_NICE, DYNO_TRACE_CONVERT_YIELD_S.
+    DYNO_TRACE_CONVERT_NICE, DYNO_TRACE_CONVERT_YIELD_S. That subprocess is
+    started as its capture's window opens and reads them then
+    (`export_child`): once to decide whether the pool's modules are worth
+    importing before the artifact exists, and again when it converts.
     """
 
     max_workers: int = 0  # 0 = auto: min(2, cpu count)
@@ -967,6 +970,42 @@ def write_derived_artifacts(
     finally:
         obs.maybe_flush_env()
     return written
+
+
+def export_child() -> int:
+    """The export child's life, from its interpreter being up at nice 19
+    (`shim._EXPORT_CHILD_CODE`): started by the shim as a capture's window
+    OPENS, it makes every import the conversion will make (the lazy ones
+    too: the pool's, where its ConvertBudget allows a second worker; the
+    span flush's, where there is a daemon to flush to), says so (`ready
+    <unix seconds>` on standard output), and blocks reading one line of
+    standard input. A JSON string is the
+    artifact's path, handed over as its write completes:
+    `write_derived_artifacts`. An empty line or end of file is a capture
+    that owes nothing (it failed, or the shim is gone): exit 0, no file
+    touched, no span opened or flushed."""
+    import zlib  # noqa: F401 - write_chrome_trace_gz's
+
+    from dynolog_tpu import failpoints, obs  # noqa: F401
+
+    if ConvertBudget.from_env().resolved_workers(2) > 1:
+        try:  # what _iter_fragments' pool imports as it starts
+            import concurrent.futures.process  # noqa: F401
+            import multiprocessing.popen_fork  # noqa: F401
+            import multiprocessing.synchronize  # noqa: F401
+        except ImportError:
+            pass  # no working pool here: _iter_fragments goes serial
+    if os.environ.get(obs.ENV_FLUSH_ENDPOINT):
+        from dynolog_tpu.client import ipc  # noqa: F401 - obs.flush_spans'
+    try:
+        os.write(1, f"ready {time.time():.6f}\n".encode())
+    except OSError:
+        pass  # nobody listens; the path still arrives
+    line = sys.stdin.readline().strip()
+    if not line:
+        return 0
+    write_derived_artifacts(json.loads(line))
+    return 0
 
 
 def find_xplane_files(target: str) -> list[str]:

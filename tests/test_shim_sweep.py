@@ -11,7 +11,6 @@ import os
 import pathlib
 import subprocess
 import sys
-import threading
 import time
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -241,25 +240,3 @@ def test_poll_loop_contains_capture_crash(tmp_path):
     assert client.last_error is not None
     assert "shim.run_trace" in client.last_error
     assert failpoints.hits("shim.run_trace") == 1
-
-
-def test_export_spawn_failpoint_falls_back_to_thread(tmp_path, monkeypatch):
-    # shim.export_spawn=error simulates an unspawnable interpreter: the
-    # profiler's export must degrade to the in-process thread, never
-    # lose the derived artifacts silently.
-    from dynolog_tpu.client.shim import JaxProfiler
-
-    failpoints.disarm_all()
-    failpoints.arm("shim.export_spawn", "error")
-    exported = threading.Event()
-    monkeypatch.setattr(
-        JaxProfiler, "_export_json",
-        staticmethod(lambda path, env=None: exported.set()))
-    profiler = JaxProfiler(export_trace_json=True)
-    xplane = tmp_path / "host.xplane.pb"
-    xplane.write_bytes(b"\x0a\x00")
-    try:
-        profiler._spawn_export(str(xplane))
-        assert exported.wait(timeout=5.0)
-    finally:
-        failpoints.disarm_all()

@@ -185,8 +185,10 @@ def test_pending_write_happy_path_runs_on_complete(tmp_path):
 
 def test_pending_write_writer_throw_surfaces_and_cleans(tmp_path):
     """Writer-side failure mid-pipeline (the convert/write worker dying):
-    wait() reports the error, on_complete never runs, the producer is
-    unblocked, and no partial artifact or tmp survives."""
+    wait() reports the error, on_complete is never handed the path (it is
+    told None once: whoever waits on the artifact, the shim's export child,
+    is sent away), the producer is unblocked, and no partial artifact or
+    tmp survives."""
     target_dir = tmp_path / "gone"
     target_dir.mkdir()
     path = target_dir / "host.xplane.pb"
@@ -205,7 +207,7 @@ def test_pending_write_writer_throw_surfaces_and_cleans(tmp_path):
     assert not fed_after_death
     decomp = pending.wait(10.0)
     assert "write_error" in decomp
-    assert completed == []
+    assert completed == [None]
     assert not path.exists()
 
 
