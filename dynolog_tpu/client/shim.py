@@ -740,6 +740,7 @@ class _ExportChild:
         import subprocess
         import sys
 
+        self.spawned_us = int(time.time() * 1e6)  # where export.boot opens
         self.proc = subprocess.Popen(
             [sys.executable, "-c", _EXPORT_CHILD_CODE], env=env, bufsize=0,
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -758,6 +759,20 @@ class _ExportChild:
             return float(at) if word == b"ready" else None
         except (OSError, ValueError):
             return None
+
+    def life_spans(self, ctx, ready_at: float, handed_at: float) -> list:
+        """The child's life before its conversion, as two spans under `ctx`
+        with the child's pid, from the three times the hand-over holds:
+        export.boot, from just before the Popen to the `ready` the child
+        stamped (fork and exec, the interpreter at nice 19, every import),
+        and export.idle, from there to the path going into its pipe."""
+        with obs.span("export.boot", ctx=ctx, start_us=self.spawned_us,
+                      now=lambda: ready_at) as boot:
+            boot.pid = self.proc.pid
+        with obs.span("export.idle", ctx=ctx, start_us=boot.end_us,
+                      now=lambda: handed_at) as idle:
+            idle.pid = self.proc.pid
+        return [boot, idle]
 
     def hand(self, xplane_path: str | None) -> bool:
         """Hands the child the artifact's path (None: nothing is owed, it
@@ -1056,8 +1071,9 @@ class JaxProfiler:
         PERF.md section 5) and an in-process thread would steal the GIL
         from the training loop (and from the next capture's stop) for its
         whole run. `child` is the one started at this capture's window: it
-        is handed the path ("warm" where it had said it was ready, and
-        `export_ready_ms` is for how long). Where none is alive to take it
+        is handed the path ("warm" where it had said it was ready: `spans`
+        are then its boot and its wait, `_ExportChild.life_spans`, and
+        `export_ready_ms` the wait's length). Where none is alive to take it
         (none was started, its spawn failed, it died early) the same child
         is started now and handed the path at once ("cold"); where no
         interpreter can be spawned, an in-process thread ("thread"). A
@@ -1068,12 +1084,14 @@ class JaxProfiler:
                 child.hand(None)
             return {}
         if child is not None:
-            now, ready_at = time.time(), child.ready_at()
+            ready_at, handed_at = child.ready_at(), time.time()
             if child.hand(xplane_path):
                 if ready_at is None:
                     return {"export_child": "cold"}
+                boot, idle = child.life_spans(ctx, ready_at, handed_at)
                 return {"export_child": "warm",
-                        "export_ready_ms": int((now - ready_at) * 1000)}
+                        "export_ready_ms": idle.dur_us // 1000,
+                        "spans": [boot, idle]}
         child = self._start_export_child(ctx)
         if child is not None and child.hand(xplane_path):
             return {"export_child": "cold"}
@@ -1876,6 +1894,8 @@ class TraceClient:
                 cap.error = cap.error or decomp.pop("write_error", None)
                 # a word, so beside `timing`, whose values stay numbers
                 export_child = decomp.pop("export_child", None)
+                # export.boot and export.idle, where the hand-over was warm
+                cap.spans += decomp.pop("spans", [])
                 cap.timing.update(decomp)
                 cap.spans += [s for s in (pending.span, pending.index_span)
                               if s is not None]
@@ -1917,8 +1937,8 @@ class TraceClient:
             if export_child is not None:
                 # how this capture's derived files were begun: by the child
                 # started at its window ("warm"; timing.export_ready_ms is
-                # how long it had been ready), by one started at the
-                # hand-over ("cold"), or in process ("thread")
+                # the span export.idle: how long it had been ready), by one
+                # started at the hand-over ("cold"), or in process ("thread")
                 manifest["export_child"] = export_child
             if cap.error:
                 manifest["error"] = cap.error

@@ -322,9 +322,12 @@ def flush_spans(
     """Drains the journal and sends each span to the daemon's IPC
     endpoint as a fire-and-forget "span" datagram (the daemon merges
     them into its own ring for `selftrace`). Best-effort: a dead daemon
-    costs nothing but the drained spans. Returns the count sent."""
+    costs nothing but the drained spans. In order of start, so a parent
+    goes before what it holds: the export child's trace.convert, which the
+    daemon's histogram feeds on, is not queued behind its planes' spans.
+    Returns the count sent."""
     journal = journal if journal is not None else JOURNAL
-    spans = journal.drain()
+    spans = sorted(journal.drain(), key=lambda s: s.start_us)
     if not spans:
         return 0
     from dynolog_tpu.client import ipc  # lazy: obs stays stdlib-only

@@ -31,6 +31,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from dynolog_tpu import obs
+
 # Protobuf fixed64 stat values decode as little-endian doubles. Module
 # level (not an inline struct.unpack format) per the dynolint
 # struct-constant rule.
@@ -717,8 +719,11 @@ class ConvertBudget:
         return budget
 
 
-def _nice_worker(nice: int) -> None:
-    """Pool-worker initializer: deprioritize before any plane work."""
+def _nice_worker(nice: int, ctx: "obs.TraceContext | None" = None) -> None:
+    """Pool-worker initializer: deprioritize before any plane work, and
+    take the conversion's span context as this process's ambient one, so
+    that a plane's spans parent to trace.convert wherever the plane runs."""
+    obs.set_current(ctx)
     try:
         if nice > 0:
             os.nice(nice)
@@ -735,14 +740,24 @@ def _convert_plane(job: tuple[int, bytes]) -> tuple:
     """The converter's unit of work: one plane decoded ONCE, its fragment
     and its PlaneSummary both made from that decode (the summary None where
     aggregating it raised: the fragment still goes). Top-level so
-    ProcessPoolExecutor can pickle it by reference."""
+    ProcessPoolExecutor can pickle it by reference.
+
+    Third of the result: the call's two spans, convert.plane round all of
+    it and convert.decode round `_decode_plane` alone, under the ambient
+    context and with the pid of the process that ran it. They travel with
+    the result because a pool worker has no journal anyone flushes
+    (`_iter_fragments` records them in the caller's)."""
     pid, plane_buf = job
-    plane = _decode_plane(plane_buf, 0, len(plane_buf))
-    try:
-        summary = _plane_summary(plane)
-    except Exception:  # noqa: BLE001 - a summarizer bug must not cost
-        summary = None  # the trace.json.gz; the caller finds the None
-    return _plane_json(pid, plane), summary
+    spans = obs.SpanJournal()
+    with obs.span("convert.plane", journal=spans):
+        with obs.span("convert.decode", journal=spans):
+            plane = _decode_plane(plane_buf, 0, len(plane_buf))
+        try:
+            summary = _plane_summary(plane)
+        except Exception:  # noqa: BLE001 - a summarizer bug must not cost
+            summary = None  # the trace.json.gz; the caller finds the None
+        fragment = _plane_json(pid, plane)
+    return fragment, summary, spans.snapshot()
 
 
 def _fork_safe() -> bool:
@@ -765,10 +780,19 @@ def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
     yielding. Pool failure — at setup (sandboxes without working fork)
     OR mid-run (a worker OOM-killed: BrokenProcessPool, a RuntimeError)
     — falls back to serial conversion of the REMAINING planes: a dead
-    pool must degrade to slow conversion, never to a missing artifact."""
+    pool must degrade to slow conversion, never to a missing artifact.
+    Each plane's spans are recorded here, in this process's journal, as
+    its pair is yielded: once a plane, where it was converted."""
     jobs = list(enumerate(plane_bufs, start=1))
     workers = budget.resolved_workers(len(jobs))
     done = 0
+
+    def keep_spans(converted: tuple) -> tuple:
+        fragment, summary, spans = converted
+        for span in spans:
+            obs.JOURNAL.record(span)
+        return fragment, summary
+
     if workers > 1 and _fork_safe():
         try:
             from concurrent.futures import ProcessPoolExecutor
@@ -776,16 +800,16 @@ def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
             with ProcessPoolExecutor(
                 max_workers=workers,
                 initializer=_nice_worker,
-                initargs=(budget.nice,),
+                initargs=(budget.nice, obs.current()),
             ) as pool:
                 for converted in pool.map(_convert_plane, jobs):
-                    yield converted
+                    yield keep_spans(converted)
                     done += 1
             return
         except (OSError, RuntimeError):
             pass  # pool died; planes [done:] convert serially below
     for i, job in enumerate(jobs[done:], start=done + 1):
-        yield _convert_plane(job)
+        yield keep_spans(_convert_plane(job))
         if (budget.yield_s > 0 and budget.yield_every_planes > 0
                 and i % budget.yield_every_planes == 0 and i < len(jobs)):
             time.sleep(budget.yield_s)
@@ -942,11 +966,16 @@ def write_derived_artifacts(
 
     Self-tracing: the whole conversion runs under a trace.convert span —
     parented to the capture's TRACE_CONTEXT when the shim handed one down
-    via $DYNO_TRACE_CTX — and when $DYNO_OBS_ENDPOINT names a daemon, the
-    span is flushed back to it on the way out (the daemon folds the
-    duration into the dynolog_trace_convert_seconds scrape histogram and
+    via $DYNO_TRACE_CTX — with one convert.plane span a plane under it and
+    that plane's convert.decode inside (`_convert_plane`; the pid is the
+    pool worker's where one ran it). What is not plane work (the read, the
+    pool's forks and pipes, gzip, both writes) is trace.convert's self
+    time. When $DYNO_OBS_ENDPOINT names a daemon, the spans are flushed
+    back to it on the way out, after both files are renamed (the daemon
+    folds trace.convert's duration into the
+    dynolog_trace_convert_seconds scrape histogram, and all of them into
     the `selftrace` journal)."""
-    from dynolog_tpu import failpoints, obs
+    from dynolog_tpu import failpoints
 
     # Fault drill: trace.convert=throw kills this export exactly the way
     # a SIGKILL'd/crashed export child does (the xplane is already on
@@ -986,7 +1015,7 @@ def export_child() -> int:
     touched, no span opened or flushed."""
     import zlib  # noqa: F401 - write_chrome_trace_gz's
 
-    from dynolog_tpu import failpoints, obs  # noqa: F401
+    from dynolog_tpu import failpoints  # noqa: F401
 
     if ConvertBudget.from_env().resolved_workers(2) > 1:
         try:  # what _iter_fragments' pool imports as it starts

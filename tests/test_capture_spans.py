@@ -23,10 +23,11 @@ from dynolog_tpu.client.shim import (
     STEP_MARKS, PendingWrite, RecordingProfiler, TraceClient, TraceConfig,
     job_cost)
 
+EXPORT = {"export.boot", "export.idle"}  # a warm export child's, by the shim
 TABLE = {
     "shim.config_fetch", "shim.capture", "shim.profiler_start", "shim.window",
     "shim.collect", "shim.feed", "shim.xplane_write", "shim.finish",
-    "shim.artifact_write"}
+    "shim.artifact_write"} | EXPORT
 IN_MANIFEST = TABLE - {"shim.finish", "shim.artifact_write"}
 
 
@@ -60,17 +61,43 @@ class SpanSink:
         pass
 
 
+class WarmChild(shim._ExportChild):
+    """An export child from the shim's side with no process behind it:
+    spawned 40 ms ago, ready 10 ms ago, and there to take whatever it is
+    handed."""
+
+    class proc:
+        pid = 4242
+
+    def __init__(self):
+        now = time.time()
+        self.spawned_us, self._ready_at = int((now - 0.04) * 1e6), now - 0.01
+        self.handed: list = []
+
+    def ready_at(self) -> float:
+        return self._ready_at
+
+    def hand(self, path) -> bool:
+        self.handed.append(path)
+        return True
+
+
 class SpanningProfiler:
     """JaxProfiler's shape without jax: stop() records shim.collect and
     shim.feed, hands them back in its decomposition, and feeds a
-    PendingWrite under the request's context.
+    PendingWrite under the request's context, whose completion is
+    JaxProfiler's own hand-over to the `WarmChild` of the capture's window
+    (a warmup has no window and no child).
     `hold` keeps the write open until the test closes its queue."""
 
     def __init__(self, clock=time.time, on_collect=None, hold: bool = False):
         self.clock, self.on_collect, self.hold = clock, on_collect, hold
         self.obs_ctx = None
         self.held: list[PendingWrite] = []
-        self._dir = self._pending = None
+        self._dir = self._pending = self._child = None
+
+    def warm_export(self, ctx=None) -> None:
+        self._child = WarmChild()
 
     def start(self, trace_dir: str) -> None:
         self._dir = trace_dir
@@ -78,15 +105,20 @@ class SpanningProfiler:
             self.clock.advance(60.7)
 
     def stop(self) -> None:
-        write_ctx = self.obs_ctx or obs.current()
+        export_ctx = obs.current()
+        write_ctx = self.obs_ctx or export_ctx
         with obs.span("shim.collect", now=self.clock) as collect:
             if self.on_collect:
                 self.on_collect()
         with obs.span("shim.feed", now=self.clock) as feed:
             run_dir = os.path.join(self._dir, "plugins", "profile", "run")
             os.makedirs(run_dir, exist_ok=True)
+            child, self._child = self._child, None
             pending = PendingWrite(
-                os.path.join(run_dir, "host.xplane.pb"), ctx=write_ctx)
+                os.path.join(run_dir, "host.xplane.pb"), ctx=write_ctx,
+                on_complete=child and (
+                    lambda path: shim.JaxProfiler()._spawn_export(
+                        path, export_ctx, child)))
             pending.queue.put(memoryview(b"x" * 4096))
             if self.hold:
                 self.held.append(pending)
@@ -169,7 +201,10 @@ def test_one_capture_yields_every_span_under_one_trace_id(tmp_path):
         "shim.profiler_start": capture, "shim.window": capture,
         "shim.collect": capture, "shim.feed": capture,
         "shim.xplane_write": request, "shim.finish": request,
-        "shim.artifact_write": finish}
+        "shim.artifact_write": finish,
+        "export.boot": capture, "export.idle": capture}
+    assert {spans[name].pid for name in EXPORT} == {WarmChild.proc.pid}
+    assert spans["shim.capture"].pid == os.getpid()
 
 
 def test_children_lie_inside_their_parents(tmp_path):
@@ -342,7 +377,7 @@ def test_job_cost(marks, spans, steps, cost):
 def test_a_backend_that_records_no_spans_keeps_working(tmp_path):
     manifest, spans, _ = one_capture(tmp_path, RecordingProfiler())
     assert manifest["status"] == "ok"
-    assert set(spans) == TABLE - {
+    assert set(spans) == TABLE - EXPORT - {
         "shim.collect", "shim.feed", "shim.xplane_write"}
     assert set(manifest["timing"]) == {
         "received_ms", "profiler_start_ms", "profiler_stop_ms"}

@@ -128,6 +128,104 @@ def test_a_child_handed_a_path_writes_what_a_direct_call_writes(
     assert b"trace.convert" in sock.recv(4096)
 
 
+def flushed_spans(sock) -> list:
+    """Every span datagram at the socket, in order of arrival, as obs.Span."""
+    from dynolog_tpu.client import ipc
+
+    sock.settimeout(0.5)
+    spans = []
+    while True:
+        try:
+            frame = sock.recv(4096)
+        except TimeoutError:
+            return spans
+        size, kind = ipc.METADATA.unpack_from(frame)
+        assert kind.rstrip(b"\0") == ipc.MSG_TYPE_SPAN
+        assert size == ipc.SPAN.size
+        trace_id, span_id, parent_id, start_us, dur_us, pid, _, name = (
+            ipc.SPAN.unpack_from(frame, ipc.METADATA.size))
+        spans.append(obs.Span(
+            name.rstrip(b"\0").decode(), trace_id, span_id, parent_id,
+            start_us, dur_us, pid))
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_a_conversion_spans_every_plane_and_its_decode(
+        xplane, direct, daemon_socket, workers):
+    """One convert.plane a plane with its convert.decode inside, all
+    inside trace.convert and under the trace id handed down; a plane's pid
+    is the process that converted it; the files are what they were."""
+    name, sock = daemon_socket
+    ctx = obs.TraceContext.mint()
+    child = spawn_child({obs.ENV_FLUSH_ENDPOINT: name,
+                         obs.ENV_TRACE_CTX: ctx.header(),
+                         "DYNO_TRACE_CONVERT_WORKERS": str(workers)})
+    assert child.stdout.readline().startswith(b"ready ")
+    _, err = child.communicate((json.dumps(xplane) + "\n").encode(), 60)
+    assert child.returncode == 0, err
+    assert derived(xplane) == direct
+    spans = flushed_spans(sock)
+    n_planes = len(trace.plane_index(Path(xplane).read_bytes()))
+    assert n_planes == 4
+    convert, rest = spans[0], spans[1:]  # the parent is flushed first
+    assert convert.name == "trace.convert" and convert.pid == child.pid
+    assert convert.parent_id == ctx.span_id
+    assert {s.trace_id for s in spans} == {ctx.trace_id}
+    planes = [s for s in rest if s.name == "convert.plane"]
+    decodes = {s.parent_id: s for s in rest if s.name == "convert.decode"}
+    assert len(planes) == len(decodes) == n_planes
+    assert len(rest) == 2 * n_planes
+    for plane in planes:
+        assert plane.parent_id == convert.span_id
+        assert convert.start_us <= plane.start_us
+        assert plane.end_us <= convert.end_us
+        decode = decodes[plane.span_id]
+        assert plane.start_us <= decode.start_us
+        assert decode.end_us <= plane.end_us
+        assert decode.pid == plane.pid
+    pids = {s.pid for s in planes}
+    if workers == 1:
+        assert pids == {child.pid}
+        ends = [(s.start_us, s.end_us) for s in planes]
+        assert ends == sorted(ends)  # one after the other, in file order
+    else:
+        assert child.pid not in pids and 1 <= len(pids) <= workers
+
+
+def test_a_flush_sends_a_parent_before_what_it_holds(daemon_socket):
+    """Whatever order the journal holds them in (a plane's spans are
+    recorded before the trace.convert they lie in closes), the flush goes
+    in order of start."""
+    name, sock = daemon_socket
+    journal = obs.SpanJournal()
+    ctx = obs.TraceContext.mint()
+    for span_name, start_us in (("convert.decode", 30), ("convert.plane", 20),
+                                ("trace.convert", 10)):
+        journal.record(obs.Span(span_name, ctx.trace_id, obs.mint_id(),
+                                ctx.span_id, start_us, 5))
+    assert obs.flush_spans(name, journal) == 3
+    assert [s.name for s in flushed_spans(sock)] == [
+        "trace.convert", "convert.plane", "convert.decode"]
+
+
+def test_the_in_process_fallback_spans_its_planes_in_the_shims_journal(
+        xplane, direct):
+    """The `thread` hand-over converts in the job's own process: the same
+    spans, the job's pid, in the journal the shim's next flush drains."""
+    obs.JOURNAL.drain()  # the fixture's own direct call
+    shim.JaxProfiler._export_json(xplane)
+    assert derived(xplane) == direct
+    spans = obs.JOURNAL.drain()
+    (convert,) = [s for s in spans if s.name == "trace.convert"]
+    planes = [s for s in spans if s.name == "convert.plane"]
+    decodes = [s for s in spans if s.name == "convert.decode"]
+    assert len(planes) == len(decodes) == 4 and len(spans) == 9
+    assert {s.parent_id for s in planes} == {convert.span_id}
+    assert {s.parent_id for s in decodes} == {s.span_id for s in planes}
+    assert {s.pid for s in spans} == {os.getpid()}
+    assert {s.trace_id for s in spans} == {convert.trace_id}
+
+
 @pytest.mark.parametrize("said", [b"", b"\n"], ids=["eof", "empty-line"])
 def test_a_pipe_closed_without_a_path_ends_the_child_quietly(
         xplane, daemon_socket, said):
@@ -265,10 +363,14 @@ def test_every_hand_over_yields_both_files(xplane, direct, hand_over, how):
     profiler = session_profiler()
     said, child = hand_over(profiler, xplane)
     assert said["export_child"] == how
-    assert ("export_ready_ms" in said) is (how == "warm")
+    assert ("export_ready_ms" in said) is ("spans" in said) is (
+        how == "warm")
     if how == "warm":
         assert type(said["export_ready_ms"]) is int
         assert 0 <= said["export_ready_ms"] < 20_000
+        boot, idle = said["spans"]
+        assert (boot.name, idle.name) == ("export.boot", "export.idle")
+        assert boot.pid == idle.pid == child.proc.pid
     profiler._export_thread.join(60)
     assert not profiler._export_thread.is_alive()
     assert derived(xplane) == direct
@@ -353,11 +455,45 @@ def test_the_manifest_says_how_the_export_began(
     timing = manifest["timing"]
     assert ("export_ready_ms" in timing) is (how == "warm")
     assert all(type(v) is int for v in timing.values()), timing
+    spans = {row["name"]: row for row in manifest["spans"]}
+    life = {"export.boot", "export.idle"}  # a warm child's, and only its
+    assert life & set(spans) == (life if how == "warm" else set())
     if how == "warm":
         # spawned as the window opened: ready for most of its 600 ms
         assert 0 < timing["export_ready_ms"] <= 600 + timing["collect_ms"] + 50
     assert wait_until(lambda: len(derived_of(manifest)) == 2), derived_of(
         manifest)
+    client.profiler._export_thread.join(30)
+    assert wait_until(lambda: not live_children())
+
+
+def test_a_warm_childs_boot_and_wait_are_spans_of_its_capture(
+        tmp_path, monkeypatch, started):
+    manifest, client = capture(tmp_path, monkeypatch, "life", duration_ms=600)
+    assert manifest["export_child"] == "warm"
+    (child,) = started
+    rows = {row["name"]: row for row in manifest["spans"]}
+    boot, idle, window, capture_ = (rows[name] for name in (
+        "export.boot", "export.idle", "shim.window", "shim.capture"))
+    # the boot opens inside the window, just before the Popen, and ends
+    # where the wait begins: the `ready` the child stamped
+    assert window["start_us"] <= boot["start_us"] <= window["start_us"] + 40_000
+    assert boot["dur_us"] > 0
+    assert boot["start_us"] + boot["dur_us"] == idle["start_us"]
+    assert boot["parent_id"] == idle["parent_id"] == capture_["span_id"]
+    # measured once: the timing key is the span, truncated
+    assert manifest["timing"]["export_ready_ms"] == idle["dur_us"] // 1000
+    # the wait ends at the hand-over, which the write's end releases
+    write = rows["shim.xplane_write"]
+    handed = idle["start_us"] + idle["dur_us"]
+    assert write["start_us"] + write["dur_us"] <= handed
+    # and the flush after the manifest's rename carries both, as the child's
+    sent = {s.name: s for s in client._client.sent}
+    trace_id = int(manifest["trace_ctx"].split("/")[0], 16)
+    for name in ("export.boot", "export.idle"):
+        assert sent[name].pid == child.proc.pid != os.getpid()
+        assert sent[name].trace_id == trace_id
+        assert f"{sent[name].span_id:016x}" == rows[name]["span_id"]
     client.profiler._export_thread.join(30)
     assert wait_until(lambda: not live_children())
 

@@ -31,7 +31,7 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from dynolog_tpu import trace  # noqa: E402
+from dynolog_tpu import obs, trace  # noqa: E402
 
 FIXTURE = REPO / "tests" / "fixtures" / "bench.xplane.pb"
 
@@ -179,10 +179,12 @@ def test_budget_serial_yields_between_plane_batches(xplane, monkeypatch):
 def test_pool_death_degrades_to_serial(xplane, monkeypatch):
     # A pool dying MID-RUN (worker OOM-killed -> BrokenProcessPool, a
     # RuntimeError) must not cost the artifact: the remaining planes
-    # convert serially and the output stays identical.
+    # convert serially and the output stays identical. Every plane is
+    # spanned once, where it was converted.
     import concurrent.futures
 
     single = _read_gz(trace.write_chrome_trace_gz_single(xplane))
+    obs.JOURNAL.drain()
 
     class DyingPool:
         def __init__(self, *a, **k):
@@ -202,9 +204,41 @@ def test_pool_death_degrades_to_serial(xplane, monkeypatch):
     monkeypatch.setattr(trace, "_fork_safe", lambda: True)
     monkeypatch.setattr(
         concurrent.futures, "ProcessPoolExecutor", DyingPool)
-    out = trace.write_chrome_trace_gz(
-        xplane, budget=trace.ConvertBudget(max_workers=2))
+    with obs.span("trace.convert", ctx=obs.TraceContext.mint()) as convert:
+        out = trace.write_chrome_trace_gz(
+            xplane, budget=trace.ConvertBudget(max_workers=2))
     assert _read_gz(out) == single
+    spans = obs.JOURNAL.drain()
+    assert [s.name for s in spans] == [
+        "convert.decode", "convert.plane"] * 4 + ["trace.convert"]
+    for decode, plane in zip(spans[0:8:2], spans[1:8:2]):
+        assert decode.parent_id == plane.span_id
+        assert plane.parent_id == convert.span_id
+        assert decode.trace_id == plane.trace_id == convert.trace_id
+        assert plane.pid == os.getpid()
+
+
+def test_convert_plane_hands_back_its_fragment_its_summary_and_its_spans():
+    # The unit of work as a pool worker returns it: the spans travel with
+    # the result, and the journal of the process that ran it stays empty.
+    obs.JOURNAL.drain()
+    data = FIXTURE.read_bytes()
+    bufs = list(trace.iter_plane_bufs(data))
+    ctx = obs.TraceContext.mint()
+    trace._nice_worker(0, ctx)  # what the pool runs in a worker first
+    try:
+        fragment, summary, spans = trace._convert_plane((1, bufs[0]))
+    finally:
+        obs.set_current(None)
+    assert fragment == trace._plane_fragment((1, bufs[0]))
+    assert summary == trace.summarize_xplane_bytes(data)[0]
+    decode, plane = spans  # in order of their ends
+    assert (decode.name, plane.name) == ("convert.decode", "convert.plane")
+    assert plane.parent_id == ctx.span_id and plane.trace_id == ctx.trace_id
+    assert decode.parent_id == plane.span_id
+    assert plane.start_us <= decode.start_us <= decode.end_us <= plane.end_us
+    assert all(len(s.name) < obs.NAME_BYTES for s in spans)
+    assert obs.JOURNAL.drain() == []
 
 
 def test_out_of_range_gzip_level_clamped(xplane):
