@@ -351,15 +351,19 @@ def _costs(buf, stat_spans, kinds: dict) -> dict:
     return found
 
 
-def _decode_plane(buf, start: int, end: int) -> _Plane:
+def _decode_plane(
+    buf, start: int, end: int, top: list | None = None
+) -> _Plane:
     """The one decode of the plane at buf[start:end]: metadata first (the
     stats' names, then every op's names and cost model), then every line
-    once and every event once. Nothing is copied but the names."""
+    once and every event once. Nothing is copied but the names. `top` is
+    `_fields(buf, start, end)` where the caller has walked the plane's top
+    level already (to weigh it, `_plane_weight`)."""
     plane = _Plane(bytes=end - start)
     line_spans, metadata_spans = [], []
     kinds: dict[int, str] = {}  # stat metadata id -> its COST_STATS name
     at = start
-    for num, wt, x, y in _fields(buf, start, end):
+    for num, wt, x, y in top or _fields(buf, start, end):
         kind = CONTENT_FIELDS.get(num, "other")
         plane.content[kind] = plane.content.get(kind, 0) + y - at
         at = y
@@ -536,10 +540,66 @@ def summarize_xplane_bytes(
 
 
 def iter_plane_bufs(data: bytes):
-    """Yields each plane's raw protobuf buffer from a serialized XSpace —
-    the unit of work the parallel converter fans out over."""
+    """Yields each plane's raw protobuf buffer from a serialized XSpace, a
+    copy a plane: how the single-shot reference and the tests walk a file.
+    The converter does not: the process that read the file converts by
+    offsets into `data` (`_plane_spans`) and cuts out only the planes it
+    sends a forked worker (`_iter_fragments`)."""
     for a, b in _plane_spans(data):
         yield data[a:b]
+
+
+# What one entry of a plane's event-metadata map weighs, in bytes of
+# `lines` (`_plane_weight`).
+METADATA_ENTRY_WEIGHT = 200
+
+# The least a forked worker's share has to weigh, in `_plane_weight`'s unit
+# (8-9 thousand to a millisecond of a device plane's `convert.plane` on the
+# chip machine, so about 60 ms): what a fork, its pipes and the fragment's
+# way back cost. From the spans of PR 39's traced runs (PERF.md section 5),
+# two workers: a worker's first plane started 42.4-74.8 ms after
+# `trace.convert` opened (less the read: 42-71 ms), and the last fragment
+# took 12.7-40.7 ms from its plane's end through the pipe to the span's
+# close. It lies between what today's one-chip artifacts hold beside their
+# device plane (`/host:CPU`, 81-105 thousand, 16-24 ms: no fork) and what
+# four device planes leave the second process (170-250 ms: a fork).
+FORK_WORTH_WEIGHT = 500_000
+
+
+def _plane_weight(top: list) -> tuple[int, int]:
+    """(weight, lines) of a plane from one walk of its top level (`top`:
+    its `_fields`, the walk `_decode_plane` begins with and takes over; no
+    line and no metadata entry is opened). The weight says what converting
+    the plane will cost, and that is events and op metadata, not size: the
+    bytes under `lines` (field 3) plus METADATA_ENTRY_WEIGHT for every
+    entry of the event-metadata map (field 4). `/host:metadata`, the
+    largest plane of every artifact (1.1-27.4 MB of HLO in its stats),
+    holds one entry and no line, weighs 200 and converts in 0.1-0.3 ms.
+
+    How it maps to milliseconds, from artifacts kept from the three
+    one-chip cells and converted on the chip machine at nice 19 (PR 40,
+    chip call 1): an event costs about 6 us wherever it lies and a
+    metadata entry about 23 us (its names and a dozen stats opened for the
+    cost model); a device plane's event is 52-56 bytes of its line, so its
+    `convert.plane` lasts a millisecond for every 8-9 thousand of weight
+    (olmo2-1b's `/device:TPU:0`: 27.7 k events in 1.54 MB of lines, 7600
+    entries, weight 3.06 M, 348 ms; the hybrid job's 1.74 M, 219 ms;
+    olmo2-7b-2l's 274 k, 31 ms); a host thread's event is 16 bytes, so
+    `/host:CPU` (2.7-4.3 k events, 183-195 entries, weight 81-105 k) takes
+    16-24 ms, twice what its weight says. The rule needs no better: the
+    shares it tells apart lie a factor of five under the threshold (one
+    chip: 81-105 k beside the device plane) and of 2.6-4.4 over it (four
+    chips: a worker's two device planes, 1.30 and 2.18 M)."""
+    line_bytes = lines = entries = 0
+    for num, wt, x, y in top:
+        if wt != 2:
+            continue
+        if num == 3:
+            line_bytes += y - x
+            lines += 1
+        elif num == 4:
+            entries += 1
+    return line_bytes + METADATA_ENTRY_WEIGHT * entries, lines
 
 
 def plane_index(data) -> list[dict]:
@@ -661,19 +721,22 @@ class ConvertBudget:
     unbudgeted converters pile up across back-to-back captures and
     take CPU from every later one and from the job. Knobs:
 
-    - max_workers: plane-conversion parallelism. >1 fans planes out over
-      a process pool (the work is pure-Python and GIL-bound, so threads
-      cannot parallelize it); 1 converts serially in-process with no pool
-      at all. Capped by the plane count — and the pool only engages from
-      a (near-)single-threaded process like the shim's export subprocess
-      (fork safety; see _iter_fragments), degrading to serial elsewhere.
+    - max_workers: the most processes that convert planes at one time, THE
+      CALLER ONE OF THEM (the work is pure-Python and GIL-bound, so threads
+      cannot parallelize it): 1 never forks; the default is the caller and
+      at most one forked worker. An upper bound, not a request: how many
+      of them an artifact gets is read from the artifact (`_shares`: a
+      worker is forked only for a share of planes worth a fork, and at one
+      chip none is), and only from a (near-)single-threaded process like
+      the shim's export subprocess (fork safety; see _iter_fragments),
+      serial elsewhere.
     - gzip_level: zlib level for the streamed trace.json.gz. Default 1:
       the artifact is a scratch view, and level 1 costs a fraction of the
       default level-9 `gzip.open` CPU for ~15-25% larger output.
-    - nice: niceness ADDED to each pool worker (os.nice increment), so
+    - nice: niceness ADDED to each forked worker (os.nice increment), so
       parallel conversion can never compete with a training loop at
-      normal priority. Serial in-process conversion does not re-nice the
-      caller (the shim's export subprocess is already nice 19).
+      normal priority. The caller is not re-niced (the shim's export
+      subprocess is already nice 19).
     - yield_every_planes / yield_s: in serial mode, sleep yield_s after
       every yield_every_planes planes — plane-batch yielding that bounds
       the converter's CPU duty cycle on single-core hosts where even a
@@ -684,16 +747,19 @@ class ConvertBudget:
     DYNO_TRACE_CONVERT_NICE, DYNO_TRACE_CONVERT_YIELD_S. That subprocess is
     started as its capture's window opens and reads them then
     (`export_child`): once to decide whether the pool's modules are worth
-    importing before the artifact exists, and again when it converts.
+    importing before the artifact exists (it cannot know yet whether the
+    artifact will be worth a fork), and again when it converts.
     """
 
-    max_workers: int = 0  # 0 = auto: min(2, cpu count)
+    max_workers: int = 0  # 0 = auto: min(2, cpu count), the caller counted
     gzip_level: int = 1
     nice: int = 10
     yield_every_planes: int = 4
     yield_s: float = 0.0
 
     def resolved_workers(self, n_planes: int) -> int:
+        """Processes the budget allows over `n_planes` planes, the caller
+        among them: 1 is the caller alone."""
         workers = self.max_workers
         if workers <= 0:
             workers = min(2, os.cpu_count() or 1)
@@ -736,22 +802,29 @@ def _plane_fragment(job: tuple[int, bytes]) -> bytes:
     return _convert_plane(job)[0]
 
 
-def _convert_plane(job: tuple[int, bytes]) -> tuple:
+def _convert_plane(
+    job: tuple[int, bytes], start: int = 0, end: int | None = None,
+    top: list | None = None,
+) -> tuple:
     """The converter's unit of work: one plane decoded ONCE, its fragment
     and its PlaneSummary both made from that decode (the summary None where
-    aggregating it raised: the fragment still goes). Top-level so
-    ProcessPoolExecutor can pickle it by reference.
+    aggregating it raised: the fragment still goes). `job` is the plane's
+    Chrome-trace pid and the buffer that holds it: the plane's own bytes
+    where a worker was sent them (top-level so ProcessPoolExecutor can
+    pickle it by reference), the whole file with the plane at [start:end)
+    where the process that read it converts (`top`: `_decode_plane`'s).
 
     Third of the result: the call's two spans, convert.plane round all of
     it and convert.decode round `_decode_plane` alone, under the ambient
     context and with the pid of the process that ran it. They travel with
     the result because a pool worker has no journal anyone flushes
     (`_iter_fragments` records them in the caller's)."""
-    pid, plane_buf = job
+    pid, buf = job
     spans = obs.SpanJournal()
     with obs.span("convert.plane", journal=spans):
         with obs.span("convert.decode", journal=spans):
-            plane = _decode_plane(plane_buf, 0, len(plane_buf))
+            plane = _decode_plane(
+                buf, start, len(buf) if end is None else end, top)
         try:
             summary = _plane_summary(plane)
         except Exception:  # noqa: BLE001 - a summarizer bug must not cost
@@ -772,28 +845,93 @@ def _fork_safe() -> bool:
     return threading.active_count() == 1 and "jax" not in sys.modules
 
 
-def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
-    """Per-plane (JSON fragment, PlaneSummary) pairs (`_convert_plane`), in
-    plane order, under the budget: a
-    nice'd process pool when the budget allows >1 worker (and there is
-    more than one plane to win on), else serial with plane-batch
-    yielding. Pool failure — at setup (sandboxes without working fork)
-    OR mid-run (a worker OOM-killed: BrokenProcessPool, a RuntimeError)
-    — falls back to serial conversion of the REMAINING planes: a dead
-    pool must degrade to slow conversion, never to a missing artifact.
-    Each plane's spans are recorded here, in this process's journal, as
-    its pair is yielded: once a plane, where it was converted."""
-    jobs = list(enumerate(plane_bufs, start=1))
-    workers = budget.resolved_workers(len(jobs))
-    done = 0
+def _shares(
+    weights: list[tuple[int, int]], processes: int
+) -> tuple[list[int], list[int], int]:
+    """Who converts which plane, from every plane's `_plane_weight`: (the
+    caller's planes, the workers' planes, how many workers to fork), each
+    list in the order its planes are to be converted. Longest share first
+    over the planes that hold lines: heaviest first, each to the process
+    that carries least so far, a worker counted as starting one fork
+    (FORK_WORTH_WEIGHT) behind, so the caller (which ties go to) converts
+    the heaviest plane itself, and at once. A plane with no line in it
+    stays with the caller, where its bytes are, last. As many of
+    `processes` as leave EVERY forked worker a share of FORK_WORTH_WEIGHT
+    or more: with today's artifacts the caller alone wherever one device
+    plane is the conversion (one chip), one worker at four chips. No
+    worker: every plane the caller's, in file order."""
+    lined = sorted((i for i, (_, lines) in enumerate(weights) if lines),
+                   key=lambda i: -weights[i][0])
+    lineless = [i for i, (_, lines) in enumerate(weights) if not lines]
+    for n in range(min(processes, len(lined)), 1, -1):
+        loads = [sum(weights[i][0] for i in lineless)] + [
+            FORK_WORTH_WEIGHT] * (n - 1)
+        mine, theirs = [], []
+        for i in lined:
+            k = loads.index(min(loads))
+            loads[k] += weights[i][0]
+            (theirs if k else mine).append(i)
+        # every worker's share, less the fork it started behind by
+        if min(loads[1:]) - FORK_WORTH_WEIGHT >= FORK_WORTH_WEIGHT:
+            return mine + lineless, theirs, n - 1
+    return list(range(len(weights))), [], 0
+
+
+def _iter_fragments(data: bytes, budget: ConvertBudget):
+    """Per-plane (JSON fragment, PlaneSummary) pairs (`_convert_plane`) of
+    the serialized XSpace `data`, in plane order, under the budget.
+
+    The caller is a converter: it holds the bytes, so it converts by offsets
+    into them, nothing copied, pickled or piped. Where the budget allows a
+    second process and forking is safe, every plane is weighed before any
+    is converted (`_plane_weight`) and the planes are split (`_shares`):
+    the caller keeps the heaviest plane and every plane with no line in
+    it; nice'd workers are forked only for shares that outweigh a fork
+    (FORK_WORTH_WEIGHT), BEFORE the caller starts on its own share, and are
+    sent their planes' bytes heaviest first. The pairs go out in file
+    order once the caller's share is done, a worker's as they fall due.
+    Where no share is worth a fork (every one-chip artifact today), under
+    `max_workers=1` and from a multithreaded caller: no pool, file order,
+    with plane-batch yielding.
+
+    Pool failure — at setup (sandboxes without working fork) OR mid-run (a
+    worker OOM-killed: BrokenProcessPool, a RuntimeError) — leaves the
+    caller converting what the pool has not handed back: a dead pool must
+    degrade to slow conversion, never to a missing artifact. Each plane's
+    spans are recorded here, in this process's journal, once a plane,
+    whichever process converted it."""
+    spans = _plane_spans(data)
+    processes = budget.resolved_workers(len(spans))
+    tops, theirs, workers = [None] * len(spans), [], 0
+    if processes > 1 and _fork_safe():
+        tops = [_fields(data, a, b) for a, b in spans]
+        ours, theirs, workers = _shares(
+            [_plane_weight(top) for top in tops], processes)
 
     def keep_spans(converted: tuple) -> tuple:
-        fragment, summary, spans = converted
-        for span in spans:
+        fragment, summary, plane_spans = converted
+        for span in plane_spans:
             obs.JOURNAL.record(span)
         return fragment, summary
 
-    if workers > 1 and _fork_safe():
+    def convert(i: int) -> tuple:
+        return keep_spans(_convert_plane((i + 1, data), *spans[i], tops[i]))
+
+    mine: dict[int, tuple] = {}  # the caller's share, converted, by plane
+    sent: dict = {}  # a worker's plane -> its Future
+    done = 0
+
+    def due(i: int) -> tuple:
+        if i in mine:
+            return mine.pop(i)
+        if i in sent:
+            try:
+                return keep_spans(sent.pop(i).result())
+            except (OSError, RuntimeError):
+                pass  # the pool died under this plane
+        return convert(i)
+
+    if workers:
         try:
             from concurrent.futures import ProcessPoolExecutor
 
@@ -802,16 +940,23 @@ def _iter_fragments(plane_bufs: list[bytes], budget: ConvertBudget):
                 initializer=_nice_worker,
                 initargs=(budget.nice, obs.current()),
             ) as pool:
-                for converted in pool.map(_convert_plane, jobs):
-                    yield keep_spans(converted)
-                    done += 1
+                # the first submit forks; the workers pull from one queue
+                for i in theirs:
+                    a, b = spans[i]
+                    sent[i] = pool.submit(_convert_plane, (i + 1, data[a:b]))
+                for i in ours:
+                    mine[i] = convert(i)
+                for i in range(len(spans)):
+                    yield due(i)
+                    done = i + 1
             return
         except (OSError, RuntimeError):
-            pass  # pool died; planes [done:] convert serially below
-    for i, job in enumerate(jobs[done:], start=done + 1):
-        yield keep_spans(_convert_plane(job))
+            pass  # no pool to be had; planes [done:] convert below
+    for i in range(done, len(spans)):
+        yield due(i)
+        n = i + 1  # planes out so far
         if (budget.yield_s > 0 and budget.yield_every_planes > 0
-                and i % budget.yield_every_planes == 0 and i < len(jobs)):
+                and n % budget.yield_every_planes == 0 and n < len(spans)):
             time.sleep(budget.yield_s)
 
 
@@ -868,10 +1013,12 @@ def write_chrome_trace_gz(
     it raised) is appended to it as the plane's fragment goes: the same
     decode made both (`_convert_plane`).
 
-    Streamed and budgeted: planes convert to JSON fragments in a nice'd
-    worker pool (or serially, per `budget`), and each fragment goes
-    through a chunked `zlib.compressobj` at the budget's gzip level as it
-    arrives — the full event list is never materialized, and the CPU cost
+    Streamed and budgeted: planes convert to JSON fragments where the
+    bytes are, beside a nice'd worker only where the artifact has a share
+    of planes worth a fork (`_iter_fragments`, per `budget`), and each
+    fragment goes through a chunked `zlib.compressobj` at the budget's
+    gzip level in file order — the event list is never materialized (the
+    fragments are, beside a worker, until their turn), and the CPU cost
     is a fraction of the old monolithic level-9 `gzip.open` + `json.dump`
     (kept as `write_chrome_trace_gz_single`, the tests' reference).
     Write-then-rename, tmp unlinked on failure: a reader (TensorBoard, an
@@ -893,8 +1040,7 @@ def write_chrome_trace_gz(
         comp = zlib.compressobj(level, zlib.DEFLATED, 16 + zlib.MAX_WBITS)
         yield comp.compress(b'{"displayTimeUnit": "ns", "traceEvents": [')
         first = True
-        for fragment, summary in _iter_fragments(
-                list(iter_plane_bufs(data)), budget):
+        for fragment, summary in _iter_fragments(data, budget):
             if summaries is not None:
                 summaries.append(summary)
             if not fragment:
@@ -967,10 +1113,13 @@ def write_derived_artifacts(
     Self-tracing: the whole conversion runs under a trace.convert span —
     parented to the capture's TRACE_CONTEXT when the shim handed one down
     via $DYNO_TRACE_CTX — with one convert.plane span a plane under it and
-    that plane's convert.decode inside (`_convert_plane`; the pid is the
-    pool worker's where one ran it). What is not plane work (the read, the
-    pool's forks and pipes, gzip, both writes) is trace.convert's self
-    time. When $DYNO_OBS_ENDPOINT names a daemon, the spans are flushed
+    that plane's convert.decode inside (`_convert_plane`; the pid is this
+    process's, or a forked worker's for the planes one was sent: only an
+    artifact with a share of planes worth a fork gets one,
+    `_iter_fragments`). What is not plane work (the read, the planes
+    weighed, a worker's fork and pipes where there is one, gzip, both
+    writes) is trace.convert's self time. When $DYNO_OBS_ENDPOINT names a
+    daemon, the spans are flushed
     back to it on the way out, after both files are renamed (the daemon
     folds trace.convert's duration into the
     dynolog_trace_convert_seconds scrape histogram, and all of them into
@@ -1005,8 +1154,9 @@ def export_child() -> int:
     """The export child's life, from its interpreter being up at nice 19
     (`shim._EXPORT_CHILD_CODE`): started by the shim as a capture's window
     OPENS, it makes every import the conversion will make (the lazy ones
-    too: the pool's, where its ConvertBudget allows a second worker; the
-    span flush's, where there is a daemon to flush to), says so (`ready
+    too: the pool's, where its ConvertBudget allows a second process, since
+    whether the artifact is worth one shows only once it exists; the span
+    flush's, where there is a daemon to flush to), says so (`ready
     <unix seconds>` on standard output), and blocks reading one line of
     standard input. A JSON string is the
     artifact's path, handed over as its write completes:

@@ -13,6 +13,10 @@ Four contracts:
 - BUDGET: ConvertBudget's knobs are honored — max_workers=1 never
   touches a process pool, env overrides parse (and malformed ones are
   ignored), serial conversion yields between plane batches.
+- THE RULE: who converts which plane is read from the artifact — the
+  caller converts the heaviest plane and every plane with no line in it,
+  a worker is forked only for a share of planes worth a fork and is sent
+  them heaviest first, and the files are what one process writes.
 - HYGIENE: every derived-artifact writer cleans its .tmp on failure (the
   orphaned-tmp leak), and stream_write is atomic with the same
   guarantee.
@@ -49,6 +53,37 @@ def _read_gz(path: str) -> str:
         return f.read()
 
 
+WORKER_PID = 1  # what InProcessPool's "worker" stamps its spans with
+
+
+class InProcessPool:
+    """ProcessPoolExecutor's face over the calling process: what a forked
+    worker would run, run here as it is submitted (so before the caller's
+    own share, as a worker forked first would begin), the spans of its
+    result stamped with WORKER_PID. `jobs` holds what was pickled."""
+
+    def __init__(self, *a, **k):
+        self.kwargs = k
+        self.jobs = []
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def submit(self, fn, job):
+        import concurrent.futures
+
+        self.jobs.append(job)
+        fragment, summary, spans = fn(job)
+        for span in spans:
+            span.pid = WORKER_PID
+        future = concurrent.futures.Future()
+        future.set_result((fragment, summary, spans))
+        return future
+
+
 def test_fixture_regenerates_identically():
     # The checked-in fixture IS its generator's output — a drifted
     # generator (or a hand-edited fixture) fails here, keeping its
@@ -74,24 +109,37 @@ def test_streamed_serial_matches_single_shot(xplane):
                if e["ph"] == "X")
 
 
-def test_streamed_parallel_matches_single_shot(xplane):
-    # The pool only engages from a (near-)single-threaded process (fork
+def test_streamed_parallel_matches_single_shot(tmp_path):
+    # A worker is forked only from a (near-)single-threaded process (fork
     # safety — see _iter_fragments), and this pytest session is not one
     # (jax threads): run the parallel conversion the way production does,
     # in a clean subprocess, then compare against the in-process single
-    # shot.
+    # shot. The artifact is one worth a fork: four planes of 3000 ops.
     import subprocess
 
+    from xspace_fixture import build_xspace
+
+    xplane = str(tmp_path / "host.xplane.pb")
+    with open(xplane, "wb") as f:
+        f.write(build_xspace(ops_per_plane=3000, events_per_line=200))
     single = _read_gz(trace.write_chrome_trace_gz_single(xplane))
     code = (
+        "import os; from dynolog_tpu import obs; "
         "from dynolog_tpu.trace import ConvertBudget, write_chrome_trace_gz"
         f"; write_chrome_trace_gz({xplane!r}, "
-        "budget=ConvertBudget(max_workers=2))")
-    subprocess.run(
+        "budget=ConvertBudget(max_workers=2)); "
+        "print(os.getpid(), *[s.pid for s in obs.JOURNAL.snapshot() "
+        "if s.name == 'convert.plane'])")
+    ran = subprocess.run(
         [sys.executable, "-c", code], check=True, cwd=str(REPO),
-        env={**os.environ, "PYTHONPATH": str(REPO)})
+        env={**os.environ, "PYTHONPATH": str(REPO)}, capture_output=True,
+        text=True)
     parallel = _read_gz(trace._derived_path(xplane, ".trace.json.gz"))
     assert parallel == single
+    caller, *pids = ran.stdout.split()
+    # two of four alike the caller's, two one forked worker's
+    assert len(pids) == 4 and pids.count(caller) == 2
+    assert len(set(pids)) == 2
 
 
 def test_pool_skipped_in_multithreaded_process(xplane, monkeypatch):
@@ -126,18 +174,21 @@ def test_budget_serial_never_spawns_pool(xplane, monkeypatch):
 
 
 def test_budget_single_plane_never_spawns_pool(tmp_path, monkeypatch):
-    # Parallelism is capped by the plane count: one plane, any worker
-    # budget -> serial.
+    # Parallelism is capped by the planes that hold lines: one plane,
+    # however heavy, any worker budget, a caller that may fork -> the
+    # caller converts it.
     import concurrent.futures
 
     from xspace_fixture import build_xspace
 
     path = tmp_path / "one.xplane.pb"
-    path.write_bytes(build_xspace(planes=1, events_per_line=10))
+    path.write_bytes(build_xspace(
+        planes=1, events_per_line=10, ops_per_plane=6000))
 
     def boom(*a, **k):
         raise AssertionError("single plane must not create a pool")
 
+    monkeypatch.setattr(trace, "_fork_safe", lambda: True)
     monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor", boom)
     out = trace.write_chrome_trace_gz(
         str(path), budget=trace.ConvertBudget(max_workers=8))
@@ -160,9 +211,10 @@ def test_budget_from_env_and_malformed_values():
     dflt = trace.ConvertBudget()
     assert bad.max_workers == dflt.max_workers
     assert bad.yield_s == dflt.yield_s
-    # resolved_workers: auto caps at cpu count and plane count.
+    # resolved_workers: auto caps at cpu count and plane count; the
+    # caller is one of them, so auto is the caller and one worker at most.
     assert trace.ConvertBudget(max_workers=8).resolved_workers(2) == 2
-    assert trace.ConvertBudget(max_workers=0).resolved_workers(64) >= 1
+    assert 1 <= trace.ConvertBudget(max_workers=0).resolved_workers(64) <= 2
 
 
 def test_budget_serial_yields_between_plane_batches(xplane, monkeypatch):
@@ -176,32 +228,37 @@ def test_budget_serial_yields_between_plane_batches(xplane, monkeypatch):
     assert sleeps == [0.01]
 
 
-def test_pool_death_degrades_to_serial(xplane, monkeypatch):
-    # A pool dying MID-RUN (worker OOM-killed -> BrokenProcessPool, a
-    # RuntimeError) must not cost the artifact: the remaining planes
-    # convert serially and the output stays identical. Every plane is
-    # spanned once, where it was converted.
+@pytest.mark.parametrize("dies", ["at-setup", "at-submit", "mid-run"])
+def test_pool_death_degrades_to_serial(xplane, monkeypatch, dies):
+    # A pool that cannot start (no working fork: OSError) or dies MID-RUN
+    # (worker OOM-killed -> BrokenProcessPool, a RuntimeError) must not
+    # cost the artifact: the caller converts what the pool has not handed
+    # back and the output stays identical. Every plane is spanned once,
+    # where it was converted.
     import concurrent.futures
 
     single = _read_gz(trace.write_chrome_trace_gz_single(xplane))
     obs.JOURNAL.drain()
+    broken = concurrent.futures.process.BrokenProcessPool("worker died")
 
-    class DyingPool:
+    class DyingPool(InProcessPool):
         def __init__(self, *a, **k):
-            pass
+            if dies == "at-setup":
+                raise OSError("no semaphores here")
+            super().__init__(*a, **k)
 
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-        def map(self, fn, jobs):
-            yield fn(jobs[0])  # one plane succeeds...
-            raise concurrent.futures.process.BrokenProcessPool(
-                "worker died")
+        def submit(self, fn, job):
+            if dies == "at-submit":
+                raise broken
+            if self.jobs:  # one plane succeeds, the next dies with the pool
+                self.jobs.append(job)
+                future = concurrent.futures.Future()
+                future.set_exception(broken)
+                return future
+            return super().submit(fn, job)
 
     monkeypatch.setattr(trace, "_fork_safe", lambda: True)
+    monkeypatch.setattr(trace, "FORK_WORTH_WEIGHT", 1000)
     monkeypatch.setattr(
         concurrent.futures, "ProcessPoolExecutor", DyingPool)
     with obs.span("trace.convert", ctx=obs.TraceContext.mint()) as convert:
@@ -215,7 +272,8 @@ def test_pool_death_degrades_to_serial(xplane, monkeypatch):
         assert decode.parent_id == plane.span_id
         assert plane.parent_id == convert.span_id
         assert decode.trace_id == plane.trace_id == convert.trace_id
-        assert plane.pid == os.getpid()
+    workers = {s.pid for s in spans} - {os.getpid()}
+    assert workers == ({WORKER_PID} if dies == "mid-run" else set())
 
 
 def test_convert_plane_hands_back_its_fragment_its_summary_and_its_spans():
@@ -643,23 +701,6 @@ def test_bytes_that_are_not_utf8_become_the_replacement_character():
         json.dumps(e) for e in trace._plane_events(1, plane)).encode()
 
 
-class InProcessPool:
-    """ProcessPoolExecutor's face over the calling process: what the pool
-    would run, run here, so a test can count it."""
-
-    def __init__(self, *a, **k):
-        pass
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def map(self, fn, jobs):
-        return map(fn, jobs)
-
-
 @pytest.mark.parametrize("workers", [1, 2])
 def test_write_derived_artifacts_decodes_each_plane_once(
         xplane, tmp_path, monkeypatch, workers):
@@ -670,29 +711,44 @@ def test_write_derived_artifacts_decodes_each_plane_once(
     apart.parent.mkdir()
     shutil.copy(xplane, apart)
     budget = trace.ConvertBudget(max_workers=workers)
-    decoded = []
+    decoded, walked = [], []
     decode = trace._decode_plane
 
-    def counting(buf, start, end):
+    def counting(buf, start, end, top=None):
         decoded.append(end - start)
-        return decode(buf, start, end)
+        walked.append(top is not None)
+        return decode(buf, start, end, top)
 
     monkeypatch.setattr(trace, "_decode_plane", counting)
     pools = []
     if workers > 1:
+        # the fixture's four planes are alike and light: worth a fork here
+        monkeypatch.setattr(trace, "FORK_WORTH_WEIGHT", 1000)
         monkeypatch.setattr(trace, "_fork_safe", lambda: True)
         monkeypatch.setattr(
             concurrent.futures, "ProcessPoolExecutor",
-            lambda *a, **k: pools.append(k) or InProcessPool())
+            lambda *a, **k: pools.append(InProcessPool(*a, **k))
+            or pools[-1])
     written = trace.write_derived_artifacts(xplane, budget)
     planes = [len(b) for b in trace.iter_plane_bufs(FIXTURE.read_bytes())]
-    assert decoded == planes  # once a plane, in order
-    assert len(pools) == workers - 1
+    # once a plane: in file order under one process, the worker's two
+    # (as they are submitted) before the caller's two under two
+    assert decoded == [planes[i] for i in (
+        (0, 1, 2, 3) if workers == 1 else (1, 3, 0, 2))]
+    # and its top level walked once: the walk that weighed a plane is
+    # handed to the decode of the planes the caller keeps
+    assert walked == (
+        [False] * 4 if workers == 1 else [False, False, True, True])
+    # the caller is one of `workers`: the pool holds the others
+    assert [pool.kwargs["max_workers"] for pool in pools] == [1] * (
+        workers - 1)
+    assert [pid for pool in pools for pid, _ in pool.jobs] == [2, 4] * (
+        workers - 1)
     assert sorted(os.path.basename(p) for p in written) == [
         "host.summary.json", "host.trace.json.gz"]
     # called apart, the two writers decode every plane twice between them
     # and write the same bytes (the gzip's header holds no time)
-    del decoded[:]
+    del decoded[:], walked[:]
     trace.write_summary_json(str(apart))
     trace.write_chrome_trace_gz(str(apart), budget=budget)
     assert sorted(decoded) == sorted(planes * 2)
@@ -728,3 +784,113 @@ def test_each_derived_file_survives_the_others_failure(
         with open(written[0]) as f:
             assert json.load(f) == trace._summarize_planes(
                 trace.summarize_xplane_bytes(FIXTURE.read_bytes()))
+
+
+# -- who converts which plane is read from the artifact ------------------------
+
+
+def _rule_plane(name: str, ops: int = 0, events: int = 0,
+                stat_bytes: int = 0) -> bytes:
+    """One plane as the rule sees it: `ops` entries of event metadata,
+    `events` events on one line (no line where there are none), and
+    `stat_bytes` of one plane-level stat (what `/host:metadata` is made
+    of)."""
+    import xspace_fixture as xf
+
+    body = xf._field_varint(1, 7) + xf._field_str(2, name)
+    if events:
+        body += xf._field_bytes(3, xf._line(1, "XLA Ops", 1000, [
+            xf._event(e % max(ops, 1) + 1, e * 2 * US, US)
+            for e in range(events)]))
+    for op in range(1, ops + 1):
+        body += xf._field_bytes(4, xf._event_metadata(
+            op, f"%fusion.{op} = bf16[8,8]{{1,0}} fusion(%p{op})", ""))
+    if stat_bytes:
+        body += xf._field_bytes(6, xf._stat(1, raw=b"\0" * stat_bytes))
+    return body
+
+
+def _heavy(i: int, ops: int) -> bytes:
+    return _rule_plane(f"/device:TPU:{i}", ops=ops, events=40)
+
+
+# case -> (the planes in file order, the Chrome-trace pids pickled for a
+# worker in the order they are sent; none: no pool is made)
+RULE_CASES = {
+    # one heavy plane, second in the file, beside a light plane with lines
+    # and a lineless plane ten times the heavy one's bytes: what every
+    # one-chip artifact looks like
+    "one-heavy": (lambda: [
+        _rule_plane("/host:CPU", ops=5, events=300), _heavy(0, 3500),
+        _rule_plane("/host:metadata", stat_bytes=10 * len(_heavy(0, 3500))),
+    ], None),
+    # two heavy planes: the heavier (fourth in the file) the caller's, the
+    # other a worker's; the light plane rides with the caller, who carries
+    # less once the fork is counted
+    "two-heavy": (lambda: [
+        _rule_plane("/host:metadata", stat_bytes=3_000_000), _heavy(0, 3000),
+        _rule_plane("/host:CPU", ops=5, events=300), _heavy(1, 3400),
+    ], [2]),
+    # four heavy planes, in the file neither by weight nor against it, as
+    # a four-chip artifact holds them: second and fourth heaviest to the
+    # worker, heaviest first
+    "four-heavy": (lambda: [
+        _heavy(0, 3200), _rule_plane("#Chip0 Misc"), _heavy(1, 3600),
+        _rule_plane("/host:metadata", stat_bytes=3_000_000),
+        _heavy(2, 3000), _heavy(3, 3400),
+        _rule_plane("/host:CPU", ops=5, events=300),
+    ], [6, 5]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_the_artifact_decides_who_converts_which_plane(
+        tmp_path, monkeypatch, case):
+    import concurrent.futures
+
+    import xspace_fixture as xf
+
+    build, sent = RULE_CASES[case]
+    planes = build()
+    weights = [trace._plane_weight(trace._fields(p, 0, len(p)))
+               for p in planes]
+    heaviest = max(range(len(planes)), key=weights.__getitem__)
+    lineless = [i + 1 for i, (_, lines) in enumerate(weights) if not lines]
+    assert lineless and max(planes, key=len) is planes[lineless[-1] - 1]
+    data = b"".join(xf._field_bytes(1, p) for p in planes)
+    files = {}
+    pools = []
+    monkeypatch.setattr(trace, "_fork_safe", lambda: True)
+    monkeypatch.setattr(
+        concurrent.futures, "ProcessPoolExecutor",
+        lambda *a, **k: pools.append(InProcessPool(*a, **k)) or pools[-1])
+    for workers in (1, 0):  # one process; the default budget
+        path = tmp_path / str(workers) / "host.xplane.pb"
+        path.parent.mkdir()
+        path.write_bytes(data)
+        obs.JOURNAL.drain()
+        trace.write_derived_artifacts(
+            str(path), trace.ConvertBudget(max_workers=workers))
+        spans = obs.JOURNAL.drain()
+        files[workers] = {
+            name: (path.parent / name).read_bytes()
+            for name in ("host.summary.json", "host.trace.json.gz")}
+        by_name = {name: [s for s in spans if s.name == name]
+                   for name in ("convert.plane", "convert.decode")}
+        # one convert.plane and one convert.decode a plane, whoever ran it
+        assert [len(v) for v in by_name.values()] == [len(planes)] * 2
+        assert ({s.parent_id for s in by_name["convert.decode"]}
+                == {s.span_id for s in by_name["convert.plane"]})
+    assert files[0] == files[1]  # byte for byte what one process writes
+    pids = [s.pid for s in by_name["convert.plane"]]
+    if sent is None:
+        assert pools == [] and set(pids) == {os.getpid()}
+        return
+    (pool,) = pools  # made once, under the default budget alone
+    assert pool.kwargs["max_workers"] == 1
+    # heaviest first, each plane's own bytes, never the one with no line
+    assert [pid for pid, _ in pool.jobs] == sent
+    assert [buf for _, buf in pool.jobs] == [planes[pid - 1] for pid in sent]
+    assert heaviest + 1 not in sent and not set(lineless) & set(sent)
+    assert pids.count(WORKER_PID) == len(sent)
+    assert pids.count(os.getpid()) == len(planes) - len(sent)
