@@ -291,6 +291,35 @@ def _op_findings(diff: dict, base_shapes: dict, cur_shapes: dict,
         })
 
 
+def _scope_finding(diff: dict) -> dict | None:
+    """The scope (`jax.named_scope` of the job, `trace.op_scope`) whose
+    per-call self time grew most: which mechanism slowed, beside the op
+    findings that say which op."""
+    def grew_pct(row):
+        return row["delta_ms_per_call"] / row["base_ms_per_call"] * 100.0
+
+    grown = [row for row in diff.get("scopes", [])
+             if row["scope"] != trace.NO_SCOPE and row["base_ms_per_call"] > 0
+             and row["impact_ms"] > NOISE_IMPACT_MS
+             and grew_pct(row) > NOISE_PCT]
+    if not grown:
+        return None
+    row = max(grown, key=lambda r: r["impact_ms"])
+    pct = grew_pct(row)
+    return {
+        "kind": "scope_growth",
+        "op": None,
+        "scope": row["scope"],
+        "severity_pct": round(pct, 1),
+        "impact_ms": row["impact_ms"],
+        "message": (
+            f"time under scope {row['scope']} grew {pct:.0f}% per op event "
+            f"({row['base_ms_per_call']:.4f} -> {row['ms_per_call']:.4f} ms "
+            f"x {row['count']} events = {row['impact_ms']:+.3f} ms): the "
+            "mechanism that slowed"),
+    }
+
+
 def diagnose(base_summary: dict, cur_summary: dict, top: int = 10) -> dict:
     """The diagnosis pass: diff two summaries, mine the op-level
     patterns, rank findings by estimated total impact. Pure function —
@@ -306,6 +335,9 @@ def diagnose(base_summary: dict, cur_summary: dict, top: int = 10) -> dict:
     _step_findings(diff, findings)
     _op_findings(diff, base_shapes, cur_shapes, findings)
     findings.sort(key=lambda f: -abs(f["impact_ms"] or 0))
+    scope = _scope_finding(diff)
+    if scope is not None:
+        findings.insert(min(1, len(findings)), scope)  # beside the first
     regressed = [f for f in findings
                  if f["kind"].endswith(("_regression", "_growth"))
                  or f["kind"] == "new_op"]
@@ -320,6 +352,7 @@ def diagnose(base_summary: dict, cur_summary: dict, top: int = 10) -> dict:
         "finding_count": len(findings),
         "steps": diff.get("steps"),
         "ops": diff["ops"][:max(top, 1)],
+        "scopes": diff.get("scopes", [])[:max(top, 1)],
     }
 
 

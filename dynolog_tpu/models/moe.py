@@ -1,7 +1,8 @@
 """Dropless mixture-of-experts MLP, expert-parallel over the mesh's `expert` axis.
 
 Replaces the dense MLP of the flagship transformer
-(dynolog_tpu.models.transformer) when `cfg.n_experts > 0`. The reference
+(dynolog_tpu.models.transformer) when `cfg.n_experts > 0`, in every layer
+after the first `cfg.first_dense_layers`. The reference
 framework has no model code at all (it is a monitoring daemon, SURVEY §2.9);
 this layer exists so the daemon's trace and telemetry paths are exercised by
 the job an engineer with a slow sparse model actually runs: experts that
@@ -16,13 +17,27 @@ The layer, for token t with normalised input h_t (E experts, k a token):
                                                 cfg.moe_norm_topk)
     y_t  = sum over e in K_t of
            g_te * W_down_e (silu(W_gate_e h_t) * (W_up_e h_t))
+           + S(h_t)                            where cfg.n_shared_experts:
+                                               the experts every token
+                                               visits, side by side as one
+                                               SwiGLU of n_shared x the
+                                               expert width, ungated
 
-and two terms for the loss, each a mean over ALL the step's tokens:
+An expert's width is cfg.moe_d_ff (d_ff where 0). Where cfg.n_experts_held
+is set, the chip holds that many of the E experts from index
+cfg.first_expert_held on, as one chip of an expert-parallel layer does: the
+router still scores all E and a token keeps its k, and a choice that falls
+on an expert not held adds nothing to y_t. S and the loss terms are whole.
+
+Two terms for the loss, each a mean over ALL the step's tokens:
 
     balance = E * sum_e f_e P_e     f_e: the share of the routed assignments
                                     that went to e (first choices alone, or
                                     all T x k where cfg.moe_balance_all_k),
-                                    P_e: the mean of s_te over tokens
+                                    P_e: the mean of s_te over tokens;
+                                    where cfg.moe_seq_aux both are taken a
+                                    sequence at a time and the sequences'
+                                    terms averaged
     z       = mean_t logsumexp_e(h_t W_r)^2
 
 There is no capacity: whatever the routing, every (token, choice) copy is
@@ -38,6 +53,7 @@ capture's ops carry them):
                   the row tiles that exist) over the experts held here
     moe.combine   the way back (the second all-to-all), the copies unsorted
                   and summed under the gates
+    moe.shared    the shared experts' SwiGLU, outside the dispatch
 
 Under a mesh the layer is a `shard_map`: each chip routes its own tokens
 over all E experts and holds E / expert of them, with the experts' hidden
@@ -68,21 +84,29 @@ from dynolog_tpu.parallel.sharding import BATCH_AXES, PARAM_RULES
 def init_moe_layer(rng, cfg):
     """MoE layer params: router + stacked expert SwiGLU weights."""
     dtype = jnp.dtype(cfg.dtype)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
+    held = cfg.n_experts_held or e
 
     def dense(key, shape, fan_in):
         return (
             jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
         ).astype(dtype)
 
-    k = jax.random.split(rng, 4)
-    return {
+    k = jax.random.split(rng, 7)
+    layer = {
         # kept f32 end-to-end (routing numerics) — no bf16 round-trip
         "router": jax.random.normal(k[0], (d, e), jnp.float32) / math.sqrt(d),
-        "experts_gate": dense(k[1], (e, d, f), d),
-        "experts_up": dense(k[2], (e, d, f), d),
-        "experts_down": dense(k[3], (e, f, d), f),
+        "experts_gate": dense(k[1], (held, d, f), d),
+        "experts_up": dense(k[2], (held, d, f), d),
+        "experts_down": dense(k[3], (held, f, d), f),
     }
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        layer.update(
+            shared_gate=dense(k[4], (d, fs), d),
+            shared_up=dense(k[5], (d, fs), d),
+            shared_down=dense(k[6], (fs, d), fs))
+    return layer
 
 
 def _take_rows(rows, idx):
@@ -186,10 +210,11 @@ def _count(ids, n: int):
         dtype=jnp.int32)
 
 
-def _route(router, h, cfg, stat_axes):
-    """h [T, D] -> (gates [T, k] float32, chosen [T, k] int32, balance, z):
-    the two loss terms are over all the step's tokens, `stat_axes` being the
-    mesh axes these T are a share over (None: they are all)."""
+def _route(router, h, cfg, stat_axes, seqs: int):
+    """h [T, D], `seqs` sequences back to back -> (gates [T, k] float32,
+    chosen [T, k] int32, balance, z): the two loss terms are over all the
+    step's tokens, `stat_axes` being the mesh axes these T are a share over
+    (None: they are all)."""
     e = cfg.n_experts
     logits = h.astype(jnp.float32) @ router  # [T, E]; tiny, numerics matter
     probs = jax.nn.softmax(logits, axis=-1)
@@ -197,12 +222,24 @@ def _route(router, h, cfg, stat_axes):
     if cfg.moe_norm_topk:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     counted = chosen if cfg.moe_balance_all_k else chosen[:, :1]
+    z = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    tokens = jnp.float32(h.shape[0])
+    if cfg.moe_seq_aux:
+        # a sequence at a time, then the sequences' terms averaged; every
+        # chip's sequences are whole, so over chips the mean of their means
+        in_seq = h.shape[0] // seqs
+        assigned = jax.vmap(lambda ids: _count(ids, e))(
+            counted.reshape(seqs, -1)).astype(jnp.float32)
+        prob = jnp.sum(probs.reshape(seqs, in_seq, e), axis=1)
+        balance = jnp.mean(e * jnp.sum(
+            assigned / (in_seq * counted.shape[1]) * prob / in_seq, axis=1))
+        if stat_axes:
+            balance = jax.lax.pmean(balance, stat_axes)
+            z, tokens = jax.lax.psum((z, tokens), stat_axes)
+        return gates, chosen, balance, z / tokens
     sums = (
-        _count(counted, e).astype(jnp.float32),
-        jnp.sum(probs, axis=0),
-        jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
-        jnp.float32(h.shape[0]),
-    )
+        _count(counted, e).astype(jnp.float32), jnp.sum(probs, axis=0), z,
+        tokens)
     if stat_axes:
         sums = jax.lax.psum(sums, stat_axes)
     assigned, prob, z, tokens = sums
@@ -223,14 +260,28 @@ def _moe_local(router, w_gate, w_up, w_down, x, *, cfg, ep, tp, stat_axes,
     h = x.reshape(tokens, d)
 
     with jax.named_scope("moe.route"):
-        gates, chosen, balance, z = _route(router, h, cfg, stat_axes)
+        gates, chosen, balance, z = _route(router, h, cfg, stat_axes, b)
 
     with jax.named_scope("moe.dispatch"):
         expert_of = chosen.reshape(copies)
+        share = ep == 1 and held < cfg.n_experts
+        if share:
+            # counted from the first expert held, round the E: the copies
+            # for the experts held here sort to the front, in their order
+            expert_of = (expert_of - cfg.first_expert_held) % cfg.n_experts
         order = jnp.argsort(expert_of).astype(jnp.int32)  # stable
         place = jnp.argsort(order).astype(jnp.int32)  # where each copy went
         rows = take_rows(h, order // k, place, k)  # [copies, D] by expert
         group_sizes = _count(expert_of, cfg.n_experts)
+        if share:
+            group_sizes = group_sizes[:held]
+            there = (jnp.arange(copies) < jnp.sum(group_sizes))[:, None]
+            # A copy for an expert that is not here is a zero row, and so is
+            # what comes back for it: the grouped products' transpose writes
+            # no row past its groups, and what the buffer holds there would
+            # flow into every token's gradient (NaN in two of six runs on
+            # the chip).
+            rows = jnp.where(there, rows, 0)
         if ep > 1:
             # sent[source chip, expert held here]
             sent = jax.lax.all_gather(group_sizes, "expert").reshape(
@@ -258,11 +309,13 @@ def _moe_local(router, w_gate, w_up, w_down, x, *, cfg, ep, tp, stat_axes,
         # A grouped product writes no row past its groups: what the buffer
         # holds there (and what the transpose hands back for it) is not
         # ours, and 0 x NaN is NaN. Rows that are not there are zeros.
-        return jnp.where(there, out, 0) if ep > 1 else out
+        return jnp.where(there, out, 0) if ep > 1 or share else out
 
     with jax.named_scope("moe.experts"):
         act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
         out = jax.lax.ragged_dot(act, w_down, group_sizes)
+        if share:  # a copy for an expert that is not here adds nothing
+            out = jnp.where(there, out, 0)
 
     with jax.named_scope("moe.combine"):
         if ep > 1:
@@ -288,6 +341,15 @@ def moe_mlp(layer, x, cfg, mesh=None):
     local = partial(
         _moe_local, cfg=cfg, ep=1, tp=1, stat_axes=None, ragged=False)
     if mesh is not None:
+        if layer["experts_gate"].shape[0] < cfg.n_experts:
+            raise ValueError(
+                f"a share of {layer['experts_gate'].shape[0]} of "
+                f"{cfg.n_experts} experts is one chip's: under a mesh the "
+                "`expert` axis divides them")
+        if cfg.moe_seq_aux and mesh.shape["seq"] > 1:
+            raise ValueError(
+                "moe_seq_aux balances a sequence at a time: the `seq` axis "
+                "would cut the sequences")
         token_spec = P(BATCH_AXES, "seq", None)
         local = jax.shard_map(
             partial(
@@ -299,4 +361,9 @@ def moe_mlp(layer, x, cfg, mesh=None):
                       PARAM_RULES["experts_up"], PARAM_RULES["experts_down"],
                       token_spec),
             out_specs=(token_spec, P(), P()), check_vma=False)
-    return jax.checkpoint(local)(*weights, x)
+    y, balance, z = jax.checkpoint(local)(*weights, x)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            gate = jax.nn.silu(x @ layer["shared_gate"])
+            y = y + (gate * (x @ layer["shared_up"])) @ layer["shared_down"]
+    return y, balance, z

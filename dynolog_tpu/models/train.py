@@ -36,7 +36,8 @@ def state_shardings(optimizer, params, mesh):
     return param_shardings, opt_shardings
 
 
-def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
+def make_train_state(rng, cfg: TransformerConfig, mesh=None,
+                     lr: float | None = None):
     """(params, opt_state), placed on the mesh when one is given.
 
     The init is one jitted program either way: each matrix's float32 draw
@@ -48,8 +49,9 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     parameters' layout explicitly: its zeros depend on no input, so
     propagation leaves them whole on device 0 (seen on four v5e chips: 6 GB
     of Adam state on chip 0 and a 15.9 GB peak there in the first step).
+    `lr` None: the configuration's `learning_rate`.
     """
-    optimizer = make_optimizer(lr)
+    optimizer = make_optimizer(cfg.learning_rate if lr is None else lr)
     param_shardings = opt_shardings = None
     if mesh is not None:
         param_shardings, opt_shardings = state_shardings(
@@ -61,9 +63,11 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     return params, opt_state
 
 
-def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
+def make_train_step(cfg: TransformerConfig, mesh=None,
+                    lr: float | None = None):
     """Returns a jitted (params, opt_state, tokens) -> (params, opt_state,
-    loss) step; sharded over `mesh` when given.
+    loss) step; sharded over `mesh` when given. `lr` None: the
+    configuration's `learning_rate`.
 
     params and opt_state are donated: the update writes into the buffers it
     read, so the resident state is held once, not twice. A caller must
@@ -76,7 +80,7 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
     ahead of time (`.lower().compile()`) refuses its own outputs as inputs
     ("compiled for input shardings that disagree").
     """
-    optimizer = make_optimizer(lr)
+    optimizer = make_optimizer(cfg.learning_rate if lr is None else lr)
 
     # ring/flash attention and the expert layer (shard_map) need the mesh at
     # trace time
@@ -86,8 +90,9 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 3e-4):
 
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, fwd_mesh)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("adam"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if mesh is not None:
             params, opt_state = jax.lax.with_sharding_constraint(
                 (params, opt_state), state_shardings(optimizer, params, mesh))

@@ -14,6 +14,8 @@ observes it.
 
 from __future__ import annotations
 
+import contextlib
+import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -22,6 +24,8 @@ import jax.numpy as jnp
 
 
 LAYER_TYPES = ("full_attention", "linear_attention")
+ROPE_SCALING_KEYS = ("type", "factor", "original_max_position_embeddings",
+                     "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
 
 
 @dataclass(frozen=True)
@@ -36,6 +40,10 @@ class TransformerConfig:
     # (`rope_parameters.rope_theta: null`)
     rope_theta: float | None = 10000.0
     dtype: str = "bfloat16"
+    # Adam's step size where `make_train_step` is given none: a job's, as a
+    # configuration states it (a job at the first step of a linear warm-up
+    # has its maximum over the warm-up's steps).
+    learning_rate: float = 3e-4
     # "reference": plain-XLA attention; "flash": Pallas MXU kernel
     # (dynolog_tpu.ops.flash_attention); "ring": sequence-parallel ring
     # attention over the mesh's seq axis (requires a mesh at call time).
@@ -45,18 +53,48 @@ class TransformerConfig:
     # RoPE applied), as published configs state them.
     norm_eps: float = 1e-6
     qk_norm: bool = False
-    # MoE: n_experts > 0 replaces every dense MLP with a dropless
-    # top-k-routed mixture of SwiGLU experts (dynolog_tpu.models.moe),
-    # expert-parallel over the mesh's `expert` axis. The loss gains
-    # moe_aux_weight x the balancing term (over first choices, or over all
-    # k where moe_balance_all_k) + moe_z_weight x the router z-loss, each a
-    # mean over layers; moe_norm_topk renormalises a token's k gates.
+    # MoE: n_experts > 0 replaces the dense MLP of every layer after the
+    # first `first_dense_layers` with a dropless top-k-routed mixture of
+    # SwiGLU experts (dynolog_tpu.models.moe), expert-parallel over the
+    # mesh's `expert` axis. The loss gains moe_aux_weight x the balancing
+    # term (over first choices, or over all k where moe_balance_all_k; over
+    # the step's tokens, or a sequence at a time and then averaged where
+    # moe_seq_aux) + moe_z_weight x the router z-loss, each a mean over the
+    # expert layers; moe_norm_topk renormalises a token's k gates.
     n_experts: int = 0
     moe_top_k: int = 2
     moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
     moe_balance_all_k: bool = False
+    moe_seq_aux: bool = False
     moe_z_weight: float = 0.0
+    first_dense_layers: int = 0
+    # an expert's width where it is not d_ff (0: d_ff), and the experts
+    # every token visits beside those it is routed to: one SwiGLU of width
+    # n_shared_experts x the expert width, added to the routed sum
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    # One chip's share of an expert-parallel layer: the router scores all
+    # n_experts and a token keeps its moe_top_k, but only the
+    # n_experts_held experts from index first_expert_held on exist here;
+    # a choice that falls on another adds nothing. 0: every expert.
+    n_experts_held: int = 0
+    first_expert_held: int = 0
+    # "mha": q, k, v of d_model / n_heads a head. "mla": latent attention
+    # (dynolog_tpu.models.mla): keys and values expanded from a compressed
+    # latent of kv_lora_rank, a rotary part of qk_rope_head_dim shared by
+    # the heads beside qk_nope_head_dim without position, values of
+    # v_head_dim.
+    attn_type: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN, as a published config's `rope_scaling` group states it (a dict
+    # from JSON; ROPE_SCALING_KEYS): the rotary frequencies blended between
+    # theta's and theta's over `factor`, and the softmax scale times
+    # (0.1 mscale_all_dim ln factor + 1)^2. None: plain RoPE.
+    rope_scaling: tuple | None = None
     # A hybrid model: each layer's kind, "full_attention" or
     # "linear_attention" (a gated-delta-net layer,
     # dynolog_tpu.models.linear_attention, over n_heads heads of
@@ -69,6 +107,23 @@ class TransformerConfig:
     linear_allow_neg_eigval: bool = False
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            # a group from JSON: the configuration keys a jitted function
+            unknown = set(self.rope_scaling) - set(ROPE_SCALING_KEYS)
+            if unknown or self.rope_scaling.get("type") != "yarn":
+                raise ValueError(
+                    f"rope_scaling {self.rope_scaling}: type 'yarn' with "
+                    f"keys among {ROPE_SCALING_KEYS}")
+            object.__setattr__(
+                self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
+        if self.attn_type not in ("mha", "mla"):
+            raise ValueError(f"attn_type {self.attn_type!r}: 'mha' or 'mla'")
+        held = self.n_experts_held or self.n_experts
+        if not 0 <= self.first_expert_held <= self.n_experts - held:
+            raise ValueError(
+                f"experts {self.first_expert_held} to "
+                f"{self.first_expert_held + held} are not among the "
+                f"{self.n_experts} the router scores")
         if self.layer_types is None:
             return
         # a list from JSON: the configuration keys a jitted function
@@ -86,6 +141,18 @@ class TransformerConfig:
     @property
     def has_linear_layers(self) -> bool:
         return any(self.is_linear(i) for i in range(self.n_layers))
+
+    def is_sparse(self, i: int) -> bool:
+        """Whether layer i's MLP is the expert layer."""
+        return self.n_experts > 0 and i >= self.first_dense_layers
+
+    @property
+    def n_sparse_layers(self) -> int:
+        return sum(self.is_sparse(i) for i in range(self.n_layers))
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def head_dim(self) -> int:
@@ -130,6 +197,10 @@ def init_params(rng, cfg: TransformerConfig):
             from dynolog_tpu.models.linear_attention import init_linear_layer
 
             layer.update(init_linear_layer(k[0], cfg))
+        elif cfg.attn_type == "mla":
+            from dynolog_tpu.models.mla import init_mla_layer
+
+            layer.update(init_mla_layer(k[0], cfg))
         else:
             layer.update(
                 wq=dense(k[0], (d, d), d), wk=dense(k[1], (d, d), d),
@@ -137,7 +208,7 @@ def init_params(rng, cfg: TransformerConfig):
             if cfg.qk_norm:
                 layer.update(q_scale=jnp.ones((d,), dtype),
                              k_scale=jnp.ones((d,), dtype))
-        if cfg.n_experts > 0:
+        if cfg.is_sparse(i):
             from dynolog_tpu.models.moe import init_moe_layer
 
             layer.update(init_moe_layer(k[4], cfg))
@@ -158,37 +229,64 @@ def _rmsnorm(x, scale, eps):
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale
 
 
-def _rope(x, positions, theta):
-    """Rotary embeddings over the last (head_dim) axis. x: [B, S, H, D]."""
-    half = x.shape[-1] // 2
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 mscale ln(factor) + 1 (1 at factor 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_freqs(half: int, theta, scaling: dict | None = None):
+    """The rotary frequencies of the `half` pairs: theta^(-i/half), and
+    under YaRN the blend of that and the same over `factor`, by the linear
+    ramp between the pairs that turn beta_fast and beta_slow times in the
+    original_max_position_embeddings positions the model was trained on
+    (the fast pairs keep their frequency, the slow ones are interpolated)."""
     freqs = jnp.exp(
         -jnp.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
     )
+    if scaling is None:
+        return freqs
+
+    def pair_turning(turns):  # the pair that turns `turns` times
+        return half * math.log(
+            scaling["original_max_position_embeddings"]
+            / (turns * 2 * math.pi)) / math.log(theta)
+
+    low = max(math.floor(pair_turning(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(scaling["beta_slow"])), 2 * half - 1)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 0.001),
+        0.0, 1.0)
+    return freqs / scaling["factor"] * ramp + freqs * (1.0 - ramp)
+
+
+def _rope(x, positions, theta, scaling: dict | None = None):
+    """Rotary embeddings over the last (head_dim) axis. x: [B, S, H, D].
+    Under YaRN (`scaling`) cos and sin are also scaled by
+    yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)."""
+    half = x.shape[-1] // 2
+    freqs = _rope_freqs(half, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        amp = (yarn_mscale(scaling["factor"], scaling["mscale"])
+               / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]))
+        cos, sin = cos * amp, sin * amp
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    q, k = x @ layer["wq"], x @ layer["wk"]
-    if cfg.qk_norm:
-        q = _rmsnorm(q, layer["q_scale"], cfg.norm_eps)
-        k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
-    q, k = q.reshape(b, s, h, hd), k.reshape(b, s, h, hd)
-    v = (x @ layer["wv"]).reshape(b, s, h, hd)
-    if cfg.rope_theta is not None:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-
+def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
+                       scale=None):
+    """Causal softmax attention by cfg.attn_impl. q, k: [B, S, H, D],
+    v: [B, S, H, Dv] -> [B, S, H, Dv]; `scale` None: D ** -0.5."""
+    s, hd = q.shape[1], q.shape[-1]
     if cfg.attn_impl == "flash":
         from dynolog_tpu.ops.flash_attention import flash_attention
 
         def attn(q, k, v):
-            return flash_attention(q, k, v, True)
+            return flash_attention(q, k, v, True, scale=scale)
 
         if mesh is not None:
             # A Mosaic kernel is opaque to the SPMD partitioner ("cannot be
@@ -202,55 +300,102 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
             attn = jax.shard_map(
                 attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
-        out = attn(q, k, v).reshape(b, s, d)
-    elif cfg.attn_impl == "ring":
+        return attn(q, k, v)
+    if cfg.attn_impl == "ring":
         from dynolog_tpu.parallel.ring_attention import ring_attention
 
         if mesh is None:
             raise ValueError("attn_impl='ring' requires a mesh")
-        out = ring_attention(q, k, v, mesh, causal=True).reshape(b, s, d)
+        if scale is not None or v.shape[-1] != hd:
+            raise ValueError(
+                "attn_impl='ring' runs heads of one width at the scale "
+                "D ** -0.5: not latent attention's")
+        return ring_attention(q, k, v, mesh, causal=True)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if scale is None:
+        scores = scores / jnp.sqrt(hd).astype(q.dtype)
     else:
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(hd).astype(x.dtype)
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(causal[None, None], scores.astype(jnp.float32), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, d)
-    return out @ layer["wo"]
+        scores = scores * jnp.asarray(scale, q.dtype)
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    scores = jnp.where(causal[None, None], scores.astype(jnp.float32), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
+    b, s, d = x.shape
+    h, hd = cfg.n_heads, cfg.head_dim
+    with jax.named_scope("attn"):
+        q, k = x @ layer["wq"], x @ layer["wk"]
+        if cfg.qk_norm:
+            q = _rmsnorm(q, layer["q_scale"], cfg.norm_eps)
+            k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
+        q, k = q.reshape(b, s, h, hd), k.reshape(b, s, h, hd)
+        v = (x @ layer["wv"]).reshape(b, s, h, hd)
+        if cfg.rope_theta is not None:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+    # The kernels stay outside the scope: the TPU's compiler names a
+    # kernel's op after the path's component before `pallas_call`
+    # (`jvp_flash_attention_fwd_.2`), and under `attn` every capture of a
+    # dense job would name its three kernels anew. A kernel's own name is
+    # its scope (`trace.op_scope`).
+    with (contextlib.nullcontext() if cfg.attn_impl == "flash"
+          else jax.named_scope("attn")):
+        out = _softmax_attention(q, k, v, cfg, mesh)
+    with jax.named_scope("attn"):
+        return out.reshape(b, s, d) @ layer["wo"]
 
 
 def _mlp(layer, x):
-    gate = jax.nn.silu(x @ layer["w_gate"])
-    return (gate * (x @ layer["w_up"])) @ layer["w_down"]
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(x @ layer["w_gate"])
+        return (gate * (x @ layer["w_up"])) @ layer["w_down"]
 
 
 def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
     """tokens [B, S] int32 → (logits [B, S, vocab] f32, the expert layers'
     weighted loss terms, a mean over layers: 0 for a dense model)."""
-    x = params["embedding"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens]
     positions = jnp.broadcast_to(
         jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
     )
     aux = jnp.zeros((), jnp.float32)
     for i, layer in enumerate(params["layers"]):
-        h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
+        # a layer's two norms go with what they feed
         if cfg.is_linear(i):
             from dynolog_tpu.models.linear_attention import gated_delta_net
 
+            with jax.named_scope("gdn.project"):
+                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
             x = x + gated_delta_net(layer, h, cfg)
+        elif cfg.attn_type == "mla":
+            from dynolog_tpu.models.mla import latent_attention
+
+            with jax.named_scope("mla.project"):
+                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
+            x = x + latent_attention(layer, h, positions, cfg, mesh)
         else:
+            with jax.named_scope("attn"):
+                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
             x = x + _attention(layer, h, positions, cfg, mesh)
-        h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
-        if cfg.n_experts > 0:
+        if cfg.is_sparse(i):
             from dynolog_tpu.models.moe import moe_mlp
 
+            with jax.named_scope("moe.route"):
+                h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
             y, balance, z = moe_mlp(layer, h, cfg, mesh)
             aux = aux + (cfg.moe_aux_weight * balance
-                         + cfg.moe_z_weight * z) / cfg.n_layers
+                         + cfg.moe_z_weight * z) / cfg.n_sparse_layers
         else:
+            with jax.named_scope("mlp"):
+                h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
             y = _mlp(layer, h)
         x = x + y
-    x = _rmsnorm(x, params["final_scale"], cfg.norm_eps)
-    return (x @ params["w_out"]).astype(jnp.float32), aux
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["final_scale"], cfg.norm_eps)
+        return (x @ params["w_out"]).astype(jnp.float32), aux
 
 
 def forward(params, tokens, cfg: TransformerConfig, mesh=None):
@@ -267,11 +412,12 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None):
     MoE enabled the expert layers' balancing and z terms are added, under
     cfg.moe_aux_weight and cfg.moe_z_weight."""
     logits, aux = _forward_with_aux(params, tokens, cfg, mesh)
-    logits = logits[:, :-1]
-    targets = tokens[:, 1:]
-    logprobs = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)
-    return jnp.mean(nll) + aux
+    with jax.named_scope("head"):
+        logits = logits[:, :-1]
+        targets = tokens[:, 1:]
+        logprobs = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)
+        return jnp.mean(nll) + aux
 
 
 @partial(jax.jit, static_argnames=("cfg",))

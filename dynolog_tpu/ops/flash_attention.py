@@ -41,10 +41,15 @@ def _pick_block(seq_len: int, target: int) -> int:
     return b
 
 
-def reference_attention(q, k, v, *, causal: bool = True):
-    """Plain-XLA attention; q,k,v: [B, S, H, D] -> [B, S, H, D]."""
+def reference_attention(q, k, v, *, causal: bool = True, scale=None):
+    """Plain-XLA attention; q, k: [B, S, H, D], v: [B, S, H, Dv] ->
+    [B, S, H, Dv]. `scale` None: D ** -0.5."""
     d = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d).astype(q.dtype)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if scale is None:
+        scores = scores / jnp.sqrt(d).astype(q.dtype)
+    else:
+        scores = scores * jnp.asarray(scale, q.dtype)
     scores = scores.astype(jnp.float32)
     if causal:
         s_q, s_k = q.shape[1], k.shape[1]
@@ -57,22 +62,30 @@ def reference_attention(q, k, v, *, causal: bool = True):
 # ---------------------------------------------------------------- forward
 
 
+def _scale(head_dim: int, scale):
+    """The softmax scale: the caller's, or head_dim ** -0.5."""
+    if scale is None:
+        return jax.lax.rsqrt(jnp.float32(head_dim))
+    return jnp.float32(scale)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
-                causal):
+                causal, scale):
     """One (batch*head, q-block) program. q_ref: [1, block_q, D];
-    k_ref/v_ref: [1, S, D]; o_ref: [1, block_q, D]; lse_ref: [1, 1, S]
+    k_ref: [1, S, D]; v_ref: [1, S, Dv]; o_ref: [1, block_q, Dv];
+    lse_ref: [1, 1, S]
     (full row — Mosaic block shapes must tile (8, 128) or span the array;
     each program stores its own [block_q] slice)."""
     qi = pl.program_id(1)
     seq_len = k_ref.shape[1]
     head_dim = q_ref.shape[2]
-    scale = jax.lax.rsqrt(jnp.float32(head_dim))
+    scale = _scale(head_dim, scale)
 
     q = q_ref[0].astype(jnp.float32) * scale  # [block_q, D]
 
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
 
@@ -108,27 +121,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
     lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l_safe)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k):
-    """[B*H, S, D] inputs -> (out [B*H, S, D], lse [B*H, 1, S] f32)."""
+def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
+    """q, k [B*H, S, D], v [B*H, S, Dv] -> (out [B*H, S, Dv],
+    lse [B*H, 1, S] f32)."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     kernel = functools.partial(
-        _fwd_kernel, block_q=bq, block_k=bk, causal=causal)
+        _fwd_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale)
     return pl.pallas_call(
         kernel,
         grid=(bh, s // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         name="flash_attention_fwd",
@@ -139,15 +154,15 @@ def _flash_forward(q, k, v, causal, block_q, block_k):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_q, block_k, causal):
+               block_q, block_k, causal, scale):
     """dQ for one (batch*head, q-block): loop over visible key blocks."""
     qi = pl.program_id(1)
     seq_len = k_ref.shape[1]
     head_dim = q_ref.shape[2]
-    scale = jax.lax.rsqrt(jnp.float32(head_dim))
+    scale = _scale(head_dim, scale)
 
     qs = q_ref[0].astype(jnp.float32) * scale      # pre-scaled Q block
-    do = do_ref[0].astype(jnp.float32)             # [block_q, D]
+    do = do_ref[0].astype(jnp.float32)             # [block_q, Dv]
     lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]    # [block_q]
     delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)]
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
@@ -180,16 +195,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_q, block_k, causal):
+                dk_ref, dv_ref, *, block_q, block_k, causal, scale):
     """dK and dV for one (batch*head, k-block): loop over query blocks at
     or below this key block's diagonal."""
     ki = pl.program_id(1)
     seq_len = q_ref.shape[1]
     head_dim = q_ref.shape[2]
-    scale = jax.lax.rsqrt(jnp.float32(head_dim))
+    scale = _scale(head_dim, scale)
 
     k_blk = k_ref[0].astype(jnp.float32)            # [block_k, D]
-    v_blk = v_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0].astype(jnp.float32)            # [block_k, Dv]
     k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
 
     def body(qb, carry):
@@ -208,7 +223,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(s - lse[:, None])
         dv_new = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [block_k, D]
+            preferred_element_type=jnp.float32)     # [block_k, Dv]
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -224,33 +239,38 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         qb_start = 0
     n_qb = seq_len // block_q
-    zeros = jnp.zeros((block_k, head_dim), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qb_start, n_qb, body, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(qb_start, n_qb, body, (
+        jnp.zeros((block_k, head_dim), jnp.float32),
+        jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
-    """[B*H, S, D] residuals + cotangent g -> (dq, dk, dv)."""
+def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
+                    scale=None):
+    """Residuals q, k [B*H, S, D], v, out [B*H, S, Dv] + cotangent g ->
+    (dq, dk, dv)."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
         keepdims=False)[:, None, :]  # [BH, 1, S]
 
-    qkv_full = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
+    qk_full = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
+    vo_full = pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0))
     row_full = pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, block_q=bq, block_k=bk, causal=causal),
+            _dq_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale),
         grid=(bh, s // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            qkv_full,
-            qkv_full,
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
+            qk_full,
+            vo_full,
+            pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
             row_full,
             row_full,
         ],
@@ -259,29 +279,29 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
         name="flash_attention_bwd_dq",
     )(q, k, v, g, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, block_q=bq, block_k=bk, causal=causal),
+            _dkv_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale),
         grid=(bh, s // bk),
         in_specs=[
-            qkv_full,
+            qk_full,
             pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
-            qkv_full,
+            pl.BlockSpec((1, bk, dv), lambda b, i: (b, i, 0)),
+            vo_full,
             row_full,
             row_full,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
         ],
         name="flash_attention_bwd_dkv",
     )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 # -------------------------------------------------------------- public op
@@ -297,36 +317,46 @@ def _from_bh(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=True, block_q=512, block_k=512):
-    """Flash attention; q,k,v: [B, S, H, D] -> [B, S, H, D].
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
+                    scale=None):
+    """Flash attention; q, k: [B, S, H, D], v: [B, S, H, Dv] ->
+    [B, S, H, Dv]. Keys may be wider than values (latent attention expands
+    to keys of 192 and values of 128): the score products run over D, the
+    P.V and dV products over Dv, nothing is padded. `scale` is the softmax
+    scale, D ** -0.5 where None.
 
     Forward and backward both run as Pallas kernels; only O(S) residuals
     (q, k, v, out, lse) are saved.
 
     Default 512x512 blocks: larger blocks halve each program's full-K/V
-    re-reads. The sweep that chose them (benchmarks/flash_attention_bench
-    .py) predates the current machine and has not been repeated on it.
+    re-reads. What the three kernels reach of their roofline at 512x512 on
+    a TPU v5e (useful causal work over traced time against the bfloat16
+    peak, `perfbench/kernel_costs.py`; one capture a cell, PR 40's and PR
+    41's chip runs, PERF.md section 5): forward 31 % at sequence 2048 and
+    39-40 % at 4096, dq 64 % and 73 %, dkv 54 % and 59-61 % at heads of
+    128; forward 44 %, dq 59 %, dkv 56 % at keys of 192 and values of 128.
+    The products bound all three; they run on float32 operands.
     """
     b, _, h, _ = q.shape
     out, _ = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k)
+        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k, scale)
     return _from_bh(out, b, h)
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_k):
+def _vjp_fwd(q, k, v, causal, block_q, block_k, scale):
     b, _, h, _ = q.shape
     out, lse = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k)
+        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k, scale)
     return _from_bh(out, b, h), (q, k, v, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_k, res, g):
+def _vjp_bwd(causal, block_q, block_k, scale, res, g):
     q, k, v, out_bh, lse = res
     b, _, h, _ = q.shape
     dq, dk, dv = _flash_backward(
         _to_bh(q), _to_bh(k), _to_bh(v), out_bh, lse, _to_bh(g),
-        causal, block_q, block_k)
+        causal, block_q, block_k, scale)
     return _from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h)
 
 

@@ -52,10 +52,10 @@ def init_pipeline_params(rng, cfg: TransformerConfig, mesh):
         f"n_layers={cfg.n_layers} must divide into pipe={n_stages} stages"
     )
     assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
-            and not cfg.has_linear_layers), (
+            and not cfg.has_linear_layers and cfg.attn_type == "mha"), (
         "pipeline path supports the dense/reference transformer config: no "
-        "experts, no linear_attention layer (a stage would run it as "
-        "attention)"
+        "experts, no linear_attention layer and no latent attention (a "
+        "stage would run either as multi-head attention)"
     )
 
     params = init_params(rng, cfg)
@@ -95,10 +95,10 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
     """
     n_stages = mesh.shape["pipe"]
     assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
-            and not cfg.has_linear_layers), (
+            and not cfg.has_linear_layers and cfg.attn_type == "mha"), (
         "pipeline path supports the dense/reference transformer config: no "
-        "experts, no linear_attention layer (a stage would run it as "
-        "attention)"
+        "experts, no linear_attention layer and no latent attention (a "
+        "stage would run either as multi-head attention)"
     )
 
     def local(layers, embedding, w_out, final_scale, tokens_local):

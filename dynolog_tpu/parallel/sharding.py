@@ -112,6 +112,18 @@ PARAM_RULES = {
     # replication in silence: 88 M parameters a layer at Olmo-Hybrid-7B's
     # widths. The small ones (a weight a channel a tap, a number a head, a
     # head's norm scale) are replicated; gdn_norm_scale by "scale" above.
+    # The shared experts (one SwiGLU beside the routed ones): as the dense
+    # MLP's, tensor-parallel on `model`, whole on every chip of `expert`.
+    "shared_gate": P(None, "model"),
+    "shared_up": P(None, "model"),
+    "shared_down": P("model", None),
+    # Latent attention (dynolog_tpu.models.mla): wq and wo by the rules
+    # above (columns are heads x head size); the expansion from the latent
+    # by heads on `model` too (columns are heads x (nope + value));
+    # the compression (the latent and the one rotary key every head
+    # shares) whole on every chip; mla_kv_scale by "scale" above.
+    "mla_dkv": P(),
+    "mla_ukv": P(None, "model"),
     "gdn_q": P(None, "model"),
     "gdn_k": P(None, "model"),
     "gdn_v": P(None, "model"),

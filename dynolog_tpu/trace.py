@@ -268,6 +268,9 @@ class PlaneSummary:
     event_metadata: int = 0  # entries of the event-metadata map
     duration_ps: int = 0  # max event end across lines
     ops: dict = field(default_factory=dict)  # name -> OpAggregate
+    # scope (op_scope of the op's `tf_op`) -> [self_ps, events], over the
+    # lines the op table reads: the self times add up as the ops' do
+    scopes: dict = field(default_factory=dict)
     line_names: list = field(default_factory=list)
     step_durations_ps: list = field(default_factory=list)  # "Steps" line
 
@@ -287,8 +290,51 @@ def _op_key(name: str, group: bool) -> str:
 
 
 # The stats the op table reads, by XStatMetadata.name: the cost model's two
-# numbers and the XProf category string.
-COST_STATS = ("flops", "bytes_accessed", "hlo_category")
+# numbers, the XProf category string, and the path the framework gave the
+# op (`tf_op`: "jit(step)/transpose(jvp(moe.route))/dot_general:"), from
+# which its scope reads.
+COST_STATS = ("flops", "bytes_accessed", "hlo_category", "tf_op")
+TEXT_STATS = ("hlo_category", "tf_op")
+
+NO_SCOPE = "(none)"
+# Components of an op's path that the framework writes, not the program:
+# what a transform or a control-flow primitive leaves in the name stack.
+# `name(inner)` of a transform reads as `inner`; `jit(f)` names a function,
+# not a scope, and goes whole.
+SCOPE_WRAPPERS = frozenset((
+    "jvp", "transpose", "vmap", "pmap", "remat", "checkpoint", "custom_jvp",
+    "custom_vjp"))
+SCOPE_FUNCTIONS = frozenset(("jit", "pjit"))
+SCOPE_STRUCTURE = frozenset((
+    "checkpoint", "rematted_computation", "remat", "while", "body", "cond",
+    "scan", "closed_call", "core_call", "shard_map", "pallas_call",
+    "custom_jvp_call", "custom_vjp_call", "custom_lin"))
+
+
+def op_scope(tf_op: str) -> str:
+    """The scope of an op by the path in its metadata: the outermost
+    component the program wrote itself (`jax.named_scope`), NO_SCOPE where
+    there is none. The trailing component is the primitive
+    ("dot_general:") and is set aside; so are the framework's own
+    (SCOPE_STRUCTURE, `jit(...)`, `branch_1_fun`), and a transform's
+    wrapper is read through to what it wraps (`transpose(jvp(attn))` ->
+    `attn`, `jvp()` -> nothing). An op XLA made itself carries its own name
+    alone ("ragged-dot-none:", or no path at all): NO_SCOPE. Of an op XLA
+    merged from several ("a/b/mul;a/c/add:") the first path is read."""
+    parts = tf_op.split(";", 1)[0].rstrip(":").split("/")[:-1]
+    for part in parts:
+        while part.endswith(")") and "(" in part:
+            head, _, inner = part.partition("(")
+            if head in SCOPE_FUNCTIONS:
+                part = ""
+            elif head in SCOPE_WRAPPERS:
+                part = inner[:-1]
+            else:
+                break
+        if part and part not in SCOPE_STRUCTURE and not (
+                part.startswith("branch_") and part.endswith("_fun")):
+            return part
+    return NO_SCOPE
 
 
 @dataclass
@@ -343,7 +389,7 @@ def _costs(buf, stat_spans, kinds: dict) -> dict:
             elif num == 5 and wt == 2:
                 text = buf[x:y]
         kind = kinds.get(sid)
-        if kind == "hlo_category":
+        if kind in TEXT_STATS:
             if text is not None:
                 found[kind] = text.decode(errors="replace")
         elif kind is not None and value is not None:
@@ -460,7 +506,9 @@ def _plane_summary(
     # "XLA Ops" line when present so step-number and module events don't
     # pollute it and async copies don't double count compute time.
     has_xla_ops = "XLA Ops" in out.line_names
-    by_id: dict[int, tuple] = {}  # metadata id -> (its row, flops, bytes)
+    # metadata id -> (its row, flops, bytes, its scope's [self_ps, events]):
+    # an op's path is read once an op, not once an event
+    by_id: dict[int, tuple] = {}
     for _, lname, _, events in plane.lines:
         out.events += len(events)
         count_ops = not has_xla_ops or lname == "XLA Ops"
@@ -489,8 +537,10 @@ def _plane_summary(
                     agg.shapes.add(shape)
                 row = by_id[meta_id] = (
                     agg, costs.get("flops", 0.0),
-                    costs.get("bytes_accessed", 0.0))
-            agg, flops, nbytes = row
+                    costs.get("bytes_accessed", 0.0),
+                    out.scopes.setdefault(
+                        op_scope(costs.get("tf_op", "")), [0, 0]))
+            agg, flops, nbytes, scope = row
             if own and (own.get("flops") or own.get("bytes_accessed")):
                 flops = own.get("flops", 0.0)
                 nbytes = own.get("bytes_accessed", 0.0)
@@ -499,6 +549,8 @@ def _plane_summary(
             agg.flops += flops
             agg.bytes_accessed += nbytes
             agg.self_ps += duration_ps
+            scope[0] += duration_ps
+            scope[1] += 1
             if offset_ps < last_end_ps:
                 overlap = True
             last_end_ps = end_ps
@@ -508,23 +560,25 @@ def _plane_summary(
 
 
 def _take_held_time(events: list, by_id: dict) -> None:
-    """Takes from each row's `self_ps` the time of the events that lie
-    directly inside its events on this line, and counts them (`held`): the
+    """Takes from each row's `self_ps`, and from its scope's, the time of
+    the events that lie directly inside its events on this line, and counts
+    them (`held`): the
     second pass of a line on which events overlap. A line not in start
     order (an enclosing event before what it holds) is sorted first. An
     event lies inside the innermost event still open at its start, or in
     none: one that overlaps it without lying inside is left whole."""
     events = sorted(events, key=lambda ev: (ev[1], -ev[2]))
-    open_events: list[tuple] = []  # (end, its row), innermost last
+    open_events: list[tuple] = []  # (end, its by_id row), innermost last
     for meta_id, offset_ps, duration_ps, _ in events:
         while open_events and open_events[-1][0] <= offset_ps:
             open_events.pop()
         end_ps = offset_ps + duration_ps
         if open_events and end_ps <= open_events[-1][0]:
-            holder = open_events[-1][1]
+            holder, _, _, scope = open_events[-1][1]
             holder.self_ps -= duration_ps
             holder.held += 1
-        open_events.append((end_ps, by_id[meta_id][0]))
+            scope[0] -= duration_ps
+        open_events.append((end_ps, by_id[meta_id]))
 
 
 def _plane_spans(data) -> list[tuple[int, int]]:
@@ -1308,6 +1362,21 @@ def _summarize_planes(planes: list[PlaneSummary]) -> dict:
                     for name, agg in sorted(
                         p.ops.items(), key=lambda kv: -kv[1].total_ps)
                     if agg.held and "XLA Ops" in p.line_names},
+                # which mechanism the time went to: self time, its share and
+                # the events by the scope the program gave the op
+                # (`jax.named_scope`, read from the op's `tf_op` by
+                # `op_scope`); they add up to the plane's busy time as
+                # `top_ops`' `self_ms` does. NO_SCOPE: ops with no path (XLA's
+                # own copies, a kernel it substituted) or none of the
+                # program's in it. A device's op line alone
+                "scopes": {
+                    name: {"self_ms": round(ps / 1e9, 3),
+                           "pct": round(100.0 * ps / op_ps, 1) if op_ps
+                           else 0.0,
+                           "count": count}
+                    for name, (ps, count) in sorted(
+                        p.scopes.items(), key=lambda kv: -kv[1][0])
+                    if "XLA Ops" in p.line_names},
                 # what the plane's bytes are made of: `<field>_bytes` add
                 # up to `bytes`; entries of the event-metadata map
                 "bytes": p.bytes,
@@ -1420,7 +1489,37 @@ def diff_summaries(base: dict, cur: dict) -> dict:
         row["impact_ms"] = round(impact, 3)
         out["ops"].append(row)
     out["ops"].sort(key=lambda r: -abs(r["impact_ms"]))
+    # The same by scope, over the device planes: which mechanism grew. A
+    # summary from before scopes were read (a stored baseline) has none.
+    base_scopes, cur_scopes = _scope_totals(base), _scope_totals(cur)
+    rows = []
+    for name in base_scopes.keys() & cur_scopes.keys():
+        (b_ms, b_n), (c_ms, c_n) = base_scopes[name], cur_scopes[name]
+        if not b_n or not c_n:
+            continue
+        bpc, cpc = b_ms / b_n, c_ms / c_n
+        rows.append({
+            "scope": name,
+            "base_ms_per_call": round(bpc, 4),
+            "ms_per_call": round(cpc, 4),
+            "delta_ms_per_call": round(cpc - bpc, 4),
+            "count": c_n,
+            "impact_ms": round((cpc - bpc) * c_n, 3),
+        })
+    if rows:
+        out["scopes"] = sorted(rows, key=lambda r: -abs(r["impact_ms"]))
     return out
+
+
+def _scope_totals(summary: dict) -> dict:
+    """scope -> [self_ms, events] over the planes of a summary."""
+    totals: dict = {}
+    for plane in summary.get("planes", []):
+        for name, row in plane.get("scopes", {}).items():
+            total = totals.setdefault(name, [0.0, 0])
+            total[0] += row["self_ms"]
+            total[1] += row["count"]
+    return totals
 
 
 def _print_diff(diff: dict, baseline: str, top: int) -> None:
@@ -1559,6 +1658,9 @@ def main(argv: list[str] | None = None) -> int:
         for name, row in p["loops"].items():
             print(f"    {name:<36} {row['count']:>8} events "
                   f"{row['total_ms']:>9.3f} ms, holding {row['inside']}")
+        for name, row in p["scopes"].items():
+            print(f"    scope {name:<30} {row['count']:>8} events "
+                  f"{row['self_ms']:>9.3f} ms self {row['pct']:>5.1f} %")
     _print_content(summary["planes"])
     if "steps" in summary:
         s = summary["steps"]

@@ -553,7 +553,7 @@ def _oracle(data: bytes, group: bool, by_category: bool):
             for stat in stats:
                 kind = plane.stat_metadata[stat.metadata_id].name
                 which = stat.WhichOneof("value")
-                if kind == "hlo_category" and which == "str_value":
+                if kind in ("hlo_category", "tf_op") and which == "str_value":
                     found[kind] = stat.str_value
                 elif kind in ("flops", "bytes_accessed") and which in (
                         "double_value", "uint64_value", "int64_value",
@@ -598,9 +598,14 @@ def _oracle(data: bytes, group: bool, by_category: bool):
                 agg.total_ps += ev.duration_ps
                 agg.count += 1
                 held = inside.get(at, ())
-                agg.self_ps += ev.duration_ps - sum(
+                self_ps = ev.duration_ps - sum(
                     line.events[i].duration_ps for i in held)
+                agg.self_ps += self_ps
                 agg.held += len(held)
+                scope = want.scopes.setdefault(
+                    trace.op_scope(model.get("tf_op", "")), [0, 0])
+                scope[0] += self_ps
+                scope[1] += 1
                 agg.flops += paid.get("flops", 0.0)
                 agg.bytes_accessed += paid.get("bytes_accessed", 0.0)
                 shape = trace._op_shape(name)
