@@ -29,7 +29,7 @@ import struct
 import sys
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 from dynolog_tpu import obs
 
@@ -352,6 +352,9 @@ class _Plane:
     # a line: (id, name, timestamp_ns, [(metadata id, offset_ps, duration_ps,
     # the event's own costs or None)])
     lines: list = field(default_factory=list)
+    # the metadata entries and events the generic path had to read
+    # (`_decode_plane`): 0 wherever every tag in them is one byte
+    generic: int = 0
 
 
 def _map_entry(buf, a: int, b: int) -> tuple[int, list]:
@@ -373,9 +376,13 @@ def _map_entry(buf, a: int, b: int) -> tuple[int, list]:
 
 def _costs(buf, stat_spans, kinds: dict) -> dict:
     """{COST_STATS name: value} of the XStats at the spans, by the plane's
-    `kinds` {stat metadata id: COST_STATS name}. The callers hand over the
-    stats worth opening: one whose metadata id leads it in one byte, as
-    producers write it, and is none of `kinds` they step over, unread."""
+    `kinds` {stat metadata id: COST_STATS name}, through `_fields`: the
+    generic reading of a stat. The callers hand over the stats worth
+    opening: one whose metadata id leads it in one byte, as producers write
+    it, and is none of `kinds` they step over, unread. It reads the few
+    stats an event keeps (`_decode_plane`) and those of a metadata entry
+    that `_read_entry` handed back (`_entry_generic`); an entry `_read_entry`
+    knows has its wanted stats read where they lie, to the same answer."""
     found = {}
     for a, b in stat_spans:
         sid, value, text = 0, None, None
@@ -397,6 +404,296 @@ def _costs(buf, stat_spans, kinds: dict) -> dict:
     return found
 
 
+def _entry_generic(buf, a: int, b: int, kinds: dict) -> tuple:
+    """`_read_entry`'s answer for any valid entry, through `_fields`: a
+    list of the entry's fields, one of the value's, one for each stat
+    opened. Raises ValueError on truncated or malformed input."""
+    mid, inner = _map_entry(buf, a, b)
+    name = disp = ""
+    stat_spans = []
+    for num, wt, x, y in inner:
+        if wt != 2:
+            continue
+        if num == 2:
+            name = buf[x:y].decode(errors="replace")
+        elif num == 4:  # display_name (3 is `metadata`: opaque bytes)
+            disp = buf[x:y].decode(errors="replace")
+        elif num == 5 and kinds and (
+                y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
+                or buf[x + 1] in kinds):
+            stat_spans.append((x, y))
+    return mid, name, disp or name, _costs(buf, stat_spans, kinds)
+
+
+def _read_entry(buf, i: int, end: int, kinds: dict) -> tuple | None:
+    """One entry of a plane's event-metadata map, the {key, XEventMetadata}
+    at buf[i:end], read in ONE pass written for its wire layout: (id, name,
+    display_name or name, {COST_STATS name: value}), or None where the
+    entry holds what this loop does not know.
+
+    What it reads: the key (tag 0x08), and inside the value (0x12) the id
+    (0x08), `name` (0x12), `display_name` (0x22) and each `stats` field
+    (0x2A) where it lies. A stat's two leading bytes say whether it is
+    worth opening, by the test the generic path makes: one that leads with
+    its metadata id in one byte (0x08, id) which is none of the plane's
+    `kinds` is stepped over by its length; any other (a wanted id, an id of
+    two bytes, a stat that does not lead with its id) is read there and
+    then: the id (0x08), `double_value` (0x11), `uint64_value`,
+    `int64_value`, `ref_value` (0x18, 0x20, 0x38), `str_value` (0x2A). What
+    it steps over unread: `metadata` (0x1A) and every other field of a
+    one-byte tag that is a varint or length-delimited, at all three levels.
+    As in `_map_entry`, the id read later stands, and a value said twice
+    stands as the later one. No list of fields, no tuple a field; a length
+    or an id of two bytes (a name's, most ids) is put together in line.
+
+    What it hands back (None): a tag above 0x7F (a field number above 15),
+    field 0, a fixed-width field other than `double_value`, a message whose
+    fields do not end where it ends, a read past the buffer. The caller
+    then reads the entry by `_entry_generic`, which gives any valid entry's
+    answer and raises ValueError for truncated and malformed input."""
+    mid = 0
+    name = disp = ""
+    found = {}
+    try:
+        while i < end:
+            tag = buf[i]
+            i += 1
+            if tag == 0x12:  # the value: XEventMetadata
+                size = buf[i]
+                i += 1
+                if size > 0x7F:
+                    c = buf[i]
+                    i += 1
+                    if c > 0x7F:
+                        size, i = _read_varint(buf, i - 2)
+                    else:
+                        size += (c << 7) - 0x80
+                value_end = i + size
+                name = disp = ""
+                found = {}
+                while i < value_end:
+                    tag = buf[i]
+                    i += 1
+                    if tag == 0x2A:  # stats
+                        size = buf[i]
+                        i += 1
+                        if size > 0x7F:
+                            c = buf[i]
+                            i += 1
+                            if c > 0x7F:
+                                size, i = _read_varint(buf, i - 2)
+                            else:
+                                size += (c << 7) - 0x80
+                        stat_end = i + size
+                        if not (kinds and (
+                                size < 2 or buf[i] != 0x08
+                                or buf[i + 1] > 0x7F or buf[i + 1] in kinds)):
+                            i = stat_end  # not one of `kinds`: unread
+                            continue
+                        sid, value, text = 0, None, None
+                        while i < stat_end:
+                            tag = buf[i]
+                            i += 1
+                            if (tag == 0x08 or tag == 0x18 or tag == 0x20
+                                    or tag == 0x38):
+                                v = buf[i]
+                                i += 1
+                                if v > 0x7F:
+                                    v &= 0x7F
+                                    shift = 7
+                                    while True:
+                                        c = buf[i]
+                                        i += 1
+                                        v |= (c & 0x7F) << shift
+                                        if c < 0x80:
+                                            break
+                                        shift += 7
+                                if tag == 0x08:
+                                    sid = v
+                                else:  # uint64, int64, ref: as written
+                                    value = float(v)
+                            elif tag == 0x2A:  # str_value
+                                size = buf[i]
+                                i += 1
+                                if size > 0x7F:
+                                    c = buf[i]
+                                    i += 1
+                                    if c > 0x7F:
+                                        size, i = _read_varint(buf, i - 2)
+                                    else:
+                                        size += (c << 7) - 0x80
+                                text = buf[i:i + size]
+                                i += size
+                            elif tag == 0x11:  # double_value
+                                value = FLOAT64.unpack_from(buf, i)[0]
+                                i += 8
+                            elif tag > 0x7F or tag < 8:
+                                return None
+                            elif tag & 7 == 0:
+                                while buf[i] > 0x7F:
+                                    i += 1
+                                i += 1
+                            elif tag & 7 == 2:
+                                size, i = _read_varint(buf, i)
+                                i += size
+                            else:
+                                return None
+                        if i != stat_end:
+                            return None
+                        kind = kinds.get(sid)
+                        if kind in TEXT_STATS:
+                            if text is not None:
+                                found[kind] = text.decode("utf-8", "replace")
+                        elif kind is not None and value is not None:
+                            found[kind] = value
+                    elif tag == 0x12 or tag == 0x22:  # name, display_name
+                        size = buf[i]
+                        i += 1
+                        if size > 0x7F:
+                            c = buf[i]
+                            i += 1
+                            if c > 0x7F:
+                                size, i = _read_varint(buf, i - 2)
+                            else:
+                                size += (c << 7) - 0x80
+                        if tag == 0x12:
+                            name = buf[i:i + size].decode("utf-8", "replace")
+                        else:
+                            disp = buf[i:i + size].decode("utf-8", "replace")
+                        i += size
+                    elif tag == 0x08:
+                        mid = buf[i]
+                        i += 1
+                        if mid > 0x7F:
+                            mid, i = _read_varint(buf, i - 1)
+                    elif tag > 0x7F or tag < 8:
+                        return None
+                    elif tag & 7 == 2:  # `metadata` (0x1A): opaque bytes
+                        size, i = _read_varint(buf, i)
+                        i += size
+                    elif tag & 7 == 0:
+                        while buf[i] > 0x7F:
+                            i += 1
+                        i += 1
+                    else:
+                        return None
+                if i != value_end:
+                    return None
+            elif tag == 0x08:  # the key
+                mid = buf[i]
+                i += 1
+                if mid > 0x7F:
+                    mid, i = _read_varint(buf, i - 1)
+            elif tag > 0x7F or tag < 8:
+                return None
+            elif tag & 7 == 0:
+                while buf[i] > 0x7F:
+                    i += 1
+                i += 1
+            elif tag & 7 == 2:
+                size, i = _read_varint(buf, i)
+                i += size
+            else:
+                return None
+    except (IndexError, struct.error):
+        return None
+    if i != end:
+        return None
+    return mid, name, disp or name, found
+
+
+def _read_event(buf, i: int, end: int, own) -> tuple | None:
+    """The event at buf[i:end] as `_decode_plane` keeps it, (metadata id,
+    offset_ps, duration_ps, its own costs or None), read in one pass
+    written for an XEvent's wire layout; None where the event holds what
+    this loop does not know, as `_read_entry` for its message.
+
+    The three varints `metadata_id`, `offset_ps`, `duration_ps` (0x08,
+    0x10, 0x18) are read where they lie, the one read later standing. A
+    `stats` field (0x22) is kept only where the line's stats are looked at
+    (`own`: the plane's `kinds`, or None) and its leading bytes say it may
+    be one of them, by the test `_read_entry` makes; every other is stepped
+    over by its length, as is any other varint (`num_occurrences`, 0x28) or
+    length-delimited field of a one-byte tag. `_costs` opens what was kept,
+    for the few events that keep any."""
+    meta_id = offset_ps = duration_ps = 0
+    stat_spans = None
+    try:
+        while i < end:
+            tag = buf[i]
+            i += 1
+            if tag == 0x22:  # stats
+                size = buf[i]
+                i += 1
+                if size > 0x7F:
+                    size, i = _read_varint(buf, i - 1)
+                if own and (
+                        size < 2 or buf[i] != 0x08
+                        or buf[i + 1] > 0x7F or buf[i + 1] in own):
+                    if stat_spans is None:
+                        stat_spans = []
+                    stat_spans.append((i, i + size))
+                i += size
+            elif tag == 0x10 or tag == 0x18 or tag == 0x08:
+                v = buf[i]
+                i += 1
+                if v > 0x7F:  # offsets and durations take this path
+                    v &= 0x7F
+                    shift = 7
+                    while True:
+                        c = buf[i]
+                        i += 1
+                        v |= (c & 0x7F) << shift
+                        if c < 0x80:
+                            break
+                        shift += 7
+                if tag == 0x10:
+                    offset_ps = v
+                elif tag == 0x18:
+                    duration_ps = v
+                else:
+                    meta_id = v
+            elif tag > 0x7F or tag < 8:
+                return None
+            elif tag & 7 == 0:
+                while buf[i] > 0x7F:
+                    i += 1
+                i += 1
+            elif tag & 7 == 2:
+                size, i = _read_varint(buf, i)
+                i += size
+            else:
+                return None
+    except IndexError:
+        return None
+    if i != end:
+        return None
+    return (meta_id, offset_ps, duration_ps,
+            _costs(buf, stat_spans, own) if stat_spans else None)
+
+
+def _event_generic(buf, a: int, b: int, own) -> tuple:
+    """`_read_event`'s answer for any valid event, through `_fields`: a
+    list of the event's fields and a tuple a field. Raises ValueError on
+    truncated or malformed input."""
+    meta_id = offset_ps = duration_ps = 0
+    stat_spans = []
+    for num, wt, x, y in _fields(buf, a, b):
+        if wt == 0:
+            if num == 1:
+                meta_id = x
+            elif num == 2:
+                offset_ps = x
+            elif num == 3:
+                duration_ps = x
+        elif num == 4 and wt == 2 and own and (
+                y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
+                or buf[x + 1] in own):
+            stat_spans.append((x, y))
+    return (meta_id, offset_ps, duration_ps,
+            _costs(buf, stat_spans, own) if stat_spans else None)
+
+
 def _decode_plane(
     buf, start: int, end: int, top: list | None = None
 ) -> _Plane:
@@ -404,7 +701,18 @@ def _decode_plane(
     stats' names, then every op's names and cost model), then every line
     once and every event once. Nothing is copied but the names. `top` is
     `_fields(buf, start, end)` where the caller has walked the plane's top
-    level already (to weigh it, `_plane_weight`)."""
+    level already (to weigh it, `_plane_weight`).
+
+    The plane's top level, its stat-metadata map and a line's top level go
+    through `_fields`. The two messages a plane holds by the thousand are
+    each opened once and only as far as they are read, by a loop written
+    for their own wire layout: an entry of the event-metadata map by
+    `_read_entry`, an event by `_read_event`. The input decides: a message
+    that holds anything those loops do not know (a tag above 0x7F, a fixed
+    width, fields that do not end where the message ends) is read by the
+    generic path (`_entry_generic`, `_event_generic`), that message alone,
+    to the same answer for any valid message and the same ValueError for a
+    broken one; `_Plane.generic` counts them."""
     plane = _Plane(bytes=end - start)
     line_spans, metadata_spans = [], []
     kinds: dict[int, str] = {}  # stat metadata id -> its COST_STATS name
@@ -433,63 +741,46 @@ def _decode_plane(
     # Cost-model stats (flops, bytes_accessed) and the hlo_category string
     # hang off the event METADATA, one set per op instance.
     plane.event_metadata = len(metadata_spans)
+    names, shown, costs = plane.names, plane.shown, plane.costs
+    generic = 0
     for a, b in metadata_spans:
-        mid, inner = _map_entry(buf, a, b)
-        name = disp = ""
-        stat_spans = []
-        for num, wt, x, y in inner:
-            if wt != 2:
-                continue
-            if num == 2:
-                name = buf[x:y].decode(errors="replace")
-            elif num == 4:  # display_name (3 is `metadata`: opaque bytes)
-                disp = buf[x:y].decode(errors="replace")
-            elif num == 5 and kinds and (
-                    y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
-                    or buf[x + 1] in kinds):
-                stat_spans.append((x, y))
-        plane.names[mid] = name
-        plane.shown[mid] = disp or name
-        plane.costs[mid] = _costs(buf, stat_spans, kinds)
+        entry = _read_entry(buf, a, b, kinds)
+        if entry is None:
+            generic += 1
+            entry = _entry_generic(buf, a, b, kinds)
+        mid, names[mid], shown[mid], costs[mid] = entry  # left to right
     lines = []
     for a, b in line_spans:
-        lid, lname, ts_ns, event_spans = 0, "", 0, []
-        for num, wt, x, y in _fields(buf, a, b):
-            if num == 4 and wt == 2:
-                event_spans.append((x, y))
-            elif num == 1 and wt == 0:
+        lid, lname, ts_ns = 0, "", 0
+        line = _fields(buf, a, b)
+        for num, wt, x, y in line:
+            if num == 4:
+                continue  # an event: read below, once every line has a name
+            if num == 1 and wt == 0:
                 lid = x
             elif num == 2 and wt == 2:
                 lname = buf[x:y].decode(errors="replace")
             elif num == 3 and wt == 0:
                 ts_ns = x
-        lines.append((lid, lname, ts_ns, event_spans))
+        lines.append((lid, lname, ts_ns, line))
     has_xla_ops = any(lname == "XLA Ops" for _, lname, _, _ in lines)
-    for lid, lname, ts_ns, event_spans in lines:
+    for lid, lname, ts_ns, line in lines:
         # Per-occurrence stats override the metadata's cost model where a
         # producer emits them per event; only the lines the op table reads
         # (see _plane_summary) have theirs looked at.
         own = kinds if not has_xla_ops or lname == "XLA Ops" else None
         events = []
-        for a, b in event_spans:
-            meta_id = offset_ps = duration_ps = 0
-            stat_spans = []
-            for num, wt, x, y in _fields(buf, a, b):
-                if wt == 0:
-                    if num == 1:
-                        meta_id = x
-                    elif num == 2:
-                        offset_ps = x
-                    elif num == 3:
-                        duration_ps = x
-                elif num == 4 and wt == 2 and own and (
-                        y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
-                        or buf[x + 1] in own):
-                    stat_spans.append((x, y))
-            events.append((
-                meta_id, offset_ps, duration_ps,
-                _costs(buf, stat_spans, own) if stat_spans else None))
+        add = events.append
+        for num, wt, x, y in line:
+            if num != 4 or wt != 2:
+                continue
+            event = _read_event(buf, x, y, own)
+            if event is None:
+                generic += 1
+                event = _event_generic(buf, x, y, own)
+            add(event)
         plane.lines.append((lid, lname, ts_ns, events))
+    plane.generic = generic
     return plane
 
 
@@ -604,20 +895,36 @@ def iter_plane_bufs(data: bytes):
 
 
 # What one entry of a plane's event-metadata map weighs, in bytes of
-# `lines` (`_plane_weight`).
-METADATA_ENTRY_WEIGHT = 200
+# `lines` (`_plane_weight`): what converting an entry costs over what a
+# byte of a device plane's events costs. Fitted again by PR 42, whose loops
+# made both cheaper (least squares over the twelve device planes of the six
+# capture cells' kept artifacts, each converted seven times on the chip
+# machine at nice 19: an entry 21.2 -> 15.2 us, an event 5.8 -> 4.5 us of
+# 47.5-55.7 bytes, so 182 -> 170 bytes an entry; PR 40 had put it at 200).
+METADATA_ENTRY_WEIGHT = 170
 
 # The least a forked worker's share has to weigh, in `_plane_weight`'s unit
-# (8-9 thousand to a millisecond of a device plane's `convert.plane` on the
-# chip machine, so about 60 ms): what a fork, its pipes and the fragment's
-# way back cost. From the spans of PR 39's traced runs (PERF.md section 5),
-# two workers: a worker's first plane started 42.4-74.8 ms after
-# `trace.convert` opened (less the read: 42-71 ms), and the last fragment
-# took 12.7-40.7 ms from its plane's end through the pipe to the span's
-# close. It lies between what today's one-chip artifacts hold beside their
-# device plane (`/host:CPU`, 81-105 thousand, 16-24 ms: no fork) and what
-# four device planes leave the second process (170-250 ms: a fork).
-FORK_WORTH_WEIGHT = 500_000
+# (10-12 thousand to a millisecond of a device plane's `convert.plane` on
+# the chip machine since PR 42, so about 60 ms, the same 60 ms as the
+# 500 000 PR 40 set at 8-9 thousand a millisecond): what a fork, its pipes
+# and the fragment's way back cost. From the spans of PR 39's and PR 40's
+# traced runs (PERF.md section 5): a worker's first plane starts 38-75 ms
+# after the caller could have started its own, and the last fragment takes
+# 13-41 ms from its plane's end through the pipe to the span's close.
+# Offline, with PR 42's loops, on the kept four-chip artifacts (chip
+# machine, nice 19, median of seven): one worker against none converts
+# `olmo2-13b-v5e4`'s in 246.1 against 268.5 ms (its share of 1.16 M, 102 ms
+# of work, buys 22) and the sparse job's in 386.8 against 464.5 (1.91 M,
+# 181 ms, buys 78), so a fork's whole cost is 80-103 ms and a share of
+# 0.70-0.95 M would lose up to 20 ms by it: no artifact of today's cells
+# holds one. The threshold lies between what the one-chip artifacts hold
+# beside their device plane (`/host:CPU`, 74-100 thousand, 14-22 ms: no
+# fork) and what four device planes leave the second process (1.16 and
+# 1.91 M: a fork). It is also how far behind the split counts a worker as
+# starting, so it has to stay under the heaviest plane of a four-chip
+# artifact (0.78 M at 13b-v5e4), or the caller is dealt three device
+# planes of four.
+FORK_WORTH_WEIGHT = 700_000
 
 
 def _plane_weight(top: list) -> tuple[int, int]:
@@ -628,22 +935,26 @@ def _plane_weight(top: list) -> tuple[int, int]:
     bytes under `lines` (field 3) plus METADATA_ENTRY_WEIGHT for every
     entry of the event-metadata map (field 4). `/host:metadata`, the
     largest plane of every artifact (1.1-27.4 MB of HLO in its stats),
-    holds one entry and no line, weighs 200 and converts in 0.1-0.3 ms.
+    holds one entry and no line, weighs 170 and converts in 0.1-0.3 ms.
 
-    How it maps to milliseconds, from artifacts kept from the three
-    one-chip cells and converted on the chip machine at nice 19 (PR 40,
-    chip call 1): an event costs about 6 us wherever it lies and a
-    metadata entry about 23 us (its names and a dozen stats opened for the
-    cost model); a device plane's event is 52-56 bytes of its line, so its
-    `convert.plane` lasts a millisecond for every 8-9 thousand of weight
-    (olmo2-1b's `/device:TPU:0`: 27.7 k events in 1.54 MB of lines, 7600
-    entries, weight 3.06 M, 348 ms; the hybrid job's 1.74 M, 219 ms;
-    olmo2-7b-2l's 274 k, 31 ms); a host thread's event is 16 bytes, so
-    `/host:CPU` (2.7-4.3 k events, 183-195 entries, weight 81-105 k) takes
-    16-24 ms, twice what its weight says. The rule needs no better: the
-    shares it tells apart lie a factor of five under the threshold (one
-    chip: 81-105 k beside the device plane) and of 2.6-4.4 over it (four
-    chips: a worker's two device planes, 1.30 and 2.18 M)."""
+    How it maps to milliseconds, from artifacts kept from the six capture
+    cells and converted on the chip machine at nice 19 (PR 42, chip call
+    1; PR 40's reading of the code before in brackets): an event costs
+    about 4.5 us [6] through the whole conversion wherever it lies and a
+    metadata entry about 15 us [23] (`_read_entry`: its names, and the
+    four stats of its dozen that the cost model wants, read where they
+    lie; then its row of the summary and its name `json.dumps`-ed); a
+    device plane's event is 48-56 bytes of its line, so its
+    `convert.plane` lasts a millisecond for every 10-12 thousand of weight
+    [8-9] (olmo2-1b's `/device:TPU:0`: 27.9 k events in 1.55 MB of lines,
+    7600 entries, weight 2.84 M, 234 ms [348]; the hybrid job's 1.61 M,
+    152 ms [219]; deepseek-v2-lite's 1.00 M, 83 ms; olmo2-7b-2l's 252 k,
+    21 ms [31]); a host thread's event is 16 bytes, so `/host:CPU`
+    (2.6-5.3 k events, 183-272 entries, weight 74-138 k) takes 14-28 ms,
+    twice what its weight says. The rule needs no better: the shares it
+    tells apart lie a factor of seven under the threshold (one chip:
+    74-100 k beside the device plane) and of 1.7-2.7 over it (four chips:
+    a worker's two device planes, 1.16 and 1.91 M)."""
     line_bytes = lines = entries = 0
     for num, wt, x, y in top:
         if wt != 2:
@@ -868,17 +1179,26 @@ def _convert_plane(
     pickle it by reference), the whole file with the plane at [start:end)
     where the process that read it converts (`top`: `_decode_plane`'s).
 
-    Third of the result: the call's two spans, convert.plane round all of
-    it and convert.decode round `_decode_plane` alone, under the ambient
-    context and with the pid of the process that ran it. They travel with
-    the result because a pool worker has no journal anyone flushes
-    (`_iter_fragments` records them in the caller's)."""
+    Third of the result: the call's spans, convert.plane round all of it
+    and convert.decode round `_decode_plane` alone, under the ambient
+    context and with the pid of the process that ran it; and, ONLY for a
+    plane some of whose metadata entries or events the generic path had to
+    read (`_Plane.generic` above zero), convert.generic laid over that
+    plane's convert.decode (its start, its length, its child): the
+    journal's mark of a decode that left the loops written for the wire
+    layout. They travel with the result because a pool worker has no
+    journal anyone flushes (`_iter_fragments` records them in the
+    caller's)."""
     pid, buf = job
     spans = obs.SpanJournal()
     with obs.span("convert.plane", journal=spans):
-        with obs.span("convert.decode", journal=spans):
+        with obs.span("convert.decode", journal=spans) as decode:
             plane = _decode_plane(
                 buf, start, len(buf) if end is None else end, top)
+        if plane.generic:
+            spans.record(replace(
+                decode, name="convert.generic", span_id=obs.mint_id(),
+                parent_id=decode.span_id))
         try:
             summary = _plane_summary(plane)
         except Exception:  # noqa: BLE001 - a summarizer bug must not cost

@@ -153,8 +153,12 @@ def flushed_spans(sock) -> list:
             start_us, dur_us, pid))
 
 
-# four planes of 3000 ops: two of them outweigh a fork (trace._shares)
-WORTH_A_FORK = {"ops_per_plane": 3000, "events_per_line": 200}
+# four planes whose op metadata alone weighs 1.2 forks: two of them
+# outweigh a fork (trace._shares), whatever the two constants are fitted to
+WORTH_A_FORK = {
+    "ops_per_plane": int(
+        1.2 * trace.FORK_WORTH_WEIGHT / trace.METADATA_ENTRY_WEIGHT),
+    "events_per_line": 200}
 
 
 @pytest.mark.parametrize("workers, xplane, forked", [

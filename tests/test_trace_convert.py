@@ -114,14 +114,16 @@ def test_streamed_parallel_matches_single_shot(tmp_path):
     # safety — see _iter_fragments), and this pytest session is not one
     # (jax threads): run the parallel conversion the way production does,
     # in a clean subprocess, then compare against the in-process single
-    # shot. The artifact is one worth a fork: four planes of 3000 ops.
+    # shot. The artifact is one worth a fork: four planes whose op metadata
+    # alone weighs 1.2 forks.
     import subprocess
 
     from xspace_fixture import build_xspace
 
     xplane = str(tmp_path / "host.xplane.pb")
     with open(xplane, "wb") as f:
-        f.write(build_xspace(ops_per_plane=3000, events_per_line=200))
+        f.write(build_xspace(ops_per_plane=_ops_weighing(1.2),
+                             events_per_line=200))
     single = _read_gz(trace.write_chrome_trace_gz_single(xplane))
     code = (
         "import os; from dynolog_tpu import obs; "
@@ -791,7 +793,309 @@ def test_each_derived_file_survives_the_others_failure(
                 trace.summarize_xplane_bytes(FIXTURE.read_bytes()))
 
 
+# -- a message is opened once and only as far as it is read -------------------
+
+FLOPS, NBYTES, CATEGORY, TF_OP, UNWANTED, FAR = 1, 200, 3, 4, 9, 300
+WANTED = ((FLOPS, "flops"), (NBYTES, "bytes_accessed"),
+          (CATEGORY, "hlo_category"), (TF_OP, "tf_op"))
+
+
+def _fixed(num: int, wire_type: int) -> bytes:
+    """A fixed32 (wire type 5) or fixed64 (1) field of zeros."""
+    import xspace_fixture as xf
+
+    return xf._varint(num << 3 | wire_type) + bytes(4 if wire_type == 5 else 8)
+
+
+def _wire_plane(entries=(), events=(), line_name: str = "ops") -> bytes:
+    """A plane that names the four cost stats (one under a two-byte id),
+    holds `entries` in its event-metadata map and `events` on one line."""
+    import xspace_fixture as xf
+
+    body = xf._field_varint(1, 1) + xf._field_str(2, "/device:TPU:0")
+    for sid, name in WANTED + ((UNWANTED, "program_id"), (FAR, "source")):
+        body += xf._field_bytes(5, xf._stat_metadata(sid, name))
+    for entry in entries:
+        body += xf._field_bytes(4, entry)
+    line = (xf._field_varint(1, 7) + xf._field_str(2, line_name)
+            + xf._field_varint(3, 1000))
+    for event in events:
+        line += xf._field_bytes(4, event)
+    return body + xf._field_bytes(3, line)
+
+
+def _decoded(plane: bytes, generic: bool = False):
+    """`_decode_plane`'s answer less its count of generic reads, or the
+    error's type; with `generic`, every entry and event through the generic
+    path, as if neither loop knew anything."""
+    import dataclasses
+
+    with pytest.MonkeyPatch.context() as patch:
+        if generic:
+            patch.setattr(trace, "_read_entry", lambda *a: None)
+            patch.setattr(trace, "_read_event", lambda *a: None)
+        try:
+            got = dataclasses.asdict(
+                trace._decode_plane(plane, 0, len(plane)))
+        except Exception as e:  # noqa: BLE001 - the type is the answer
+            return type(e), None
+    return got, got.pop("generic")
+
+
+def _wire_cases() -> dict:
+    """case -> (entries, events, how many of them the generic path reads)"""
+    import itertools
+    import random
+
+    import xspace_fixture as xf
+
+    orders = random.Random(7)
+    v, b = xf._field_varint, xf._field_bytes
+    stats = (xf._stat(FLOPS, uint=4096), xf._stat(UNWANTED, uint=7),
+             xf._stat(CATEGORY, text="loop fusion"),
+             xf._stat(NBYTES, double=2048.5),
+             xf._stat(TF_OP, text="jit(step)/mlp/dot_general:"),
+             xf._stat(FAR, text="models/transformer.py:120"))
+    parts = [v(1, 300), xf._field_str(2, "%fusion.1 = bf16[8]{0} fusion()"),
+             b(3, b"\x08\x96\x01" * 9), xf._field_str(4, "fusion.1"),
+             *(b(5, stat) for stat in stats)]
+    name = xf._field_str(2, "%copy.2 = f32[4]{0} copy(%p)")
+    ev = (v(1, 300), v(2, 5 * US), v(3, 7 * US),
+          b(4, xf._stat(FLOPS, double=1.5)), b(4, xf._stat(UNWANTED, uint=9)),
+          b(4, xf._stat(NBYTES, uint=64)))
+    cases = {
+        # the wire allows a message's fields in any order: an entry for each
+        # of 120 seeded orders of the value's ten fields, an event for each
+        # of the 720 orders of its six
+        "any-order": (
+            [v(1, 300) + b(2, b"".join(orders.sample(parts, len(parts))))
+             for _ in range(120)],
+            [b"".join(order) for order in itertools.permutations(ev)], 0),
+        "absent-fields": (
+            [b"", v(1, 5), b(2, b""), v(1, 6) + b(2, v(1, 6)),
+             v(1, 7) + b(2, name), b(2, xf._field_str(4, "shown alone")),
+             v(1, 8) + b(2, b(5, stats[0]))],
+            [b"", v(1, 300), v(2, US), v(3, US), v(3, US) + v(1, 7),
+             b(4, xf._stat(FLOPS, uint=1))], 0),
+        # producers are free to set the id in the key, in the message, or
+        # in both: the one read later stands
+        "id-in-key-only": ([v(1, 11) + b(2, name)], [v(1, 11)], 0),
+        "id-in-value-only": ([b(2, v(1, 12) + name)], [v(1, 12)], 0),
+        "ids-differ": (
+            [v(1, 13) + b(2, v(1, 14) + name),
+             b(2, v(1, 15) + name) + v(1, 16),
+             v(1, 17) + b(2, name + v(1, 18)) + v(1, 19)],
+            [v(1, 13) + v(1, 14)], 0),
+        "a-value-said-twice": (
+            [v(1, 20) + b(2, b"".join(parts)) + b(2, v(1, 21) + name)], [], 0),
+        # a stat that does not lead with its id is opened, whatever it is
+        "stat-led-by-its-value": (
+            [v(1, 22) + b(2, name + b(5, v(3, 77) + v(1, FLOPS))
+                          + b(5, v(3, 78) + v(1, UNWANTED))
+                          + b(5, xf._field_str(5, "late") + v(1, CATEGORY)))],
+            [v(1, 22) + b(4, v(3, 99) + v(1, FLOPS))
+             + b(4, v(4, 98) + v(1, UNWANTED))], 0),
+        # an id of two bytes: opened, kept where it is one of the kinds
+        "stat-id-of-two-bytes": (
+            [v(1, 23) + b(2, name + b(5, xf._stat(NBYTES, uint=640))
+                          + b(5, xf._stat(FAR, uint=1)))],
+            [v(1, 23) + b(4, xf._stat(NBYTES, uint=32))
+             + b(4, xf._stat(FAR, uint=2))], 0),
+        "every-kind-of-value": (
+            [v(1, 24) + b(2, name
+                          + b(5, v(1, FLOPS) + v(4, 5))  # int64_value
+                          + b(5, v(1, NBYTES) + v(7, 6))  # ref_value
+                          + b(5, v(1, TF_OP) + v(3, 1))  # a number: not text
+                          + b(5, xf._stat(CATEGORY, text="a") + v(1, FLOPS))
+                          + b(5, v(1, FLOPS) + v(3, 1) + v(3, 2))),  # the later
+             # a stat that is empty, and one with an id and no value
+             v(1, 25) + b(2, name + b(5, b"") + b(5, v(1, FLOPS)))],
+            [v(1, 24) + b(4, v(1, FLOPS) + v(7, 3))], 0),
+        # `metadata` (3), `num_occurrences` (5) and fields nobody knows, of
+        # a one-byte tag, are stepped over at every level
+        "stepped-over": (
+            [v(9, 1) + b(10, b"\xff" * 300) + v(1, 26) + b(2, v(9, 2) + b(
+                3, b"\x08\x96\x01" * 50) + name + b(15, b"x" * 200) + b(
+                5, v(9, 1 << 40) + v(1, FLOPS) + b(6, b"raw") + v(3, 8))
+                + v(15, 1 << 62))],
+            [v(5, 3) + v(1, 26) + b(15, b"\x22" * 130) + v(9, 1 << 35)
+             + v(2, US) + b(4, v(9, 1) + v(1, FLOPS) + b(6, b"r") + v(3, 9))],
+            0),
+        "names-not-utf8-and-long": (
+            [v(1, 27) + b(2, b(2, b"bad\xff\xfe" * 40) + b(4, b"\xc3" * 3))],
+            [], 0),
+        # what neither loop knows goes to the generic path, that message
+        # alone, and the count says so
+        "tag-above-0x7f": (
+            [v(1, 28) + b(2, name + v(16, 1)),
+             v(16, 1) + v(1, 29) + b(2, name),
+             v(1, 30) + b(2, name + b(5, v(1, FLOPS) + v(3, 4) + b(17, b"x"))),
+             v(1, 31) + b(2, name)],
+            [v(1, 28) + v(16, 1) + v(2, US), v(1, 31) + v(2, US)], 4),
+        "fixed-widths": (
+            [v(1, 32) + b(2, name + _fixed(6, 5)), _fixed(7, 1) + v(1, 33),
+             v(1, 34) + b(2, name + b(5, v(1, FLOPS) + _fixed(9, 5)
+                                      + v(3, 4))),
+             v(1, 35) + b(2, name + b(5, v(1, UNWANTED) + _fixed(9, 5)))],
+            [v(1, 32) + _fixed(6, 5) + v(3, US), v(1, 33) + _fixed(6, 1),
+             v(1, 35) + b(4, v(1, UNWANTED) + _fixed(9, 5))], 5),
+    }
+    return cases
+
+
+@pytest.mark.parametrize("case", sorted(_wire_cases()))
+def test_the_loops_read_what_the_generic_path_reads(case):
+    entries, events, generic = _wire_cases()[case]
+    for line_name in ("ops", "XLA Ops"):
+        plane = _wire_plane(entries, events, line_name)
+        want, every = _decoded(plane, generic=True)
+        assert isinstance(want, dict) and every == len(entries) + len(events)
+        assert _decoded(plane) == (want, generic)
+    # and beside "XLA Ops" another line's own stats are not looked at
+    import xspace_fixture as xf
+
+    plane += xf._field_bytes(3, xf._line(8, "Async XLA Ops", 0, list(events)))
+    want, _ = _decoded(plane, generic=True)
+    assert _decoded(plane) == (want, generic + sum(
+        trace._read_event(ev, 0, len(ev), None) is None for ev in events))
+    assert all(own is None for ev in want["lines"][1][3] for own in ev[3:])
+
+
+def _cuts(message: bytes, wrap) -> list:
+    return [wrap(message[:k]) for k in range(len(message))]
+
+
+def _truncations() -> dict:
+    """case -> planes in which one message is cut short at every length,
+    the lengths round it as a writer of the whole message leaves them or
+    (a plane cut whole) as the cut leaves them."""
+    import xspace_fixture as xf
+
+    v, b = xf._field_varint, xf._field_bytes
+    stat = xf._stat(FLOPS, double=2.5) + v(9, 300)
+    value = (v(1, 300) + xf._field_str(2, "%op.1 = f32[] op()") + b(5, stat)
+             + xf._field_str(4, "op.1") + b(5, xf._stat(UNWANTED, uint=1)))
+    entry = v(1, 300) + b(2, value)
+    event = (v(1, 300) + v(2, 5 * US) + v(3, 7 * US) + b(4, stat)
+             + b(4, xf._stat(FAR, text="x")))
+    whole = _wire_plane([entry], [event])
+    return {
+        "an-entry": _cuts(entry, lambda cut: _wire_plane([cut], [event])),
+        "an-entrys-value": _cuts(value, lambda cut: _wire_plane(
+            [v(1, 300) + b(2, cut)], [event])),
+        "an-entrys-stat": _cuts(stat, lambda cut: _wire_plane(
+            [v(1, 300) + b(2, v(1, 300) + b(5, cut))], [event])),
+        "an-event": _cuts(event, lambda cut: _wire_plane([entry], [cut])),
+        "an-events-stat": _cuts(stat, lambda cut: _wire_plane(
+            [entry], [v(1, 300) + b(4, cut)])),
+        "a-plane": [whole[:k] for k in range(len(whole))],
+        # a length that says more than is there, and a varint with no end
+        "lengths-that-lie": [
+            _wire_plane([v(1, 300) + b"\x12\x7f" + value], [event]),
+            _wire_plane([v(1, 300) + b(2, v(1, 300) + b"\x2a\x40" + stat)]),
+            _wire_plane([entry], [v(1, 300) + b"\x22\x40" + stat]),
+            _wire_plane([entry], [b"\x10" + b"\xff" * 4]),
+            _wire_plane([b"\x08" + b"\xff" * 3], [event]),
+            # a stat of one byte is a tag with nothing behind it
+            _wire_plane([v(1, 300) + b(2, b(5, b"\x08"))], [event]),
+            _wire_plane([entry], [v(1, 300) + b(4, b"\x08")])],
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_truncations()))
+def test_a_message_cut_short_raises_what_the_generic_path_raises(case):
+    planes = _truncations()[case]
+    outcomes = [_decoded(plane) for plane in planes]
+    assert [got for got, _ in outcomes] == [
+        _decoded(plane, generic=True)[0] for plane in planes]
+    # truncated and malformed input is a ValueError, never an IndexError
+    errors = {got for got, _ in outcomes if isinstance(got, type)}
+    assert errors == {ValueError}
+    whole = [got for got, _ in outcomes if not isinstance(got, type)]
+    if case == "a-plane":
+        # only a cut between two top-level fields leaves a plane to read
+        plane = planes[-1] + b"\0"
+        ends = {start for _, start, _, _ in _wire_fields(plane)}
+        assert [k for k, (got, _) in enumerate(outcomes)
+                if not isinstance(got, type)] == sorted(
+                    k for k in ends if k < len(planes))
+    elif case == "lengths-that-lie":
+        assert not whole
+    else:
+        # a cut between two fields leaves a shorter message, read alike
+        assert whole and len(whole) < len(planes) / 2
+
+
+def test_bytes_changed_at_random_decode_alike_or_raise_value_error():
+    # Whatever the bytes, the decode that adapts and the one that takes
+    # the generic path for every message agree: the same plane, or a
+    # ValueError from both.
+    import random
+
+    entries, events, _ = _wire_cases()["stepped-over"]
+    more = _wire_cases()["any-order"]
+    plane = _wire_plane(entries + more[0][:3], events + more[1][:5])
+    rng = random.Random(42)
+    seen = set()
+    for _ in range(600):
+        changed = bytearray(plane)
+        for _ in range(rng.choice((1, 1, 2, 3))):
+            changed[rng.randrange(len(changed))] = rng.choice(
+                (0, 1, 0x08, 0x12, 0x22, 0x2A, 0x7F, 0x80, 0xFF,
+                 rng.randrange(256)))
+        changed = bytes(changed)
+        got, _ = _decoded(changed)
+        # by what they print: a double changed into a NaN equals nothing
+        assert repr(got) == repr(_decoded(changed, generic=True)[0])
+        seen.add(ValueError if got is ValueError else dict)
+    assert seen == {ValueError, dict}
+
+
+@pytest.mark.parametrize("generic", [0, 2])
+def test_a_plane_that_left_the_fast_path_says_so_in_a_span(generic):
+    # convert.generic is laid over the convert.decode of a plane some of
+    # whose messages the generic path had to read, and of no other plane.
+    import xspace_fixture as xf
+
+    entries, events, _ = _wire_cases()["id-in-key-only"]
+    if generic:  # one entry and one event with a two-byte tag
+        entries = entries + [xf._field_varint(16, 1)]
+        events = events + [xf._field_varint(16, 1)]
+    plane = _wire_plane(entries, events)
+    assert trace._decode_plane(plane, 0, len(plane)).generic == generic
+    ctx = obs.TraceContext.mint()
+    obs.set_current(ctx)
+    try:
+        _, summary, spans = trace._convert_plane((1, plane))
+    finally:
+        obs.set_current(None)
+    assert summary is not None
+    names = [s.name for s in spans]
+    if not generic:
+        assert names == ["convert.decode", "convert.plane"]
+        return
+    assert names == ["convert.decode", "convert.generic", "convert.plane"]
+    decode, mark, _ = spans
+    assert (mark.start_us, mark.dur_us, mark.pid, mark.trace_id) == (
+        decode.start_us, decode.dur_us, decode.pid, ctx.trace_id)
+    assert mark.parent_id == decode.span_id != mark.span_id
+    # and the conversion's journal holds it, once, whoever converted
+    obs.JOURNAL.drain()
+    data = xf._field_bytes(1, plane) + xf._field_bytes(
+        1, _wire_plane(*_wire_cases()["absent-fields"][:2]))
+    fragments = list(trace._iter_fragments(
+        data, trace.ConvertBudget(max_workers=1)))
+    assert len(fragments) == 2
+    assert [s.name for s in obs.JOURNAL.drain()].count("convert.generic") == 1
+
+
 # -- who converts which plane is read from the artifact ------------------------
+
+
+def _ops_weighing(forks: float) -> int:
+    """Entries of an event-metadata map that weigh `forks` times the least
+    a forked worker's share has to weigh (`trace._plane_weight`)."""
+    return int(forks * trace.FORK_WORTH_WEIGHT / trace.METADATA_ENTRY_WEIGHT)
 
 
 def _rule_plane(name: str, ops: int = 0, events: int = 0,
@@ -815,36 +1119,47 @@ def _rule_plane(name: str, ops: int = 0, events: int = 0,
     return body
 
 
-def _heavy(i: int, ops: int) -> bytes:
-    return _rule_plane(f"/device:TPU:{i}", ops=ops, events=40)
+def _heavy(i: int, forks: float) -> bytes:
+    """A device plane whose op metadata alone weighs `forks` times what a
+    worker's share has to weigh (a few hundred bytes of events besides)."""
+    return _rule_plane(f"/device:TPU:{i}", ops=_ops_weighing(forks), events=40)
 
 
 # case -> (the planes in file order, the Chrome-trace pids pickled for a
-# worker in the order they are sent; none: no pool is made)
+# worker in the order they are sent; none: no pool is made). The heavy
+# planes are sized in forks, not in ops, so the cases say what they said
+# under PR 40's constants (3000-3600 ops at 200 against 500 000) under any
+# refit of the two: PR 42's 170 and 700 000 make them 4941-5929 ops.
 RULE_CASES = {
     # one heavy plane, second in the file, beside a light plane with lines
     # and a lineless plane ten times the heavy one's bytes: what every
     # one-chip artifact looks like
     "one-heavy": (lambda: [
-        _rule_plane("/host:CPU", ops=5, events=300), _heavy(0, 3500),
-        _rule_plane("/host:metadata", stat_bytes=10 * len(_heavy(0, 3500))),
+        _rule_plane("/host:CPU", ops=5, events=300), _heavy(0, 1.4),
+        _rule_plane("/host:metadata", stat_bytes=10 * len(_heavy(0, 1.4))),
     ], None),
     # two heavy planes: the heavier (fourth in the file) the caller's, the
     # other a worker's; the light plane rides with the caller, who carries
     # less once the fork is counted
     "two-heavy": (lambda: [
-        _rule_plane("/host:metadata", stat_bytes=3_000_000), _heavy(0, 3000),
-        _rule_plane("/host:CPU", ops=5, events=300), _heavy(1, 3400),
+        _rule_plane("/host:metadata", stat_bytes=3_000_000), _heavy(0, 1.2),
+        _rule_plane("/host:CPU", ops=5, events=300), _heavy(1, 1.36),
     ], [2]),
     # four heavy planes, in the file neither by weight nor against it, as
     # a four-chip artifact holds them: second and fourth heaviest to the
     # worker, heaviest first
     "four-heavy": (lambda: [
-        _heavy(0, 3200), _rule_plane("#Chip0 Misc"), _heavy(1, 3600),
+        _heavy(0, 1.28), _rule_plane("#Chip0 Misc"), _heavy(1, 1.44),
         _rule_plane("/host:metadata", stat_bytes=3_000_000),
-        _heavy(2, 3000), _heavy(3, 3400),
+        _heavy(2, 1.2), _heavy(3, 1.36),
         _rule_plane("/host:CPU", ops=5, events=300),
     ], [6, 5]),
+    # two planes each just under a fork's weight: the worker would be left
+    # the lighter alone, which no fork is worth, so the caller converts both
+    "two-not-quite": (lambda: [
+        _heavy(0, 0.9), _rule_plane("/host:CPU", ops=5, events=300),
+        _heavy(1, 0.95), _rule_plane("/host:metadata", stat_bytes=1_000_000),
+    ], None),
 }
 
 
@@ -899,3 +1214,55 @@ def test_the_artifact_decides_who_converts_which_plane(
     assert heaviest + 1 not in sent and not set(lineless) & set(sent)
     assert pids.count(WORKER_PID) == len(sent)
     assert pids.count(os.getpid()) == len(planes) - len(sent)
+
+
+# (bytes under `lines`, entries of the event-metadata map, lines) of every
+# plane, in file order, of an artifact kept from each capture cell of the
+# benchmark (PR 42, chip call 1; the four-chip two from PR 40's), and the
+# decision the spans bear out there (PERF.md section 5): at one chip the
+# caller converts alone, at four one worker is forked for two device planes.
+_LIGHT = (0, 1, 0)  # `#Chip<i> Host Interface`, `#Chip<i> Misc`, ...
+CELL_PLANES = {
+    "olmo2-1b.capture": ([
+        _LIGHT, (1552198, 7600, 5), _LIGHT, _LIGHT, _LIGHT,
+        (68712, 183, 8), (0, 0, 0)], []),
+    "olmo2-7b-2l.capture": ([
+        _LIGHT, (123856, 751, 5), _LIGHT, _LIGHT, _LIGHT,
+        (42754, 183, 7), (0, 0, 0)], []),
+    "olmo-hybrid-7b.capture": ([
+        _LIGHT, (844185, 4502, 5), _LIGHT, _LIGHT, _LIGHT,
+        (44872, 183, 8), (0, 0, 0)], []),
+    "deepseek-v2-lite.capture": ([
+        _LIGHT, (341916, 3848, 6), _LIGHT, _LIGHT, _LIGHT,
+        (45762, 208, 8), (0, 0, 0)], []),
+    "olmo2-13b-v5e4.capture": ([
+        _LIGHT, (246172, 3146, 7), _LIGHT, _LIGHT, (165593, 2416, 7), _LIGHT,
+        _LIGHT, (167119, 2416, 7), _LIGHT, _LIGHT, (171622, 2416, 7), _LIGHT,
+        (0, 2, 0), _LIGHT, (57941, 226, 24), (0, 0, 0)], [10, 4]),
+    "olmoe-1b-7b-v5e4.capture": ([
+        _LIGHT, (305617, 5653, 7), _LIGHT, _LIGHT, (209837, 4422, 7), _LIGHT,
+        _LIGHT, (207822, 4380, 7), _LIGHT, _LIGHT, (208357, 4391, 7), _LIGHT,
+        (0, 2, 0), _LIGHT, (91562, 272, 25), (0, 0, 0)], [4, 7]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANES))
+def test_the_fitted_constants_decide_each_cell_as_its_spans_say(cell):
+    planes, theirs = CELL_PLANES[cell]
+    weights = [(line_bytes + trace.METADATA_ENTRY_WEIGHT * entries, lines)
+               for line_bytes, entries, lines in planes]
+    ours, got, workers = trace._shares(weights, 2)
+    assert (got, workers) == (theirs, 1 if theirs else 0)
+    assert sorted(ours + got) == list(range(len(planes)))
+    heaviest = max(range(len(planes)), key=lambda i: weights[i][0])
+    if not theirs:
+        assert ours == list(range(len(planes)))  # file order, nothing weighed
+        # what lies beside the device plane is a sixth of a fork at most
+        beside = sum(w for i, (w, _) in enumerate(weights) if i != heaviest)
+        assert beside * 6 <= trace.FORK_WORTH_WEIGHT
+        return
+    # the caller converts the heaviest plane first, and every lineless one
+    assert ours[0] == heaviest
+    assert all(weights[i][1] for i in got)
+    share = sum(weights[i][0] for i in got)
+    assert share >= 1.6 * trace.FORK_WORTH_WEIGHT  # well clear of the line
