@@ -90,12 +90,14 @@ def init_linear_layer(rng, cfg) -> dict:
     }
 
 
-def _causal_conv(x, w):
+def _causal_conv(x, w, bias=None):
     """x [B, S, channels], w [taps, channels]: the last tap is the token's
-    own, zeros stand before the first token."""
+    own, zeros stand before the first token; `bias` [channels] where the
+    convolution has one (a Mamba-2 block's)."""
     taps, s = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + s] * w[j] for j in range(taps))
+    out = sum(padded[:, j:j + s] * w[j] for j in range(taps))
+    return out if bias is None else out + bias
 
 
 def _l2norm(x):

@@ -12,16 +12,28 @@ between the chips in every layer of every captured step.
 The layer, for token t with normalised input h_t (E experts, k a token):
 
     s_t  = softmax_e(h_t W_r)                  float32, over all E experts
-    K_t  = the k largest s_t
+                                               (sigmoid of each score alone
+                                               where cfg.moe_score says so)
+    K_t  = the k largest s_t                   (of s_t + b where
+                                               cfg.moe_select_bias:
+                                               `router_bias`, a number an
+                                               expert that moves the choice
+                                               and not the gates; no
+                                               gradient reaches it)
     g_te = s_te for e in K_t                   (/ sum over K_t of s_te where
-                                                cfg.moe_norm_topk)
+                                               cfg.moe_norm_topk; times
+                                               cfg.moe_gate_scale)
     y_t  = sum over e in K_t of
            g_te * W_down_e (silu(W_gate_e h_t) * (W_up_e h_t))
+                                               (W_down_e relu(W_up_e h_t)^2,
+                                               two matrices an expert, where
+                                               cfg.mlp_act is "relu2")
            + S(h_t)                            where cfg.n_shared_experts:
                                                the experts every token
                                                visits, side by side as one
-                                               SwiGLU of n_shared x the
-                                               expert width, ungated
+                                               expert of cfg.shared_d_ff
+                                               (n_shared x the expert width
+                                               unless stated), ungated
 
 An expert's width is cfg.moe_d_ff (d_ff where 0). Where cfg.n_experts_held
 is set, the chip holds that many of the E experts from index
@@ -34,7 +46,8 @@ Two terms for the loss, each a mean over ALL the step's tokens:
     balance = E * sum_e f_e P_e     f_e: the share of the routed assignments
                                     that went to e (first choices alone, or
                                     all T x k where cfg.moe_balance_all_k),
-                                    P_e: the mean of s_te over tokens;
+                                    P_e: the mean of s_te over tokens
+                                    (of s_te / sum_e s_te under sigmoid);
                                     where cfg.moe_seq_aux both are taken a
                                     sequence at a time and the sequences'
                                     terms averaged
@@ -82,7 +95,8 @@ from dynolog_tpu.parallel.sharding import BATCH_AXES, PARAM_RULES
 
 
 def init_moe_layer(rng, cfg):
-    """MoE layer params: router + stacked expert SwiGLU weights."""
+    """MoE layer params: router + stacked expert weights (no `*_gate`
+    matrix where cfg.mlp_act is "relu2")."""
     dtype = jnp.dtype(cfg.dtype)
     d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
     held = cfg.n_experts_held or e
@@ -96,16 +110,19 @@ def init_moe_layer(rng, cfg):
     layer = {
         # kept f32 end-to-end (routing numerics) — no bf16 round-trip
         "router": jax.random.normal(k[0], (d, e), jnp.float32) / math.sqrt(d),
-        "experts_gate": dense(k[1], (held, d, f), d),
         "experts_up": dense(k[2], (held, d, f), d),
         "experts_down": dense(k[3], (held, f, d), f),
     }
+    if cfg.moe_select_bias:
+        layer["router_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.n_shared_experts:
-        fs = cfg.n_shared_experts * f
-        layer.update(
-            shared_gate=dense(k[4], (d, fs), d),
-            shared_up=dense(k[5], (d, fs), d),
-            shared_down=dense(k[6], (fs, d), fs))
+        fs = cfg.shared_d_ff
+        layer.update(shared_up=dense(k[5], (d, fs), d),
+                     shared_down=dense(k[6], (fs, d), fs))
+    if cfg.mlp_act == "swiglu":
+        layer["experts_gate"] = dense(k[1], (held, d, f), d)
+        if cfg.n_shared_experts:
+            layer["shared_gate"] = dense(k[4], (d, cfg.shared_d_ff), d)
     return layer
 
 
@@ -210,17 +227,31 @@ def _count(ids, n: int):
         dtype=jnp.int32)
 
 
-def _route(router, h, cfg, stat_axes, seqs: int):
+def _route(router, bias, h, cfg, stat_axes, seqs: int):
     """h [T, D], `seqs` sequences back to back -> (gates [T, k] float32,
     chosen [T, k] int32, balance, z): the two loss terms are over all the
     step's tokens, `stat_axes` being the mesh axes these T are a share over
-    (None: they are all)."""
+    (None: they are all). `bias` [E] or None: added for the choice alone."""
     e = cfg.n_experts
     logits = h.astype(jnp.float32) @ router  # [T, E]; tiny, numerics matter
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, chosen = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.moe_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        gates, chosen = jax.lax.top_k(scores, cfg.moe_top_k)
+    else:
+        chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias), cfg.moe_top_k)[1]
+        gates = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.moe_norm_topk:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        if cfg.moe_score == "sigmoid":
+            total = total + 1e-20  # the source's guard against a sum of 0
+        gates = gates / total
+    if cfg.moe_gate_scale != 1.0:
+        gates = gates * cfg.moe_gate_scale
     counted = chosen if cfg.moe_balance_all_k else chosen[:, :1]
     z = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     tokens = jnp.float32(h.shape[0])
@@ -248,19 +279,22 @@ def _route(router, h, cfg, stat_axes, seqs: int):
     return gates, chosen, balance, z / tokens
 
 
-def _moe_local(router, w_gate, w_up, w_down, x, *, cfg, ep, tp, stat_axes,
-               ragged):
-    """The layer on one chip's tokens x [B, S, D] with the experts held
-    here (w_*: [E / ep, ...]); `ep` chips share the experts, `tp` each
-    expert's hidden dimension."""
+def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
+    """The layer on one chip's tokens x [B, S, D]. `routing`: the router,
+    and its selection bias where it has one; `experts`: the matrices of the
+    experts held here ([E / ep, ...]: gate, up and down, or up and down of
+    ReLU^2 experts). `ep` chips share the experts, `tp` each expert's
+    hidden dimension."""
+    router, bias = routing if len(routing) > 1 else (routing[0], None)
+    w_gate, w_up, w_down = experts if len(experts) > 2 else (None, *experts)
     b, s, d = x.shape
     k = cfg.moe_top_k
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     tokens, copies = b * s, b * s * k
     h = x.reshape(tokens, d)
 
     with jax.named_scope("moe.route"):
-        gates, chosen, balance, z = _route(router, h, cfg, stat_axes, b)
+        gates, chosen, balance, z = _route(router, bias, h, cfg, stat_axes, b)
 
     with jax.named_scope("moe.dispatch"):
         expert_of = chosen.reshape(copies)
@@ -312,7 +346,10 @@ def _moe_local(router, w_gate, w_up, w_down, x, *, cfg, ep, tp, stat_axes,
         return jnp.where(there, out, 0) if ep > 1 or share else out
 
     with jax.named_scope("moe.experts"):
-        act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        if w_gate is None:
+            act = jnp.square(jax.nn.relu(product(rows, w_up)))
+        else:
+            act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
         out = jax.lax.ragged_dot(act, w_down, group_sizes)
         if share:  # a copy for an expert that is not here adds nothing
             out = jnp.where(there, out, 0)
@@ -336,14 +373,16 @@ def moe_mlp(layer, x, cfg, mesh=None):
     """MoE feed-forward. x: [B, S, D] -> (y [B, S, D], balance, z): the
     layer's output and its two loss terms (module docstring), to be scaled
     by cfg.moe_aux_weight and cfg.moe_z_weight."""
-    weights = (layer["router"], layer["experts_gate"], layer["experts_up"],
-               layer["experts_down"])
+    routing = ("router", "router_bias") if cfg.moe_select_bias else ("router",)
+    experts = ("experts_up", "experts_down")
+    if cfg.mlp_act == "swiglu":
+        experts = ("experts_gate", *experts)
     local = partial(
         _moe_local, cfg=cfg, ep=1, tp=1, stat_axes=None, ragged=False)
     if mesh is not None:
-        if layer["experts_gate"].shape[0] < cfg.n_experts:
+        if layer["experts_up"].shape[0] < cfg.n_experts:
             raise ValueError(
-                f"a share of {layer['experts_gate'].shape[0]} of "
+                f"a share of {layer['experts_up'].shape[0]} of "
                 f"{cfg.n_experts} experts is one chip's: under a mesh the "
                 "`expert` axis divides them")
         if cfg.moe_seq_aux and mesh.shape["seq"] > 1:
@@ -357,13 +396,19 @@ def moe_mlp(layer, x, cfg, mesh=None):
                 tp=mesh.shape["model"], stat_axes=BATCH_AXES + ("seq",),
                 ragged=mesh.devices.flat[0].platform == "tpu"),
             mesh=mesh,
-            in_specs=(PARAM_RULES["router"], PARAM_RULES["experts_gate"],
-                      PARAM_RULES["experts_up"], PARAM_RULES["experts_down"],
+            in_specs=(tuple(PARAM_RULES[name] for name in routing),
+                      tuple(PARAM_RULES[name] for name in experts),
                       token_spec),
             out_specs=(token_spec, P(), P()), check_vma=False)
-    y, balance, z = jax.checkpoint(local)(*weights, x)
+    y, balance, z = jax.checkpoint(local)(
+        tuple(layer[name] for name in routing),
+        tuple(layer[name] for name in experts), x)
     if cfg.n_shared_experts:
         with jax.named_scope("moe.shared"):
-            gate = jax.nn.silu(x @ layer["shared_gate"])
-            y = y + (gate * (x @ layer["shared_up"])) @ layer["shared_down"]
+            if cfg.mlp_act == "relu2":
+                act = jnp.square(jax.nn.relu(x @ layer["shared_up"]))
+            else:
+                act = jax.nn.silu(x @ layer["shared_gate"]) * (
+                    x @ layer["shared_up"])
+            y = y + act @ layer["shared_down"]
     return y, balance, z

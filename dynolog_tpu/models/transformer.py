@@ -24,6 +24,17 @@ import jax.numpy as jnp
 
 
 LAYER_TYPES = ("full_attention", "linear_attention")
+BLOCK_TYPES = ("mamba2", "moe", "attention", "mlp")
+# A mixer's kind -> (its norm's scale in the layer, the scope that norm runs
+# under: a mixer's norm goes with the phase it feeds).
+MIXER_NORMS = {
+    "attention": ("attn_scale", "attn"),
+    "mla": ("attn_scale", "mla.project"),
+    "linear_attention": ("attn_scale", "gdn.project"),
+    "mamba2": ("ssm_scale", "ssm.project"),
+    "mlp": ("mlp_scale", "mlp"),
+    "moe": ("mlp_scale", "moe.route"),
+}
 ROPE_SCALING_KEYS = ("type", "factor", "original_max_position_embeddings",
                      "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
 
@@ -105,6 +116,41 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = False
+    # A model of one-mixer blocks: each block's kind in order, one of
+    # BLOCK_TYPES, as many as n_layers ("mamba2": a Mamba-2 state-space
+    # block, dynolog_tpu.models.mamba2; "moe": the expert layer;
+    # "attention"; "mlp": a plain MLP of d_ff). A block is
+    # x + mixer(norm(x)) with ONE norm, where a layer is an attention-kind
+    # mixer and then an MLP-kind mixer. None: every layer is that pair.
+    block_types: tuple | None = None
+    # Attention's key/value heads where they are fewer than the query heads
+    # (query head j reads key/value head j // (n_heads / n_kv_heads)), and
+    # a head's width where it is not d_model / n_heads. 0: as n_heads has it.
+    n_kv_heads: int = 0
+    attn_head_dim: int = 0
+    # A "mamba2" block: ssm_heads heads of ssm_head_dim, their B and C
+    # shared by ssm_heads / ssm_groups heads a group, a state of ssm_state a
+    # channel, a causal convolution of ssm_conv_kernel taps, the recurrence
+    # computed in chunks of ssm_chunk positions.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # The MLP's and the experts' activation: "swiglu" (silu(x W_gate) *
+    # (x W_up), three matrices) or "relu2" (relu(x W_up)^2, two).
+    mlp_act: str = "swiglu"
+    # The router: "softmax" over the experts, or "sigmoid" of each score
+    # alone. moe_select_bias: a number an expert (`router_bias`) added to
+    # the scores for the CHOICE of the k and not for their gates; no
+    # gradient reaches it. moe_gate_scale multiplies the gates.
+    # moe_shared_d_ff: the shared expert's width where it is not
+    # n_shared_experts x the expert width.
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_gate_scale: float = 1.0
+    moe_shared_d_ff: int = 0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -118,6 +164,37 @@ class TransformerConfig:
                 self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
         if self.attn_type not in ("mha", "mla"):
             raise ValueError(f"attn_type {self.attn_type!r}: 'mha' or 'mla'")
+        if self.mlp_act not in ("swiglu", "relu2"):
+            raise ValueError(f"mlp_act {self.mlp_act!r}: 'swiglu' or 'relu2'")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_score {self.moe_score!r}: 'softmax' or 'sigmoid'")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(
+                f"{self.n_heads} query heads do not divide into groups over "
+                f"{self.kv_heads} key/value heads")
+        if self.block_types is not None:
+            # a list from JSON: the configuration keys a jitted function
+            object.__setattr__(self, "block_types", tuple(self.block_types))
+            unknown = set(self.block_types) - set(BLOCK_TYPES)
+            if unknown or len(self.block_types) != self.n_layers:
+                raise ValueError(
+                    f"block_types {self.block_types}: one of {BLOCK_TYPES} "
+                    f"for each of the {self.n_layers} blocks")
+            if (self.layer_types is not None or self.attn_type != "mha"
+                    or self.first_dense_layers):
+                raise ValueError(
+                    "block_types states every block's one mixer: not beside "
+                    "layer_types, latent attention or first_dense_layers")
+            if "mamba2" in self.block_types and (
+                    min(self.ssm_heads, self.ssm_head_dim, self.ssm_state) < 1
+                    or self.ssm_heads % self.ssm_groups):
+                raise ValueError(
+                    f"a mamba2 block of {self.ssm_heads} heads of "
+                    f"{self.ssm_head_dim}, state {self.ssm_state}, in "
+                    f"{self.ssm_groups} groups")
+            if "moe" in self.block_types and not self.n_experts:
+                raise ValueError("a moe block of no experts (n_experts 0)")
         held = self.n_experts_held or self.n_experts
         if not 0 <= self.first_expert_held <= self.n_experts - held:
             raise ValueError(
@@ -146,17 +223,35 @@ class TransformerConfig:
         """Whether layer i's MLP is the expert layer."""
         return self.n_experts > 0 and i >= self.first_dense_layers
 
+    def mixers(self, i: int) -> tuple:
+        """The mixers of layer i in order, each x + mixer(norm(x)): the one
+        `block_types` states, else the layer's attention-kind mixer
+        ("attention", "mla" or "linear_attention") and its "mlp" or "moe"."""
+        if self.block_types is not None:
+            return (self.block_types[i],)
+        first = ("linear_attention" if self.is_linear(i)
+                 else "mla" if self.attn_type == "mla" else "attention")
+        return first, "moe" if self.is_sparse(i) else "mlp"
+
     @property
     def n_sparse_layers(self) -> int:
-        return sum(self.is_sparse(i) for i in range(self.n_layers))
+        return sum("moe" in self.mixers(i) for i in range(self.n_layers))
 
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def shared_d_ff(self) -> int:
+        return self.moe_shared_d_ff or self.n_shared_experts * self.expert_d_ff
+
+    @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
 
     @classmethod
     def llama_8b_like(cls) -> "TransformerConfig":
@@ -186,40 +281,44 @@ def init_params(rng, cfg: TransformerConfig):
         "final_scale": jnp.ones((cfg.d_model,), dtype),
         "layers": [],
     }
+    d, f = cfg.d_model, cfg.d_ff
+    h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[2 + i], 7)
-        d, f = cfg.d_model, cfg.d_ff
-        layer = {
-            "attn_scale": jnp.ones((d,), dtype),
-            "mlp_scale": jnp.ones((d,), dtype),
-        }
-        if cfg.is_linear(i):
-            from dynolog_tpu.models.linear_attention import init_linear_layer
+        layer = {}
+        for kind in cfg.mixers(i):
+            layer[MIXER_NORMS[kind][0]] = jnp.ones((d,), dtype)
+            if kind == "mamba2":
+                from dynolog_tpu.models.mamba2 import init_mamba2_layer
 
-            layer.update(init_linear_layer(k[0], cfg))
-        elif cfg.attn_type == "mla":
-            from dynolog_tpu.models.mla import init_mla_layer
+                layer.update(init_mamba2_layer(k[0], cfg))
+            elif kind == "linear_attention":
+                from dynolog_tpu.models.linear_attention import (
+                    init_linear_layer)
 
-            layer.update(init_mla_layer(k[0], cfg))
-        else:
-            layer.update(
-                wq=dense(k[0], (d, d), d), wk=dense(k[1], (d, d), d),
-                wv=dense(k[2], (d, d), d), wo=dense(k[3], (d, d), d))
-            if cfg.qk_norm:
-                layer.update(q_scale=jnp.ones((d,), dtype),
-                             k_scale=jnp.ones((d,), dtype))
-        if cfg.is_sparse(i):
-            from dynolog_tpu.models.moe import init_moe_layer
+                layer.update(init_linear_layer(k[0], cfg))
+            elif kind == "mla":
+                from dynolog_tpu.models.mla import init_mla_layer
 
-            layer.update(init_moe_layer(k[4], cfg))
-        else:
-            layer.update(
-                {
-                    "w_gate": dense(k[4], (d, f), d),
-                    "w_up": dense(k[5], (d, f), d),
-                    "w_down": dense(k[6], (f, d), f),
-                }
-            )
+                layer.update(init_mla_layer(k[0], cfg))
+            elif kind == "attention":
+                layer.update(
+                    wq=dense(k[0], (d, h * hd), d),
+                    wk=dense(k[1], (d, kv * hd), d),
+                    wv=dense(k[2], (d, kv * hd), d),
+                    wo=dense(k[3], (h * hd, d), h * hd))
+                if cfg.qk_norm:
+                    layer.update(q_scale=jnp.ones((h * hd,), dtype),
+                                 k_scale=jnp.ones((kv * hd,), dtype))
+            elif kind == "moe":
+                from dynolog_tpu.models.moe import init_moe_layer
+
+                layer.update(init_moe_layer(k[4], cfg))
+            else:
+                if cfg.mlp_act == "swiglu":
+                    layer["w_gate"] = dense(k[4], (d, f), d)
+                layer.update(w_up=dense(k[5], (d, f), d),
+                             w_down=dense(k[6], (f, d), f))
         params["layers"].append(layer)
     return params
 
@@ -279,9 +378,11 @@ def _rope(x, positions, theta, scaling: dict | None = None):
 
 def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
                        scale=None):
-    """Causal softmax attention by cfg.attn_impl. q, k: [B, S, H, D],
-    v: [B, S, H, Dv] -> [B, S, H, Dv]; `scale` None: D ** -0.5."""
+    """Causal softmax attention by cfg.attn_impl. q: [B, S, H, D], k:
+    [B, S, Hkv, D], v: [B, S, Hkv, Dv] -> [B, S, H, Dv], query head j on
+    key/value head j // (H / Hkv); `scale` None: D ** -0.5."""
     s, hd = q.shape[1], q.shape[-1]
+    group = q.shape[2] // k.shape[2]
     if cfg.attn_impl == "flash":
         from dynolog_tpu.ops.flash_attention import flash_attention
 
@@ -306,11 +407,14 @@ def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
 
         if mesh is None:
             raise ValueError("attn_impl='ring' requires a mesh")
-        if scale is not None or v.shape[-1] != hd:
+        if scale is not None or v.shape[-1] != hd or group > 1:
             raise ValueError(
-                "attn_impl='ring' runs heads of one width at the scale "
-                "D ** -0.5: not latent attention's")
+                "attn_impl='ring' runs as many key/value heads as query "
+                "heads, of one width, at the scale D ** -0.5: not latent "
+                "attention's, nor grouped heads")
         return ring_attention(q, k, v, mesh, causal=True)
+    if group > 1:  # the plain path writes k and v out a query head each
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     if scale is None:
         scores = scores / jnp.sqrt(hd).astype(q.dtype)
@@ -323,15 +427,15 @@ def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
 
 
 def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
+    b, s, _ = x.shape
+    h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     with jax.named_scope("attn"):
         q, k = x @ layer["wq"], x @ layer["wk"]
         if cfg.qk_norm:
             q = _rmsnorm(q, layer["q_scale"], cfg.norm_eps)
             k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
-        q, k = q.reshape(b, s, h, hd), k.reshape(b, s, h, hd)
-        v = (x @ layer["wv"]).reshape(b, s, h, hd)
+        q, k = q.reshape(b, s, h, hd), k.reshape(b, s, kv, hd)
+        v = (x @ layer["wv"]).reshape(b, s, kv, hd)
         if cfg.rope_theta is not None:
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -344,13 +448,44 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
           else jax.named_scope("attn")):
         out = _softmax_attention(q, k, v, cfg, mesh)
     with jax.named_scope("attn"):
-        return out.reshape(b, s, d) @ layer["wo"]
+        return out.reshape(b, s, h * hd) @ layer["wo"]
 
 
-def _mlp(layer, x):
+def _mlp(layer, x, act="swiglu"):
     with jax.named_scope("mlp"):
+        if act == "relu2":
+            return jnp.square(jax.nn.relu(x @ layer["w_up"])) @ layer["w_down"]
         gate = jax.nn.silu(x @ layer["w_gate"])
         return (gate * (x @ layer["w_up"])) @ layer["w_down"]
+
+
+def _mixer(kind, layer, x, positions, cfg: TransformerConfig, mesh):
+    """One mixer of a layer on the residual stream x -> (what it adds to x,
+    its weighted share of the loss's expert terms or None)."""
+    scale, scope = MIXER_NORMS[kind]
+    with jax.named_scope(scope):
+        h = _rmsnorm(x, layer[scale], cfg.norm_eps)
+    if kind == "mamba2":
+        from dynolog_tpu.models.mamba2 import mamba2_mixer
+
+        return mamba2_mixer(layer, h, cfg), None
+    if kind == "linear_attention":
+        from dynolog_tpu.models.linear_attention import gated_delta_net
+
+        return gated_delta_net(layer, h, cfg), None
+    if kind == "mla":
+        from dynolog_tpu.models.mla import latent_attention
+
+        return latent_attention(layer, h, positions, cfg, mesh), None
+    if kind == "attention":
+        return _attention(layer, h, positions, cfg, mesh), None
+    if kind == "mlp":
+        return _mlp(layer, h, cfg.mlp_act), None
+    from dynolog_tpu.models.moe import moe_mlp
+
+    y, balance, z = moe_mlp(layer, h, cfg, mesh)
+    return y, (cfg.moe_aux_weight * balance
+               + cfg.moe_z_weight * z) / cfg.n_sparse_layers
 
 
 def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
@@ -363,36 +498,11 @@ def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
     )
     aux = jnp.zeros((), jnp.float32)
     for i, layer in enumerate(params["layers"]):
-        # a layer's two norms go with what they feed
-        if cfg.is_linear(i):
-            from dynolog_tpu.models.linear_attention import gated_delta_net
-
-            with jax.named_scope("gdn.project"):
-                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
-            x = x + gated_delta_net(layer, h, cfg)
-        elif cfg.attn_type == "mla":
-            from dynolog_tpu.models.mla import latent_attention
-
-            with jax.named_scope("mla.project"):
-                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
-            x = x + latent_attention(layer, h, positions, cfg, mesh)
-        else:
-            with jax.named_scope("attn"):
-                h = _rmsnorm(x, layer["attn_scale"], cfg.norm_eps)
-            x = x + _attention(layer, h, positions, cfg, mesh)
-        if cfg.is_sparse(i):
-            from dynolog_tpu.models.moe import moe_mlp
-
-            with jax.named_scope("moe.route"):
-                h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
-            y, balance, z = moe_mlp(layer, h, cfg, mesh)
-            aux = aux + (cfg.moe_aux_weight * balance
-                         + cfg.moe_z_weight * z) / cfg.n_sparse_layers
-        else:
-            with jax.named_scope("mlp"):
-                h = _rmsnorm(x, layer["mlp_scale"], cfg.norm_eps)
-            y = _mlp(layer, h)
-        x = x + y
+        for kind in cfg.mixers(i):
+            y, terms = _mixer(kind, layer, x, positions, cfg, mesh)
+            x = x + y
+            if terms is not None:
+                aux = aux + terms
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["final_scale"], cfg.norm_eps)
         return (x @ params["w_out"]).astype(jnp.float32), aux

@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -42,9 +43,13 @@ def _pick_block(seq_len: int, target: int) -> int:
 
 
 def reference_attention(q, k, v, *, causal: bool = True, scale=None):
-    """Plain-XLA attention; q, k: [B, S, H, D], v: [B, S, H, Dv] ->
-    [B, S, H, Dv]. `scale` None: D ** -0.5."""
+    """Plain-XLA attention; q: [B, S, H, D], k: [B, S, Hkv, D], v:
+    [B, S, Hkv, Dv] -> [B, S, H, Dv], k and v repeated to the H query heads
+    where they are fewer. `scale` None: D ** -0.5."""
     d = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     if scale is None:
         scores = scores / jnp.sqrt(d).astype(q.dtype)
@@ -121,11 +126,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
     lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l_safe)
 
 
+def _kv_row(group: int):
+    """The index map of a whole key or value head beside query head b of
+    the grid: row b // group of [B*Hkv, S, D] (b where the heads are as
+    many). The programs of a group follow one another, so the row is
+    fetched once a group and never written out a query head each."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
+
+
 def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
-    """q, k [B*H, S, D], v [B*H, S, Dv] -> (out [B*H, S, Dv],
-    lse [B*H, 1, S] f32)."""
+    """q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv] -> (out
+    [B*H, S, Dv], lse [B*H, 1, S] f32)."""
     bh, s, d = q.shape
     dv = v.shape[2]
+    kv_row = _kv_row(bh // k.shape[0])
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     kernel = functools.partial(
@@ -135,8 +151,8 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
         grid=(bh, s // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s, d), kv_row),
+            pl.BlockSpec((1, s, dv), kv_row),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
@@ -195,9 +211,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_q, block_k, causal, scale):
+                dk_ref, dv_ref, *sums, block_q, block_k, causal, scale):
     """dK and dV for one (batch*head, k-block): loop over query blocks at
-    or below this key block's diagonal."""
+    or below this key block's diagonal. Under grouped heads the grid has a
+    third, innermost axis over the query heads of the key/value head's
+    group: each program adds its query head's part into `sums` (dk and dv
+    in float32, in fast memory), the group's first clears them and its last
+    writes them out."""
     ki = pl.program_id(1)
     seq_len = q_ref.shape[1]
     head_dim = q_ref.shape[2]
@@ -242,24 +262,42 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk, dv = jax.lax.fori_loop(qb_start, n_qb, body, (
         jnp.zeros((block_k, head_dim), jnp.float32),
         jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if not sums:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    dk_sum, dv_sum = sums
+    member = pl.program_id(2)
+
+    @pl.when(member == 0)
+    def _():
+        dk_sum[...] = jnp.zeros_like(dk_sum)
+        dv_sum[...] = jnp.zeros_like(dv_sum)
+
+    dk_sum[...] += dk
+    dv_sum[...] += dv
+
+    @pl.when(member == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = dk_sum[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sum[...].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                     scale=None):
-    """Residuals q, k [B*H, S, D], v, out [B*H, S, Dv] + cotangent g ->
-    (dq, dk, dv)."""
+    """Residuals q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv], out
+    [B*H, S, Dv] + cotangent g -> (dq, dk, dv), dk and dv summed over the
+    query heads of a key/value head's group."""
     bh, s, d = q.shape
     dv = v.shape[2]
+    group = bh // k.shape[0]
+    kv_row = _kv_row(group)
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
         keepdims=False)[:, None, :]  # [BH, 1, S]
 
-    qk_full = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
-    vo_full = pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0))
     row_full = pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0))
 
     dq = pl.pallas_call(
@@ -268,8 +306,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         grid=(bh, s // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            qk_full,
-            vo_full,
+            pl.BlockSpec((1, s, d), kv_row),
+            pl.BlockSpec((1, s, dv), kv_row),
             pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
             row_full,
             row_full,
@@ -279,26 +317,48 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         name="flash_attention_bwd_dq",
     )(q, k, v, g, lse, delta)
 
+    if group == 1:
+        grid, scratch = (bh, s // bk), ()
+
+        def head(b, i):  # the query head's whole row beside key block i
+            return (b, 0, 0)
+
+        def block(b, i):
+            return (b, i, 0)
+    else:
+        # (key/value head, key block, the group's query heads): the last
+        # axis innermost, so a key block's sums stay in fast memory
+        grid = (bh // group, s // bk, group)
+        scratch = (pltpu.VMEM((bk, d), jnp.float32),
+                   pltpu.VMEM((bk, dv), jnp.float32))
+
+        def head(b, i, member):
+            return (b * group + member, 0, 0)
+
+        def block(b, i, member):
+            return (b, i, 0)
+
     dk, dv_ = pl.pallas_call(
         functools.partial(
             _dkv_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale),
-        grid=(bh, s // bk),
+        grid=grid,
         in_specs=[
-            qk_full,
-            pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i: (b, i, 0)),
-            vo_full,
-            row_full,
-            row_full,
+            pl.BlockSpec((1, s, d), head),
+            pl.BlockSpec((1, bk, d), block),
+            pl.BlockSpec((1, bk, dv), block),
+            pl.BlockSpec((1, s, dv), head),
+            pl.BlockSpec((1, 1, s), head),
+            pl.BlockSpec((1, 1, s), head),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bk, d), block),
+            pl.BlockSpec((1, bk, dv), block),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        scratch_shapes=scratch,
         name="flash_attention_bwd_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv_
@@ -320,8 +380,12 @@ def _from_bh(x, b, h):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
                     scale=None):
-    """Flash attention; q, k: [B, S, H, D], v: [B, S, H, Dv] ->
-    [B, S, H, Dv]. Keys may be wider than values (latent attention expands
+    """Flash attention; q: [B, S, H, D], k: [B, S, Hkv, D], v:
+    [B, S, Hkv, Dv] -> [B, S, H, Dv]. The key/value heads may be fewer than
+    the query heads (grouped-query attention): query head j reads key/value
+    head j // (H / Hkv), k and v are never written out a query head each,
+    and dk and dv come back at Hkv heads, summed over each group inside the
+    dkv kernel. Keys may be wider than values (latent attention expands
     to keys of 192 and values of 128): the score products run over D, the
     P.V and dV products over Dv, nothing is padded. `scale` is the softmax
     scale, D ** -0.5 where None.
@@ -354,10 +418,11 @@ def _vjp_fwd(q, k, v, causal, block_q, block_k, scale):
 def _vjp_bwd(causal, block_q, block_k, scale, res, g):
     q, k, v, out_bh, lse = res
     b, _, h, _ = q.shape
+    kv = k.shape[2]
     dq, dk, dv = _flash_backward(
         _to_bh(q), _to_bh(k), _to_bh(v), out_bh, lse, _to_bh(g),
         causal, block_q, block_k, scale)
-    return _from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h)
+    return _from_bh(dq, b, h), _from_bh(dk, b, kv), _from_bh(dv, b, kv)
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

@@ -42,6 +42,19 @@ from dynolog_tpu.models.transformer import (
 )
 
 
+def _require_dense_pairs(cfg: TransformerConfig) -> None:
+    assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
+            and not cfg.has_linear_layers and cfg.attn_type == "mha"
+            and cfg.block_types is None and cfg.kv_heads == cfg.n_heads
+            and cfg.mlp_act == "swiglu"), (
+        "pipeline path supports the dense/reference transformer config: no "
+        "experts, no linear_attention layer, no latent attention, no "
+        "one-mixer blocks (block_types: a mamba2 block among them), no "
+        "grouped key/value heads and no ReLU^2 MLP (a stage would run each "
+        "as multi-head attention and a SwiGLU MLP)"
+    )
+
+
 def init_pipeline_params(rng, cfg: TransformerConfig, mesh):
     """Transformer params with the layer stack stacked along a leading
     [n_layers] axis (sharded over `pipe`); embedding/head replicated."""
@@ -51,12 +64,7 @@ def init_pipeline_params(rng, cfg: TransformerConfig, mesh):
     assert cfg.n_layers % n_stages == 0, (
         f"n_layers={cfg.n_layers} must divide into pipe={n_stages} stages"
     )
-    assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
-            and not cfg.has_linear_layers and cfg.attn_type == "mha"), (
-        "pipeline path supports the dense/reference transformer config: no "
-        "experts, no linear_attention layer and no latent attention (a "
-        "stage would run either as multi-head attention)"
-    )
+    _require_dense_pairs(cfg)
 
     params = init_params(rng, cfg)
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *params["layers"])
@@ -94,12 +102,7 @@ def pipeline_loss(params, tokens, cfg: TransformerConfig, mesh, n_micro: int):
     tokens: global [B, S]; B must divide by data x n_micro.
     """
     n_stages = mesh.shape["pipe"]
-    assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
-            and not cfg.has_linear_layers and cfg.attn_type == "mha"), (
-        "pipeline path supports the dense/reference transformer config: no "
-        "experts, no linear_attention layer and no latent attention (a "
-        "stage would run either as multi-head attention)"
-    )
+    _require_dense_pairs(cfg)
 
     def local(layers, embedding, w_out, final_scale, tokens_local):
         p_idx = jax.lax.axis_index("pipe")

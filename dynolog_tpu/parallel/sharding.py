@@ -124,6 +124,23 @@ PARAM_RULES = {
     # shares) whole on every chip; mla_kv_scale by "scale" above.
     "mla_dkv": P(),
     "mla_ukv": P(None, "model"),
+    # The router's selection bias (a number an expert): as the router.
+    "router_bias": P(),
+    # A Mamba-2 block (dynolog_tpu.models.mamba2): the one projection in
+    # (columns z | x B C | dt) by columns on `model`, as every matrix out of
+    # d_model is, the output matrix the other way. The columns are three
+    # runs of heads, so the partitioner re-lays the slices where `model`
+    # cuts across them; a whole group of heads a chip (ssm_groups dividing
+    # by the axis) keeps the recurrence and the grouped norm local. The
+    # small ones (a weight a channel a tap and its bias, a number a head)
+    # are replicated; ssm_scale and ssm_norm_scale by "scale" above.
+    "ssm_in": P(None, "model"),
+    "ssm_out": P("model", None),
+    "ssm_conv": P(),
+    "ssm_conv_bias": P(),
+    "ssm_a_log": P(),
+    "ssm_dt_bias": P(),
+    "ssm_d": P(),
     "gdn_q": P(None, "model"),
     "gdn_k": P(None, "model"),
     "gdn_v": P(None, "model"),

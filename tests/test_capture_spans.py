@@ -280,7 +280,11 @@ def test_overlapping_captures_each_list_their_own_spans(tmp_path):
         assert not Path(cfg1.manifest_path(os.getpid())).exists()
         # The second's flush took the first's early spans with it (the
         # daemon merges by trace id); the first's manifest still lists
-        # them, from its own state and not from the journal.
+        # them, from its own state and not from the journal. (The finisher
+        # flushes after it has written the manifest: wait for the flush.)
+        deadline = time.time() + 10.0
+        while time.time() < deadline and not sink.sent:
+            time.sleep(0.005)
         assert {s.trace_id for s in sink.sent} == {
             first.trace_id, second.trace_id}
         profiler.held[0].queue.close()

@@ -213,29 +213,46 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_the_kernels_compile_for_the_chip_at_the_published_widths(one_chip):
-    """Keys of 192 (a lane and a half) and values of 128, sequence 4096,
-    blocks of 512: what interpret mode cannot refuse (a block that does not
-    tile, more fast memory than a kernel may use) Mosaic would. A compile
-    that passes is no chip run."""
-    def shape(width):
+@pytest.mark.parametrize("heads, kv_heads, d_qk, d_v, scale", [
+    (16, 16, 192, 128, 0.11472),  # latent attention's
+    (32, 2, 128, 128, None),  # Nemotron-3-Nano's grouped heads
+], ids=["latent", "grouped"])
+def test_the_kernels_compile_for_the_chip_at_the_published_widths(
+        one_chip, heads, kv_heads, d_qk, d_v, scale):
+    """Keys of 192 (a lane and a half) and values of 128; 32 query heads on
+    2 key/value heads of 128 with the dkv kernel's two float32 sums in fast
+    memory; sequence 4096, blocks of 512: what interpret mode cannot refuse
+    (a block that does not tile, more fast memory than a kernel may use)
+    Mosaic would. Grouped, k, v, dk and dv stay at 2 heads: no operand or
+    result of a kernel holds them at 32. A compile that passes is no chip
+    run. (Both cases here: one file a worker describes the topology in.)"""
+    def shape(n, width):
         return jax.ShapeDtypeStruct(
-            (1, 4096, 16, width), jnp.bfloat16, sharding=one_chip)
+            (1, 4096, n, width), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(
-            q, k, v, True, scale=0.11472).astype(jnp.float32))
+            q, k, v, True, scale=scale).astype(jnp.float32))
 
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            shape(192), shape(192), shape(128)).compile()
+            shape(heads, d_qk), shape(kv_heads, d_qk),
+            shape(kv_heads, d_v)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
-    text = compiled.as_text()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom_call_target" in line and "flash_attention" in line]
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
                    "flash_attention_bwd_dkv"):
-        assert kernel in text
+        assert any(kernel in line for line in calls), kernel
+    if kv_heads < heads:
+        dkv = next(line for line in calls if "flash_attention_bwd_dkv" in line)
+        assert "bf16[2,4096,128]" in dkv.split("custom-call(")[0]  # dk, dv
+        fwd = next(line for line in calls if "flash_attention_fwd" in line)
+        assert fwd.count("bf16[2,4096,128]") == 2  # k and v as they came
+        assert not any("bf16[32,4096,128]" in line.split("custom-call(")[1]
+                       and "bf16[2,4096,128]" not in line for line in calls)
 
 
 # -- the model whole -----------------------------------------------------
@@ -507,7 +524,6 @@ def test_the_scopes_change_no_ops_name_or_count(cfg, monkeypatch):
 
 # -- which mechanism the time went to ------------------------------------
 
-TF_OP = 7  # the stat metadata id of `tf_op` in the synthetic planes
 # (op, its path, microseconds or what it holds)
 SCOPED_STEP = (
     ("%fusion.1 = f32[8]{0} fusion(%a)",
@@ -537,41 +553,8 @@ WANT_SCOPES_US = {  # self time a step; the while's own is its slack
 
 
 def scoped_xspace(steps: int = 2, scale: dict | None = None) -> bytes:
-    """One device plane whose ops carry their paths in `tf_op`, SCOPED_STEP
-    `steps` times over; `scale` lengthens the ops under a scope."""
-    scale = scale or {}
-    ids: dict = {}
-    paths: dict = {}
-    events: list = []
-
-    def lay(spec, at_ps):
-        for name, path, what in spec:
-            meta = ids.setdefault(name, len(ids) + 1)
-            paths[meta] = path
-            if isinstance(what, list):
-                slot = len(events)
-                events.append(b"")
-                end_ps = lay(what, at_ps) + xf.NESTED_SLACK_US * 1_000_000
-                events[slot] = xf._event(meta, at_ps, end_ps - at_ps)
-            else:
-                end_ps = at_ps + int(what * 1_000_000 * scale.get(
-                    trace.op_scope(path or ""), 1))
-                events.append(xf._event(meta, at_ps, end_ps - at_ps))
-            at_ps = end_ps
-        return at_ps
-
-    at_ps = 0
-    for _ in range(steps):
-        at_ps = lay(SCOPED_STEP, at_ps) + 100_000
-    plane = xf._field_str(2, "/device:TPU:0")
-    plane += xf._field_bytes(3, xf._line(0, "XLA Ops", 0, events))
-    for name, meta in ids.items():
-        stats = () if paths[meta] is None else (
-            xf._stat(TF_OP, text=paths[meta]),)
-        plane += xf._field_bytes(4, xf._event_metadata(
-            meta, name, xf._shown(name), stats))
-    plane += xf._field_bytes(5, xf._stat_metadata(TF_OP, "tf_op"))
-    return xf._field_bytes(1, plane)
+    """SCOPED_STEP `steps` times over (`xf.build_scoped_xspace`)."""
+    return xf.build_scoped_xspace(SCOPED_STEP, trace.op_scope, steps, scale)
 
 
 @pytest.mark.parametrize("path, scope", [

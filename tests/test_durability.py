@@ -526,7 +526,17 @@ def test_daemon_sigkill_restart_keeps_rules_breakers_and_backlog(
         for f in flags)
     daemon2 = start_daemon(bin_dir, kernel_interval_s=1, extra_flags=flags2)
     try:
-        doc = daemon2.rpc({"fn": "health"})
+        # A restored component waits for its owner (HealthRestore): the
+        # sink's appears once a collector loop has built its logger, which
+        # on a loaded machine may come after the RPC server answers.
+        docs = []
+
+        def claimed():
+            docs.append(daemon2.rpc({"fn": "health"}))
+            return "relay_sink" in docs[-1]["components"]
+
+        assert _wait(claimed, timeout_s=10, interval_s=0.02)
+        doc = docs[-1]
         assert doc["durability"]["snapshot"]["recovered"] is True
         # Breaker/degraded state survived the crash: reported BEFORE any
         # local failure could re-derive it.

@@ -210,6 +210,50 @@ def build_nested_xspace(steps: int = 3, scale: dict | None = None,
     return _field_bytes(1, plane)
 
 
+TF_OP = 7  # the stat metadata id of `tf_op` in a scoped plane
+
+
+def build_scoped_xspace(step: tuple, scope_of, steps: int = 2,
+                        scale: dict | None = None) -> bytes:
+    """One device plane whose ops carry their paths in `tf_op`: `step`, rows
+    of (op, its path or None, microseconds or the rows it holds), `steps`
+    times over; `scale` lengthens the ops under a scope (`scope_of(path)`,
+    the product's reading of a path)."""
+    scale = scale or {}
+    ids: dict = {}
+    paths: dict = {}
+    events: list = []
+
+    def lay(spec, at_ps):
+        for name, path, what in spec:
+            meta = ids.setdefault(name, len(ids) + 1)
+            paths[meta] = path
+            if isinstance(what, list):
+                slot = len(events)
+                events.append(b"")
+                end_ps = lay(what, at_ps) + NESTED_SLACK_US * 1_000_000
+                events[slot] = _event(meta, at_ps, end_ps - at_ps)
+            else:
+                end_ps = at_ps + int(what * 1_000_000 * scale.get(
+                    scope_of(path or ""), 1))
+                events.append(_event(meta, at_ps, end_ps - at_ps))
+            at_ps = end_ps
+        return at_ps
+
+    at_ps = 0
+    for _ in range(steps):
+        at_ps = lay(step, at_ps) + 100_000
+    plane = _field_str(2, "/device:TPU:0")
+    plane += _field_bytes(3, _line(0, "XLA Ops", 0, events))
+    for name, meta in ids.items():
+        stats = () if paths[meta] is None else (
+            _stat(TF_OP, text=paths[meta]),)
+        plane += _field_bytes(4, _event_metadata(
+            meta, name, _shown(name), stats))
+    plane += _field_bytes(5, _stat_metadata(TF_OP, "tf_op"))
+    return _field_bytes(1, plane)
+
+
 def _shown(name: str) -> str:
     """'%fusion.3 = f32[8,8]{1,0} fusion(%b)' -> 'fusion.3'."""
     return name[1:].split(" ", 1)[0]
