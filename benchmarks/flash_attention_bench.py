@@ -1,20 +1,29 @@
 """Flash-attention kernel benchmark: Pallas MXU kernel vs plain-XLA
 attention on the attached TPU chip (forward and forward+backward), across
-sequence lengths. Kernel-level evidence beside the benchmark the driver
-runs (perfbench/), whose cells time the daemon and the shim. Runs on a
-TPU or not at all: the kernels have no interpret mode of their own.
+sequence lengths; then each of the three kernels alone at the shapes the
+benchmark's cells run them at: microseconds a call on the device's own
+clock (a profiler trace of forward+backward calls, the kernels' events
+found by name) and the share of the bfloat16 roofline that is, by the
+count the driver's benchmark holds them to (perfbench/kernel_costs.py,
+perfbench/peaks.json). Kernel-level evidence beside the benchmark the
+driver runs (perfbench/), whose cells time the daemon and the shim. Runs
+on a TPU or not at all: the kernels have no interpret mode of their own.
 
 Usage: python benchmarks/flash_attention_bench.py [--seqs 1024,2048,4096]
+       [--shapes olmo2-1b,deepseek-v2-lite] [--blocks 512x512,256x512]
 """
 
 from __future__ import annotations
 
 import argparse
 import sys
+import tempfile
 import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(1, str(ROOT / "perfbench"))  # kernel_costs and what it imports
 
 from dynolog_tpu._jaxinit import enable_compile_cache, require_tpu
 
@@ -26,6 +35,19 @@ import jax.numpy as jnp
 from dynolog_tpu.ops.flash_attention import flash_attention, reference_attention
 
 B, H, D = 4, 8, 128
+
+# The attention of the benchmark's cells, as perfbench/kernel_costs.py reads
+# a configuration's `job` (batch x seq, query heads, d_model = heads x
+# width), and the key/value heads beside it.
+CELL_SHAPES = {
+    "olmo2-1b": ({"batch": 1, "seq": 2048, "n_heads": 16, "d_model": 2048}, 16),
+    "olmo2-7b": ({"batch": 1, "seq": 4096, "n_heads": 32, "d_model": 4096}, 32),
+    "deepseek-v2-lite": ({"batch": 2, "seq": 4096, "n_heads": 16,
+                          "attn_type": "mla", "qk_nope_head_dim": 128,
+                          "qk_rope_head_dim": 64, "v_head_dim": 128}, 16),
+    "nemotron-3-nano": ({"batch": 2, "seq": 4096, "n_heads": 32,
+                         "d_model": 4096}, 2),
+}
 
 
 def chain_fwd(attn, n):
@@ -107,17 +129,71 @@ def bench_interleaved(fns, args, iters, rounds=4):
     }
 
 
+def kernel_rows(attn, job: dict, kv_heads: int, device, calls: int = 10):
+    """[(kernel, microseconds a call, share of its roofline in %)] of the
+    three kernels under one forward+backward of `attn` at the job's shape,
+    bfloat16: the events of a profiler trace over `calls` calls, by the
+    reducer, the operation count and the peaks of perfbench/."""
+    import cells
+    import kernel_costs
+
+    job = dict(job, dtype="bfloat16")
+    d_qk, d_v = kernel_costs.head_widths(job)
+    keys = jax.random.split(jax.random.PRNGKey(job["seq"]), 3)
+    q, k, v = (
+        jax.random.normal(key, (job["batch"], job["seq"], heads, width),
+                          jnp.bfloat16)
+        for key, heads, width in zip(
+            keys, (job["n_heads"], kv_heads, kv_heads), (d_qk, d_qk, d_v)))
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
+    jax.block_until_ready(grad(q, k, v))  # compile + warm
+    peaks = cells.load_peaks(device.device_kind)
+    rows = []
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for _ in range(calls):
+                out = grad(q, k, v)
+            jax.block_until_ready(out)
+        (path,) = Path(tmp).rglob("*.xplane.pb")
+        run = {"trace": {"path": str(path)}, "device": {"count": 1}}
+        for kernel in kernel_costs.KERNELS:
+            ns, events = kernel_costs.kernel_events(run, kernel)
+            flops, nbytes = kernel_costs.call_cost(job, kernel)
+            least_s = max(flops / peaks["bf16_flops_per_s"],
+                          nbytes / peaks["hbm_bytes_per_s"])
+            rows.append((kernel, ns / events / 1e3,
+                         100.0 * least_s * events / (ns / 1e9)))
+    return rows
+
+
+def kernel_table(device, names, blocks) -> None:
+    print(f"\n{'cell shape':>18} {'blocks':>9} {'kernel':>24} "
+          f"{'us a call':>10} {'roofline %':>10}")
+    for name in names:
+        job, kv_heads = CELL_SHAPES[name]
+        for bq, bk in blocks:
+            attn = lambda q, k, v: flash_attention(  # noqa: E731
+                q, k, v, True, bq, bk)
+            for kernel, us, pct in kernel_rows(attn, job, kv_heads, device):
+                print(f"{name:>18} {f'{bq}x{bk}':>9} {kernel:>24} "
+                      f"{us:10.1f} {pct:10.1f}", flush=True)
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--seqs", default="1024,2048,4096,8192")
     parser.add_argument("--iters", type=int, default=20)
+    parser.add_argument("--shapes", default=",".join(CELL_SHAPES))
+    parser.add_argument("--blocks", default="512x512")
     args = parser.parse_args()
 
     dev = require_tpu("flash_attention_bench.py")[0]
     print(f"device: {dev} ({dev.device_kind}); compile cache {CACHE_DIR}",
           file=sys.stderr)
     rows = []
-    for s in [int(x) for x in args.seqs.split(",")]:
+    for s in [int(x) for x in args.seqs.split(",") if x]:
         rng = jax.random.PRNGKey(s)
         kq, kk, kv = jax.random.split(rng, 3)
         shape = (B, s, H, D)
@@ -144,6 +220,10 @@ def main() -> None:
     for r in rows:
         print(f"{r['seq']:>6} {fmt(r['flash_fwd_ms'])} {fmt(r['xla_fwd_ms'])}"
               f" {fmt(r['flash_fwdbwd_ms'])} {fmt(r['xla_fwdbwd_ms'])}")
+
+    kernel_table(
+        dev, [x for x in args.shapes.split(",") if x],
+        [tuple(int(n) for n in b.split("x")) for b in args.blocks.split(",")])
 
 
 if __name__ == "__main__":

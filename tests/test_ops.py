@@ -7,6 +7,8 @@ call fails), so every flash test asks for interpret mode itself through the
 with ppermute.
 """
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -89,6 +91,87 @@ def test_flash_bf16(pallas_interpret):
     assert jnp.allclose(
         out.astype(jnp.float32), ref.astype(jnp.float32), atol=3e-2
     )
+
+
+def _out_and_grads(attn, q, k, v, g):
+    """(out, dq, dk, dv) of attn at q, k, v under the cotangent g."""
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out, *vjp(g))
+
+
+_RESULTS = ("out", "dq", "dk", "dv")
+_cases: dict = {}
+
+
+def _case(key, heads, kv_heads, d, dv, seq, blocks, causal, dtype):
+    """The kernels' and the reference's (out, dq, dk, dv) on one seeded
+    draw, both in float32 for the comparison: computed once a case, under
+    the caller's interpret mode, and shared by the four results' tests. The
+    reference runs in float32 on the same (rounded) inputs, so what is
+    compared is the kernels' own rounding."""
+    if key not in _cases:
+        keys = jax.random.split(
+            jax.random.PRNGKey(zlib.crc32(repr(key).encode())), 4)
+        q, k, v, g = (
+            jax.random.normal(rng, (2, seq, h, w), dtype)
+            for rng, h, w in zip(
+                keys, (heads, kv_heads, kv_heads, heads), (d, d, dv, dv)))
+        got = _out_and_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal, *blocks),
+            q, k, v, g)
+        assert [x.dtype for x in got] == [dtype] * 4
+        assert [x.shape for x in got] == [g.shape, q.shape, k.shape, v.shape]
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+        want = _out_and_grads(
+            lambda q, k, v: reference_attention(q, k, v, causal=causal),
+            *f32)
+        _cases[key] = dict(zip(_RESULTS, zip(
+            (x.astype(jnp.float32) for x in got), want)))
+    return _cases[key]
+
+
+# (query heads, key/value heads, width of q and k, width of v, blocks,
+# causal) at S 128, four query blocks or more: the first program (only a
+# diagonal tile) and the last run, tiles wholly below the diagonal and
+# tiles it crosses, and a tile that it leaves wholly dark for some rows.
+_BF16_CASES = {
+    "heads-128": (2, 2, 128, 128, (32, 32), True),
+    "keys-192-values-128": (2, 2, 192, 128, (32, 16), True),
+    "4-heads-on-1": (4, 1, 128, 128, (16, 32), True),
+    "not-causal": (2, 2, 128, 128, (32, 64), False),
+}
+
+
+@pytest.mark.parametrize("result", _RESULTS)
+@pytest.mark.parametrize("case", _BF16_CASES)
+def test_flash_bf16_out_and_grads(pallas_interpret, case, result):
+    """bfloat16 in, bfloat16 out: the MXU takes every operand of a product
+    as bfloat16, p and ds among them, so a result is off by roundings of
+    2^-9 that mostly average out over a row's keys, and by its own
+    rounding on the way out: held to 2^-6 of
+    the result's largest magnitude (measured 0.002 to 0.006 on these draws;
+    a mask, a block or a head off by one reads 0.3 or more)."""
+    heads, kv_heads, d, dv, blocks, causal = _BF16_CASES[case]
+    got, want = _case(case, heads, kv_heads, d, dv, 128, blocks, causal,
+                      jnp.bfloat16)[result]
+    worst = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    assert worst < 2 ** -6, worst
+
+
+# query blocks larger than key blocks and the other way round, equal, and
+# no power of two, causal and not, in float32: nothing is rounded, so the
+# old tolerances hold for every result
+_F32_BLOCKS = [(32, 16), (16, 32), (16, 16), (48, 32)]
+
+
+@pytest.mark.parametrize("result", _RESULTS)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", _F32_BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+def test_flash_f32_blocks(pallas_interpret, blocks, causal, result):
+    got, want = _case(("f32", blocks, causal), 2, 2, 16, 16, 96, blocks,
+                      causal, jnp.float32)[result]
+    atol = 1e-5 if result == "out" else 1e-4
+    assert jnp.allclose(got, want, atol=atol), float(jnp.abs(got - want).max())
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
