@@ -1,0 +1,207 @@
+"""Grouped-product benchmark: the products of an expert layer that holds a
+share of its experts (dynolog_tpu/models/moe.py, `ep == 1`), alone on the
+attached TPU chip, at the shapes of the two benchmark cells that run them
+(`nemotron-3-nano.capture`: 49152 copies of 2688 over 16 held experts of
+1856, two matrices an expert; `deepseek-v2-lite.capture`: 49152 copies of
+2048 over 16 of 1408, three). For each layout of the held rows (the groups
+as the sort leaves them, back to back; each group moved to the next multiple
+of 128, 256 or 512 rows, zero rows between, which is `moe._aligned`) and each
+product (`jax.lax.ragged_dot`, XLA's own kernel; the Pallas grouped product
+JAX ships, `megablox` `gmm` / `tgmm`, at a few tilings) it prints
+
+- the rows the products visit over the rows that exist, and the rows of
+  the buffer every gather and mask of the layer passes over: what the
+  layout pays, counts from the routing alone (over several seeds);
+- microseconds a call on the device's own clock of the product into the
+  expert (`in`: [rows, d] x [16, d, f]) and out of it (`out`), forward and
+  both transposes (the gradient for the rows and the one for the weights):
+  the module events of a profiler trace, one jitted program a direction;
+- what a step of the cell pays for its grouped products by those numbers
+  (the layer is rematerialised, so a product into the expert runs forward
+  twice).
+
+Routing is near even, as the cells' seeds route: a token's k distinct
+experts by seeded scores, an expert's load within a fifth of the mean
+(PERF.md section 6, PR 43). Kernel-level evidence beside the benchmark the
+driver runs (perfbench/). Runs on a TPU or not at all.
+
+Usage: python benchmarks/grouped_product_bench.py
+       [--shapes nemotron-3-nano,deepseek-v2-lite] [--aligns 0,128,256,512]
+       [--tilings 512x512x512,512x1024x1024] [--seeds 6]
+"""
+
+from __future__ import annotations
+
+import argparse
+import sys
+import tempfile
+from functools import partial
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(1, str(ROOT / "perfbench"))  # the benchmark's trace reducer
+
+from dynolog_tpu._jaxinit import enable_compile_cache, require_tpu
+
+CACHE_DIR = enable_compile_cache()
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+from dynolog_tpu.models import moe
+
+# tokens a step, choices a token, experts the router scores, experts held,
+# model width, expert width, matrices into an expert, expert blocks a step
+SHAPES = {
+    "nemotron-3-nano": dict(tokens=8192, k=6, experts=128, held=16, d=2688,
+                            f=1856, into=1, blocks=3),
+    "deepseek-v2-lite": dict(tokens=8192, k=6, experts=64, held=16, d=2048,
+                             f=1408, into=2, blocks=4),
+}
+SKEW = 0.08  # an expert's log load about the mean, standard deviation
+DIRECTIONS = ("fwd", "d_rows", "d_weights")
+
+
+def draw_groups(seed: int, shape: dict) -> np.ndarray:
+    """Rows for each held expert under one seeded routing of a step."""
+    rng = np.random.default_rng(seed)
+    scores = rng.gumbel(size=(shape["tokens"], shape["experts"]))
+    scores += SKEW * rng.normal(size=shape["experts"])
+    chosen = np.argpartition(-scores, shape["k"], axis=1)[:, :shape["k"]]
+    return np.bincount(
+        chosen.reshape(-1), minlength=shape["experts"])[:shape["held"]]
+
+
+def layout(sizes: np.ndarray, copies: int, align: int):
+    """(group_sizes for the products, which of the buffer's rows hold a
+    copy): the groups as they fall where `align` is 0, else the module's
+    aligned layout."""
+    sizes = jnp.asarray(sizes, jnp.int32)
+    if not align:
+        return sizes, jnp.arange(copies) < jnp.sum(sizes)
+    rounded, _, came = moe._aligned(sizes, copies, align)
+    return rounded, came < copies
+
+
+def programs(product):
+    """The three directions of `product(rows, weights, group_sizes)`, one
+    jitted program each, named so that a trace's module events tell them
+    apart."""
+
+    def fwd(rows, weights, sizes, ct):
+        return product(rows, weights, sizes)
+
+    def d_rows(rows, weights, sizes, ct):
+        return jax.vjp(lambda r: product(r, weights, sizes), rows)[1](ct)[0]
+
+    def d_weights(rows, weights, sizes, ct):
+        return jax.vjp(lambda w: product(rows, w, sizes), weights)[1](ct)[0]
+
+    return {f.__name__: jax.jit(f) for f in (fwd, d_rows, d_weights)}
+
+
+def module_us(path: str, name: str) -> float:
+    """Median device time of the executions of program `jit_<name>`."""
+    import xplane
+
+    plane = xplane.find_plane(xplane.load(path), xplane.device_plane_name(0))
+    line = xplane.find_line(plane, xplane.XLA_MODULES)
+    took = [ev.duration_ns for ev in xplane._events(line)
+            if ev.name.startswith(f"jit_{name}")]
+    if not took:
+        raise RuntimeError(f"no execution of jit_{name} in {path}")
+    return float(np.median(took)) / 1e3
+
+
+def time_products(product, shape: dict, sizes, real, calls: int = 5) -> dict:
+    """{"in" | "out": {direction: microseconds a call}} at one layout."""
+    n = real.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(n), 4)
+    out = {}
+    for side, (a, b), key in (("in", (shape["d"], shape["f"]), keys[:2]),
+                              ("out", (shape["f"], shape["d"]), keys[2:])):
+        rows = jnp.where(real[:, None], jax.random.normal(
+            key[0], (n, a), jnp.bfloat16), 0)
+        weights = (jax.random.normal(
+            key[1], (shape["held"], a, b)) / np.sqrt(a)).astype(jnp.bfloat16)
+        ct = jnp.where(real[:, None], jnp.ones((n, b), jnp.bfloat16), 0)
+        fns = programs(product)
+        for fn in fns.values():
+            jax.block_until_ready(fn(rows, weights, sizes, ct))  # compile
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for fn in fns.values():
+                    for _ in range(calls):
+                        done = fn(rows, weights, sizes, ct)
+                    jax.block_until_ready(done)
+            (path,) = Path(tmp).rglob("*.xplane.pb")
+            out[side] = {name: module_us(str(path), name) for name in fns}
+    return out
+
+
+def step_ms(shape: dict, us: dict) -> float:
+    """A step's grouped products: a product into the expert runs forward
+    twice (the layer is rematerialised), the one out of it once (nothing of
+    the backward pass reads its result)."""
+    layer = shape["into"] * (
+        2 * us["in"]["fwd"] + us["in"]["d_rows"] + us["in"]["d_weights"]
+    ) + sum(us["out"].values())
+    return shape["blocks"] * layer / 1e3
+
+
+def main() -> None:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--shapes", default=",".join(SHAPES))
+    parser.add_argument("--aligns", default="0,128,256,512")
+    parser.add_argument("--tilings", default="512x512x512,512x1024x1024")
+    parser.add_argument("--seeds", type=int, default=6)
+    args = parser.parse_args()
+    aligns = [int(a) for a in args.aligns.split(",")]
+    products = {"ragged_dot": jax.lax.ragged_dot}
+    for tiling in filter(None, args.tilings.split(",")):
+        products[f"gmm {tiling}"] = partial(
+            megablox.gmm, preferred_element_type=jnp.bfloat16,
+            tiling=tuple(int(t) for t in tiling.split("x")))
+
+    dev = require_tpu("grouped_product_bench.py")[0]
+    print(f"device: {dev} ({dev.device_kind}); compile cache {CACHE_DIR}",
+          file=sys.stderr)
+    for name in args.shapes.split(","):
+        shape = SHAPES[name]
+        copies = shape["tokens"] * shape["k"]
+        draws = [draw_groups(seed, shape) for seed in range(args.seeds)]
+        print(f"\n{name}: {copies} copies, held rows a step "
+              f"{min(d.sum() for d in draws)}-{max(d.sum() for d in draws)}, "
+              f"a group {min(d.min() for d in draws)}-"
+              f"{max(d.max() for d in draws)} rows ({args.seeds} seeds)")
+        print(f"{'layout':>12} {'buffer rows':>11} {'visited/exist':>13} "
+              f"{'product':>18} "
+              + " ".join(f"{side + '.' + d:>13}"
+                         for side in ("in", "out") for d in DIRECTIONS)
+              + f" {'ms a step':>10}")
+        for align in aligns:
+            paid = np.mean([
+                (-(-d // align) * align).sum() / d.sum() if align else 1.0
+                for d in draws])
+            sizes, real = layout(draws[0], copies, align)
+            label = (f"{f'aligned {align}' if align else 'as they fall':>12} "
+                     f"{real.shape[0]:11d} {paid:13.3f}")
+            for product_name, product in products.items():
+                try:
+                    us = time_products(product, shape, sizes, real)
+                except (jax.errors.JaxRuntimeError, ValueError) as e:
+                    # a tiling the compiler or the kernel refuses (fast
+                    # memory, a row count it does not divide) is a table cell
+                    print(f"{label} {product_name:>18} refused: "
+                          f"{str(e).splitlines()[0][:120]}", flush=True)
+                    continue
+                print(f"{label} {product_name:>18} "
+                      + " ".join(f"{us[side][d]:13.1f}"
+                                 for side in ("in", "out") for d in DIRECTIONS)
+                      + f" {step_ms(shape, us):10.2f}", flush=True)
+
+if __name__ == "__main__":
+    main()

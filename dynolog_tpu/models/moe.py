@@ -60,13 +60,35 @@ capture's ops carry them):
     moe.route     router, top-k, gates, the two loss terms
     moe.dispatch  the copies sorted by expert; under expert parallelism sent
                   to the chip that holds their expert (an all-to-all over
-                  `expert`) and put in expert order there
+                  `expert`) and put in expert order there; on one chip laid
+                  out from tile boundaries (below)
     moe.experts   three grouped matrix products (`jax.lax.ragged_dot`; on the
-                  TPU XLA lowers each to one Mosaic kernel that visits only
-                  the row tiles that exist) over the experts held here
+                  TPU XLA lowers each to one Mosaic kernel that walks the
+                  groups' rows a tile at a time) over the experts held here
     moe.combine   the way back (the second all-to-all), the copies unsorted
                   and summed under the gates
     moe.shared    the shared experts' SwiGLU, outside the dispatch
+
+Where one chip computes its own copies (`expert` 1 or no mesh: every expert
+or a share of them held), an expert's rows fill whole tiles. The sort leaves
+group e starting wherever the groups before it end, and the grouped product
+is handed the groups' lengths at run time: it cannot know where a boundary
+falls, only pay for it, a row tile that holds the end of one expert's rows
+and the start of the next's being worked once for each. At sixteen groups of
+384 +- 30 rows that was most of the kernel's time (11 TFLOP/s on a chip whose
+other products run at 85-124; PERF.md section 6, PR 43 and 46). So group e
+starts at the sum of the earlier groups' lengths EACH ROUNDED UP to a
+multiple of ALIGN, zero rows between its last copy and the next boundary,
+and the products are handed the rounded lengths. The buffer is static and
+holds the worst routing, copies + held x ALIGN rows (every copy on held
+experts, every group one partial tile): no capacity, nothing dropped, one
+path whatever the router does. The layout costs no pass over rows: it is
+folded into the two index vectors the copies are gathered by on the way in
+and on the way back (`_aligned`, from the groups' lengths alone). A padding
+row is a zero row and no token's place points at it: it multiplies to zero
+through either activation, adds nothing to an expert's weight gradient, and
+a copy for an expert that is not here lands nowhere and comes back as zero,
+so the output, the loss and every gradient are those of the plain sum.
 
 Under a mesh the layer is a `shard_map`: each chip routes its own tokens
 over all E experts and holds E / expert of them, with the experts' hidden
@@ -79,7 +101,10 @@ all-to-all). The two share everything but that one call. With `expert` 1,
 or no mesh, the layer runs without its exchange.
 
 The layer is rematerialised (`jax.checkpoint`): its worst-case buffers are
-0.5 GB each at OLMoE's widths and a step keeps only the layer's input.
+0.5 GB each at OLMoE's widths and a step keeps only the layer's input, and
+on one chip the layout beside it (the rounded lengths and the two index
+vectors, 0.5 MB: the backward pass sorts and lays out nothing again, which
+also keeps the maps out of a capture's metadata a second time).
 """
 
 from __future__ import annotations
@@ -89,9 +114,21 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from dynolog_tpu.parallel.sharding import BATCH_AXES, PARAM_RULES
+
+# The rows a group's start is rounded up to where one chip computes its own
+# copies (module docstring). XLA's grouped product works the rows by tiles
+# of 256 on a v5e: from boundaries of 128 a tile still straddles two experts
+# and nothing is gained (benchmarks/grouped_product_bench.py; PERF.md
+# section 6, PR 46). 512 and not 256, because a group whose length at even
+# routing is a whole number of tiles (768 rows an expert) spills into one
+# more tile or not by the seed at 256 and the step follows it; at 512 both
+# jobs that hold a share keep a third of a group as headroom. A constant of
+# the kernel, not of a job.
+ALIGN = 512
 
 
 def init_moe_layer(rng, cfg):
@@ -172,6 +209,37 @@ def _regroup(sizes, lands_at, n: int, nowhere: int):
         sizes.shape[0] - 1)
     return jnp.where(
         at < ends[-1], lands_at[seg] + at - (ends - sizes)[seg], nowhere)
+
+
+def _aligned(sizes, n_sorted: int, align: int):
+    """The layout of the module docstring, from the groups' lengths alone.
+    The copies sorted by expert hold the groups `sizes` long back to back
+    from place 0 of `n_sorted`; in the buffer group e starts at the sum of
+    the earlier groups' lengths each rounded up to a multiple of `align`.
+    Returns (rounded, lands, came): the rounded lengths; for each sorted
+    place the buffer's row it lands at (the buffer's length,
+    n_sorted + len(sizes) * align: a copy of no group, which lands nowhere);
+    for each of the buffer's rows the sorted place it came from (n_sorted: a
+    row of padding, which came from nowhere). A row has moved by the padding
+    of the groups before it: compares and sums over the groups, no gather
+    from a table of sixteen (XLA:TPU writes one out as sixteen selects)."""
+    n = n_sorted + sizes.shape[0] * align
+    rounded = jax.lax.div(sizes + (align - 1), align) * align
+    pad = rounded - sizes
+    ends, rounded_ends = jnp.cumsum(sizes), jnp.cumsum(rounded)
+    at = jnp.arange(n_sorted, dtype=jnp.int32)[:, None]
+    lands = jnp.where(
+        at[:, 0] < ends[-1],
+        at[:, 0] + jnp.sum(jnp.where(at >= ends, pad, 0), axis=1), n)
+    row = jnp.arange(n, dtype=jnp.int32)[:, None]
+    before = row >= rounded_ends  # the groups that end before this row
+    # in a group's padding the row has passed that group's last copy too
+    copy = (row[:, 0] < rounded_ends[-1]) & (
+        jnp.sum(row >= rounded_ends - pad, axis=1) == jnp.sum(before, axis=1))
+    came = jnp.where(
+        copy, row[:, 0] - jnp.sum(jnp.where(before, pad, 0), axis=1),
+        n_sorted)
+    return rounded, lands, came
 
 
 def _exchange_rows(rows, send_sizes, recv_sizes, n_out, axis, ragged):
@@ -298,24 +366,27 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
 
     with jax.named_scope("moe.dispatch"):
         expert_of = chosen.reshape(copies)
-        share = ep == 1 and held < cfg.n_experts
-        if share:
+        if ep == 1:
             # counted from the first expert held, round the E: the copies
             # for the experts held here sort to the front, in their order
             expert_of = (expert_of - cfg.first_expert_held) % cfg.n_experts
         order = jnp.argsort(expert_of).astype(jnp.int32)  # stable
         place = jnp.argsort(order).astype(jnp.int32)  # where each copy went
-        rows = take_rows(h, order // k, place, k)  # [copies, D] by expert
         group_sizes = _count(expert_of, cfg.n_experts)
-        if share:
-            group_sizes = group_sizes[:held]
-            there = (jnp.arange(copies) < jnp.sum(group_sizes))[:, None]
-            # A copy for an expert that is not here is a zero row, and so is
-            # what comes back for it: the grouped products' transpose writes
-            # no row past its groups, and what the buffer holds there would
-            # flow into every token's gradient (NaN in two of six runs on
-            # the chip).
-            rows = jnp.where(there, rows, 0)
+        if ep == 1:
+            # `place`: a copy's row of the aligned buffer, or its length (n)
+            # for an expert that is not here; `order`: a row's copy, or
+            # `copies` for padding. Both read as a zero row, forward and
+            # back: a copy for an expert that is not here adds nothing.
+            group_sizes, lands, came = _aligned(
+                group_sizes[:held], copies, ALIGN)
+            n = copies + held * ALIGN
+            place = lands[place]
+            order = order.at[came].get(mode="fill", fill_value=copies)
+            group_sizes, place, order = (
+                checkpoint_name(a, "moe.layout")
+                for a in (group_sizes, place, order))
+        rows = take_rows(h, order // k, place, k)  # by expert
         if ep > 1:
             # sent[source chip, expert held here]
             sent = jax.lax.all_gather(group_sizes, "expert").reshape(
@@ -336,23 +407,24 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
                 n, n)
             rows = take_rows(rows, to_expert, to_source)
             group_sizes = sent.sum(axis=0)
-            there = (jnp.arange(n) < jnp.sum(group_sizes))[:, None]
+        there = (jnp.arange(n) < jnp.sum(group_sizes))[:, None]
 
     def product(lhs, rhs):
-        out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
         # A grouped product writes no row past its groups: what the buffer
         # holds there (and what the transpose hands back for it) is not
-        # ours, and 0 x NaN is NaN. Rows that are not there are zeros.
-        return jnp.where(there, out, 0) if ep > 1 or share else out
+        # ours, and 0 x NaN is NaN (two of six runs on the chip lost their
+        # loss to it). Rows that are not there are zeros.
+        return jnp.where(
+            there, jax.lax.ragged_dot(lhs, rhs, group_sizes), 0)
 
     with jax.named_scope("moe.experts"):
         if w_gate is None:
             act = jnp.square(jax.nn.relu(product(rows, w_up)))
         else:
             act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        # no mask: the way back reads no row past the groups, and its
+        # transpose hands zeros for them
         out = jax.lax.ragged_dot(act, w_down, group_sizes)
-        if share:  # a copy for an expert that is not here adds nothing
-            out = jnp.where(there, out, 0)
 
     with jax.named_scope("moe.combine"):
         if ep > 1:
@@ -400,7 +472,11 @@ def moe_mlp(layer, x, cfg, mesh=None):
                       tuple(PARAM_RULES[name] for name in experts),
                       token_spec),
             out_specs=(token_spec, P(), P()), check_vma=False)
-    y, balance, z = jax.checkpoint(local)(
+    # one chip keeps its layout beside the layer's input (module docstring)
+    keep = jax.checkpoint_policies.save_only_these_names("moe.layout")
+    exchanges = mesh is not None and mesh.shape["expert"] > 1
+    y, balance, z = jax.checkpoint(
+        local, policy=None if exchanges else keep)(
         tuple(layer[name] for name in routing),
         tuple(layer[name] for name in experts), x)
     if cfg.n_shared_experts:

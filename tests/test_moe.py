@@ -1,6 +1,8 @@
 """The dropless expert layer (dynolog_tpu/models/moe.py) against its
 equations written plainly, on the CPU in float32: alone under forced uneven
-routings, over a four-device `expert` mesh against one device (output, loss,
+routings, the tile-aligned layout of one chip's own copies (its index maps
+from counts alone, and a held share under routings that fill and starve its
+groups), over a four-device `expert` mesh against one device (output, loss,
 one step's weights, and the four shares' parts adding up to the whole), and
 what the block gained for OLMoE's config (q/k norm, unrenormalised gates,
 the balancing term over all k choices, the router z-loss) against the plain
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynolog_tpu.models import moe
 from dynolog_tpu.models.moe import init_moe_layer, moe_mlp
 from dynolog_tpu.models.train import (
     make_batch, make_train_state, make_train_step)
@@ -56,10 +59,17 @@ def plain_layer(layer, x, cfg, held=None):
     picks = jax.nn.one_hot(chosen, cfg.n_experts)  # [T, k, E]
     gates = (best[..., None] * picks).sum(1)  # [T, E]
     y = jnp.zeros_like(h)
+    if cfg.n_experts_held:  # the layer's matrices are its share's alone
+        held = range(cfg.first_expert_held,
+                     cfg.first_expert_held + cfg.n_experts_held)
     for e in range(cfg.n_experts) if held is None else held:
-        act = jax.nn.silu(h @ layer["experts_gate"][e]) * (
-            h @ layer["experts_up"][e])
-        y = y + gates[:, e:e + 1] * (act @ layer["experts_down"][e])
+        i = e - cfg.first_expert_held
+        if cfg.mlp_act == "relu2":
+            act = jnp.square(jax.nn.relu(h @ layer["experts_up"][i]))
+        else:
+            act = jax.nn.silu(h @ layer["experts_gate"][i]) * (
+                h @ layer["experts_up"][i])
+        y = y + gates[:, e:e + 1] * (act @ layer["experts_down"][i])
     counted = picks if cfg.moe_balance_all_k else picks[:, :1]
     balance = cfg.n_experts * jnp.sum(
         counted.mean((0, 1)) * probs.mean(0))
@@ -67,11 +77,11 @@ def plain_layer(layer, x, cfg, held=None):
     return y.reshape(x.shape), balance, z
 
 
-def forced_layer(favoured, starved=None, strength=10.0):
+def forced_layer(favoured, starved=None, strength=10.0, cfg=CFG, lean=4.0):
     """A layer whose router sends every token's first choices to
     `favoured` and none to `starved`: the inputs below share a direction
-    `u`, and the router reads it."""
-    layer = init_moe_layer(jax.random.PRNGKey(3), CFG)
+    `u` (as far as `lean`), and the router reads it."""
+    layer = init_moe_layer(jax.random.PRNGKey(3), cfg)
     u = jnp.ones((CFG.d_model,)) / np.sqrt(CFG.d_model)
     router = layer["router"]
     for rank, e in enumerate(favoured):
@@ -79,7 +89,7 @@ def forced_layer(favoured, starved=None, strength=10.0):
     if starved is not None:
         router = router.at[:, starved].add(-strength * u)
     x = 0.5 * jax.random.normal(
-        jax.random.PRNGKey(4), (4, 16, CFG.d_model)) + 4.0 * u
+        jax.random.PRNGKey(4), (4, 16, CFG.d_model)) + lean * u
     return dict(layer, router=router), x
 
 
@@ -87,6 +97,14 @@ def close(a, b, tol=1e-5):
     scale = float(jnp.max(jnp.abs(b))) + 1e-12
     assert float(jnp.max(jnp.abs(a - b))) <= tol * scale, (
         float(jnp.max(jnp.abs(a - b))), scale)
+
+
+def scalar(f, cfg):
+    """A number that reads all of the layer `f` returns."""
+    def g(layer, x):
+        y, balance, z = f(layer, x, cfg)
+        return jnp.sum(jnp.sin(y)) + balance + z
+    return g
 
 
 def assert_trees_close(got, want, tol=1e-5):
@@ -105,16 +123,153 @@ def test_uneven_routing_equals_the_plain_sum_forward_and_gradients():
     counts = np.bincount(np.asarray(chosen).reshape(-1), minlength=8)
     assert counts[5] == 64 and counts[2] == 0 and len(set(counts)) > 2
 
-    def scalar(f):
-        def g(layer, x):
-            y, balance, z = f(layer, x, CFG)
-            return jnp.sum(jnp.sin(y)) + balance + z
-        return g
-
     close(moe_mlp(layer, x, CFG)[0], plain_layer(layer, x, CFG)[0])
-    got = jax.grad(scalar(moe_mlp), argnums=(0, 1))(layer, x)
-    want = jax.grad(scalar(plain_layer), argnums=(0, 1))(layer, x)
+    got = jax.grad(scalar(moe_mlp, CFG), argnums=(0, 1))(layer, x)
+    want = jax.grad(scalar(plain_layer, CFG), argnums=(0, 1))(layer, x)
     assert_trees_close(got, want)
+
+
+def _counts(kind, held, n_sorted, rng):
+    if kind == "none":
+        return np.zeros(held, int)
+    if kind == "one-group":  # one expert holds every copy there is
+        return np.bincount([rng.integers(held)] * n_sorted, minlength=held)
+    if kind == "all-held":  # every copy is some held expert's
+        return np.bincount(rng.integers(0, held, n_sorted), minlength=held)
+    return np.bincount(  # some copies are for experts that are not here
+        rng.integers(0, held, rng.integers(0, n_sorted + 1)), minlength=held)
+
+
+@pytest.mark.parametrize("align", [1, 8, moe.ALIGN])
+@pytest.mark.parametrize("kind", ["none", "one-group", "all-held", "some"])
+def test_the_aligned_maps_from_counts_alone(kind, align):
+    """Every group starts on a multiple of `align`, each copy of a group
+    lands once, in order, and what is left of the buffer is nowhere both
+    ways: no place lands in padding and padding came from no place."""
+    rng = np.random.default_rng(align)
+    for _ in range(12):
+        held, n_sorted = int(rng.integers(1, 7)), int(rng.integers(1, 80))
+        sizes = _counts(kind, held, n_sorted, rng)
+        rounded, lands, came = (np.asarray(a) for a in moe._aligned(
+            jnp.asarray(sizes, jnp.int32), n_sorted, align))
+        n = n_sorted + held * align
+        assert lands.shape == (n_sorted,) and came.shape == (n,)
+        assert (rounded % align == 0).all()
+        assert ((0 <= rounded - sizes) & (rounded - sizes < align)).all()
+        starts = np.cumsum(rounded) - rounded
+        assert (starts % align == 0).all() and rounded.sum() <= n
+        want_lands = np.full(n_sorted, n)
+        want_came = np.full(n, n_sorted)
+        sorted_starts = np.cumsum(sizes) - sizes
+        for e in range(held):
+            for i in range(sizes[e]):
+                want_lands[sorted_starts[e] + i] = starts[e] + i
+                want_came[starts[e] + i] = sorted_starts[e] + i
+        np.testing.assert_array_equal(lands, want_lands)
+        np.testing.assert_array_equal(came, want_came)
+        there = lands < n  # the maps undo each other where a row is real
+        np.testing.assert_array_equal(
+            came[lands[there]], np.arange(n_sorted)[there])
+        assert (came < n_sorted).sum() == sizes.sum()
+
+
+# experts 2-5 of the eight are held, as one chip of an expert-parallel
+# layer holds them
+SHARE = TransformerConfig(**{
+    **CFG.__dict__, "n_experts_held": 4, "first_expert_held": 2})
+ROUTINGS = {
+    # nothing forced: an eighth of the copies an expert, half of them held
+    "even": dict(favoured=[], lean=0.0),
+    # every copy of every token on held experts: the buffer's worst case
+    "all-on-held": dict(favoured=[3, 4]),
+    # every first choice on ONE held expert, the second not held: one group
+    # of many tiles, three empty ones
+    "one-held-expert": dict(favoured=[5, 7]),
+}
+
+
+@pytest.mark.parametrize("align", [8, moe.ALIGN])
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_a_held_share_equals_the_plain_sum_forward_and_gradients(
+        routing, align, monkeypatch):
+    """The share's output, the gradient for its input and for every held
+    expert's matrices, under routings that spread, fill and starve its
+    groups; at a tile of 8 rows a group is several tiles and ends inside
+    one, at the module's own every group is one partial tile."""
+    monkeypatch.setattr(moe, "ALIGN", align)
+    layer, x = forced_layer(cfg=SHARE, **ROUTINGS[routing])
+    chosen = np.asarray(jax.lax.top_k(
+        x.reshape(-1, 32) @ layer["router"], 2)[1])
+    held = (chosen >= 2) & (chosen < 6)
+    if routing == "all-on-held":
+        assert held.all()
+    elif routing == "one-held-expert":
+        assert set(chosen[held]) == {5} and held.sum() == 64
+    else:
+        assert 0 < held.sum() < held.size and len(set(chosen[held])) == 4
+    assert layer["experts_up"].shape[0] == 4
+    got_y = moe_mlp(layer, x, SHARE)[0]
+    close(got_y, plain_layer(layer, x, SHARE)[0])
+    got = jax.grad(scalar(moe_mlp, SHARE), argnums=(0, 1))(layer, x)
+    want = jax.grad(scalar(plain_layer, SHARE), argnums=(0, 1))(layer, x)
+    assert_trees_close(got, want)
+    for name in ("experts_gate", "experts_up", "experts_down"):
+        for e in range(4):  # an expert no copy reached learns nothing
+            reached = bool((chosen == 2 + e).any())
+            assert bool(jnp.any(got[0][name][e] != 0)) == reached, (name, e)
+
+
+def test_a_token_no_held_expert_takes_gets_exactly_nothing():
+    """Its copies land nowhere and come from nowhere: the routed part of
+    its output is 0.0, not the rounding of something small."""
+    layer, x = forced_layer(cfg=SHARE, **ROUTINGS["even"])
+    chosen = np.asarray(jax.lax.top_k(
+        x.reshape(-1, 32) @ layer["router"], 2)[1])
+    none_held = ~((chosen >= 2) & (chosen < 6)).any(axis=1)
+    assert 4 < none_held.sum() < 60
+    y = np.asarray(moe_mlp(layer, x, SHARE)[0]).reshape(-1, 32)
+    assert (y[none_held] == 0.0).all()
+    assert (np.abs(y[~none_held]).max(axis=1) > 1e-4).all()
+
+
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+def test_what_lies_past_the_groups_reaches_no_gradient(act, monkeypatch):
+    """On the TPU a grouped product writes no row past its (rounded)
+    groups, forward or transposed. Here those rows are made NaN; neither
+    the output nor any gradient may see them, with experts of three
+    matrices and of two, and groups that end inside a tile."""
+    real = jax.lax.ragged_dot
+
+    def past(x, group_sizes):
+        rows = jnp.arange(x.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(group_sizes), x, jnp.nan)
+
+    @jax.custom_vjp
+    def dirty(lhs, rhs, group_sizes):
+        return real(lhs, rhs, group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        return past(real(lhs, rhs, group_sizes), group_sizes), (
+            lhs, rhs, group_sizes)
+
+    def bwd(res, ct):
+        lhs, rhs, group_sizes = res
+        d_lhs, d_rhs = jax.vjp(
+            lambda a, b: real(a, b, group_sizes), lhs, rhs)[1](ct)
+        return past(d_lhs, group_sizes), d_rhs, None
+
+    dirty.defvjp(fwd, bwd)
+    monkeypatch.setattr(moe, "ALIGN", 8)
+    cfg = TransformerConfig(**{**SHARE.__dict__, "mlp_act": act})
+    layer, x = forced_layer(cfg=cfg, **ROUTINGS["even"])
+    assert ("experts_gate" in layer) == (act == "swiglu")
+    want = jax.grad(scalar(moe_mlp, cfg), argnums=(0, 1))(layer, x)
+    close(moe_mlp(layer, x, cfg)[0], plain_layer(layer, x, cfg)[0])
+    monkeypatch.setattr(jax.lax, "ragged_dot", dirty)
+    got = jax.grad(scalar(moe_mlp, cfg), argnums=(0, 1))(layer, x)
+    assert all(bool(jnp.all(jnp.isfinite(g)))
+               for g in jax.tree_util.tree_leaves(got))
+    assert_trees_close(got, want, tol=1e-6)
 
 
 @pytest.mark.parametrize("spec", [
