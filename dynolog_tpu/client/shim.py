@@ -16,7 +16,7 @@ giving the daemon's metric history — and its auto-trigger rules — an
 application-level job<id>.* signal. With DYNO_TPU_RING_EVERY_N set (or a
 RingConfig passed in), the shim also runs a continuous capture ring:
 1-in-N steps it samples a short window, promotes the XSpace to a compact
-op-level profile under the convert budget, and retains the newest K per
+op-level profile, and retains the newest K per
 model in a TTL'd ring directory — the always-on feed
 `python -m dynolog_tpu.diagnose --ring` diagnoses (see docs/DIAGNOSIS.md).
 
@@ -316,8 +316,8 @@ class RingConfig:
 class CaptureRing:
     """Rolling, sampled profile ring: every 1-in-N training steps
     (rate-capped), capture a short window and *promote* the raw XSpace
-    to a compact op-level profile (trace.compact_profile, under the
-    PR 2 ConvertBudget), retaining the newest K per model in a TTL'd
+    to a compact op-level profile (trace.compact_profile, in this
+    process), retaining the newest K per model in a TTL'd
     ring directory. The raw xspace and its temp session dir are deleted
     after promotion — the ring stores diagnosis-ready summaries, not
     trace trees, so always-on profiling costs kilobytes, not gigabytes.
@@ -399,8 +399,7 @@ class CaptureRing:
                 with open(xplanes[-1], "rb") as f:
                     data = f.read()
                 profile = trace_mod.compact_profile(
-                    data, top=self.config.top_ops,
-                    budget=trace_mod.ConvertBudget.from_env())
+                    data, top=self.config.top_ops)
             path = self._store(profile)
             self.captures += 1
             self.last_path = path
@@ -596,7 +595,7 @@ class PendingWrite:
         self.span: obs.Span | None = None  # shim.xplane_write, once done
         # `xspace`: the whole serialized XSpace the chunks are views of,
         # where the feeder holds it; the writer then lists its planes
-        # ({"name", "bytes"} each, trace.plane_index) for the manifest.
+        # ({"name", "bytes"} each, xspace.plane_index) for the manifest.
         self._xspace = xspace
         self.planes: list | None = None
         self.index_span: obs.Span | None = None  # shim.plane_index
@@ -641,14 +640,14 @@ class PendingWrite:
         a few fields a plane, so four device planes of megabytes cost what
         one does. An XSpace that does not parse costs the capture nothing
         but the rows."""
-        from dynolog_tpu import trace as trace_mod
+        from dynolog_tpu.xspace import plane_index
 
         xspace, self._xspace = self._xspace, None
         if xspace is None:
             return
         with obs.span("shim.plane_index", ctx=ctx) as index:
             try:
-                self.planes = trace_mod.plane_index(xspace)
+                self.planes = plane_index(xspace)
             except ValueError:
                 pass
         self.index_span = index
@@ -803,8 +802,9 @@ class JaxProfiler:
     `python -m dynolog_tpu.trace` read) to disk in chunks in milliseconds,
     then produces the same derived trace.json.gz from a deprioritized
     background process (no GIL stolen from the training loop) running the
-    streamed, CPU-budgeted converter (trace.ConvertBudget; TRACE_CONVERT_*
-    config keys tune it per capture — see docs/TRACE_PIPELINE.md). That
+    streamed converter (trace.ConvertBudget: its one setting, how many
+    processes may convert at a time, a capture sets by the
+    TRACE_CONVERT_WORKERS config key — see docs/TRACE_PIPELINE.md). That
     process is started as the capture's window opens (`warm_export`), so
     its interpreter and imports are done while the window and the drain
     last, and it is handed the artifact's path as the write completes.
@@ -824,8 +824,8 @@ class JaxProfiler:
         self.export_trace_json = export_trace_json
         self._default_export = export_trace_json
         self.tracer_levels: dict[str, int] = {}
-        # Converter CPU-budget env overrides for the export subprocess
-        # (TRACE_CONVERT_* config keys -> DYNO_TRACE_CONVERT_* env).
+        # What a capture's TRACE_CONVERT_WORKERS config key adds to the
+        # export subprocess's environment (trace.ConvertBudget.from_env).
         self.convert_env: dict[str, str] = {}
         self._sess = None
         self._local_devices: int | None = None
@@ -845,15 +845,6 @@ class JaxProfiler:
         # the writer thread's span parents to the request. None (ring
         # samples, warmup): no marks, and the ambient context instead.
         self.obs_ctx: obs.TraceContext | None = None
-
-    # Config key -> the converter budget env var the export child reads
-    # (trace.ConvertBudget.from_env).
-    _CONVERT_KEYS = {
-        "TRACE_CONVERT_WORKERS": "DYNO_TRACE_CONVERT_WORKERS",
-        "TRACE_CONVERT_GZIP_LEVEL": "DYNO_TRACE_CONVERT_GZIP_LEVEL",
-        "TRACE_CONVERT_NICE": "DYNO_TRACE_CONVERT_NICE",
-        "TRACE_CONVERT_YIELD_S": "DYNO_TRACE_CONVERT_YIELD_S",
-    }
 
     def configure(self, raw: dict) -> None:
         """Applies per-capture options from the on-demand config text.
@@ -875,9 +866,9 @@ class JaxProfiler:
         if "TRACE_JSON" in raw:
             self.export_trace_json = raw["TRACE_JSON"].lower() not in (
                 "0", "false", "no")
-        for key, env_key in self._CONVERT_KEYS.items():
-            if key in raw:
-                self.convert_env[env_key] = raw[key]
+        if "TRACE_CONVERT_WORKERS" in raw:
+            self.convert_env["DYNO_TRACE_CONVERT_WORKERS"] = raw[
+                "TRACE_CONVERT_WORKERS"]
 
     def start(self, trace_dir: str) -> None:
         import jax
@@ -1038,8 +1029,8 @@ class JaxProfiler:
         env = dict(os.environ)
         env["PYTHONPATH"] = pkg_parent + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        # Per-capture converter budget (TRACE_CONVERT_* config keys): the
-        # child's ConvertBudget.from_env picks these up.
+        # Per-capture converter setting (TRACE_CONVERT_WORKERS config
+        # key): the child's ConvertBudget.from_env picks it up.
         env.update(self.convert_env)
         # Self-tracing hand-off: the capture's span context (handed in,
         # since the hand-over runs on the writer thread, whose ambient
@@ -1097,7 +1088,7 @@ class JaxProfiler:
             return {"export_child": "cold"}
         self._export_thread = threading.Thread(
             target=self._export_json,
-            args=(xplane_path, dict(self.convert_env)),
+            args=(xplane_path,),
             name="dynolog_tpu_trace_export",
             daemon=True,
         )
@@ -1105,23 +1096,17 @@ class JaxProfiler:
         return {"export_child": "thread"}
 
     @staticmethod
-    def _export_json(
-        xplane_path: str, convert_env: dict | None = None
-    ) -> None:
+    def _export_json(xplane_path: str) -> None:
         try:
             from dynolog_tpu import trace as trace_mod
 
-            # In-process thread fallback. The per-capture TRACE_CONVERT_*
-            # knobs only exist in convert_env (normally applied to the
-            # export CHILD's environment), so merge them over the process
-            # env here — and force the serial converter: a process pool
-            # forks, and forking from a thread of a process full of XLA
-            # runtime threads is deadlock-prone (the same reason
-            # _spawn_export avoids preexec_fn).
-            budget = trace_mod.ConvertBudget.from_env(
-                {**os.environ, **(convert_env or {})})
-            budget.max_workers = 1
-            trace_mod.write_derived_artifacts(xplane_path, budget)
+            # In-process thread fallback: the serial converter whatever
+            # the setting says. A process pool forks, and forking from a
+            # thread of a process full of XLA runtime threads is
+            # deadlock-prone (the same reason _spawn_export avoids
+            # preexec_fn).
+            trace_mod.write_derived_artifacts(
+                xplane_path, trace_mod.ConvertBudget(max_workers=1))
         except Exception:  # noqa: BLE001 - derived artifacts only; the
             # xplane.pb (the canonical trace) is already on disk.
             pass

@@ -2,12 +2,10 @@
 
 The daemon + shim capture traces (`dyno gputrace` → jax.profiler); this
 module answers the operator's next question — *what did the device spend
-its time on* — without TensorBoard: it decodes the profiler's XSpace
-protobuf directly (pure stdlib, each plane once, by offsets into the one
-buffer: no tensorflow/protobuf dependency; field numbers verified against
-traces captured by this repo's own e2e flow and against the wheel's
-descriptor) and prints per-plane op aggregates; the same decode feeds the
-Chrome trace the shim's export child writes beside the summary.
+its time on* — without TensorBoard: from the one decode of each plane that
+`dynolog_tpu.xspace` makes (the module that knows the XSpace wire format;
+pure stdlib) it prints per-plane op aggregates, and the same decode feeds
+the Chrome trace the shim's export child writes beside the summary.
 
 CLI::
 
@@ -25,163 +23,21 @@ import glob
 import json
 import math
 import os
-import struct
 import sys
 import threading
 import time
 from dataclasses import dataclass, field, replace
 
 from dynolog_tpu import obs
-
-# Protobuf fixed64 stat values decode as little-endian doubles. Module
-# level (not an inline struct.unpack format) per the dynolint
-# struct-constant rule.
-FLOAT64 = struct.Struct("<d")
-
-# The XSpace schema subset the decoder reads, message -> {field name: pinned
-# number}. Originally pinned empirically against traces this repo's own e2e
-# flow captures; now also verifiable against the xplane FileDescriptor
-# embedded in the installed wheel (verify_schema_pins() — a jax upgrade that
-# renumbers a field fails loudly instead of silently mis-summarizing).
-_SCHEMA_PINS = {
-    "XSpace": {"planes": 1},
-    "XPlane": {
-        "name": 2, "lines": 3, "event_metadata": 4, "stat_metadata": 5,
-        "stats": 6,
-    },
-    "XLine": {"id": 1, "name": 2, "timestamp_ns": 3, "events": 4},
-    "XEvent": {
-        "metadata_id": 1, "offset_ps": 2, "duration_ps": 3, "stats": 4,
-    },
-    "XEventMetadata": {"id": 1, "name": 2, "display_name": 4, "stats": 5},
-    "XStat": {
-        "metadata_id": 1, "double_value": 2, "uint64_value": 3,
-        "int64_value": 4, "str_value": 5, "ref_value": 7,
-    },
-    "XStatMetadata": {"id": 1, "name": 2},
-}
-
-
-def _load_xplane_descriptor():
-    """Loads the generated xplane_pb2 module from an installed wheel
-    WITHOUT importing the heavyweight package around it (the generated
-    code needs only google.protobuf; ~80ms vs ~15s for `import
-    tensorflow`). Returns the module or None."""
-    import importlib.util
-
-    candidates = [
-        ("tensorflow", "tsl/profiler/protobuf/xplane_pb2.py"),
-        ("tensorflow", "core/profiler/protobuf/xplane_pb2.py"),
-        ("tensorboard_plugin_profile", "protobuf/xplane_pb2.py"),
-        ("xprof", "protobuf/xplane_pb2.py"),
-    ]
-    for pkg, rel in candidates:
-        try:
-            spec = importlib.util.find_spec(pkg)
-        except (ImportError, ValueError):
-            continue
-        if not spec or not spec.submodule_search_locations:
-            continue
-        for root in spec.submodule_search_locations:
-            path = os.path.join(root, rel)
-            if not os.path.exists(path):
-                continue
-            try:
-                mspec = importlib.util.spec_from_file_location(
-                    "dynolog_tpu._xplane_pb2", path)
-                mod = importlib.util.module_from_spec(mspec)
-                mspec.loader.exec_module(mod)
-                return mod
-            except Exception:  # noqa: BLE001 - any wheel/protobuf
-                continue  # incompatibility: try the next candidate
-    return None
-
-
-def verify_schema_pins() -> tuple[bool | None, list[str]]:
-    """Cross-checks _SCHEMA_PINS against the embedded FileDescriptor.
-    Returns (ok, mismatches); ok is None when no wheel ships a
-    descriptor to check against (the pins stand as-is)."""
-    mod = _load_xplane_descriptor()
-    if mod is None:
-        return None, []
-    mismatches = []
-    for msg_name, fields in _SCHEMA_PINS.items():
-        msg = getattr(mod, msg_name, None)
-        if msg is None:
-            mismatches.append(f"{msg_name}: message missing from descriptor")
-            continue
-        by_name = {f.name: f.number for f in msg.DESCRIPTOR.fields}
-        for fname, pinned in fields.items():
-            actual = by_name.get(fname)
-            if actual != pinned:
-                mismatches.append(
-                    f"{msg_name}.{fname}: pinned field {pinned}, "
-                    f"wheel descriptor says {actual}")
-    return (not mismatches), mismatches
-
-
-def _read_varint(buf, i: int) -> tuple[int, int]:
-    value = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        value |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return value, i
-        shift += 7
-
-
-def _fields(buf, i: int, end: int) -> list[tuple[int, int, int, int]]:
-    """The fields of the message at buf[i:end], decoded in one loop over the
-    one buffer the file was read into: (number, wire type, a, b). A varint's
-    `a` is its value; a length-delimited or fixed field's `a` is where its
-    payload starts, cut out (`buf[a:b]`) only where it is used: a name, never
-    HLO bytes. `b` is where the field ends, so a message's fields tile
-    [i, end). Raises ValueError on truncated or malformed input."""
-    out = []
-    add = out.append
-    try:
-        while i < end:
-            tag = buf[i]
-            i += 1
-            if tag > 0x7F:  # a field number above 15
-                tag, i = _read_varint(buf, i - 1)
-            if tag < 8:
-                raise ValueError("field 0")
-            wt = tag & 7
-            if wt == 0:
-                v = buf[i]
-                i += 1
-                if v > 0x7F:  # inlined: offsets and durations take this path
-                    v &= 0x7F
-                    shift = 7
-                    while True:
-                        b = buf[i]
-                        i += 1
-                        v |= (b & 0x7F) << shift
-                        if b < 0x80:
-                            break
-                        shift += 7
-                add((tag >> 3, 0, v, i))
-            elif wt == 2:
-                size = buf[i]
-                i += 1
-                if size > 0x7F:
-                    size, i = _read_varint(buf, i - 1)
-                add((tag >> 3, 2, i, i + size))
-                i += size
-            elif wt == 1 or wt == 5:
-                width = 8 if wt == 1 else 4
-                add((tag >> 3, wt, i, i + width))
-                i += width
-            else:
-                raise ValueError(f"unsupported wire type {wt}")
-    except IndexError as e:
-        raise ValueError("truncated message") from e
-    if i != end:  # the last field runs past the message's end
-        raise ValueError("truncated field")
-    return out
-
+from dynolog_tpu.xspace import (
+    CONTENT_FIELDS,
+    _decode_plane,
+    _Plane,
+    _plane_outline,
+    _plane_spans,
+    iter_plane_bufs,
+    verify_schema_pins,
+)
 
 # (op-name fragment, kind of collective), first match wins, `_` read as `-`:
 # XLA names an op it inserts itself after its opcode (`all-reduce.3`) and one
@@ -247,14 +103,6 @@ def _op_shape(name: str) -> str:
     return token.split("{", 1)[0]
 
 
-# What a plane's bytes are made of, by the XPlane field that holds them
-# (the account an operator asks "why is my trace 13 MB" of): the events
-# live in `lines`; everything else is said once a plane, whatever the
-# window's length.
-CONTENT_FIELDS = {3: "lines", 4: "event_metadata", 5: "stat_metadata",
-                  6: "stats"}
-
-
 @dataclass
 class PlaneSummary:
     name: str
@@ -288,13 +136,6 @@ def _op_key(name: str, group: bool) -> str:
             name = base[0]
     return name
 
-
-# The stats the op table reads, by XStatMetadata.name: the cost model's two
-# numbers, the XProf category string, and the path the framework gave the
-# op (`tf_op`: "jit(step)/transpose(jvp(moe.route))/dot_general:"), from
-# which its scope reads.
-COST_STATS = ("flops", "bytes_accessed", "hlo_category", "tf_op")
-TEXT_STATS = ("hlo_category", "tf_op")
 
 NO_SCOPE = "(none)"
 # Components of an op's path that the framework writes, not the program:
@@ -335,453 +176,6 @@ def op_scope(tf_op: str) -> str:
                 part.startswith("branch_") and part.endswith("_fun")):
             return part
     return NO_SCOPE
-
-
-@dataclass
-class _Plane:
-    """One plane, decoded once: what its PlaneSummary and its Chrome-trace
-    fragment are both made from."""
-
-    name: str = ""
-    bytes: int = 0
-    content: dict = field(default_factory=dict)  # as PlaneSummary.content
-    event_metadata: int = 0  # entries of the map, as they come
-    names: dict = field(default_factory=dict)  # event metadata id -> name
-    shown: dict = field(default_factory=dict)  # id -> display_name or name
-    costs: dict = field(default_factory=dict)  # id -> {COST_STATS name: value}
-    # a line: (id, name, timestamp_ns, [(metadata id, offset_ps, duration_ps,
-    # the event's own costs or None)])
-    lines: list = field(default_factory=list)
-    # the metadata entries and events the generic path had to read
-    # (`_decode_plane`): 0 wherever every tag in them is one byte
-    generic: int = 0
-
-
-def _map_entry(buf, a: int, b: int) -> tuple[int, list]:
-    """One map<int64, message> entry: (id, the value's fields). The id may
-    arrive as the entry's key (field 1) or as the embedded message's own
-    field 1 (XEventMetadata.id, XStatMetadata.id): producers are free to set
-    either, and the one read later stands."""
-    mid, inner = 0, []
-    for num, wt, x, y in _fields(buf, a, b):
-        if num == 1 and wt == 0:
-            mid = x
-        elif num == 2 and wt == 2:
-            inner = _fields(buf, x, y)
-            for en, ew, ex, _ in inner:
-                if en == 1 and ew == 0:
-                    mid = ex
-    return mid, inner
-
-
-def _costs(buf, stat_spans, kinds: dict) -> dict:
-    """{COST_STATS name: value} of the XStats at the spans, by the plane's
-    `kinds` {stat metadata id: COST_STATS name}, through `_fields`: the
-    generic reading of a stat. The callers hand over the stats worth
-    opening: one whose metadata id leads it in one byte, as producers write
-    it, and is none of `kinds` they step over, unread. It reads the few
-    stats an event keeps (`_decode_plane`) and those of a metadata entry
-    that `_read_entry` handed back (`_entry_generic`); an entry `_read_entry`
-    knows has its wanted stats read where they lie, to the same answer."""
-    found = {}
-    for a, b in stat_spans:
-        sid, value, text = 0, None, None
-        for num, wt, x, y in _fields(buf, a, b):
-            if num == 1 and wt == 0:
-                sid = x
-            elif num == 2 and wt == 1:
-                value = FLOAT64.unpack_from(buf, x)[0]
-            elif num in (3, 4, 7) and wt == 0:
-                value = float(x)
-            elif num == 5 and wt == 2:
-                text = buf[x:y]
-        kind = kinds.get(sid)
-        if kind in TEXT_STATS:
-            if text is not None:
-                found[kind] = text.decode(errors="replace")
-        elif kind is not None and value is not None:
-            found[kind] = value
-    return found
-
-
-def _entry_generic(buf, a: int, b: int, kinds: dict) -> tuple:
-    """`_read_entry`'s answer for any valid entry, through `_fields`: a
-    list of the entry's fields, one of the value's, one for each stat
-    opened. Raises ValueError on truncated or malformed input."""
-    mid, inner = _map_entry(buf, a, b)
-    name = disp = ""
-    stat_spans = []
-    for num, wt, x, y in inner:
-        if wt != 2:
-            continue
-        if num == 2:
-            name = buf[x:y].decode(errors="replace")
-        elif num == 4:  # display_name (3 is `metadata`: opaque bytes)
-            disp = buf[x:y].decode(errors="replace")
-        elif num == 5 and kinds and (
-                y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
-                or buf[x + 1] in kinds):
-            stat_spans.append((x, y))
-    return mid, name, disp or name, _costs(buf, stat_spans, kinds)
-
-
-def _read_entry(buf, i: int, end: int, kinds: dict) -> tuple | None:
-    """One entry of a plane's event-metadata map, the {key, XEventMetadata}
-    at buf[i:end], read in ONE pass written for its wire layout: (id, name,
-    display_name or name, {COST_STATS name: value}), or None where the
-    entry holds what this loop does not know.
-
-    What it reads: the key (tag 0x08), and inside the value (0x12) the id
-    (0x08), `name` (0x12), `display_name` (0x22) and each `stats` field
-    (0x2A) where it lies. A stat's two leading bytes say whether it is
-    worth opening, by the test the generic path makes: one that leads with
-    its metadata id in one byte (0x08, id) which is none of the plane's
-    `kinds` is stepped over by its length; any other (a wanted id, an id of
-    two bytes, a stat that does not lead with its id) is read there and
-    then: the id (0x08), `double_value` (0x11), `uint64_value`,
-    `int64_value`, `ref_value` (0x18, 0x20, 0x38), `str_value` (0x2A). What
-    it steps over unread: `metadata` (0x1A) and every other field of a
-    one-byte tag that is a varint or length-delimited, at all three levels.
-    As in `_map_entry`, the id read later stands, and a value said twice
-    stands as the later one. No list of fields, no tuple a field; a length
-    or an id of two bytes (a name's, most ids) is put together in line.
-
-    What it hands back (None): a tag above 0x7F (a field number above 15),
-    field 0, a fixed-width field other than `double_value`, a message whose
-    fields do not end where it ends, a read past the buffer. The caller
-    then reads the entry by `_entry_generic`, which gives any valid entry's
-    answer and raises ValueError for truncated and malformed input."""
-    mid = 0
-    name = disp = ""
-    found = {}
-    try:
-        while i < end:
-            tag = buf[i]
-            i += 1
-            if tag == 0x12:  # the value: XEventMetadata
-                size = buf[i]
-                i += 1
-                if size > 0x7F:
-                    c = buf[i]
-                    i += 1
-                    if c > 0x7F:
-                        size, i = _read_varint(buf, i - 2)
-                    else:
-                        size += (c << 7) - 0x80
-                value_end = i + size
-                name = disp = ""
-                found = {}
-                while i < value_end:
-                    tag = buf[i]
-                    i += 1
-                    if tag == 0x2A:  # stats
-                        size = buf[i]
-                        i += 1
-                        if size > 0x7F:
-                            c = buf[i]
-                            i += 1
-                            if c > 0x7F:
-                                size, i = _read_varint(buf, i - 2)
-                            else:
-                                size += (c << 7) - 0x80
-                        stat_end = i + size
-                        if not (kinds and (
-                                size < 2 or buf[i] != 0x08
-                                or buf[i + 1] > 0x7F or buf[i + 1] in kinds)):
-                            i = stat_end  # not one of `kinds`: unread
-                            continue
-                        sid, value, text = 0, None, None
-                        while i < stat_end:
-                            tag = buf[i]
-                            i += 1
-                            if (tag == 0x08 or tag == 0x18 or tag == 0x20
-                                    or tag == 0x38):
-                                v = buf[i]
-                                i += 1
-                                if v > 0x7F:
-                                    v &= 0x7F
-                                    shift = 7
-                                    while True:
-                                        c = buf[i]
-                                        i += 1
-                                        v |= (c & 0x7F) << shift
-                                        if c < 0x80:
-                                            break
-                                        shift += 7
-                                if tag == 0x08:
-                                    sid = v
-                                else:  # uint64, int64, ref: as written
-                                    value = float(v)
-                            elif tag == 0x2A:  # str_value
-                                size = buf[i]
-                                i += 1
-                                if size > 0x7F:
-                                    c = buf[i]
-                                    i += 1
-                                    if c > 0x7F:
-                                        size, i = _read_varint(buf, i - 2)
-                                    else:
-                                        size += (c << 7) - 0x80
-                                text = buf[i:i + size]
-                                i += size
-                            elif tag == 0x11:  # double_value
-                                value = FLOAT64.unpack_from(buf, i)[0]
-                                i += 8
-                            elif tag > 0x7F or tag < 8:
-                                return None
-                            elif tag & 7 == 0:
-                                while buf[i] > 0x7F:
-                                    i += 1
-                                i += 1
-                            elif tag & 7 == 2:
-                                size, i = _read_varint(buf, i)
-                                i += size
-                            else:
-                                return None
-                        if i != stat_end:
-                            return None
-                        kind = kinds.get(sid)
-                        if kind in TEXT_STATS:
-                            if text is not None:
-                                found[kind] = text.decode("utf-8", "replace")
-                        elif kind is not None and value is not None:
-                            found[kind] = value
-                    elif tag == 0x12 or tag == 0x22:  # name, display_name
-                        size = buf[i]
-                        i += 1
-                        if size > 0x7F:
-                            c = buf[i]
-                            i += 1
-                            if c > 0x7F:
-                                size, i = _read_varint(buf, i - 2)
-                            else:
-                                size += (c << 7) - 0x80
-                        if tag == 0x12:
-                            name = buf[i:i + size].decode("utf-8", "replace")
-                        else:
-                            disp = buf[i:i + size].decode("utf-8", "replace")
-                        i += size
-                    elif tag == 0x08:
-                        mid = buf[i]
-                        i += 1
-                        if mid > 0x7F:
-                            mid, i = _read_varint(buf, i - 1)
-                    elif tag > 0x7F or tag < 8:
-                        return None
-                    elif tag & 7 == 2:  # `metadata` (0x1A): opaque bytes
-                        size, i = _read_varint(buf, i)
-                        i += size
-                    elif tag & 7 == 0:
-                        while buf[i] > 0x7F:
-                            i += 1
-                        i += 1
-                    else:
-                        return None
-                if i != value_end:
-                    return None
-            elif tag == 0x08:  # the key
-                mid = buf[i]
-                i += 1
-                if mid > 0x7F:
-                    mid, i = _read_varint(buf, i - 1)
-            elif tag > 0x7F or tag < 8:
-                return None
-            elif tag & 7 == 0:
-                while buf[i] > 0x7F:
-                    i += 1
-                i += 1
-            elif tag & 7 == 2:
-                size, i = _read_varint(buf, i)
-                i += size
-            else:
-                return None
-    except (IndexError, struct.error):
-        return None
-    if i != end:
-        return None
-    return mid, name, disp or name, found
-
-
-def _read_event(buf, i: int, end: int, own) -> tuple | None:
-    """The event at buf[i:end] as `_decode_plane` keeps it, (metadata id,
-    offset_ps, duration_ps, its own costs or None), read in one pass
-    written for an XEvent's wire layout; None where the event holds what
-    this loop does not know, as `_read_entry` for its message.
-
-    The three varints `metadata_id`, `offset_ps`, `duration_ps` (0x08,
-    0x10, 0x18) are read where they lie, the one read later standing. A
-    `stats` field (0x22) is kept only where the line's stats are looked at
-    (`own`: the plane's `kinds`, or None) and its leading bytes say it may
-    be one of them, by the test `_read_entry` makes; every other is stepped
-    over by its length, as is any other varint (`num_occurrences`, 0x28) or
-    length-delimited field of a one-byte tag. `_costs` opens what was kept,
-    for the few events that keep any."""
-    meta_id = offset_ps = duration_ps = 0
-    stat_spans = None
-    try:
-        while i < end:
-            tag = buf[i]
-            i += 1
-            if tag == 0x22:  # stats
-                size = buf[i]
-                i += 1
-                if size > 0x7F:
-                    size, i = _read_varint(buf, i - 1)
-                if own and (
-                        size < 2 or buf[i] != 0x08
-                        or buf[i + 1] > 0x7F or buf[i + 1] in own):
-                    if stat_spans is None:
-                        stat_spans = []
-                    stat_spans.append((i, i + size))
-                i += size
-            elif tag == 0x10 or tag == 0x18 or tag == 0x08:
-                v = buf[i]
-                i += 1
-                if v > 0x7F:  # offsets and durations take this path
-                    v &= 0x7F
-                    shift = 7
-                    while True:
-                        c = buf[i]
-                        i += 1
-                        v |= (c & 0x7F) << shift
-                        if c < 0x80:
-                            break
-                        shift += 7
-                if tag == 0x10:
-                    offset_ps = v
-                elif tag == 0x18:
-                    duration_ps = v
-                else:
-                    meta_id = v
-            elif tag > 0x7F or tag < 8:
-                return None
-            elif tag & 7 == 0:
-                while buf[i] > 0x7F:
-                    i += 1
-                i += 1
-            elif tag & 7 == 2:
-                size, i = _read_varint(buf, i)
-                i += size
-            else:
-                return None
-    except IndexError:
-        return None
-    if i != end:
-        return None
-    return (meta_id, offset_ps, duration_ps,
-            _costs(buf, stat_spans, own) if stat_spans else None)
-
-
-def _event_generic(buf, a: int, b: int, own) -> tuple:
-    """`_read_event`'s answer for any valid event, through `_fields`: a
-    list of the event's fields and a tuple a field. Raises ValueError on
-    truncated or malformed input."""
-    meta_id = offset_ps = duration_ps = 0
-    stat_spans = []
-    for num, wt, x, y in _fields(buf, a, b):
-        if wt == 0:
-            if num == 1:
-                meta_id = x
-            elif num == 2:
-                offset_ps = x
-            elif num == 3:
-                duration_ps = x
-        elif num == 4 and wt == 2 and own and (
-                y - x < 2 or buf[x] != 0x08 or buf[x + 1] > 0x7F
-                or buf[x + 1] in own):
-            stat_spans.append((x, y))
-    return (meta_id, offset_ps, duration_ps,
-            _costs(buf, stat_spans, own) if stat_spans else None)
-
-
-def _decode_plane(
-    buf, start: int, end: int, top: list | None = None
-) -> _Plane:
-    """The one decode of the plane at buf[start:end]: metadata first (the
-    stats' names, then every op's names and cost model), then every line
-    once and every event once. Nothing is copied but the names. `top` is
-    `_fields(buf, start, end)` where the caller has walked the plane's top
-    level already (to weigh it, `_plane_weight`).
-
-    The plane's top level, its stat-metadata map and a line's top level go
-    through `_fields`. The two messages a plane holds by the thousand are
-    each opened once and only as far as they are read, by a loop written
-    for their own wire layout: an entry of the event-metadata map by
-    `_read_entry`, an event by `_read_event`. The input decides: a message
-    that holds anything those loops do not know (a tag above 0x7F, a fixed
-    width, fields that do not end where the message ends) is read by the
-    generic path (`_entry_generic`, `_event_generic`), that message alone,
-    to the same answer for any valid message and the same ValueError for a
-    broken one; `_Plane.generic` counts them."""
-    plane = _Plane(bytes=end - start)
-    line_spans, metadata_spans = [], []
-    kinds: dict[int, str] = {}  # stat metadata id -> its COST_STATS name
-    at = start
-    for num, wt, x, y in top or _fields(buf, start, end):
-        kind = CONTENT_FIELDS.get(num, "other")
-        plane.content[kind] = plane.content.get(kind, 0) + y - at
-        at = y
-        if wt != 2:
-            continue
-        if num == 2:
-            plane.name = buf[x:y].decode(errors="replace")
-        elif num == 3:
-            line_spans.append((x, y))
-        elif num == 4:
-            metadata_spans.append((x, y))
-        elif num == 5:
-            sid, inner = _map_entry(buf, x, y)
-            sname = ""
-            for en, ew, ex, ey in inner:
-                if en == 2 and ew == 2:
-                    sname = buf[ex:ey].decode(errors="replace")
-            kinds.pop(sid, None)  # an id said twice: the later entry stands
-            if sname in COST_STATS:
-                kinds[sid] = sname
-    # Cost-model stats (flops, bytes_accessed) and the hlo_category string
-    # hang off the event METADATA, one set per op instance.
-    plane.event_metadata = len(metadata_spans)
-    names, shown, costs = plane.names, plane.shown, plane.costs
-    generic = 0
-    for a, b in metadata_spans:
-        entry = _read_entry(buf, a, b, kinds)
-        if entry is None:
-            generic += 1
-            entry = _entry_generic(buf, a, b, kinds)
-        mid, names[mid], shown[mid], costs[mid] = entry  # left to right
-    lines = []
-    for a, b in line_spans:
-        lid, lname, ts_ns = 0, "", 0
-        line = _fields(buf, a, b)
-        for num, wt, x, y in line:
-            if num == 4:
-                continue  # an event: read below, once every line has a name
-            if num == 1 and wt == 0:
-                lid = x
-            elif num == 2 and wt == 2:
-                lname = buf[x:y].decode(errors="replace")
-            elif num == 3 and wt == 0:
-                ts_ns = x
-        lines.append((lid, lname, ts_ns, line))
-    has_xla_ops = any(lname == "XLA Ops" for _, lname, _, _ in lines)
-    for lid, lname, ts_ns, line in lines:
-        # Per-occurrence stats override the metadata's cost model where a
-        # producer emits them per event; only the lines the op table reads
-        # (see _plane_summary) have theirs looked at.
-        own = kinds if not has_xla_ops or lname == "XLA Ops" else None
-        events = []
-        add = events.append
-        for num, wt, x, y in line:
-            if num != 4 or wt != 2:
-                continue
-            event = _read_event(buf, x, y, own)
-            if event is None:
-                generic += 1
-                event = _event_generic(buf, x, y, own)
-            add(event)
-        plane.lines.append((lid, lname, ts_ns, events))
-    plane.generic = generic
-    return plane
 
 
 def _plane_summary(
@@ -872,26 +266,11 @@ def _take_held_time(events: list, by_id: dict) -> None:
         open_events.append((end_ps, by_id[meta_id]))
 
 
-def _plane_spans(data) -> list[tuple[int, int]]:
-    return [(a, b) for num, wt, a, b in _fields(data, 0, len(data))
-            if num == 1 and wt == 2]
-
-
 def summarize_xplane_bytes(
     data: bytes, group: bool = True, by_category: bool = False
 ) -> list[PlaneSummary]:
     return [_plane_summary(_decode_plane(data, a, b), group, by_category)
             for a, b in _plane_spans(data)]
-
-
-def iter_plane_bufs(data: bytes):
-    """Yields each plane's raw protobuf buffer from a serialized XSpace, a
-    copy a plane: how the single-shot reference and the tests walk a file.
-    The converter does not: the process that read the file converts by
-    offsets into `data` (`_plane_spans`) and cuts out only the planes it
-    sends a forked worker (`_iter_fragments`)."""
-    for a, b in _plane_spans(data):
-        yield data[a:b]
 
 
 # What one entry of a plane's event-metadata map weighs, in bytes of
@@ -926,16 +305,29 @@ METADATA_ENTRY_WEIGHT = 170
 # planes of four.
 FORK_WORTH_WEIGHT = 700_000
 
+# zlib's level for the streamed trace.json.gz: the artifact is a scratch
+# view, and level 1 costs a fraction of the default level-9 `gzip.open` CPU
+# for ~15-25% larger output.
+GZIP_LEVEL = 1
 
-def _plane_weight(top: list) -> tuple[int, int]:
-    """(weight, lines) of a plane from one walk of its top level (`top`:
-    its `_fields`, the walk `_decode_plane` begins with and takes over; no
-    line and no metadata entry is opened). The weight says what converting
-    the plane will cost, and that is events and op metadata, not size: the
-    bytes under `lines` (field 3) plus METADATA_ENTRY_WEIGHT for every
-    entry of the event-metadata map (field 4). `/host:metadata`, the
-    largest plane of every artifact (1.1-27.4 MB of HLO in its stats),
-    holds one entry and no line, weighs 170 and converts in 0.1-0.3 ms.
+# Added to a forked worker's niceness (`os.nice` takes an increment), so
+# that parallel conversion can never compete with a training loop at normal
+# priority. The caller is not re-niced: the shim's export subprocess is at
+# nice 19 already.
+WORKER_NICE = 10
+
+
+def _plane_weight(
+    line_bytes: int, lines: int, entries: int
+) -> tuple[int, int]:
+    """(weight, lines) of a plane from one walk of its top level
+    (`xspace._plane_outline`: no line and no metadata entry is opened). The
+    weight says what converting the plane will cost, and that is events
+    and op metadata, not size: the bytes under `lines` plus
+    METADATA_ENTRY_WEIGHT for every entry of the event-metadata map.
+    `/host:metadata`, the largest plane of every artifact (1.1-27.4 MB of
+    HLO in its stats), holds one entry and no line, weighs 170 and converts
+    in 0.1-0.3 ms.
 
     How it maps to milliseconds, from artifacts kept from the six capture
     cells and converted on the chip machine at nice 19 (PR 42, chip call
@@ -955,59 +347,13 @@ def _plane_weight(top: list) -> tuple[int, int]:
     tells apart lie a factor of seven under the threshold (one chip:
     74-100 k beside the device plane) and of 1.7-2.7 over it (four chips:
     a worker's two device planes, 1.16 and 1.91 M)."""
-    line_bytes = lines = entries = 0
-    for num, wt, x, y in top:
-        if wt != 2:
-            continue
-        if num == 3:
-            line_bytes += y - x
-            lines += 1
-        elif num == 4:
-            entries += 1
     return line_bytes + METADATA_ENTRY_WEIGHT * entries, lines
-
-
-def plane_index(data) -> list[dict]:
-    """[{"name", "bytes"}] for every plane of a serialized XSpace, in file
-    order: the top level only. A plane's bytes are its payload's (the
-    XSpace's framing, a tag and a length a plane, is not counted); its name
-    is read from the plane's leading fields (XPlane{id=1, name=2}; the
-    lines, metadata and events behind them are skipped unread), so the
-    cost is a few fields a plane whatever the trace's size. Raises
-    ValueError (IndexError folded in) on malformed input."""
-    view = memoryview(data)
-    try:
-        return [{"name": _plane_name(view, a, b), "bytes": b - a}
-                for num, wt, a, b in _fields(view, 0, len(view))
-                if num == 1 and wt == 2]
-    except IndexError as e:
-        raise ValueError("truncated xspace") from e
-
-
-def _plane_name(view, i: int, end: int) -> str:
-    """The name of the plane at view[i:end]: its first field 2, looked for
-    among the scalar fields that lead the message and no further than the
-    first line or metadata entry (field >= 3)."""
-    while i < end:
-        tag, i = _read_varint(view, i)
-        num, wt = tag >> 3, tag & 7
-        if wt == 0:
-            _, i = _read_varint(view, i)
-        elif wt == 2:
-            size, i = _read_varint(view, i)
-            if num == 2:
-                return bytes(view[i:i + size]).decode(errors="replace")
-            if num > 2:
-                break
-            i += size
-        else:
-            break
-    return ""
 
 
 def _plane_events(pid: int, plane_buf: bytes) -> list[dict]:
     """Chrome trace events for ONE plane (the process_name metadata event,
-    then per line a thread_name event plus the complete events).
+    then per line a thread_name event plus the complete events), as dicts:
+    the tests' reference for `_plane_json`, on no product path.
 
     Mapping: plane -> process (pid), line -> thread (tid), event ->
     complete event ("ph":"X") at ts = line.timestamp_ns + offset_ps,
@@ -1067,10 +413,10 @@ def xplane_to_chrome_trace(data: bytes) -> dict:
     xplane.pb — loadable in chrome://tracing and, minus the metadata
     field, ui.perfetto.dev).
 
-    This is the single-shot in-memory form (everything in one dict); the
-    production writer is the streamed, budgeted `write_chrome_trace_gz`,
-    which produces the same events plane by plane without materializing
-    the whole list.
+    This is the single-shot in-memory form (everything in one dict): the
+    tests' reference, on no product path. The product's writer is the
+    streamed `write_chrome_trace_gz`, which produces the same events plane
+    by plane without materializing the whole list.
     """
     events: list[dict] = []
     for pid, plane_buf in enumerate(iter_plane_bufs(data), start=1):
@@ -1080,47 +426,33 @@ def xplane_to_chrome_trace(data: bytes) -> dict:
 
 @dataclass
 class ConvertBudget:
-    """Explicit CPU budget for the background converter stage.
+    """The one setting of the background converter: how many processes may
+    convert planes at one time.
 
-    Post-processing must stay bounded and off the capture path:
-    unbudgeted converters pile up across back-to-back captures and
-    take CPU from every later one and from the job. Knobs:
+    Post-processing must stay bounded and off the capture path: unbudgeted
+    converters pile up across back-to-back captures and take CPU from every
+    later one and from the job.
 
-    - max_workers: the most processes that convert planes at one time, THE
-      CALLER ONE OF THEM (the work is pure-Python and GIL-bound, so threads
-      cannot parallelize it): 1 never forks; the default is the caller and
-      at most one forked worker. An upper bound, not a request: how many
-      of them an artifact gets is read from the artifact (`_shares`: a
-      worker is forked only for a share of planes worth a fork, and at one
-      chip none is), and only from a (near-)single-threaded process like
-      the shim's export subprocess (fork safety; see _iter_fragments),
-      serial elsewhere.
-    - gzip_level: zlib level for the streamed trace.json.gz. Default 1:
-      the artifact is a scratch view, and level 1 costs a fraction of the
-      default level-9 `gzip.open` CPU for ~15-25% larger output.
-    - nice: niceness ADDED to each forked worker (os.nice increment), so
-      parallel conversion can never compete with a training loop at
-      normal priority. The caller is not re-niced (the shim's export
-      subprocess is already nice 19).
-    - yield_every_planes / yield_s: in serial mode, sleep yield_s after
-      every yield_every_planes planes — plane-batch yielding that bounds
-      the converter's CPU duty cycle on single-core hosts where even a
-      nice-19 process competes for the only core.
+    max_workers: the most processes that convert planes at one time, THE
+    CALLER ONE OF THEM (the work is pure-Python and GIL-bound, so threads
+    cannot parallelize it): 1 never forks (a host whose job must never see
+    a fork beside it sets that); the default is the caller and at most one
+    forked worker. An upper bound, not a request: how many of them an
+    artifact gets is read from the artifact (`_shares`: a worker is forked
+    only for a share of planes worth a fork, and at one chip none is), and
+    only from a (near-)single-threaded process like the shim's export
+    subprocess (fork safety; see _iter_fragments), serial elsewhere.
 
-    Env overrides (read by `from_env`, and therefore by the shim's export
-    subprocess): DYNO_TRACE_CONVERT_WORKERS, DYNO_TRACE_CONVERT_GZIP_LEVEL,
-    DYNO_TRACE_CONVERT_NICE, DYNO_TRACE_CONVERT_YIELD_S. That subprocess is
-    started as its capture's window opens and reads them then
+    Set by DYNO_TRACE_CONVERT_WORKERS (`from_env`, and therefore the shim's
+    export subprocess, whose environment carries a capture's
+    TRACE_CONVERT_WORKERS config key under that name). That subprocess is
+    started as its capture's window opens and reads it then
     (`export_child`): once to decide whether the pool's modules are worth
     importing before the artifact exists (it cannot know yet whether the
     artifact will be worth a fork), and again when it converts.
     """
 
     max_workers: int = 0  # 0 = auto: min(2, cpu count), the caller counted
-    gzip_level: int = 1
-    nice: int = 10
-    yield_every_planes: int = 4
-    yield_s: float = 0.0
 
     def resolved_workers(self, n_planes: int) -> int:
         """Processes the budget allows over `n_planes` planes, the caller
@@ -1131,33 +463,20 @@ class ConvertBudget:
         return max(1, min(workers, n_planes))
 
     @classmethod
-    def from_env(cls, env=None) -> "ConvertBudget":
-        env = os.environ if env is None else env
-        budget = cls()
-        for key, attr, cast in (
-            ("DYNO_TRACE_CONVERT_WORKERS", "max_workers", int),
-            ("DYNO_TRACE_CONVERT_GZIP_LEVEL", "gzip_level", int),
-            ("DYNO_TRACE_CONVERT_NICE", "nice", int),
-            ("DYNO_TRACE_CONVERT_YIELD_S", "yield_s", float),
-        ):
-            raw = env.get(key)
-            if raw is None:
-                continue
-            try:
-                setattr(budget, attr, cast(raw))
-            except ValueError:
-                pass  # a malformed knob must not sink the conversion
-        return budget
+    def from_env(cls) -> "ConvertBudget":
+        try:
+            return cls(int(os.environ.get("DYNO_TRACE_CONVERT_WORKERS", 0)))
+        except ValueError:
+            return cls()  # a malformed setting must not sink the conversion
 
 
-def _nice_worker(nice: int, ctx: "obs.TraceContext | None" = None) -> None:
+def _nice_worker(ctx: "obs.TraceContext | None" = None) -> None:
     """Pool-worker initializer: deprioritize before any plane work, and
     take the conversion's span context as this process's ambient one, so
     that a plane's spans parent to trace.convert wherever the plane runs."""
     obs.set_current(ctx)
     try:
-        if nice > 0:
-            os.nice(nice)
+        os.nice(WORKER_NICE)
     except OSError:
         pass
 
@@ -1265,8 +584,7 @@ def _iter_fragments(data: bytes, budget: ConvertBudget):
     sent their planes' bytes heaviest first. The pairs go out in file
     order once the caller's share is done, a worker's as they fall due.
     Where no share is worth a fork (every one-chip artifact today), under
-    `max_workers=1` and from a multithreaded caller: no pool, file order,
-    with plane-batch yielding.
+    `max_workers=1` and from a multithreaded caller: no pool, file order.
 
     Pool failure — at setup (sandboxes without working fork) OR mid-run (a
     worker OOM-killed: BrokenProcessPool, a RuntimeError) — leaves the
@@ -1278,9 +596,10 @@ def _iter_fragments(data: bytes, budget: ConvertBudget):
     processes = budget.resolved_workers(len(spans))
     tops, theirs, workers = [None] * len(spans), [], 0
     if processes > 1 and _fork_safe():
-        tops = [_fields(data, a, b) for a, b in spans]
+        outlines = [_plane_outline(data, a, b) for a, b in spans]
+        tops = [top for top, *_ in outlines]
         ours, theirs, workers = _shares(
-            [_plane_weight(top) for top in tops], processes)
+            [_plane_weight(*counts) for _, *counts in outlines], processes)
 
     def keep_spans(converted: tuple) -> tuple:
         fragment, summary, plane_spans = converted
@@ -1312,7 +631,7 @@ def _iter_fragments(data: bytes, budget: ConvertBudget):
             with ProcessPoolExecutor(
                 max_workers=workers,
                 initializer=_nice_worker,
-                initargs=(budget.nice, obs.current()),
+                initargs=(obs.current(),),
             ) as pool:
                 # the first submit forks; the workers pull from one queue
                 for i in theirs:
@@ -1328,10 +647,6 @@ def _iter_fragments(data: bytes, budget: ConvertBudget):
             pass  # no pool to be had; planes [done:] convert below
     for i in range(done, len(spans)):
         yield due(i)
-        n = i + 1  # planes out so far
-        if (budget.yield_s > 0 and budget.yield_every_planes > 0
-                and n % budget.yield_every_planes == 0 and n < len(spans)):
-            time.sleep(budget.yield_s)
 
 
 def stream_write(path: str, chunks) -> int:
@@ -1390,11 +705,11 @@ def write_chrome_trace_gz(
     Streamed and budgeted: planes convert to JSON fragments where the
     bytes are, beside a nice'd worker only where the artifact has a share
     of planes worth a fork (`_iter_fragments`, per `budget`), and each
-    fragment goes through a chunked `zlib.compressobj` at the budget's
-    gzip level in file order — the event list is never materialized (the
-    fragments are, beside a worker, until their turn), and the CPU cost
-    is a fraction of the old monolithic level-9 `gzip.open` + `json.dump`
-    (kept as `write_chrome_trace_gz_single`, the tests' reference).
+    fragment goes through a chunked `zlib.compressobj` at GZIP_LEVEL in
+    file order — the event list is never materialized (the fragments are,
+    beside a worker, until their turn), and the CPU cost is a fraction of
+    a monolithic level-9 `gzip.open` + `json.dump`
+    (`write_chrome_trace_gz_single`, the tests' reference).
     Write-then-rename, tmp unlinked on failure: a reader (TensorBoard, an
     operator's scp) must never see a torn gzip, and a converter crash
     must not orphan a .tmp next to the trace dir."""
@@ -1404,14 +719,10 @@ def write_chrome_trace_gz(
         budget = ConvertBudget.from_env()
     data = _read_xplane(xplane_path, data)
     out_path = _derived_path(xplane_path, ".trace.json.gz")
-    # Clamp to zlib's valid range: an out-of-range level from the
-    # TRACE_CONVERT_GZIP_LEVEL config key parses as a fine int but makes
-    # compressobj raise — which would silently cost every capture its
-    # trace.json.gz (write_derived_artifacts swallows the error).
-    level = min(max(budget.gzip_level, -1), 9)
 
     def gz_chunks():
-        comp = zlib.compressobj(level, zlib.DEFLATED, 16 + zlib.MAX_WBITS)
+        comp = zlib.compressobj(
+            GZIP_LEVEL, zlib.DEFLATED, 16 + zlib.MAX_WBITS)
         yield comp.compress(b'{"displayTimeUnit": "ns", "traceEvents": [')
         first = True
         for fragment, summary in _iter_fragments(data, budget):
@@ -1434,10 +745,10 @@ def write_chrome_trace_gz(
 def write_chrome_trace_gz_single(
     xplane_path: str, data: bytes | None = None
 ) -> str:
-    """The pre-streaming converter: one in-memory dict, one monolithic
-    default-level `gzip.open` + `json.dump`. Kept as the reference that
-    tests/test_trace_convert.py compares the streamed converter against
-    — not used on any production path."""
+    """The single-shot converter: one in-memory dict, one monolithic
+    default-level `gzip.open` + `json.dump`. The reference that
+    tests/test_trace_convert.py compares the streamed converter against,
+    on no product path."""
     import gzip
 
     trace = xplane_to_chrome_trace(_read_xplane(xplane_path, data))
@@ -1591,31 +902,17 @@ def summarize(
     return _summarize_planes(planes)
 
 
-def compact_profile(
-    data: bytes,
-    top: int = 40,
-    budget: ConvertBudget | None = None,
-    group: bool = False,
-) -> dict:
+def compact_profile(data: bytes, top: int = 40, group: bool = False) -> dict:
     """Promote one serialized XSpace to a compact op-level profile — the
     continuous-capture ring's storage unit (shim.CaptureRing) and the
     diagnosis engine's comparable: the summarize() output with the op
-    table capped at `top` rows plus size metadata, produced plane by
-    plane UNDER THE CONVERT BUDGET (serial, with the budget's plane-batch
-    yielding), so ring promotion on a training host can never burst CPU
-    the way an unbudgeted whole-space summarize would."""
-    if budget is None:
-        budget = ConvertBudget.from_env()
-    planes: list[PlaneSummary] = []
-    for i, (a, b) in enumerate(_plane_spans(data), start=1):
-        # group=False by default: per-op-INSTANCE rows (fusion.116, not
-        # fusion) are the diagnosable unit — "which fusion regressed" is
-        # the whole question the diff engine answers.
-        planes.append(_plane_summary(_decode_plane(data, a, b), group=group))
-        if (budget.yield_s > 0 and budget.yield_every_planes > 0
-                and i % budget.yield_every_planes == 0):
-            time.sleep(budget.yield_s)
-    profile = _summarize_planes(planes)
+    table capped at `top` rows plus size metadata, produced plane by plane
+    in the calling process (no pool: ring promotion on a training host
+    forks nothing beside the job)."""
+    # group=False by default: per-op-INSTANCE rows (fusion.116, not fusion)
+    # are the diagnosable unit — "which fusion regressed" is the whole
+    # question the diff engine answers.
+    profile = _summarize_planes(summarize_xplane_bytes(data, group=group))
     profile["top_ops"] = profile["top_ops"][:top]
     profile["xspace_bytes"] = len(data)
     return profile

@@ -40,10 +40,8 @@ if [ "$1" = "--tests" ]; then
     if [ ! -f "$out" ] || [ "$t" -nt "$out" ] ||
        [ build/obj/libdynotpu_core.a -nt "$out" ]; then
       echo "LINK $out"
-      extra=""
-      [ "$name" = ShmRingBufferTest ] && extra="-lrt"
       $CXX $FLAGS "$t" build/obj/libdynotpu_core.a -o "$out" \
-        -lpthread -ldl $extra
+        -lpthread -ldl
     fi
   done
 fi

@@ -23,7 +23,7 @@ import psutil
 import pytest
 
 import xspace_fixture
-from dynolog_tpu import failpoints, obs, trace
+from dynolog_tpu import failpoints, obs, trace, xspace
 from dynolog_tpu.client import shim
 from test_capture_spans import (
     config, fake_session, make_client, wait_manifest)
@@ -182,7 +182,7 @@ def test_a_conversion_spans_every_plane_and_its_decode(
     assert child.returncode == 0, err
     assert derived(xplane) == direct
     spans = flushed_spans(sock)
-    n_planes = len(trace.plane_index(Path(xplane).read_bytes()))
+    n_planes = len(xspace.plane_index(Path(xplane).read_bytes()))
     assert n_planes == 4
     convert, rest = spans[0], spans[1:]  # the parent is flushed first
     assert convert.name == "trace.convert" and convert.pid == child.pid
@@ -405,7 +405,7 @@ def test_export_spawn_failpoint_falls_back_to_thread(xplane, monkeypatch):
     exported = threading.Event()
     monkeypatch.setattr(
         shim.JaxProfiler, "_export_json",
-        staticmethod(lambda path, env=None: exported.set()))
+        staticmethod(lambda path: exported.set()))
     profiler = shim.JaxProfiler(export_trace_json=True)
     assert profiler._spawn_export(xplane) == {"export_child": "thread"}
     assert exported.wait(timeout=5.0)
@@ -581,6 +581,34 @@ def test_a_capture_whose_export_is_off_starts_no_child(
         tmp_path, monkeypatch, "off", 100, extra="TRACE_JSON=0")
     assert manifest["status"] == "ok" and "export_child" not in manifest
     assert started == [] and client.profiler._export_thread is None
+
+
+def test_a_setting_that_went_reaches_nothing(tmp_path, monkeypatch):
+    """A request that still carries TRACE_CONVERT_GZIP_LEVEL, _NICE or
+    _YIELD_S is treated as one with any key the shim does not know: kept
+    in TraceConfig.raw, the capture completes, and of the converter's keys
+    only the one that is left reaches the export child's environment."""
+    gone = {"TRACE_CONVERT_GZIP_LEVEL": "12", "TRACE_CONVERT_NICE": "3",
+            "TRACE_CONVERT_YIELD_S": "0.5"}
+    extra = "\n".join(f"{k}={v}" for k, v in gone.items())
+    assert gone.items() <= shim.TraceConfig.parse(extra).raw.items()
+    envs = []
+    real = shim._ExportChild.__init__
+    monkeypatch.setattr(
+        shim._ExportChild, "__init__",
+        lambda self, env: envs.append(env) or real(self, env))
+    manifest, client = capture(
+        tmp_path, monkeypatch, "gone", 100,
+        extra=extra + "\nTRACE_CONVERT_WORKERS=1")
+    assert manifest["status"] == "ok"
+    assert manifest["export_child"] in ("warm", "cold")
+    (env,) = envs
+    assert {k: v for k, v in env.items() if "TRACE_CONVERT" in k} == {
+        "DYNO_TRACE_CONVERT_WORKERS": "1"}
+    assert wait_until(lambda: len(derived_of(manifest)) == 2), derived_of(
+        manifest)
+    client.profiler._export_thread.join(30)
+    assert wait_until(lambda: not live_children())
 
 
 def test_a_capture_whose_stop_raises_sends_its_child_away(

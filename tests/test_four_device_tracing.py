@@ -20,7 +20,7 @@ sys.path.insert(0, str(REPO / "tests"))
 
 import xspace_fixture as xf  # noqa: E402
 from daemon_utils import run_dyno, start_daemon, stop_daemon  # noqa: E402
-from dynolog_tpu import diagnose, obs, trace  # noqa: E402
+from dynolog_tpu import diagnose, obs, trace, xspace  # noqa: E402
 from dynolog_tpu.client.shim import (  # noqa: E402
     RecordingProfiler, TraceClient, TraceConfig)
 
@@ -57,12 +57,12 @@ def four_chip_xspace() -> tuple[bytes, list[bytes]]:
     return b"".join(xf._field_bytes(1, p) for p in planes), planes
 
 
-# ------------------------------------------------------------- trace.py
+# -------------------------------------------------- xspace.py, trace.py
 
 
 def test_plane_index_names_every_plane_and_counts_its_payload():
     data, planes = four_chip_xspace()
-    index = trace.plane_index(data)
+    index = xspace.plane_index(data)
     assert [row["name"] for row in index] == [
         "/host:CPU", "/device:TPU:0", "/device:TPU:1", "/device:TPU:2",
         "/device:TPU:3"]
@@ -70,7 +70,7 @@ def test_plane_index_names_every_plane_and_counts_its_payload():
     framing = sum(1 + len(xf._varint(len(p))) for p in planes)
     assert sum(row["bytes"] for row in index) == len(data) - framing
     # the walker's own per-plane buffers agree
-    assert [len(b) for b in trace.iter_plane_bufs(data)] == [
+    assert [len(b) for b in xspace.iter_plane_bufs(data)] == [
         row["bytes"] for row in index]
 
 
@@ -78,16 +78,16 @@ def test_plane_index_reads_a_memoryview_and_skips_other_top_level_fields():
     data, planes = four_chip_xspace()
     # XSpace{errors=2, warnings=3, hostnames=4}: strings beside the planes
     data = xf._field_str(4, "host-a") + data + xf._field_str(3, "a warning")
-    assert [r["bytes"] for r in trace.plane_index(memoryview(data))] == [
+    assert [r["bytes"] for r in xspace.plane_index(memoryview(data))] == [
         len(p) for p in planes]
-    assert trace.plane_index(b"") == []
+    assert xspace.plane_index(b"") == []
 
 
 @pytest.mark.parametrize("cut", [1, 3, 40])
 def test_plane_index_refuses_a_truncated_xspace(cut):
     data, _ = four_chip_xspace()
     with pytest.raises(ValueError):
-        trace.plane_index(data[:-cut])
+        xspace.plane_index(data[:-cut])
 
 
 def test_collective_share_is_per_plane_and_hand_computable():
@@ -122,7 +122,7 @@ def environment_plane() -> bytes:
     stat_meta = xf._field_varint(1, 1) + xf._field_bytes(
         2, xf._field_varint(1, 1) + xf._field_str(2, "profile_start_time"))
     stat = xf._field_varint(1, 1) + xf._field_varint(3, 1_790_000_000 * 10**9)
-    double = xf._field_varint(1, 2) + b"\x11" + trace.FLOAT64.pack(0.5)
+    double = xf._field_varint(1, 2) + b"\x11" + xspace.FLOAT64.pack(0.5)
     return (xf._field_varint(1, 9) + xf._field_str(2, "Task Environment")
             + xf._field_bytes(5, stat_meta) + xf._field_bytes(6, stat)
             + xf._field_bytes(6, double))
@@ -138,7 +138,7 @@ CONTENT = ("lines", "event_metadata", "stat_metadata", "stats", "other")
 def test_what_a_planes_bytes_are_made_of_adds_up(build):
     data = build()
     summary = trace._summarize_planes(trace.summarize_xplane_bytes(data))
-    index = trace.plane_index(data)
+    index = xspace.plane_index(data)
     assert [(p["name"], p["bytes"]) for p in summary["planes"]] == [
         (row["name"], row["bytes"]) for row in index]
     for p in summary["planes"]:

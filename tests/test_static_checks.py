@@ -285,15 +285,15 @@ def test_cpp_sharded_pattern_synthetic(tmp_path):
 
 
 def test_cpp_sleep_in_hot_path_flagged(tmp_path):
-    root = _copy_subtree(tmp_path, ["src/ringbuffer/RingBuffer.h"])
+    root = _copy_subtree(tmp_path, ["src/common/Failpoints.h"])
     line = _mutate(
-        root, "src/ringbuffer/RingBuffer.h",
-        "    copyIn(head, src, size);\n    header_->head.store(head + size",
+        root, "src/common/Failpoints.h",
+        "    return armedCount_.load(std::memory_order_relaxed) > 0;\n",
         "    std::this_thread::sleep_for(std::chrono::milliseconds(1));\n"
-        "    copyIn(head, src, size);\n    header_->head.store(head + size")
+        "    return armedCount_.load(std::memory_order_relaxed) > 0;\n")
     findings = _findings(concurrency, root)
-    _assert_flagged(findings, "hot-path", "src/ringbuffer/RingBuffer.h", line)
-    assert any("write" in f.message for f in findings), findings
+    _assert_flagged(findings, "hot-path", "src/common/Failpoints.h", line)
+    assert any("anyArmed" in f.message for f in findings), findings
 
 
 def test_cpp_lock_in_signal_handler_flagged(tmp_path):

@@ -10,9 +10,9 @@ Four contracts:
 - PARITY: the streamed converter (serial and parallel) produces
   event-identical — in fact byte-identical decompressed — trace.json to
   the old single-shot converter on tests/fixtures/bench.xplane.pb.
-- BUDGET: ConvertBudget's knobs are honored — max_workers=1 never
-  touches a process pool, env overrides parse (and malformed ones are
-  ignored), serial conversion yields between plane batches.
+- BUDGET: ConvertBudget's one setting is honored — max_workers=1 never
+  touches a process pool, its environment key parses (and a malformed
+  value is ignored).
 - THE RULE: who converts which plane is read from the artifact — the
   caller converts the heaviest plane and every plane with no line in it,
   a worker is forked only for a share of planes worth a fork and is sent
@@ -24,6 +24,8 @@ Four contracts:
 No jax, no C++ build: pure-stdlib, default tier-1 lane.
 """
 
+import ast
+import dataclasses
 import gzip
 import json
 import os
@@ -35,7 +37,7 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from dynolog_tpu import obs, trace  # noqa: E402
+from dynolog_tpu import obs, trace, xspace  # noqa: E402
 
 FIXTURE = REPO / "tests" / "fixtures" / "bench.xplane.pb"
 
@@ -197,37 +199,19 @@ def test_budget_single_plane_never_spawns_pool(tmp_path, monkeypatch):
     assert os.path.exists(out)
 
 
-def test_budget_from_env_and_malformed_values():
-    env = {
-        "DYNO_TRACE_CONVERT_WORKERS": "3",
-        "DYNO_TRACE_CONVERT_GZIP_LEVEL": "5",
-        "DYNO_TRACE_CONVERT_NICE": "7",
-        "DYNO_TRACE_CONVERT_YIELD_S": "0.25",
-    }
-    b = trace.ConvertBudget.from_env(env)
-    assert (b.max_workers, b.gzip_level, b.nice, b.yield_s) == (3, 5, 7, 0.25)
-    # Malformed knobs fall back to defaults instead of raising.
-    bad = trace.ConvertBudget.from_env(
-        {"DYNO_TRACE_CONVERT_WORKERS": "lots",
-         "DYNO_TRACE_CONVERT_YIELD_S": ""})
-    dflt = trace.ConvertBudget()
-    assert bad.max_workers == dflt.max_workers
-    assert bad.yield_s == dflt.yield_s
+def test_budget_from_env_and_malformed_values(monkeypatch):
+    monkeypatch.setenv("DYNO_TRACE_CONVERT_WORKERS", "3")
+    assert trace.ConvertBudget.from_env() == trace.ConvertBudget(max_workers=3)
+    # A malformed or absent setting falls back to the default, not a raise.
+    for bad in ("lots", ""):
+        monkeypatch.setenv("DYNO_TRACE_CONVERT_WORKERS", bad)
+        assert trace.ConvertBudget.from_env() == trace.ConvertBudget()
+    monkeypatch.delenv("DYNO_TRACE_CONVERT_WORKERS")
+    assert trace.ConvertBudget.from_env() == trace.ConvertBudget()
     # resolved_workers: auto caps at cpu count and plane count; the
     # caller is one of them, so auto is the caller and one worker at most.
     assert trace.ConvertBudget(max_workers=8).resolved_workers(2) == 2
     assert 1 <= trace.ConvertBudget(max_workers=0).resolved_workers(64) <= 2
-
-
-def test_budget_serial_yields_between_plane_batches(xplane, monkeypatch):
-    sleeps = []
-    monkeypatch.setattr(trace.time, "sleep", lambda s: sleeps.append(s))
-    trace.write_chrome_trace_gz(
-        xplane,
-        budget=trace.ConvertBudget(
-            max_workers=1, yield_every_planes=2, yield_s=0.01))
-    # 4 planes, yield every 2, no trailing yield after the last -> 1.
-    assert sleeps == [0.01]
 
 
 @pytest.mark.parametrize("dies", ["at-setup", "at-submit", "mid-run"])
@@ -237,7 +221,7 @@ def test_pool_death_degrades_to_serial(xplane, monkeypatch, dies):
     # cost the artifact: the caller converts what the pool has not handed
     # back and the output stays identical. Every plane is spanned once,
     # where it was converted.
-    import concurrent.futures
+    import concurrent.futures.process
 
     single = _read_gz(trace.write_chrome_trace_gz_single(xplane))
     obs.JOURNAL.drain()
@@ -278,14 +262,19 @@ def test_pool_death_degrades_to_serial(xplane, monkeypatch, dies):
     assert workers == ({WORKER_PID} if dies == "mid-run" else set())
 
 
-def test_convert_plane_hands_back_its_fragment_its_summary_and_its_spans():
+def test_convert_plane_hands_back_its_fragment_its_summary_and_its_spans(
+        monkeypatch):
     # The unit of work as a pool worker returns it: the spans travel with
     # the result, and the journal of the process that ran it stays empty.
     obs.JOURNAL.drain()
     data = FIXTURE.read_bytes()
-    bufs = list(trace.iter_plane_bufs(data))
+    bufs = list(xspace.iter_plane_bufs(data))
     ctx = obs.TraceContext.mint()
-    trace._nice_worker(0, ctx)  # what the pool runs in a worker first
+    niced = []
+    # what the pool runs in a worker first (this process keeps its niceness)
+    monkeypatch.setattr(trace.os, "nice", niced.append)
+    trace._nice_worker(ctx)
+    assert niced == [trace.WORKER_NICE]
     try:
         fragment, summary, spans = trace._convert_plane((1, bufs[0]))
     finally:
@@ -301,22 +290,10 @@ def test_convert_plane_hands_back_its_fragment_its_summary_and_its_spans():
     assert obs.JOURNAL.drain() == []
 
 
-def test_out_of_range_gzip_level_clamped(xplane):
-    # TRACE_CONVERT_GZIP_LEVEL=12 parses as a fine int; the writer must
-    # clamp it instead of letting zlib.compressobj raise (which would
-    # silently cost every capture its trace.json.gz).
-    out = trace.write_chrome_trace_gz(
-        xplane, budget=trace.ConvertBudget(max_workers=1, gzip_level=12))
-    assert json.loads(_read_gz(out))["traceEvents"]
-    out = trace.write_chrome_trace_gz(
-        xplane, budget=trace.ConvertBudget(max_workers=1, gzip_level=-7))
-    assert json.loads(_read_gz(out))["traceEvents"]
-
-
-def test_export_fallback_honors_convert_env(xplane, monkeypatch):
-    # The in-process thread fallback must apply the per-capture
-    # TRACE_CONVERT_* knobs (normally injected into the export child's
-    # environment) — and stay serial regardless of the workers knob.
+def test_export_fallback_stays_serial_whatever_the_setting(
+        xplane, monkeypatch):
+    # The in-process thread fallback converts in its own process: no
+    # setting of the environment gives it a pool to fork from a thread.
     from dynolog_tpu.client.shim import JaxProfiler
 
     seen = {}
@@ -326,14 +303,9 @@ def test_export_fallback_honors_convert_env(xplane, monkeypatch):
         return []
 
     monkeypatch.setattr(trace, "write_derived_artifacts", capture)
-    JaxProfiler._export_json(
-        xplane, {"DYNO_TRACE_CONVERT_GZIP_LEVEL": "6",
-                 "DYNO_TRACE_CONVERT_WORKERS": "4",
-                 "DYNO_TRACE_CONVERT_YIELD_S": "0.5"})
-    budget = seen["budget"]
-    assert budget.gzip_level == 6
-    assert budget.yield_s == 0.5
-    assert budget.max_workers == 1  # forced serial on the thread path
+    monkeypatch.setenv("DYNO_TRACE_CONVERT_WORKERS", "4")
+    JaxProfiler._export_json(xplane)
+    assert seen["budget"] == trace.ConvertBudget(max_workers=1)
 
 
 def test_converter_failure_leaves_no_tmp(xplane, monkeypatch):
@@ -392,19 +364,52 @@ def test_shim_convert_budget_plumbing():
     from dynolog_tpu.client.shim import JaxProfiler
 
     prof = JaxProfiler()
-    prof.configure({
-        "TRACE_CONVERT_WORKERS": "1",
-        "TRACE_CONVERT_GZIP_LEVEL": "4",
-        "TRACE_CONVERT_YIELD_S": "0.1",
-    })
-    assert prof.convert_env == {
-        "DYNO_TRACE_CONVERT_WORKERS": "1",
-        "DYNO_TRACE_CONVERT_GZIP_LEVEL": "4",
-        "DYNO_TRACE_CONVERT_YIELD_S": "0.1",
-    }
-    # Per-capture: knobs reset when the next config omits them.
+    prof.configure({"TRACE_CONVERT_WORKERS": "1"})
+    assert prof.convert_env == {"DYNO_TRACE_CONVERT_WORKERS": "1"}
+    # Per-capture: the setting resets when the next config omits it.
     prof.configure({})
     assert prof.convert_env == {}
+
+
+def _package_imports(tree) -> set:
+    """The modules of this package a module's source imports, by name."""
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            assert not node.level, "a relative import: name the module"
+            found.add(node.module)
+            found.update(f"{node.module}.{a.name}" for a in node.names)
+    return {name for name in found if name.split(".")[0] == "dynolog_tpu"}
+
+
+def test_one_module_knows_the_wire_and_the_arrow_points_one_way():
+    # dynolog_tpu.xspace is the only reader of XSpace bytes: it imports
+    # nothing of the package but, at most, obs (so nothing of trace, shim
+    # or diagnose); trace makes no walk of protobuf fields of its own, and
+    # takes from xspace only what its own code uses (no alias kept for a
+    # caller's sake).
+    wire = ast.parse(pathlib.Path(xspace.__file__).read_text())
+    assert _package_imports(wire) - {
+        "dynolog_tpu", "dynolog_tpu.obs"} == set()
+    tree = ast.parse(pathlib.Path(trace.__file__).read_text())
+    named = {node.id for node in ast.walk(tree)
+             if isinstance(node, ast.Name)}
+    named |= {node.attr for node in ast.walk(tree)
+              if isinstance(node, ast.Attribute)}
+    taken = {alias.name for node in ast.walk(tree)
+             if isinstance(node, ast.ImportFrom)
+             and node.module == "dynolog_tpu.xspace" for alias in node.names}
+    assert taken and not {"_fields", "_read_varint", "struct"} & (
+        named | taken)
+    assert taken <= named, taken - named
+    assert not [name for name in taken
+                if getattr(trace, name) is not getattr(xspace, name)]
+    # and no other module of the package reads the bytes behind its back
+    for path in pathlib.Path(trace.__file__).parent.rglob("*.py"):
+        if path.name != "xspace.py":
+            assert "_read_varint" not in path.read_text(), path
 
 
 def test_summarizer_reads_fixture():
@@ -536,7 +541,7 @@ def _wire_fields(buf: bytes):
 def _oracle(data: bytes, group: bool, by_category: bool):
     """[(PlaneSummary, Chrome events)] a plane, decoded by the wheel's own
     xplane_pb2: a decoder that shares nothing with dynolog_tpu.trace."""
-    pb2 = trace._load_xplane_descriptor()
+    pb2 = xspace._load_xplane_descriptor()
     if pb2 is None:
         pytest.skip("no wheel ships an xplane descriptor")
     payloads = [data[p:e] for num, _, p, e in _wire_fields(data) if num == 1]
@@ -548,7 +553,8 @@ def _oracle(data: bytes, group: bool, by_category: bool):
             event_metadata=len(plane.event_metadata), lines=len(plane.lines),
             line_names=[line.name for line in plane.lines])
         for num, start, _, end in _wire_fields(payload):
-            want.content[trace.CONTENT_FIELDS.get(num, "other")] += end - start
+            want.content[
+                xspace.CONTENT_FIELDS.get(num, "other")] += end - start
 
         def costs(stats) -> dict:
             found = {}
@@ -642,14 +648,12 @@ def test_one_decode_equals_the_wheels_decoder(artifact, group, by_category):
     # The old walker is gone, so the oracle is the protobuf runtime's own
     # parse: every field of every PlaneSummary, the rows' order too, and
     # the fragment against json.dumps over the oracle's events.
-    import dataclasses
-
     data = ARTIFACTS[artifact]()
     got = trace.summarize_xplane_bytes(
         data, group=group, by_category=by_category)
     want = _oracle(data, group, by_category)
     assert len(got) == len(want)
-    bufs = list(trace.iter_plane_bufs(data))
+    bufs = list(xspace.iter_plane_bufs(data))
     for pid, (plane, (summary, events)) in enumerate(zip(got, want), start=1):
         assert dataclasses.asdict(plane) == dataclasses.asdict(summary)
         assert list(plane.ops) == list(summary.ops)
@@ -668,7 +672,7 @@ def test_fragments_are_json_dumps_of_the_events(artifact):
     # No wheel needed: the formatted fragment against json.dumps over the
     # dict form of the same events, byte for byte, a plane at a time.
     data = ARTIFACTS[artifact]()
-    for job in enumerate(trace.iter_plane_bufs(data), start=1):
+    for job in enumerate(xspace.iter_plane_bufs(data), start=1):
         assert trace._plane_fragment(job) == ", ".join(
             json.dumps(e) for e in trace._plane_events(*job)).encode()
 
@@ -719,7 +723,7 @@ def test_write_derived_artifacts_decodes_each_plane_once(
     shutil.copy(xplane, apart)
     budget = trace.ConvertBudget(max_workers=workers)
     decoded, walked = [], []
-    decode = trace._decode_plane
+    decode = xspace._decode_plane
 
     def counting(buf, start, end, top=None):
         decoded.append(end - start)
@@ -737,7 +741,7 @@ def test_write_derived_artifacts_decodes_each_plane_once(
             lambda *a, **k: pools.append(InProcessPool(*a, **k))
             or pools[-1])
     written = trace.write_derived_artifacts(xplane, budget)
-    planes = [len(b) for b in trace.iter_plane_bufs(FIXTURE.read_bytes())]
+    planes = [len(b) for b in xspace.iter_plane_bufs(FIXTURE.read_bytes())]
     # once a plane: in file order under one process, the worker's two
     # (as they are submitted) before the caller's two under two
     assert decoded == [planes[i] for i in (
@@ -828,15 +832,13 @@ def _decoded(plane: bytes, generic: bool = False):
     """`_decode_plane`'s answer less its count of generic reads, or the
     error's type; with `generic`, every entry and event through the generic
     path, as if neither loop knew anything."""
-    import dataclasses
-
     with pytest.MonkeyPatch.context() as patch:
         if generic:
-            patch.setattr(trace, "_read_entry", lambda *a: None)
-            patch.setattr(trace, "_read_event", lambda *a: None)
+            patch.setattr(xspace, "_read_entry", lambda *a: None)
+            patch.setattr(xspace, "_read_event", lambda *a: None)
         try:
             got = dataclasses.asdict(
-                trace._decode_plane(plane, 0, len(plane)))
+                xspace._decode_plane(plane, 0, len(plane)))
         except Exception as e:  # noqa: BLE001 - the type is the answer
             return type(e), None
     return got, got.pop("generic")
@@ -957,7 +959,7 @@ def test_the_loops_read_what_the_generic_path_reads(case):
     plane += xf._field_bytes(3, xf._line(8, "Async XLA Ops", 0, list(events)))
     want, _ = _decoded(plane, generic=True)
     assert _decoded(plane) == (want, generic + sum(
-        trace._read_event(ev, 0, len(ev), None) is None for ev in events))
+        xspace._read_event(ev, 0, len(ev), None) is None for ev in events))
     assert all(own is None for ev in want["lines"][1][3] for own in ev[3:])
 
 
@@ -1062,7 +1064,7 @@ def test_a_plane_that_left_the_fast_path_says_so_in_a_span(generic):
         entries = entries + [xf._field_varint(16, 1)]
         events = events + [xf._field_varint(16, 1)]
     plane = _wire_plane(entries, events)
-    assert trace._decode_plane(plane, 0, len(plane)).generic == generic
+    assert xspace._decode_plane(plane, 0, len(plane)).generic == generic
     ctx = obs.TraceContext.mint()
     obs.set_current(ctx)
     try:
@@ -1172,7 +1174,7 @@ def test_the_artifact_decides_who_converts_which_plane(
 
     build, sent = RULE_CASES[case]
     planes = build()
-    weights = [trace._plane_weight(trace._fields(p, 0, len(p)))
+    weights = [trace._plane_weight(*xspace._plane_outline(p, 0, len(p))[1:])
                for p in planes]
     heaviest = max(range(len(planes)), key=weights.__getitem__)
     lineless = [i + 1 for i, (_, lines) in enumerate(weights) if not lines]

@@ -194,9 +194,9 @@ def test_schema_pins_match_wheel_descriptor():
     FileDescriptor embedded in the installed wheel — a jax/tensorflow
     upgrade that renumbers a field fails HERE instead of silently
     mis-summarizing traces."""
-    from dynolog_tpu import trace
+    from dynolog_tpu import xspace
 
-    ok, mismatches = trace.verify_schema_pins()
+    ok, mismatches = xspace.verify_schema_pins()
     if ok is None:
         pytest.skip("no xplane descriptor available in this environment")
     assert ok, mismatches

@@ -44,8 +44,7 @@ Rules (conventions documented in docs/STATIC_ANALYSIS.md):
   `// unsupervised-thread: <reason>` waiver (trailing, or in the comment
   block above). One throw escaping a bare thread entrypoint is a
   std::terminate for the whole daemon — the class of outage the
-  supervision layer exists to kill. src/benchmarks/ is exempt like
-  src/tests/.
+  supervision layer exists to kill. src/tests/ is exempt.
 - unspanned: span-coverage for the control-plane self-tracing layer
   (src/core/SpanJournal.h, docs/OBSERVABILITY.md). A span-required
   function — an event-loop worker handoff (a `handleRequest` or
@@ -160,9 +159,6 @@ _THREAD_VEC_DECL = re.compile(
     r"\bstd::vector<\s*std::thread\s*>\s+([A-Za-z_]\w*)")
 _SUPERVISED = re.compile(r"supervis", re.IGNORECASE)
 _UNSUPERVISED_WAIVER = re.compile(r"unsupervised-thread\s*:\s*(\S.*)")
-# The thread rule's extra exemption (tests are already globally exempt):
-# benchmarks block and join on purpose.
-_THREAD_EXEMPT_DIRS = ("src/benchmarks/",)
 
 # Span-coverage (unspanned rule): tokens that count as "records a span",
 # the marker identifying a verb-dispatch body, and the waiver.
@@ -625,8 +621,7 @@ def run(root: pathlib.Path) -> list[Finding]:
                         hlx, rel, []).items():  # findings from .h scan only
                     infos.setdefault(name, inf)
                 sibling_vectors = _thread_vector_names(hlx)
-        if not any(rel.startswith(d) for d in _THREAD_EXEMPT_DIRS):
-            _check_thread_entrypoints(lx, rel, sibling_vectors, findings)
+        _check_thread_entrypoints(lx, rel, sibling_vectors, findings)
         for fn in fns:
             if fn.cls and fn.cls in infos and infos[fn.cls].guarded:
                 _check_guarded_use(lx, rel, fn, infos[fn.cls], findings)
