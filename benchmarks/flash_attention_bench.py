@@ -5,7 +5,9 @@ benchmark's cells run them at: microseconds a call on the device's own
 clock (a profiler trace of forward+backward calls, the kernels' events
 found by name) and the share of the bfloat16 roofline that is, by the
 count the driver's benchmark holds them to (perfbench/kernel_costs.py,
-perfbench/peaks.json). Kernel-level evidence beside the benchmark the
+perfbench/peaks.json; the windowed kernels of a shape that states a
+`sliding_window` by perfbench/window_costs.py, beside the plain ones at the
+same shape). Kernel-level evidence beside the benchmark the
 driver runs (perfbench/), whose cells time the daemon and the shim. Runs
 on a TPU or not at all: the kernels have no interpret mode of their own.
 
@@ -47,6 +49,10 @@ CELL_SHAPES = {
                           "qk_rope_head_dim": 64, "v_head_dim": 128}, 16),
     "nemotron-3-nano": ({"batch": 2, "seq": 4096, "n_heads": 32,
                          "d_model": 4096}, 2),
+    # 32 query heads of 128 on 4; the windowed layers look back 2048
+    "trinity-mini": ({"batch": 1, "seq": 8192, "n_heads": 32,
+                      "d_model": 4096, "n_kv_heads": 4, "attn_head_dim": 128,
+                      "sliding_window": 2048}, 4),
 }
 
 
@@ -129,13 +135,18 @@ def bench_interleaved(fns, args, iters, rounds=4):
     }
 
 
-def kernel_rows(attn, job: dict, kv_heads: int, device, calls: int = 10):
+def kernel_rows(attn, job: dict, kv_heads: int, device, calls: int = 10,
+                costs=None):
     """[(kernel, microseconds a call, share of its roofline in %)] of the
     three kernels under one forward+backward of `attn` at the job's shape,
     bfloat16: the events of a profiler trace over `calls` calls, by the
-    reducer, the operation count and the peaks of perfbench/."""
+    reducer, the operation count and the peaks of perfbench/. `costs`: the
+    module that names the kernels and counts their work (`KERNELS`,
+    `call_cost`); None: perfbench/kernel_costs.py, the plain kernels'."""
     import cells
     import kernel_costs
+
+    costs = costs or kernel_costs
 
     job = dict(job, dtype="bfloat16")
     d_qk, d_v = kernel_costs.head_widths(job)
@@ -158,9 +169,9 @@ def kernel_rows(attn, job: dict, kv_heads: int, device, calls: int = 10):
             jax.block_until_ready(out)
         (path,) = Path(tmp).rglob("*.xplane.pb")
         run = {"trace": {"path": str(path)}, "device": {"count": 1}}
-        for kernel in kernel_costs.KERNELS:
+        for kernel in costs.KERNELS:
             ns, events = kernel_costs.kernel_events(run, kernel)
-            flops, nbytes = kernel_costs.call_cost(job, kernel)
+            flops, nbytes = costs.call_cost(job, kernel)
             least_s = max(flops / peaks["bf16_flops_per_s"],
                           nbytes / peaks["hbm_bytes_per_s"])
             rows.append((kernel, ns / events / 1e3,
@@ -169,16 +180,25 @@ def kernel_rows(attn, job: dict, kv_heads: int, device, calls: int = 10):
 
 
 def kernel_table(device, names, blocks) -> None:
-    print(f"\n{'cell shape':>18} {'blocks':>9} {'kernel':>24} "
+    import window_costs
+
+    print(f"\n{'cell shape':>18} {'blocks':>9} {'kernel':>30} "
           f"{'us a call':>10} {'roofline %':>10}")
     for name in names:
         job, kv_heads = CELL_SHAPES[name]
+        # the plain kernels, and under them the windowed where the shape
+        # states a window
+        arms = [(None, None)] + (
+            [(job["sliding_window"], window_costs)]
+            if "sliding_window" in job else [])
         for bq, bk in blocks:
-            attn = lambda q, k, v: flash_attention(  # noqa: E731
-                q, k, v, True, bq, bk)
-            for kernel, us, pct in kernel_rows(attn, job, kv_heads, device):
-                print(f"{name:>18} {f'{bq}x{bk}':>9} {kernel:>24} "
-                      f"{us:10.1f} {pct:10.1f}", flush=True)
+            for window, costs in arms:
+                attn = lambda q, k, v: flash_attention(  # noqa: E731
+                    q, k, v, True, bq, bk, None, window)
+                for kernel, us, pct in kernel_rows(
+                        attn, job, kv_heads, device, costs=costs):
+                    print(f"{name:>18} {f'{bq}x{bk}':>9} {kernel:>30} "
+                          f"{us:10.1f} {pct:10.1f}", flush=True)
 
 
 def main() -> None:

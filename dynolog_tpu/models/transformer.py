@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 
-LAYER_TYPES = ("full_attention", "linear_attention")
+LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
 BLOCK_TYPES = ("mamba2", "moe", "attention", "mlp")
 # A mixer's kind -> (its norm's scale in the layer, the scope that norm runs
 # under: a mixer's norm goes with the phase it feeds).
@@ -34,6 +34,13 @@ MIXER_NORMS = {
     "mamba2": ("ssm_scale", "ssm.project"),
     "mlp": ("mlp_scale", "mlp"),
     "moe": ("mlp_scale", "moe.route"),
+}
+# Under cfg.post_norm, a mixer's kind -> (the scale of the norm over what it
+# adds, the scope that norm runs under: that of the phase it follows).
+POST_NORMS = {
+    "attention": ("attn_post_scale", "attn"),
+    "mlp": ("mlp_post_scale", "mlp"),
+    "moe": ("mlp_post_scale", "moe.combine"),
 }
 ROPE_SCALING_KEYS = ("type", "factor", "original_max_position_embeddings",
                      "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
@@ -151,6 +158,27 @@ class TransformerConfig:
     moe_select_bias: bool = False
     moe_gate_scale: float = 1.0
     moe_shared_d_ff: int = 0
+    # A "sliding_attention" layer (layer_types) is attention whose query at
+    # position i sees the sliding_window keys that end at i; a
+    # "full_attention" layer sees every key at or before it.
+    # rope_layer_types: the layer kinds whose attention is rotated where
+    # not all are (("sliding_attention",): the full layers carry no
+    # position). None: every attention layer, where rope_theta is stated.
+    sliding_window: int = 0
+    rope_layer_types: tuple | None = None
+    # Attention's q and k each through an RMS norm over a head's width,
+    # one learned weight shared by the heads (`q_head_scale`,
+    # `k_head_scale`), before the rotary embedding; qk_norm is OLMo-2's
+    # norm over the whole of q and k.
+    qk_head_norm: bool = False
+    # Attention's output times sigmoid(x W_g), a fourth projection (`wg`,
+    # as wide as q), before the output matrix.
+    attn_gate: bool = False
+    # A norm over what each mixer adds beside the one over what it reads:
+    # x + post_norm(mixer(norm(x))) (POST_NORMS; attention, mlp and moe).
+    post_norm: bool = False
+    # The embedding times sqrt(d_model) (a published `mup_enabled`).
+    scale_embedding: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -195,6 +223,19 @@ class TransformerConfig:
                     f"{self.ssm_groups} groups")
             if "moe" in self.block_types and not self.n_experts:
                 raise ValueError("a moe block of no experts (n_experts 0)")
+        if self.rope_layer_types is not None:
+            # a list from JSON: the configuration keys a jitted function
+            object.__setattr__(
+                self, "rope_layer_types", tuple(self.rope_layer_types))
+            if set(self.rope_layer_types) - set(LAYER_TYPES):
+                raise ValueError(
+                    f"rope_layer_types {self.rope_layer_types}: among "
+                    f"{LAYER_TYPES}")
+        if self.post_norm and (
+                self.block_types is not None or self.attn_type != "mha"):
+            raise ValueError(
+                f"post_norm follows the mixers of {tuple(POST_NORMS)}: not "
+                "one-mixer blocks, nor latent attention")
         held = self.n_experts_held or self.n_experts
         if not 0 <= self.first_expert_held <= self.n_experts - held:
             raise ValueError(
@@ -210,6 +251,33 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_types {self.layer_types}: one of {LAYER_TYPES} for "
                 f"each of the {self.n_layers} layers")
+        if "sliding_attention" in self.layer_types and (
+                self.sliding_window < 1 or self.attn_type != "mha"):
+            raise ValueError(
+                "layer_types names a sliding_attention layer: "
+                f"sliding_window {self.sliding_window} under attn_type "
+                f"{self.attn_type!r} has to be a window of 1 or more keys, "
+                "on multi-head attention")
+        if self.post_norm and self.has_linear_layers:
+            raise ValueError(
+                f"post_norm follows the mixers of {tuple(POST_NORMS)}: not a "
+                "linear_attention layer")
+
+    def layer_type(self, i: int) -> str:
+        return "full_attention" if self.layer_types is None else (
+            self.layer_types[i])
+
+    def window(self, i: int) -> int | None:
+        """The keys a query of layer i's attention sees; None: all at or
+        before it."""
+        return (self.sliding_window
+                if self.layer_type(i) == "sliding_attention" else None)
+
+    def rotary(self, i: int) -> bool:
+        """Whether layer i's attention is rotated."""
+        return self.rope_theta is not None and (
+            self.rope_layer_types is None
+            or self.layer_type(i) in self.rope_layer_types)
 
     def is_linear(self, i: int) -> bool:
         return (self.layer_types is not None
@@ -288,6 +356,8 @@ def init_params(rng, cfg: TransformerConfig):
         layer = {}
         for kind in cfg.mixers(i):
             layer[MIXER_NORMS[kind][0]] = jnp.ones((d,), dtype)
+            if cfg.post_norm:
+                layer[POST_NORMS[kind][0]] = jnp.ones((d,), dtype)
             if kind == "mamba2":
                 from dynolog_tpu.models.mamba2 import init_mamba2_layer
 
@@ -310,6 +380,12 @@ def init_params(rng, cfg: TransformerConfig):
                 if cfg.qk_norm:
                     layer.update(q_scale=jnp.ones((h * hd,), dtype),
                                  k_scale=jnp.ones((kv * hd,), dtype))
+                if cfg.qk_head_norm:
+                    layer.update(q_head_scale=jnp.ones((hd,), dtype),
+                                 k_head_scale=jnp.ones((hd,), dtype))
+                if cfg.attn_gate:
+                    layer["wg"] = dense(
+                        jax.random.fold_in(k[0], 1), (d, h * hd), d)
             elif kind == "moe":
                 from dynolog_tpu.models.moe import init_moe_layer
 
@@ -377,17 +453,18 @@ def _rope(x, positions, theta, scaling: dict | None = None):
 
 
 def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
-                       scale=None):
+                       scale=None, window=None):
     """Causal softmax attention by cfg.attn_impl. q: [B, S, H, D], k:
     [B, S, Hkv, D], v: [B, S, Hkv, Dv] -> [B, S, H, Dv], query head j on
-    key/value head j // (H / Hkv); `scale` None: D ** -0.5."""
+    key/value head j // (H / Hkv); `scale` None: D ** -0.5; `window` W: a
+    query sees the W keys that end at its own position, None: all of them."""
     s, hd = q.shape[1], q.shape[-1]
     group = q.shape[2] // k.shape[2]
     if cfg.attn_impl == "flash":
         from dynolog_tpu.ops.flash_attention import flash_attention
 
         def attn(q, k, v):
-            return flash_attention(q, k, v, True, scale=scale)
+            return flash_attention(q, k, v, True, scale=scale, window=window)
 
         if mesh is not None:
             # A Mosaic kernel is opaque to the SPMD partitioner ("cannot be
@@ -412,7 +489,8 @@ def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
                 "attn_impl='ring' runs as many key/value heads as query "
                 "heads, of one width, at the scale D ** -0.5: not latent "
                 "attention's, nor grouped heads")
-        return ring_attention(q, k, v, mesh, causal=True)
+        # (a window it refuses itself)
+        return ring_attention(q, k, v, mesh, causal=True, window=window)
     if group > 1:  # the plain path writes k and v out a query head each
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
@@ -420,13 +498,19 @@ def _softmax_attention(q, k, v, cfg: TransformerConfig, mesh=None,
         scores = scores / jnp.sqrt(hd).astype(q.dtype)
     else:
         scores = scores * jnp.asarray(scale, q.dtype)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal[None, None], scores.astype(jnp.float32), -1e30)
+    from dynolog_tpu.ops.flash_attention import band_mask
+
+    seen = band_mask(s, s, window)
+    scores = jnp.where(seen[None, None], scores.astype(jnp.float32), -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
+def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None,
+               window=None, rotary=True):
+    """`window`: the keys a query sees (None: all at or before it);
+    `rotary`: whether this layer is rotated where cfg.rope_theta is stated
+    (a layer's kind decides both, `cfg.window`, `cfg.rotary`)."""
     b, s, _ = x.shape
     h, kv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     with jax.named_scope("attn"):
@@ -435,8 +519,11 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
             q = _rmsnorm(q, layer["q_scale"], cfg.norm_eps)
             k = _rmsnorm(k, layer["k_scale"], cfg.norm_eps)
         q, k = q.reshape(b, s, h, hd), k.reshape(b, s, kv, hd)
+        if cfg.qk_head_norm:
+            q = _rmsnorm(q, layer["q_head_scale"], cfg.norm_eps)
+            k = _rmsnorm(k, layer["k_head_scale"], cfg.norm_eps)
         v = (x @ layer["wv"]).reshape(b, s, kv, hd)
-        if cfg.rope_theta is not None:
+        if rotary and cfg.rope_theta is not None:
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
     # The kernels stay outside the scope: the TPU's compiler names a
@@ -446,9 +533,12 @@ def _attention(layer, x, positions, cfg: TransformerConfig, mesh=None):
     # its scope (`trace.op_scope`).
     with (contextlib.nullcontext() if cfg.attn_impl == "flash"
           else jax.named_scope("attn")):
-        out = _softmax_attention(q, k, v, cfg, mesh)
+        out = _softmax_attention(q, k, v, cfg, mesh, window=window)
     with jax.named_scope("attn"):
-        return out.reshape(b, s, h * hd) @ layer["wo"]
+        out = out.reshape(b, s, h * hd)
+        if cfg.attn_gate:
+            out = out * jax.nn.sigmoid(x @ layer["wg"])
+        return out @ layer["wo"]
 
 
 def _mlp(layer, x, act="swiglu"):
@@ -459,9 +549,18 @@ def _mlp(layer, x, act="swiglu"):
         return (gate * (x @ layer["w_up"])) @ layer["w_down"]
 
 
-def _mixer(kind, layer, x, positions, cfg: TransformerConfig, mesh):
-    """One mixer of a layer on the residual stream x -> (what it adds to x,
-    its weighted share of the loss's expert terms or None)."""
+def _mixer(kind, i, layer, x, positions, cfg: TransformerConfig, mesh):
+    """Mixer `kind` of layer i on the residual stream x -> (what it adds to
+    x, its weighted share of the loss's expert terms or None)."""
+    y, terms = _mix(kind, i, layer, x, positions, cfg, mesh)
+    if cfg.post_norm:
+        scale, scope = POST_NORMS[kind]
+        with jax.named_scope(scope):
+            y = _rmsnorm(y, layer[scale], cfg.norm_eps)
+    return y, terms
+
+
+def _mix(kind, i, layer, x, positions, cfg: TransformerConfig, mesh):
     scale, scope = MIXER_NORMS[kind]
     with jax.named_scope(scope):
         h = _rmsnorm(x, layer[scale], cfg.norm_eps)
@@ -478,7 +577,8 @@ def _mixer(kind, layer, x, positions, cfg: TransformerConfig, mesh):
 
         return latent_attention(layer, h, positions, cfg, mesh), None
     if kind == "attention":
-        return _attention(layer, h, positions, cfg, mesh), None
+        return _attention(layer, h, positions, cfg, mesh, cfg.window(i),
+                          cfg.rotary(i)), None
     if kind == "mlp":
         return _mlp(layer, h, cfg.mlp_act), None
     from dynolog_tpu.models.moe import moe_mlp
@@ -493,13 +593,15 @@ def _forward_with_aux(params, tokens, cfg: TransformerConfig, mesh=None):
     weighted loss terms, a mean over layers: 0 for a dense model)."""
     with jax.named_scope("embed"):
         x = params["embedding"][tokens]
+        if cfg.scale_embedding:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
     positions = jnp.broadcast_to(
         jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
     )
     aux = jnp.zeros((), jnp.float32)
     for i, layer in enumerate(params["layers"]):
         for kind in cfg.mixers(i):
-            y, terms = _mixer(kind, layer, x, positions, cfg, mesh)
+            y, terms = _mixer(kind, i, layer, x, positions, cfg, mesh)
             x = x + y
             if terms is not None:
                 aux = aux + terms

@@ -50,6 +50,30 @@ Design (TPU-first, not a port — the reference does no model computation):
   the diagonal could skip the iota, the compare and the select, but a
   second loop (a second copy of the body) costs every program more than
   the mask costs the tiles that do not need it (measured: 3-9 % slower).
+- A window (`window` W, static; a model's `sliding_attention` layers): a
+  query at position i sees key j where 0 <= i - j < W. The band has a
+  second edge, so the one loop gains a second bound and the one mask a
+  second compare, and nothing else: the forward and dq loops start at
+  the key block that holds the earliest key the query block's FIRST query
+  still sees (`_key_blocks`) and end at the diagonal as before; the dkv
+  loop starts at the diagonal as before and ends at the query block that
+  holds the latest query that still sees the key block's LAST key
+  (`_query_blocks`). What is skipped: every tile wholly outside the band,
+  on both sides. What is masked: every visited tile, by `_mask` with both
+  compares; the tiles the two edges cross (at W a multiple of the block,
+  two of a query block's W / block + 1) are computed whole, so at W 2048
+  and blocks of 512 a query block visits 5 tiles for 4 tiles' worth of
+  pairs and no windowed kernel can read above 80 % of its roofline. A
+  query row that sees no key of the first tile its block visits carries
+  the maximum -1e30 and sums of masked keys through it; the first key it
+  does see multiplies both by exp(-1e30 - m) = 0. The windowed programs
+  are named `flash_attention_window_fwd`, `_bwd_dq`, `_bwd_dkv`: a
+  kernel's name is its op's name and its scope in a capture, and the
+  benchmark's readers of the plain kernels match a fragment of theirs.
+  With `window=None` the bounds and the mask are written as they were, and
+  the three programs are the plain causal ones instruction for
+  instruction (perfbench/aot.py on parent and change, PERF.md section 6,
+  PR 48).
 - The kernels carry no interpret switch: on a TPU Mosaic compiles them, and
   anywhere else the call fails. The CPU tests run the same code under
   `jax.experimental.pallas.tpu.force_tpu_interpret_mode()`, chosen in the
@@ -76,10 +100,20 @@ def _pick_block(seq_len: int, target: int) -> int:
     return b
 
 
-def reference_attention(q, k, v, *, causal: bool = True, scale=None):
+def band_mask(s_q: int, s_k: int, window=None):
+    """[s_q, s_k] bool: query i sees key j where 0 <= i - j and, under a
+    `window`, i - j < window."""
+    ahead = jnp.arange(s_q)[:, None] - jnp.arange(s_k)[None, :]
+    seen = ahead >= 0
+    return seen if window is None else seen & (ahead < window)
+
+
+def reference_attention(q, k, v, *, causal: bool = True, scale=None,
+                        window=None):
     """Plain-XLA attention; q: [B, S, H, D], k: [B, S, Hkv, D], v:
     [B, S, Hkv, Dv] -> [B, S, H, Dv], k and v repeated to the H query heads
-    where they are fewer. `scale` None: D ** -0.5."""
+    where they are fewer. `scale` None: D ** -0.5. `window` W (causal only):
+    a query sees the W keys that end at its own position."""
     d = q.shape[-1]
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -91,8 +125,7 @@ def reference_attention(q, k, v, *, causal: bool = True, scale=None):
         scores = scores * jnp.asarray(scale, q.dtype)
     scores = scores.astype(jnp.float32)
     if causal:
-        s_q, s_k = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), bool))
+        mask = band_mask(q.shape[1], k.shape[1], window)
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -133,17 +166,47 @@ def _row(ref, block, size):
     return ref[0, :, pl.ds(pl.multiple_of(block * size, size), size)]
 
 
-def _mask(s, q0, k0, q_axis: int):
+def _mask(s, q0, k0, q_axis: int, window=None):
     """The scores of a tile with the pairs a query may not see (key after
-    query) at _NEG_INF. Queries q0.. run along `q_axis` of s, keys k0..
-    along the other."""
+    query; under a `window`, key `window` or more before it) at _NEG_INF.
+    Queries q0.. run along `q_axis` of s, keys k0.. along the other."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, s, _NEG_INF)
+
+
+def _key_blocks(qi, block_q, block_k, seq_len, causal, window):
+    """(first, past the last) key block that query block qi loops over:
+    all of them; under `causal` up to its diagonal; under a `window` from
+    the block that holds the earliest key its first query still sees."""
+    if not causal:
+        return 0, seq_len // block_k
+    last = jax.lax.div(qi * block_q + block_q + block_k - 1, block_k)
+    if window is None:
+        return 0, last
+    return jax.lax.div(
+        jnp.maximum(qi * block_q - (window - 1), 0), block_k), last
+
+
+def _query_blocks(ki, block_q, block_k, seq_len, causal, window):
+    """(first, past the last) query block that key block ki loops over:
+    under `causal` from its diagonal; under a `window` to the block that
+    holds the latest query that still sees its last key."""
+    if not causal:
+        return 0, seq_len // block_q
+    first = jax.lax.div(ki * block_k, block_q)
+    if window is None:
+        return first, seq_len // block_q
+    return first, jax.lax.div(
+        jnp.minimum(ki * block_k + block_k + window - 2, seq_len - 1),
+        block_q) + 1
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
-                causal, scale):
+                causal, scale, window):
     """One (batch*head, q-block) program. q_ref: [1, block_q, D];
     k_ref: [1, S, D]; v_ref: [1, S, Dv]; o_ref: [1, block_q, Dv];
     lse_ref: [1, 1, S]
@@ -167,7 +230,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
         m, l, acc = carry  # [1, block_q] twice, [Dv, block_q]; float32
         s = _dot(_rows(k_ref, kb, block_k), q, _NT)  # [block_k, block_q]
         if causal:
-            s = _mask(s, qi * block_q, kb * block_k, 1)
+            s = _mask(s, qi * block_q, kb * block_k, 1, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -176,13 +239,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
         acc_new = acc * alpha + _dot(v_blk, p.astype(v_blk.dtype), _TN)
         return m_new, l_new, acc_new
 
-    if causal:
-        # Key blocks past this query block's diagonal are fully masked —
-        # skip them (dynamic trip count lowers to a while loop).
-        n_kb = jax.lax.div(qi * block_q + block_q + block_k - 1, block_k)
-    else:
-        n_kb = seq_len // block_k
-    m, l, acc = jax.lax.fori_loop(0, n_kb, tile, (
+    # Key blocks past this query block's diagonal (and, under a window,
+    # before the first it still sees) are fully masked — skip them (dynamic
+    # trip count lowers to a while loop). A row that sees no key of the
+    # first tiles visited carries a maximum of _NEG_INF and sums of masked
+    # keys through them; its first seen key multiplies both by exp(-1e30).
+    kb_first, kb_end = _key_blocks(
+        qi, block_q, block_k, seq_len, causal, window)
+    m, l, acc = jax.lax.fori_loop(kb_first, kb_end, tile, (
         jnp.full((1, block_q), _NEG_INF, jnp.float32),
         jnp.zeros((1, block_q), jnp.float32),
         jnp.zeros((v_ref.shape[2], block_q), jnp.float32)))
@@ -202,7 +266,15 @@ def _kv_row(group: int):
     return lambda b, i: (b // group, 0, 0)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
+def _name(kernel: str, window) -> str:
+    """A program's name, and so its op's name and its scope in a capture:
+    `flash_attention_<kernel>`, `flash_attention_window_<kernel>` under a
+    window (a reader of the plain kernels does not match the windowed)."""
+    return f"flash_attention_{'' if window is None else 'window_'}{kernel}"
+
+
+def _flash_forward(q, k, v, causal, block_q, block_k, scale=None,
+                   window=None):
     """q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv] -> (out
     [B*H, S, Dv], lse [B*H, 1, S] f32)."""
     bh, s, d = q.shape
@@ -211,7 +283,8 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     kernel = functools.partial(
-        _fwd_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale)
+        _fwd_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale,
+        window=window)
     return pl.pallas_call(
         kernel,
         grid=(bh, s // bq),
@@ -228,7 +301,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
             jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
-        name="flash_attention_fwd",
+        name=_name("fwd", window),
     )(q, k, v)
 
 
@@ -236,7 +309,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale=None):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_q, block_k, causal, scale):
+               block_q, block_k, causal, scale, window):
     """dQ for one (batch*head, q-block): loop over visible key blocks.
     Scores as [block_q, block_k] here: nothing is reduced over a tile, and
     dS·K is then a plain product."""
@@ -255,22 +328,21 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         s = _dot(qs, k_blk, _NT)                    # [block_q, block_k]
         if causal:
-            s = _mask(s, qi * block_q, kb * block_k, 0)
+            s = _mask(s, qi * block_q, kb * block_k, 0, window)
         p = jnp.exp(s - lse[:, None])
         ds = p * (_dot(do, v_blk, _NT) - delta[:, None])
         return dq + _dot(ds, k_blk, _NN)
 
-    if causal:
-        n_kb = jax.lax.div(qi * block_q + block_q + block_k - 1, block_k)
-    else:
-        n_kb = seq_len // block_k
+    kb_first, kb_end = _key_blocks(
+        qi, block_q, block_k, seq_len, causal, window)
     dq0 = jnp.zeros((block_q, head_dim), jnp.float32)
-    dq = jax.lax.fori_loop(0, n_kb, body, dq0)
+    dq = jax.lax.fori_loop(kb_first, kb_end, body, dq0)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *sums, block_q, block_k, causal, scale):
+                dk_ref, dv_ref, *sums, block_q, block_k, causal, scale,
+                window):
     """dK and dV for one (batch*head, k-block): loop over query blocks at
     or below this key block's diagonal. Scores transposed as in the
     forward, [block_k, block_q]: lse and delta are read as the rows they
@@ -293,19 +365,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = _rows(do_ref, qb, block_q)
         s = _dot(ks, q_blk, _NT)                    # [block_k, block_q]
         if causal:
-            s = _mask(s, qb * block_q, ki * block_k, 1)
+            s = _mask(s, qb * block_q, ki * block_k, 1, window)
         p = jnp.exp(s - _row(lse_ref, qb, block_q))
         dv_new = dv + _dot(p.astype(do.dtype), do, _NN)   # [block_k, Dv]
         ds = p * (_dot(v_blk, do, _NT) - _row(delta_ref, qb, block_q))
         dk_new = dk + _dot(ds.astype(q_blk.dtype), q_blk, _NN)
         return dk_new, dv_new
 
-    if causal:
-        # First query block whose rows can see this key block.
-        qb_start = jax.lax.div(ki * block_k, block_q)
-    else:
-        qb_start = 0
-    dk, dv = jax.lax.fori_loop(qb_start, seq_len // block_q, tile, (
+    # From the first query block whose rows can see this key block (and,
+    # under a window, to the last).
+    qb_first, qb_end = _query_blocks(
+        ki, block_q, block_k, seq_len, causal, window)
+    dk, dv = jax.lax.fori_loop(qb_first, qb_end, tile, (
         jnp.zeros((block_k, head_dim), jnp.float32),
         jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)))
     dk = dk * scale  # the scale of Q in dsᵀ·(scale Q), taken out of the sum
@@ -331,7 +402,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                    scale=None):
+                    scale=None, window=None):
     """Residuals q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv], out
     [B*H, S, Dv] + cotangent g -> (dq, dk, dv), dk and dv summed over the
     query heads of a key/value head's group."""
@@ -349,7 +420,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
 
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale),
+            _dq_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale,
+            window=window),
         grid=(bh, s // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
@@ -361,7 +433,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        name="flash_attention_bwd_dq",
+        name=_name("bwd_dq", window),
     )(q, k, v, g, lse, delta)
 
     if group == 1:
@@ -387,7 +459,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
 
     dk, dv_ = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale),
+            _dkv_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale,
+            window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, s, d), head),
@@ -406,7 +479,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=scratch,
-        name="flash_attention_bwd_dkv",
+        name=_name("bwd_dkv", window),
     )(q, k, v, g, lse, delta)
     return dq, dk, dv_
 
@@ -424,9 +497,9 @@ def _from_bh(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
-                    scale=None):
+                    scale=None, window=None):
     """Flash attention; q: [B, S, H, D], k: [B, S, Hkv, D], v:
     [B, S, Hkv, Dv] -> [B, S, H, Dv]. The key/value heads may be fewer than
     the query heads (grouped-query attention): query head j reads key/value
@@ -435,7 +508,9 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     dkv kernel. Keys may be wider than values (latent attention expands
     to keys of 192 and values of 128): the score products run over D, the
     P.V and dV products over Dv, nothing is padded. `scale` is the softmax
-    scale, D ** -0.5 where None.
+    scale, D ** -0.5 where None. `window` W (static, causal only): query i
+    sees key j where 0 <= i - j < W; None: every key at or before it, and
+    the three programs are the plain causal ones, names and all.
 
     Forward and backward both run as Pallas kernels; only O(S) residuals
     (q, k, v, out, lse) are saved.
@@ -455,26 +530,28 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     above 80 % (89 %). The backward kernels keep the MXU busy for about
     four fifths of a visited tile, the forward for a little over half.
     """
-    b, _, h, _ = q.shape
-    out, _ = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k, scale)
-    return _from_bh(out, b, h)
+    return _vjp_fwd(q, k, v, causal, block_q, block_k, scale, window)[0]
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_k, scale):
+def _vjp_fwd(q, k, v, causal, block_q, block_k, scale, window):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window {window}: a whole number of keys, 1 or more, that end "
+            "at the query's own position (causal)")
     b, _, h, _ = q.shape
     out, lse = _flash_forward(
-        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k, scale)
+        _to_bh(q), _to_bh(k), _to_bh(v), causal, block_q, block_k, scale,
+        window)
     return _from_bh(out, b, h), (q, k, v, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_k, scale, res, g):
+def _vjp_bwd(causal, block_q, block_k, scale, window, res, g):
     q, k, v, out_bh, lse = res
     b, _, h, _ = q.shape
     kv = k.shape[2]
     dq, dk, dv = _flash_backward(
         _to_bh(q), _to_bh(k), _to_bh(v), out_bh, lse, _to_bh(g),
-        causal, block_q, block_k, scale)
+        causal, block_q, block_k, scale, window)
     return _from_bh(dq, b, h), _from_bh(dk, b, kv), _from_bh(dv, b, kv)
 
 

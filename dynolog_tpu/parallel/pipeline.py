@@ -46,12 +46,19 @@ def _require_dense_pairs(cfg: TransformerConfig) -> None:
     assert (cfg.n_experts == 0 and cfg.attn_impl == "reference"
             and not cfg.has_linear_layers and cfg.attn_type == "mha"
             and cfg.block_types is None and cfg.kv_heads == cfg.n_heads
-            and cfg.mlp_act == "swiglu"), (
+            and cfg.mlp_act == "swiglu"
+            and all(cfg.window(i) is None and cfg.rotary(i) == cfg.rotary(0)
+                    for i in range(cfg.n_layers))
+            and not (cfg.qk_head_norm or cfg.attn_gate or cfg.post_norm
+                     or cfg.scale_embedding)), (
         "pipeline path supports the dense/reference transformer config: no "
         "experts, no linear_attention layer, no latent attention, no "
         "one-mixer blocks (block_types: a mamba2 block among them), no "
-        "grouped key/value heads and no ReLU^2 MLP (a stage would run each "
-        "as multi-head attention and a SwiGLU MLP)"
+        "grouped key/value heads, no ReLU^2 MLP, no sliding_attention "
+        "layer's window or position by layer kind, no per-head norms, "
+        "gated attention, post-mixer norms or scaled embedding (a stage "
+        "would run each as full multi-head attention and a SwiGLU MLP "
+        "between pre-norms, every layer rotated alike)"
     )
 
 

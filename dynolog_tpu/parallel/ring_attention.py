@@ -83,11 +83,18 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool):
 
 
 def ring_attention(q, k, v, mesh, *, seq_axis: str = "seq",
-                   batch_axis: str = "data", causal: bool = True):
+                   batch_axis: str = "data", causal: bool = True,
+                   window=None):
     """Exact causal attention with the sequence dim sharded over
     `seq_axis`. q,k,v: global [B, S, H, D]; heads stay replicated over the
     mesh's model axis here (the projections around this op are the
-    tensor-parallel part)."""
+    tensor-parallel part). A `window` (a sliding_attention layer's) is
+    refused: every key block goes round the whole ring and every hop is
+    summed, so a band would be computed as full attention."""
+    if window is not None:
+        raise ValueError(
+            f"ring attention sees every key at or before a query: a "
+            f"sliding_attention layer's window ({window}) is not run here")
     spec = P((batch_axis,), (seq_axis,), None, None)
     body = functools.partial(
         _ring_attention_local, axis_name=seq_axis, causal=causal)

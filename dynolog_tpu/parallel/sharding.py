@@ -93,6 +93,11 @@ PARAM_RULES = {
     "wk": P(None, "model"),
     "wv": P(None, "model"),
     "wo": P("model", None),
+    # Gated attention: the gate's matrix as wq (columns are heads x head
+    # size). The per-head norms' weights (q_head_scale, k_head_scale: one
+    # head's width, shared by the heads) and the post-mixer norms'
+    # (attn_post_scale, mlp_post_scale) are replicated, by "scale" below.
+    "wg": P(None, "model"),
     "w_gate": P(None, "model"),
     "w_up": P(None, "model"),
     "w_down": P("model", None),

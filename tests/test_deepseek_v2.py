@@ -213,26 +213,30 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads, kv_heads, d_qk, d_v, scale", [
-    (16, 16, 192, 128, 0.11472),  # latent attention's
-    (32, 2, 128, 128, None),  # Nemotron-3-Nano's grouped heads
-], ids=["latent", "grouped"])
+@pytest.mark.parametrize("heads, kv_heads, d_qk, d_v, scale, seq, window", [
+    (16, 16, 192, 128, 0.11472, 4096, None),  # latent attention's
+    (32, 2, 128, 128, None, 4096, None),  # Nemotron-3-Nano's grouped heads
+    (32, 4, 128, 128, None, 8192, 2048),  # Trinity-Mini's windowed layers
+], ids=["latent", "grouped", "windowed"])
 def test_the_kernels_compile_for_the_chip_at_the_published_widths(
-        one_chip, heads, kv_heads, d_qk, d_v, scale):
+        one_chip, heads, kv_heads, d_qk, d_v, scale, seq, window):
     """Keys of 192 (a lane and a half) and values of 128; 32 query heads on
     2 key/value heads of 128 with the dkv kernel's two float32 sums in fast
     memory; sequence 4096, blocks of 512: what interpret mode cannot refuse
     (a block that does not tile, more fast memory than a kernel may use)
     Mosaic would. Grouped, k, v, dk and dv stay at 2 heads: no operand or
-    result of a kernel holds them at 32. A compile that passes is no chip
-    run. (Both cases here: one file a worker describes the topology in.)"""
+    result of a kernel holds them at 32. Under a window of 2048 in 8192
+    positions (32 on 4 heads) the three programs carry their own names and
+    their loops start and end at run-time bounds. A compile that passes is
+    no chip run. (All cases here: one file a worker describes the topology
+    in.)"""
     def shape(n, width):
         return jax.ShapeDtypeStruct(
-            (1, 4096, n, width), jnp.bfloat16, sharding=one_chip)
+            (1, seq, n, width), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(
-            q, k, v, True, scale=scale).astype(jnp.float32))
+            q, k, v, True, scale=scale, window=window).astype(jnp.float32))
 
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -243,10 +247,13 @@ def test_the_kernels_compile_for_the_chip_at_the_published_widths(
         jax.config.update("jax_enable_compilation_cache", True)
     calls = [line for line in compiled.as_text().splitlines()
              if "custom_call_target" in line and "flash_attention" in line]
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
-        assert any(kernel in line for line in calls), kernel
-    if kv_heads < heads:
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        name = f"flash_attention_{'window_' if window else ''}{kernel}"
+        assert any(name in line for line in calls), name
+        if window:  # and no plain program beside the windowed
+            assert not any(
+                f"flash_attention_{kernel}" in line for line in calls)
+    if kv_heads == 2:
         dkv = next(line for line in calls if "flash_attention_bwd_dkv" in line)
         assert "bf16[2,4096,128]" in dkv.split("custom-call(")[0]  # dk, dv
         fwd = next(line for line in calls if "flash_attention_fwd" in line)
