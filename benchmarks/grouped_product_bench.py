@@ -1,9 +1,10 @@
 """Grouped-product benchmark: the products of an expert layer that holds a
 share of its experts (dynolog_tpu/models/moe.py, `ep == 1`), alone on the
-attached TPU chip, at the shapes of the two benchmark cells that run them
+attached TPU chip, at the shapes of the three benchmark cells that run them
 (`nemotron-3-nano.capture`: 49152 copies of 2688 over 16 held experts of
 1856, two matrices an expert; `deepseek-v2-lite.capture`: 49152 copies of
-2048 over 16 of 1408, three). For each layout of the held rows (the groups
+2048 over 16 of 1408, three; `trinity-mini.capture`: 65536 copies of 2048
+over 16 of 1024, three). For each layout of the held rows (the groups
 as the sort leaves them, back to back; each group moved to the next multiple
 of 128, 256 or 512 rows, zero rows between, which is `moe._aligned`) and each
 product (`jax.lax.ragged_dot`, XLA's own kernel; the Pallas grouped product
@@ -28,6 +29,8 @@ driver runs (perfbench/). Runs on a TPU or not at all.
 Usage: python benchmarks/grouped_product_bench.py
        [--shapes nemotron-3-nano,deepseek-v2-lite] [--aligns 0,128,256,512]
        [--tilings 512x512x512,512x1024x1024] [--seeds 6]
+       [--blocks 2048,4096,8192]
+(`--aligns ""` leaves the products out, `--blocks ""` the gathers)
 """
 
 from __future__ import annotations
@@ -60,19 +63,27 @@ SHAPES = {
                             f=1856, into=1, blocks=3),
     "deepseek-v2-lite": dict(tokens=8192, k=6, experts=64, held=16, d=2048,
                              f=1408, into=2, blocks=4),
+    "trinity-mini": dict(tokens=8192, k=8, experts=128, held=16, d=2048,
+                         f=1024, into=2, blocks=4),
 }
 SKEW = 0.08  # an expert's log load about the mean, standard deviation
 DIRECTIONS = ("fwd", "d_rows", "d_weights")
 
 
+def draw_chosen(seed: int, shape: dict, among: str = "experts") -> np.ndarray:
+    """[tokens, k]: each token's experts under one seeded routing of a step,
+    over all the router scores or (`among="held"`) the held ones alone."""
+    rng = np.random.default_rng(seed)
+    scores = rng.gumbel(size=(shape["tokens"], shape[among]))
+    scores += SKEW * rng.normal(size=shape[among])
+    return np.argpartition(-scores, shape["k"], axis=1)[:, :shape["k"]]
+
+
 def draw_groups(seed: int, shape: dict) -> np.ndarray:
     """Rows for each held expert under one seeded routing of a step."""
-    rng = np.random.default_rng(seed)
-    scores = rng.gumbel(size=(shape["tokens"], shape["experts"]))
-    scores += SKEW * rng.normal(size=shape["experts"])
-    chosen = np.argpartition(-scores, shape["k"], axis=1)[:, :shape["k"]]
     return np.bincount(
-        chosen.reshape(-1), minlength=shape["experts"])[:shape["held"]]
+        draw_chosen(seed, shape).reshape(-1),
+        minlength=shape["experts"])[:shape["held"]]
 
 
 def layout(sizes: np.ndarray, copies: int, align: int):
@@ -142,6 +153,81 @@ def time_products(product, shape: dict, sizes, real, calls: int = 5) -> dict:
     return out
 
 
+def buffer_order(chosen: np.ndarray, shape: dict):
+    """(order, used) as `moe._moe_local` lays a routing out: for each row of
+    the buffer the copy it holds (`copies`: none), and the rows in use."""
+    copies = chosen.size
+    expert_of = jnp.asarray(chosen.reshape(-1), jnp.int32)
+    order = jnp.argsort(expert_of).astype(jnp.int32)
+    rounded, _, came = moe._aligned(
+        moe._count(expert_of, shape["experts"])[:shape["held"]], copies,
+        moe.ALIGN)
+    return (order.at[came].get(mode="fill", fill_value=copies),
+            jnp.sum(rounded))
+
+
+def program_name(block: int | None) -> str:
+    """A gather program's name, none the start of another's."""
+    return f"gather_b{block:06d}" if block else "gather_whole"
+
+
+def time_gathers(shape: dict, order, used, blocks: list[int],
+                 calls: int = 5) -> dict:
+    """{"dispatch" | "combine_t": {"whole" | block: microseconds a call}}:
+    the two buffer-long gathers under one routing (`buffer_order`)."""
+    key = jax.random.PRNGKey(order.shape[0])
+    sides = {
+        "dispatch": (jax.random.normal(
+            key, (shape["tokens"], shape["d"]), jnp.bfloat16),
+            order // shape["k"]),
+        "combine_t": (jax.random.normal(
+            key, (shape["tokens"] * shape["k"], shape["d"]), jnp.bfloat16),
+            order),
+    }
+    fns, committed = {}, moe.BLOCK
+    for block in (None, *blocks):
+        def gather(rows, idx, used, block=block):
+            return moe._take_rows_used(rows, idx, used if block else None)
+
+        gather.__name__ = program_name(block)
+        moe.BLOCK = block or committed  # read as a program is traced
+        fns[block or "whole"] = jax.jit(gather)
+        for rows, idx in sides.values():
+            jax.block_until_ready(fns[block or "whole"](rows, idx, used))
+    moe.BLOCK = committed
+    out = {}
+    for side, (rows, idx) in sides.items():
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for fn in fns.values():
+                    for _ in range(calls):
+                        done = fn(rows, idx, used)
+                    jax.block_until_ready(done)
+            (path,) = Path(tmp).rglob("*.xplane.pb")
+            out[side] = {
+                label: module_us(str(path), program_name(
+                    None if label == "whole" else label)) for label in fns}
+    return out
+
+
+def print_gathers(name: str, shape: dict, blocks: list[int]) -> None:
+    rows = shape["tokens"] * shape["k"] + shape["held"] * moe.ALIGN
+    print(f"\n{name}: the two gathers into the buffer of {rows} rows, us a "
+          "call (and against the whole gather), blocks worked / blocks")
+    print(f"{'routing':>8} {'rows used':>9} {'gather':>10} {'whole':>9} "
+          + " ".join(f"{f'blocks of {b}':>26}" for b in blocks))
+    for routing, among in (("even", "experts"), ("worst", "held")):
+        order, used = buffer_order(draw_chosen(0, shape, among), shape)
+        us = time_gathers(shape, order, used, blocks)
+        used = int(used)
+        for side, took in us.items():
+            print(f"{routing:>8} {used:9d} {side:>10} {took['whole']:9.1f} "
+                  + " ".join(
+                      f"{took[b]:9.1f} ({took['whole'] / took[b]:5.2f}x) "
+                      f"{-(-used // b):3d}/{-(-rows // b):<3d}"
+                      for b in blocks), flush=True)
+
+
 def step_ms(shape: dict, us: dict) -> float:
     """A step's grouped products: a product into the expert runs forward
     twice (the layer is rematerialised), the one out of it once (nothing of
@@ -158,8 +244,10 @@ def main() -> None:
     parser.add_argument("--aligns", default="0,128,256,512")
     parser.add_argument("--tilings", default="512x512x512,512x1024x1024")
     parser.add_argument("--seeds", type=int, default=6)
+    parser.add_argument("--blocks", default="2048,4096,8192")
     args = parser.parse_args()
-    aligns = [int(a) for a in args.aligns.split(",")]
+    aligns = [int(a) for a in filter(None, args.aligns.split(","))]
+    blocks = [int(b) for b in filter(None, args.blocks.split(","))]
     products = {"ragged_dot": jax.lax.ragged_dot}
     for tiling in filter(None, args.tilings.split(",")):
         products[f"gmm {tiling}"] = partial(
@@ -172,6 +260,10 @@ def main() -> None:
     for name in args.shapes.split(","):
         shape = SHAPES[name]
         copies = shape["tokens"] * shape["k"]
+        if blocks:
+            print_gathers(name, shape, blocks)
+        if not aligns:
+            continue
         draws = [draw_groups(seed, shape) for seed in range(args.seeds)]
         print(f"\n{name}: {copies} copies, held rows a step "
               f"{min(d.sum() for d in draws)}-{max(d.sum() for d in draws)}, "
@@ -202,6 +294,7 @@ def main() -> None:
                       + " ".join(f"{us[side][d]:13.1f}"
                                  for side in ("in", "out") for d in DIRECTIONS)
                       + f" {step_ms(shape, us):10.2f}", flush=True)
+
 
 if __name__ == "__main__":
     main()

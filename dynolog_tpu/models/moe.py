@@ -82,7 +82,16 @@ multiple of ALIGN, zero rows between its last copy and the next boundary,
 and the products are handed the rounded lengths. The buffer is static and
 holds the worst routing, copies + held x ALIGN rows (every copy on held
 experts, every group one partial tile): no capacity, nothing dropped, one
-path whatever the router does. The layout costs no pass over rows: it is
+path whatever the router does. At even routing a share of an eighth to a
+quarter of the experts fills a sixth to a quarter of it, and the groups end
+where the rounded lengths add up to. A gather whose RESULT is as long as
+the buffer (the rows on their way in, forward and again when the layer is
+rematerialised; the cotangent of the rows on their way back) therefore
+starts as zeros and moves BLOCK rows a trip, as many trips as hold that sum
+(`take_rows` under `used`: a loop with a bound read at run time, under the
+scope `moe.rows`): under the worst routing every block is worked, and a row
+that is not gathered is the zero row it would have been. The gathers whose
+result is copies long stay whole. The layout costs no pass over rows: it is
 folded into the two index vectors the copies are gathered by on the way in
 and on the way back (`_aligned`, from the groups' lengths alone). A padding
 row is a zero row and no token's place points at it: it multiplies to zero
@@ -130,6 +139,15 @@ from dynolog_tpu.parallel.sharding import BATCH_AXES, PARAM_RULES
 # the kernel, not of a job.
 ALIGN = 512
 
+# The rows one trip of a gather into that buffer moves (`take_rows` told how
+# many of the buffer's rows are used; module docstring). A multiple of ALIGN.
+# Of 1024 to 16384, 4096 is the fastest at the three jobs' worst routing and
+# at or within 1 % of the fastest at their even routing: a gathered block of
+# 16 MB stays in fast memory until it is written into place, larger blocks
+# round the rows used further up (benchmarks/grouped_product_bench.py;
+# PERF.md section 6, PR 50). A constant of the kernel, not of a job.
+BLOCK = 4096
+
 
 def init_moe_layer(rng, cfg):
     """MoE layer params: router + stacked expert weights (no `*_gate`
@@ -167,8 +185,38 @@ def _take_rows(rows, idx):
     return rows.at[idx].get(mode="fill", fill_value=0)
 
 
+def _trips(used, block: int):
+    """The blocks of `block` rows that hold a buffer's first `used` rows."""
+    return jax.lax.div(used + (block - 1), block)
+
+
+def _take_rows_used(rows, idx, used):
+    """_take_rows(rows, idx) where idx is nowhere from place `used` on
+    (None: not known, one gather of the whole): the result starts as zeros
+    and BLOCK places a trip are gathered into it, up to the block that holds
+    place `used` - 1. Run as it is by `take_rows`' own forward and backward
+    functions, never differentiated."""
+    if used is None:
+        return _take_rows(rows, idx)
+    n = idx.shape[0]
+    block = min(BLOCK, n)
+
+    def body(i, out):
+        with jax.named_scope("moe.rows"):
+            # a last block that would pass the end is moved back to end
+            # there, by both slices alike: it moves again rows it has moved
+            at = i * block
+            got = _take_rows(
+                rows, jax.lax.dynamic_slice(idx, (at,), (block,)))
+            return jax.lax.dynamic_update_slice(out, got, (at, 0))
+
+    return jax.lax.fori_loop(
+        0, _trips(used, block), body,
+        jnp.zeros((n, *rows.shape[1:]), rows.dtype))
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(rows, idx, back_idx, fan=1):
+def take_rows(rows, idx, back_idx, fan=1, used=None, back_used=None):
     """rows[idx], a zero row where idx is rows.shape[0] (no such row).
 
     `back_idx` is the same map read from the other side: for each of the
@@ -176,19 +224,25 @@ def take_rows(rows, idx, back_idx, fan=1):
     place it went to (or the result's length: nowhere). The cotangent is
     then a gather too, summed over the `fan` copies of a row, where the
     transpose of a gather would be a scatter-add.
+
+    `used` / `back_used`: a number at run time from which on every place of
+    `idx` / `back_idx` is nowhere. The gather by that vector then moves the
+    blocks of rows before it and leaves the rest the zeros they are
+    (`_take_rows_used`): the same result, for the rows that exist.
     """
-    return _take_rows(rows, idx)
+    return _take_rows_used(rows, idx, used)
 
 
-def _take_rows_fwd(rows, idx, back_idx, fan):
-    return _take_rows(rows, idx), back_idx
+def _take_rows_fwd(rows, idx, back_idx, fan, used, back_used):
+    return _take_rows_used(rows, idx, used), (back_idx, back_used)
 
 
-def _take_rows_bwd(fan, back_idx, ct):
-    back = _take_rows(ct, back_idx)
+def _take_rows_bwd(fan, res, ct):
+    back_idx, back_used = res
+    back = _take_rows_used(ct, back_idx, back_used)
     if fan > 1:
         back = back.reshape(-1, fan, back.shape[-1]).sum(axis=1)
-    return back, None, None
+    return back, None, None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -373,6 +427,7 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
         order = jnp.argsort(expert_of).astype(jnp.int32)  # stable
         place = jnp.argsort(order).astype(jnp.int32)  # where each copy went
         group_sizes = _count(expert_of, cfg.n_experts)
+        used = None  # under the exchange a gather's every place may be one
         if ep == 1:
             # `place`: a copy's row of the aligned buffer, or its length (n)
             # for an expert that is not here; `order`: a row's copy, or
@@ -386,7 +441,9 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
             group_sizes, place, order = (
                 checkpoint_name(a, "moe.layout")
                 for a in (group_sizes, place, order))
-        rows = take_rows(h, order // k, place, k)  # by expert
+            # no row of the buffer from here on holds a copy
+            used = jnp.sum(group_sizes)
+        rows = take_rows(h, order // k, place, k, used=used)  # by expert
         if ep > 1:
             # sent[source chip, expert held here]
             sent = jax.lax.all_gather(group_sizes, "expert").reshape(
@@ -431,7 +488,8 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
             out = take_rows(out, to_source, to_expert)
             out = exchange_rows(
                 out, recv_sizes, send_sizes, copies, "expert", ragged)
-        out = take_rows(out, place, order).reshape(tokens, k, d)
+        out = take_rows(
+            out, place, order, back_used=used).reshape(tokens, k, d)
         y = jnp.einsum(
             "tkd,tk->td", out, gates,
             preferred_element_type=jnp.float32).astype(x.dtype)

@@ -2,7 +2,7 @@
 equations written plainly, on the CPU in float32: alone under forced uneven
 routings, the tile-aligned layout of one chip's own copies (its index maps
 from counts alone, and a held share under routings that fill and starve its
-groups), over a four-device `expert` mesh against one device (output, loss,
+groups; its gathers a block of rows at a time as far as rows are used), over a four-device `expert` mesh against one device (output, loss,
 one step's weights, and the four shares' parts adding up to the whole), and
 what the block gained for OLMoE's config (q/k norm, unrenormalised gates,
 the balancing term over all k choices, the router z-loss) against the plain
@@ -217,6 +217,98 @@ def test_a_held_share_equals_the_plain_sum_forward_and_gradients(
         for e in range(4):  # an expert no copy reached learns nothing
             reached = bool((chosen == 2 + e).any())
             assert bool(jnp.any(got[0][name][e] != 0)) == reached, (name, e)
+
+
+BLOCK = 16  # rows a trip in the cases below
+N_PLACES = 3 * BLOCK + 5  # so that a fourth block would pass the end
+
+
+@pytest.mark.parametrize(
+    "used", [0, 1, BLOCK - 1, BLOCK, BLOCK + 1, N_PLACES])
+def test_a_gather_told_the_rows_used_is_the_whole_gather(used, monkeypatch):
+    """Places before `used` point at a row or nowhere, the rest nowhere:
+    block by block the same rows arrive, under `jit` with `used` traced,
+    and the gradient is the whole gather's both ways round."""
+    monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    rng = np.random.default_rng(used)
+    rows = jnp.asarray(rng.normal(size=(24, 6)), jnp.float32)
+    idx = rng.integers(0, 25, N_PLACES)  # 24: nowhere, inside the used too
+    idx[0] = 3
+    idx = jnp.asarray(np.where(np.arange(N_PLACES) < used, idx, 24), jnp.int32)
+    want = moe._take_rows(rows, idx)
+    got = jax.jit(moe._take_rows_used)(rows, idx, jnp.int32(used))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert bool(jnp.any(got != 0)) == (used > 0)
+
+    # the buffer's side as the result (the dispatch) and as the cotangent's
+    # (the combine): a permutation and its inverse, padding between
+    perm = rng.permutation(N_PLACES)[:24]  # row i of `rows` goes to perm[i]
+    to_buffer = np.full(N_PLACES, 24)
+    to_buffer[perm] = np.arange(24)
+    last = jnp.int32(perm.max() + 1)
+    weights = jnp.asarray(rng.normal(size=(N_PLACES, 6)), jnp.float32)
+
+    def out(take, **told):
+        return lambda r: jnp.sum(weights * take(
+            r, jnp.asarray(to_buffer), jnp.asarray(perm), **told))
+
+    def back(take, **told):
+        return lambda b: jnp.sum(rows * take(
+            b, jnp.asarray(perm), jnp.asarray(to_buffer), **told))
+
+    for f, x, told in ((out, rows, dict(used=last)),
+                       (back, weights, dict(back_used=last))):
+        want_value, want_grad = jax.value_and_grad(f(moe.take_rows))(x)
+        got_value, got_grad = jax.jit(jax.value_and_grad(jax.checkpoint(
+            f(moe.take_rows, **told))))(x)
+        np.testing.assert_allclose(got_value, want_value, rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(got_grad), np.asarray(want_grad))
+
+
+@pytest.mark.parametrize("used", [0, 1, BLOCK, BLOCK + 1, 3 * BLOCK + 1])
+def test_the_trips_are_the_blocks_that_hold_the_rows_used(used, monkeypatch):
+    """ceil(used / BLOCK) by the helper alone, and by what the loop moved:
+    given places that all point at a row, it leaves the rows after its last
+    block the zeros they were."""
+    monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    trips = int(moe._trips(jnp.int32(used), BLOCK))
+    assert trips == -(-used // BLOCK)
+    rows = 1.0 + jnp.arange(24.0)[:, None] * jnp.ones((1, 6))
+    idx = jnp.arange(N_PLACES, dtype=jnp.int32) % 24
+    got = np.asarray(moe._take_rows_used(rows, idx, jnp.int32(used)))
+    moved = min(trips * BLOCK, N_PLACES)
+    np.testing.assert_array_equal(got[:moved], np.asarray(rows[idx[:moved]]))
+    assert (got[moved:] == 0).all()
+
+
+NONE_HELD = dict(favoured=[0, 7])  # both choices of every token elsewhere
+
+
+@pytest.mark.parametrize("routing", ["even", "all-on-held", "none-held"])
+def test_a_held_share_gathered_by_blocks_equals_the_plain_sum(
+        routing, monkeypatch):
+    """Blocks of 16 rows in a buffer of 128 + 4 x 8: at even routing the
+    loops stop a few blocks in, with every copy held they work them all,
+    with none held not one; output and every gradient as the plain sum's,
+    the step jitted as a job's is."""
+    monkeypatch.setattr(moe, "ALIGN", 8)
+    monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    layer, x = forced_layer(
+        cfg=SHARE, **{**ROUTINGS, "none-held": NONE_HELD}[routing])
+    chosen = np.asarray(jax.lax.top_k(
+        x.reshape(-1, 32) @ layer["router"], 2)[1])
+    held = np.bincount(chosen.reshape(-1), minlength=8)[2:6]
+    used = int((-(-held // 8) * 8).sum())
+    assert {"even": 32 < used < 96, "all-on-held": 128 <= used,
+            "none-held": used == 0}[routing], used
+    got_y = jax.jit(lambda l, x: moe_mlp(l, x, SHARE)[0])(layer, x)
+    close(got_y, plain_layer(layer, x, SHARE)[0])
+    if routing == "none-held":
+        assert not bool(jnp.any(got_y))
+    got = jax.jit(jax.grad(scalar(moe_mlp, SHARE), argnums=(0, 1)))(layer, x)
+    want = jax.grad(scalar(plain_layer, SHARE), argnums=(0, 1))(layer, x)
+    assert_trees_close(got, want)
 
 
 def test_a_token_no_held_expert_takes_gets_exactly_nothing():

@@ -608,12 +608,18 @@ def test_the_pipeline_refuses_the_job_aloud():
 def test_the_scopes_change_no_ops_name_or_count(monkeypatch):
     """`ssm.*` beside `attn`, `moe.*`, `embed`, `head`, `adam`: names in the
     ops' metadata and nothing else (tests/test_deepseek_v2.py holds the
-    dense and the latent job to the same). And a step of this job leaves no
-    loop on the device: the state goes from chunk to chunk by one product."""
+    dense and the latent job to the same). And the state-space blocks leave
+    no loop on the device (the state goes from chunk to chunk by one
+    product): a step's loops are the expert blocks', which gather into
+    their buffer a block of rows at a time."""
     cfg = _cfg()
     scoped = _step_ops(cfg)
     assert len(scoped) > 200 and any("fusion" in op for op in scoped)
-    assert not any(op.startswith("while") for op in scoped)
+    assert any(op.startswith("while") for op in scoped)
+    no_experts = dataclasses.replace(
+        cfg, block_types=("mamba2", "attention") * 2, n_layers=4,
+        n_experts=0, n_experts_held=0, first_expert_held=0)
+    assert not any(op.startswith("while") for op in _step_ops(no_experts))
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     assert _step_ops(cfg) == scoped
