@@ -19,7 +19,13 @@ JAX ships, `megablox` `gmm` / `tgmm`, at a few tilings) it prints
   the module events of a profiler trace, one jitted program a direction;
 - what a step of the cell pays for its grouped products by those numbers
   (the layer is rematerialised, so a product into the expert runs forward
-  twice).
+  twice);
+- the passes over the buffer that stop at the last held row, whole against
+  a block of rows at a time, at even routing and with every copy on a held
+  expert: the two gathers whose result is as long as the buffer
+  (`moe._take_rows_used`, `--blocks`), and what lies between the products
+  into an expert and the product out of it (`moe.activate`: the masks and
+  the SiLU or ReLU^2, forward and backward, `--act-blocks`).
 
 Routing is near even, as the cells' seeds route: a token's k distinct
 experts by seeded scores, an expert's load within a fifth of the mean
@@ -29,8 +35,9 @@ driver runs (perfbench/). Runs on a TPU or not at all.
 Usage: python benchmarks/grouped_product_bench.py
        [--shapes nemotron-3-nano,deepseek-v2-lite] [--aligns 0,128,256,512]
        [--tilings 512x512x512,512x1024x1024] [--seeds 6]
-       [--blocks 2048,4096,8192]
-(`--aligns ""` leaves the products out, `--blocks ""` the gathers)
+       [--blocks 2048,4096,8192] [--act-blocks 2048,4096,8192]
+(`--aligns ""` leaves the products out, `--blocks ""` the gathers,
+`--act-blocks ""` the activation)
 """
 
 from __future__ import annotations
@@ -166,9 +173,9 @@ def buffer_order(chosen: np.ndarray, shape: dict):
             jnp.sum(rounded))
 
 
-def program_name(block: int | None) -> str:
-    """A gather program's name, none the start of another's."""
-    return f"gather_b{block:06d}" if block else "gather_whole"
+def program_name(block: int | None, stem: str = "gather") -> str:
+    """A program's name by its block, none the start of another's."""
+    return f"{stem}_b{block:06d}" if block else f"{stem}_whole"
 
 
 def time_gathers(shape: dict, order, used, blocks: list[int],
@@ -228,6 +235,66 @@ def print_gathers(name: str, shape: dict, blocks: list[int]) -> None:
                       for b in blocks), flush=True)
 
 
+def time_activations(shape: dict, n: int, used, blocks: list[int],
+                     calls: int = 5) -> dict:
+    """{"fwd" | "bwd": {"whole" | block: microseconds a call}}: what lies
+    between the products into an expert and the product out of it, over a
+    buffer of n rows of which `used` hold a group; backward: from the
+    cotangent and the raw products to the raw products' cotangents."""
+    keys = jax.random.split(jax.random.PRNGKey(n), shape["into"] + 1)
+    ct, *raw = (
+        jax.random.normal(key, (n, shape["f"]), jnp.bfloat16) for key in keys)
+    raw = tuple(raw)
+    fns, committed = {}, moe.BLOCK
+    for block in (None, *blocks):
+        act = moe.activate if block else moe._activate_whole
+
+        def fwd(raw, ct, used, act=act):
+            return act(raw, used)
+
+        def bwd(raw, ct, used, act=act):
+            return jax.vjp(lambda *raw: act(raw, used), *raw)[1](ct)
+
+        moe.BLOCK = block or committed  # read as a program is traced
+        for direction, f in (("fwd", fwd), ("bwd", bwd)):
+            f.__name__ = program_name(block, f"act_{direction}")
+            fns[direction, block or "whole"] = jax.jit(f)
+            jax.block_until_ready(fns[direction, block or "whole"](
+                raw, ct, used))
+    moe.BLOCK = committed
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for fn in fns.values():
+                for _ in range(calls):
+                    done = fn(raw, ct, used)
+                jax.block_until_ready(done)
+        (path,) = Path(tmp).rglob("*.xplane.pb")
+        out = {"fwd": {}, "bwd": {}}
+        for (direction, label), fn in fns.items():
+            out[direction][label] = module_us(str(path), fn.__name__)
+    return out
+
+
+def print_activations(name: str, shape: dict, blocks: list[int]) -> None:
+    rows = shape["tokens"] * shape["k"] + shape["held"] * moe.ALIGN
+    print(f"\n{name}: the activation between the products ("
+          f"{'SiLU x up' if shape['into'] == 2 else 'ReLU^2'}) over the "
+          f"buffer of {rows} rows of {shape['f']}, us a call (and against "
+          "the whole pass), blocks worked / blocks")
+    print(f"{'routing':>8} {'rows used':>9} {'pass':>10} {'whole':>9} "
+          + " ".join(f"{f'blocks of {b}':>26}" for b in blocks))
+    for routing, among in (("even", "experts"), ("worst", "held")):
+        _, used = buffer_order(draw_chosen(0, shape, among), shape)
+        us = time_activations(shape, rows, used, blocks)
+        used = int(used)
+        for direction, took in us.items():
+            print(f"{routing:>8} {used:9d} {direction:>10} "
+                  f"{took['whole']:9.1f} " + " ".join(
+                      f"{took[b]:9.1f} ({took['whole'] / took[b]:5.2f}x) "
+                      f"{-(-used // b):3d}/{-(-rows // b):<3d}"
+                      for b in blocks), flush=True)
+
+
 def step_ms(shape: dict, us: dict) -> float:
     """A step's grouped products: a product into the expert runs forward
     twice (the layer is rematerialised), the one out of it once (nothing of
@@ -245,9 +312,11 @@ def main() -> None:
     parser.add_argument("--tilings", default="512x512x512,512x1024x1024")
     parser.add_argument("--seeds", type=int, default=6)
     parser.add_argument("--blocks", default="2048,4096,8192")
+    parser.add_argument("--act-blocks", default="2048,4096,8192")
     args = parser.parse_args()
     aligns = [int(a) for a in filter(None, args.aligns.split(","))]
     blocks = [int(b) for b in filter(None, args.blocks.split(","))]
+    act_blocks = [int(b) for b in filter(None, args.act_blocks.split(","))]
     products = {"ragged_dot": jax.lax.ragged_dot}
     for tiling in filter(None, args.tilings.split(",")):
         products[f"gmm {tiling}"] = partial(
@@ -262,6 +331,8 @@ def main() -> None:
         copies = shape["tokens"] * shape["k"]
         if blocks:
             print_gathers(name, shape, blocks)
+        if act_blocks:
+            print_activations(name, shape, act_blocks)
         if not aligns:
             continue
         draws = [draw_groups(seed, shape) for seed in range(args.seeds)]

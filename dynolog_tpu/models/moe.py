@@ -64,7 +64,9 @@ capture's ops carry them):
                   out from tile boundaries (below)
     moe.experts   three grouped matrix products (`jax.lax.ragged_dot`; on the
                   TPU XLA lowers each to one Mosaic kernel that walks the
-                  groups' rows a tile at a time) over the experts held here
+                  groups' rows a tile at a time) over the experts held here,
+                  and between them the activation over the rows the groups
+                  hold (`activate`, below)
     moe.combine   the way back (the second all-to-all), the copies unsorted
                   and summed under the gates
     moe.shared    the shared experts' SwiGLU, outside the dispatch
@@ -91,13 +93,26 @@ starts as zeros and moves BLOCK rows a trip, as many trips as hold that sum
 (`take_rows` under `used`: a loop with a bound read at run time, under the
 scope `moe.rows`): under the worst routing every block is worked, and a row
 that is not gathered is the zero row it would have been. The gathers whose
-result is copies long stay whole. The layout costs no pass over rows: it is
-folded into the two index vectors the copies are gathered by on the way in
-and on the way back (`_aligned`, from the groups' lengths alone). A padding
-row is a zero row and no token's place points at it: it multiplies to zero
-through either activation, adds nothing to an expert's weight gradient, and
-a copy for an expert that is not here lands nowhere and comes back as zero,
-so the output, the loss and every gradient are those of the plain sum.
+result is copies long stay whole. What lies between the grouped products is
+bounded the same way: a grouped product writes its groups' rows and nothing
+else, so the masks, the SiLU or ReLU^2 and their backward pass (`activate`,
+with a backward function of its own) start from zeros and work BLOCK rows a
+trip under the scope `moe.act` (a page of the buffer seen as [n / BLOCK,
+BLOCK, f], written in place), as many trips as hold the groups, the rows of
+the last block that lie past them masked inside the trip before anything
+reads them; the products themselves walk their groups and were never
+buffer-long. What still passes over every row of the buffer is the zero
+fills those loops start from. (Under the exchange that pass stays whole,
+`_activate_whole`: the bound is there, the rows the peers sent, but round
+the loops the TPU's compiler holds every layer's rows to the end of the
+step, and the job no longer fits its chip.) The layout costs no pass over
+rows: it is folded into the two index vectors the copies are gathered by on
+the way in and on the way back (`_aligned`, from the groups' lengths alone).
+A padding row is a zero row and no token's place points at it: it multiplies
+to zero through either activation, adds nothing to an expert's weight
+gradient, and a copy for an expert that is not here lands nowhere and comes
+back as zero, so the output, the loss and every gradient are those of the
+plain sum.
 
 Under a mesh the layer is a `shard_map`: each chip routes its own tokens
 over all E experts and holds E / expert of them, with the experts' hidden
@@ -246,6 +261,86 @@ def _take_rows_bwd(fan, res, ct):
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _act(*raw):
+    """An expert's activation from its raw product(s): ReLU^2 of the one, or
+    the SiLU of the first times the second."""
+    if len(raw) == 1:
+        return jnp.square(jax.nn.relu(raw[0]))
+    gate, up = raw
+    return jax.nn.silu(gate) * up
+
+
+def _work_rows_used(work, sources, used, n_out: int):
+    """`work`, a function of rows alone, over the first `used` rows of the
+    `sources` (each [n, f], `used` a number at run time): `n_out` results
+    that start as zeros, BLOCK rows a trip worked into them up to the block
+    that holds row `used` - 1. Inside a trip the rows from `used` on are
+    zeros BEFORE `work` sees them: a grouped product writes no row past its
+    groups, what the buffer holds there (and what a transpose hands back for
+    it) is not ours, and 0 x NaN is NaN (two of six runs on the chip lost
+    their loss to it). `work` of zero rows is zero rows.
+
+    The buffers are read and written a page at a time, [n / block, block, f]
+    indexed by the trip, so that a trip is one fusion that writes in place
+    (at a row offset the compiler cannot see to be a multiple of its tiles
+    it computes a block and then copies it). A page is BLOCK rows where the
+    buffer is a whole number of them, as every job's is, else their largest
+    common divisor."""
+    n, f = sources[0].shape
+    block = math.gcd(n, BLOCK)
+    pages = [rows.reshape(n // block, block, f) for rows in sources]
+
+    def body(i, outs):
+        with jax.named_scope("moe.act"):
+            ours = (i * block + jnp.arange(block, dtype=jnp.int32)
+                    < used)[:, None]
+            got = work(*(
+                jnp.where(ours, jax.lax.dynamic_index_in_dim(
+                    page, i, keepdims=False), 0) for page in pages))
+            return tuple(
+                jax.lax.dynamic_update_index_in_dim(out, rows, i, 0)
+                for out, rows in zip(outs, got))
+
+    outs = jax.lax.fori_loop(
+        0, _trips(used, block), body,
+        tuple(jnp.zeros_like(pages[0]) for _ in range(n_out)))
+    return tuple(out.reshape(n, f) for out in outs)
+
+
+def _activate_whole(raw, used):
+    """`activate` as one pass over every row of the buffer: the raw
+    products masked from row `used` on, then `_act`; XLA's own backward."""
+    there = (jnp.arange(raw[0].shape[0]) < used)[:, None]
+    return _act(*(jnp.where(there, rows, 0) for rows in raw))
+
+
+@jax.custom_vjp
+def activate(raw, used):
+    """What lies between the products into an expert and the product out of
+    it: `_act` of the raw grouped products (a tuple of one or two [n, f])
+    for the rows before `used`, a number at run time from which on no row
+    holds a copy, and a zero row from there on. Only the blocks that hold a
+    row before `used` are read or worked (`_work_rows_used`, under the scope
+    `moe.act`); the backward pass walks the same blocks, from the cotangent
+    and the raw products to the raw products' cotangents, zero rows from
+    `used` on. Under the worst routing every block is worked."""
+    return _work_rows_used(lambda *rows: (_act(*rows),), raw, used, 1)[0]
+
+
+def _activate_fwd(raw, used):
+    return activate(raw, used), (raw, used)
+
+
+def _activate_bwd(res, ct):
+    raw, used = res
+    return _work_rows_used(
+        lambda ct, *rows: jax.vjp(_act, *rows)[1](ct), (ct, *raw), used,
+        len(raw)), None
+
+
+activate.defvjp(_activate_fwd, _activate_bwd)
 
 
 def _starts(sizes):
@@ -464,23 +559,20 @@ def _moe_local(routing, experts, x, *, cfg, ep, tp, stat_axes, ragged):
                 n, n)
             rows = take_rows(rows, to_expert, to_source)
             group_sizes = sent.sum(axis=0)
-        there = (jnp.arange(n) < jnp.sum(group_sizes))[:, None]
-
-    def product(lhs, rhs):
-        # A grouped product writes no row past its groups: what the buffer
-        # holds there (and what the transpose hands back for it) is not
-        # ours, and 0 x NaN is NaN (two of six runs on the chip lost their
-        # loss to it). Rows that are not there are zeros.
-        return jnp.where(
-            there, jax.lax.ragged_dot(lhs, rhs, group_sizes), 0)
 
     with jax.named_scope("moe.experts"):
-        if w_gate is None:
-            act = jnp.square(jax.nn.relu(product(rows, w_up)))
-        else:
-            act = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        # the raw products, gate before up where an expert has both; what
+        # they leave past their groups `activate` lets reach nothing
+        raw = tuple(jax.lax.ragged_dot(rows, w, group_sizes)
+                    for w in (w_gate, w_up) if w is not None)
+        # Under the exchange the pass stays whole: round the loops XLA puts
+        # every layer's weight-gradient products off to the end of the step
+        # and holds their rows until then (two layers of OLMoE's over four
+        # chips: 2.7 -> 7.0 GB of temporaries; PERF.md section 6, PR 51).
+        act = (activate if ep == 1 else _activate_whole)(
+            raw, jnp.sum(group_sizes))
         # no mask: the way back reads no row past the groups, and its
-        # transpose hands zeros for them
+        # transpose's rows there `activate`'s backward pass leaves out
         out = jax.lax.ragged_dot(act, w_down, group_sizes)
 
     with jax.named_scope("moe.combine"):
@@ -539,10 +631,6 @@ def moe_mlp(layer, x, cfg, mesh=None):
         tuple(layer[name] for name in experts), x)
     if cfg.n_shared_experts:
         with jax.named_scope("moe.shared"):
-            if cfg.mlp_act == "relu2":
-                act = jnp.square(jax.nn.relu(x @ layer["shared_up"]))
-            else:
-                act = jax.nn.silu(x @ layer["shared_gate"]) * (
-                    x @ layer["shared_up"])
-            y = y + act @ layer["shared_down"]
+            into = ("shared_gate", "shared_up")[cfg.mlp_act == "relu2":]
+            y = y + _act(*(x @ layer[w] for w in into)) @ layer["shared_down"]
     return y, balance, z

@@ -2,7 +2,9 @@
 equations written plainly, on the CPU in float32: alone under forced uneven
 routings, the tile-aligned layout of one chip's own copies (its index maps
 from counts alone, and a held share under routings that fill and starve its
-groups; its gathers a block of rows at a time as far as rows are used), over a four-device `expert` mesh against one device (output, loss,
+groups; its gathers, and what lies between its grouped products, a block of
+rows at a time as far as rows are used), over a four-device `expert` mesh
+against one device (output, loss,
 one step's weights, and the four shares' parts adding up to the whole), and
 what the block gained for OLMoE's config (q/k norm, unrenormalised gates,
 the balancing term over all k choices, the router z-loss) against the plain
@@ -10,6 +12,7 @@ reference the benchmark holds the job to (perfbench/olmoe_block.py, loaded
 by path: it imports nothing of dynolog_tpu)."""
 
 import importlib.util
+import math
 import pathlib
 
 import jax
@@ -219,6 +222,15 @@ def test_a_held_share_equals_the_plain_sum_forward_and_gradients(
             assert bool(jnp.any(got[0][name][e] != 0)) == reached, (name, e)
 
 
+def rows_used(layer, x, align=8):
+    """The rows the groups of SHARE's four held experts take in the buffer,
+    each rounded up to `align`."""
+    chosen = np.asarray(jax.lax.top_k(
+        x.reshape(-1, 32) @ layer["router"], 2)[1])
+    held = np.bincount(chosen.reshape(-1), minlength=8)[2:6]
+    return int((-(-held // align) * align).sum())
+
+
 BLOCK = 16  # rows a trip in the cases below
 N_PLACES = 3 * BLOCK + 5  # so that a fourth block would pass the end
 
@@ -282,32 +294,100 @@ def test_the_trips_are_the_blocks_that_hold_the_rows_used(used, monkeypatch):
     assert (got[moved:] == 0).all()
 
 
+N_ROWS = 4 * BLOCK  # a buffer of whole blocks, as a job's is
+USED = [0, 1, BLOCK - 1, BLOCK, BLOCK + 1, N_ROWS]
+
+
+@pytest.mark.parametrize("used", USED)
+@pytest.mark.parametrize("into", [1, 2], ids=["relu2", "swiglu"])
+def test_the_activation_told_the_rows_used_is_the_whole_pass(
+        into, used, monkeypatch):
+    """`activate` against the plain form over every row of the buffer (the
+    raw products masked, the activation, `jax.vjp`), under `jit` with
+    `used` traced: value and every cotangent equal before `used` and
+    exactly zero from there on, whatever the products and the cotangent
+    hold there (NaN here, inside the last block worked and past it)."""
+    monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    rng = np.random.default_rng(used)
+    there = (np.arange(N_ROWS) < used)[:, None]
+    ct, *raw = (
+        jnp.asarray(np.where(there, rng.normal(size=(N_ROWS, 6)), np.nan),
+                    jnp.float32) for _ in range(into + 1))
+
+    def whole(*raw):  # the plain form, written out
+        if into == 1:
+            return jnp.square(jax.nn.relu(jnp.where(there, raw[0], 0)))
+        return jax.nn.silu(jnp.where(there, raw[0], 0)) * jnp.where(
+            there, raw[1], 0)
+
+    want, back = jax.vjp(whole, *raw)
+    want_cts = back(ct)
+
+    @jax.jit
+    def bounded(raw, ct, used):
+        got, back = jax.vjp(lambda *raw: moe.activate(raw, used), *raw)
+        return got, back(ct)
+
+    got, got_cts = bounded(tuple(raw), ct, jnp.int32(used))
+    assert len(got_cts) == into
+    for a, b in zip((got, *got_cts), (want, *want_cts)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert (a[used:] == 0).all() and (b[used:] == 0).all()
+        if used:
+            close(a[:used], b[:used])
+            assert np.abs(a[:used]).max() > 0
+
+
+@pytest.mark.parametrize("rows", [N_ROWS, 3 * BLOCK + BLOCK // 2])
+@pytest.mark.parametrize("used", USED[:-1])
+def test_the_activation_works_the_blocks_that_hold_the_rows_used(
+        used, rows, monkeypatch):
+    """ceil(used / block) trips, by the helper and by what the loop worked:
+    given work that marks every row it is handed, the rows after its last
+    block stay the zeros they were; inside a trip the rows from `used` on are
+    handed over as zeros. A block is BLOCK rows, or where the buffer is not
+    a whole number of them the largest common divisor of the two."""
+    monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    block = BLOCK if rows == N_ROWS else BLOCK // 2
+    trips = int(moe._trips(jnp.int32(used), block))
+    assert trips == -(-used // block)
+    source = 1.0 + jnp.arange(float(rows))[:, None] * jnp.ones((1, 6))
+    marked, seen = (np.asarray(a) for a in moe._work_rows_used(
+        lambda rows: (jnp.ones_like(rows), rows), (source,),
+        jnp.int32(used), 2))
+    worked = trips * block
+    assert (marked[:worked] == 1).all() and (marked[worked:] == 0).all()
+    np.testing.assert_array_equal(seen[:used], np.asarray(source[:used]))
+    assert (seen[used:] == 0).all()
+
+
 NONE_HELD = dict(favoured=[0, 7])  # both choices of every token elsewhere
 
 
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
 @pytest.mark.parametrize("routing", ["even", "all-on-held", "none-held"])
 def test_a_held_share_gathered_by_blocks_equals_the_plain_sum(
-        routing, monkeypatch):
+        routing, act, monkeypatch):
     """Blocks of 16 rows in a buffer of 128 + 4 x 8: at even routing the
-    loops stop a few blocks in, with every copy held they work them all,
-    with none held not one; output and every gradient as the plain sum's,
-    the step jitted as a job's is."""
+    loops (the gathers' and the activation's) stop a few blocks in, with
+    every copy held they work them all, with none held not one; output and
+    every gradient as the plain sum's, with experts of three matrices and
+    of two, the step jitted as a job's is."""
     monkeypatch.setattr(moe, "ALIGN", 8)
     monkeypatch.setattr(moe, "BLOCK", BLOCK)
+    cfg = TransformerConfig(**{**SHARE.__dict__, "mlp_act": act})
     layer, x = forced_layer(
-        cfg=SHARE, **{**ROUTINGS, "none-held": NONE_HELD}[routing])
-    chosen = np.asarray(jax.lax.top_k(
-        x.reshape(-1, 32) @ layer["router"], 2)[1])
-    held = np.bincount(chosen.reshape(-1), minlength=8)[2:6]
-    used = int((-(-held // 8) * 8).sum())
+        cfg=cfg, **{**ROUTINGS, "none-held": NONE_HELD}[routing])
+    assert ("experts_gate" in layer) == (act == "swiglu")
+    used = rows_used(layer, x)
     assert {"even": 32 < used < 96, "all-on-held": 128 <= used,
             "none-held": used == 0}[routing], used
-    got_y = jax.jit(lambda l, x: moe_mlp(l, x, SHARE)[0])(layer, x)
-    close(got_y, plain_layer(layer, x, SHARE)[0])
+    got_y = jax.jit(lambda l, x: moe_mlp(l, x, cfg)[0])(layer, x)
+    close(got_y, plain_layer(layer, x, cfg)[0])
     if routing == "none-held":
         assert not bool(jnp.any(got_y))
-    got = jax.jit(jax.grad(scalar(moe_mlp, SHARE), argnums=(0, 1)))(layer, x)
-    want = jax.grad(scalar(plain_layer, SHARE), argnums=(0, 1))(layer, x)
+    got = jax.jit(jax.grad(scalar(moe_mlp, cfg), argnums=(0, 1)))(layer, x)
+    want = jax.grad(scalar(plain_layer, cfg), argnums=(0, 1))(layer, x)
     assert_trees_close(got, want)
 
 
@@ -324,12 +404,17 @@ def test_a_token_no_held_expert_takes_gets_exactly_nothing():
     assert (np.abs(y[~none_held]).max(axis=1) > 1e-4).all()
 
 
+@pytest.mark.parametrize("block", [BLOCK, moe.BLOCK])
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
-def test_what_lies_past_the_groups_reaches_no_gradient(act, monkeypatch):
+def test_what_lies_past_the_groups_reaches_no_gradient(
+        act, block, monkeypatch):
     """On the TPU a grouped product writes no row past its (rounded)
     groups, forward or transposed. Here those rows are made NaN; neither
     the output nor any gradient may see them, with experts of three
-    matrices and of two, and groups that end inside a tile."""
+    matrices and of two, and groups that end inside a tile. Some of the NaN
+    rows lie inside the last block the activation's loop works and the rest
+    past it, at blocks of 16 rows and at the module's own (32 of this
+    buffer's 160 rows)."""
     real = jax.lax.ragged_dot
 
     def past(x, group_sizes):
@@ -352,9 +437,14 @@ def test_what_lies_past_the_groups_reaches_no_gradient(act, monkeypatch):
 
     dirty.defvjp(fwd, bwd)
     monkeypatch.setattr(moe, "ALIGN", 8)
+    monkeypatch.setattr(moe, "BLOCK", block)
     cfg = TransformerConfig(**{**SHARE.__dict__, "mlp_act": act})
     layer, x = forced_layer(cfg=cfg, **ROUTINGS["even"])
     assert ("experts_gate" in layer) == (act == "swiglu")
+    used = rows_used(layer, x)
+    # rows of a worked block past the groups, and a block that is not worked
+    rows = math.gcd(128 + 4 * 8, block)
+    assert used % rows and -(-used // rows) * rows < 128 + 4 * 8
     want = jax.grad(scalar(moe_mlp, cfg), argnums=(0, 1))(layer, x)
     close(moe_mlp(layer, x, cfg)[0], plain_layer(layer, x, cfg)[0])
     monkeypatch.setattr(jax.lax, "ragged_dot", dirty)
