@@ -1,11 +1,24 @@
-"""A gated-delta-net layer: attention replaced by a recurrence over the
-sequence, computed in chunks. The linear layers of a hybrid model
-(`TransformerConfig.layer_types`, "linear_attention"), after the Gated
-DeltaNet layer of the `flash-linear-attention` library that `olmo_hybrid`
-follows.
+"""Two delta rules: attention replaced by a recurrence over the sequence,
+computed in chunks. The linear layers of a hybrid model
+(`TransformerConfig.layer_types`), after the `flash-linear-attention`
+library's layers:
 
-The layer, for head n of H, token t, normalised input h_t (d_k the key head
-size, d_v the value head size):
+- "linear_attention": the GATED DELTA NET (`gated_delta_net`,
+  `chunked_delta_rule`; the library's Gated DeltaNet, which `olmo_hybrid`
+  follows): the state forgets by ONE number a head a token.
+- "kda": KIMI DELTA ATTENTION (`kimi_delta_attention`, `chunked_kda_rule`;
+  the library's KimiDeltaAttention, arXiv:2510.26692, which `kimi_linear`
+  follows): the state forgets by a number a CHANNEL of every head.
+
+What they share: the convolution (`_causal_conv`), the norm of q and k
+(`_l2norm`), the chunks, the solve and the loop (`_chunked_rule`: everything
+but the two products of a chunk that the decay enters), float32 where it is
+stated below, and that the rule is computed again in the backward pass. With
+one decay in every channel the second is the first, token for token
+(tests/test_kimi_linear.py).
+
+THE GATED DELTA NET, for head n of H, token t, normalised input h_t (d_k the
+key head size, d_v the value head size):
 
     q~ = h W_q, k~ = h W_k   (H x d_k each);   v~ = h W_v   (H x d_v)
     each passes a causal depthwise convolution of width K over the sequence
@@ -42,9 +55,37 @@ and the carried state are float32; the products' operands are the model's
 type. Five phases a layer under `jax.named_scope`, beside `moe.*`:
 `gdn.project`, `gdn.conv`, `gdn.chunk_prepare` (the solve, all chunks at
 once, outside the loop), `gdn.scan` (the loop), `gdn.out`.
+
+KIMI DELTA ATTENTION differs in three places (d_k = d_v = 128 at
+Kimi-Linear's widths):
+
+    beta_t = sigmoid(h_t W_b)                      never doubled
+    g_t = -exp(A_log) x softplus((h_t W_f1) W_f2 + dt_bias)
+        a number a CHANNEL of every head (H x d_k, float32), through a
+        bottleneck of a head's value width; A_log a number a head, dt_bias
+        one a channel;  alpha_t = exp(g_t), a vector
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    y_t = W_o [ RMSNorm_{d_v}(o_t) * sigmoid((h_t W_g1) W_g2) ]
+        the output gate through a bottleneck too, a SIGMOID
+
+In a chunk gamma is then [C, d_k] and the decay sits INSIDE the contraction,
+
+    A_ij = sum_d k_id k_jd exp(gamma_id - gamma_jd)      (B_ij: q_i for k_i)
+
+in the place of K K^T * D and Q K^T * D; W, the carried state's decay
+(Diag(exp gamma_C) S) and the other terms take gamma a channel where they
+took it a head, and nothing else changes. A and B are made by sub-blocks of
+SUB rows (`_channel_decay`): written out whole they are [C, C, d_k] a chunk
+a head (8.6 GB a layer at 8192 tokens), and factored once over the chunk
+they overflow float32. Five phases under `kda.project`, `kda.conv`,
+`kda.chunk_prepare`, `kda.scan`, `kda.out`; the backward pass computes again
+everything from the projections on (`kimi_delta_attention`), where the
+gated delta net's computes again its rule alone.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +93,13 @@ import jax.numpy as jnp
 from dynolog_tpu.models.transformer import _rmsnorm
 
 CHUNK = 64
+SUB = 16  # a sub-block of a chunk (`_channel_decay`)
 L2_EPS = 1e-6
+
+
+def _dense(key, shape, fan_in, dtype):
+    draw = jax.random.normal(key, shape, jnp.float32)
+    return (draw / jnp.sqrt(fan_in)).astype(dtype)
 
 
 def init_linear_layer(rng, cfg) -> dict:
@@ -65,10 +112,7 @@ def init_linear_layer(rng, cfg) -> dict:
     dk, dv, taps = (cfg.linear_key_head_dim, cfg.linear_value_head_dim,
                     cfg.linear_conv_kernel)
 
-    def dense(key, shape, fan_in):
-        draw = jax.random.normal(key, shape, jnp.float32)
-        return (draw / jnp.sqrt(fan_in)).astype(dtype)
-
+    dense = functools.partial(_dense, dtype=dtype)
     k = jax.random.split(rng, 12)
     step = jnp.exp(jax.random.uniform(
         k[11], (h,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
@@ -90,6 +134,42 @@ def init_linear_layer(rng, cfg) -> dict:
     }
 
 
+def init_kda_layer(rng, cfg) -> dict:
+    """The mixer's weights of one Kimi Delta Attention layer. The two
+    bottleneck maps (the decay's, `kda_f_*`, and the output gate's,
+    `kda_g_*`) pass through a head's value width, as the library's layer has
+    them, and carry no bias; `kda_a_log` is a number a head and
+    `kda_dt_bias` one a channel of every head, both float32 whatever the
+    model's type and drawn as `init_linear_layer` draws its own."""
+    dtype = jnp.dtype(cfg.dtype)
+    d, h = cfg.d_model, cfg.n_heads
+    dk, dv, taps = (cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+                    cfg.linear_conv_kernel)
+
+    dense = functools.partial(_dense, dtype=dtype)
+    k = jax.random.split(rng, 14)
+    step = jnp.exp(jax.random.uniform(
+        k[13], (h * dk,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "kda_q": dense(k[0], (d, h * dk), d),
+        "kda_k": dense(k[1], (d, h * dk), d),
+        "kda_v": dense(k[2], (d, h * dv), d),
+        "kda_conv_q": dense(k[3], (taps, h * dk), taps),
+        "kda_conv_k": dense(k[4], (taps, h * dk), taps),
+        "kda_conv_v": dense(k[5], (taps, h * dv), taps),
+        "kda_b": dense(k[6], (d, h), d),
+        "kda_f_down": dense(k[7], (d, dv), d),
+        "kda_f_up": dense(k[8], (dv, h * dk), dv),
+        "kda_a_log": jnp.log(jax.random.uniform(
+            k[9], (h,), jnp.float32, 1.0, 16.0)),
+        "kda_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "kda_g_down": dense(k[10], (d, dv), d),
+        "kda_g_up": dense(k[11], (dv, h * dv), dv),
+        "kda_norm_scale": jnp.ones((dv,), dtype),
+        "kda_o": dense(k[12], (h * dv, d), h * dv),
+    }
+
+
 def _causal_conv(x, w, bias=None):
     """x [B, S, channels], w [taps, channels]: the last tap is the token's
     own, zeros stand before the first token; `bias` [channels] where the
@@ -106,15 +186,104 @@ def _l2norm(x):
     return (x32 * norm).astype(x.dtype)
 
 
-def chunked_delta_rule(q, k, v, g, beta):
-    """q, k [B, S, H, d_k] (normalised and scaled), v [B, S, H, d_v], g and
-    beta [B, S, H] float32 -> o [B, S, H, d_v] in v's type and the final
-    state [B, H, d_k, d_v] float32. S has to be a multiple of CHUNK."""
+def _scalar_decay(q, k, gamma, beta):
+    """The gated delta net's: one decay a head. q, k [..., C, d_k], gamma
+    and beta [..., C] -> (lower(diag(beta) K K^T * D), lower(Q K^T * D))
+    float32, D_ij = exp(gamma_i - gamma_j), and what `_chunked_rule`
+    multiplies a chunk's rows by, [..., C, 1] each: beta exp(gamma) (K into
+    W), exp(gamma) (Q against the incoming state), exp(gamma_C - gamma) (K
+    into the next state); and exp(gamma_C) [..., 1, 1], the state's own.
+    Each is handed over as a function `_chunked_rule` calls where it needs
+    the value: the compiler schedules by the order the ops are written in,
+    and written in the order PR 36 wrote them the step of the hybrid job
+    is the program it was (the factors made up front cost it 0.27 %: my
+    chip run, PR 52, call 2)."""
+    f32 = jnp.float32
+    lower = jnp.tril(jnp.ones((gamma.shape[-1],) * 2, bool))
+    # D_ij for i >= j only: above the diagonal the exponent is positive
+    decay = jnp.exp(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    kk = jnp.einsum("nbhik,nbhjk->nbhij", k, k, preferred_element_type=f32)
+    qk = jnp.einsum("nbhik,nbhjk->nbhij", q, k, preferred_element_type=f32)
+
+    def leaving():
+        total = gamma[..., -1]  # gamma_C
+        return (jnp.exp(total[..., None] - gamma)[..., None],
+                jnp.exp(total)[..., None, None])
+
+    return (lambda: beta[..., None] * kk * decay, lambda: qk * decay,
+            lambda: (beta * jnp.exp(gamma))[..., None],
+            lambda: jnp.exp(gamma)[..., None], leaving)
+
+
+def _channel_decay(q, k, gamma, beta):
+    """Kimi Delta Attention's: a decay a channel, so it sits inside the
+    contraction. q, k [..., C, d_k], gamma [..., C, d_k], beta [..., C] ->
+    (diag(beta) A, B) float32, A_ij = sum_d k_id k_jd exp(gamma_id -
+    gamma_jd) and B the same with q_i, for i >= j (zeros above), and the
+    four factors `_scalar_decay` returns, a channel each: [..., C, d_k]
+    thrice and exp(gamma_C) [..., d_k, 1]; as functions, as it hands them.
+
+    (K * exp(gamma)) (K * exp(-gamma))^T would be one product and overflows
+    float32 inside a chunk (gamma passes -88 within 64 tokens at the
+    library's strongest decay), so the chunk is cut into sub-blocks of SUB
+    rows. Rows i of sub-block I against every column j of an EARLIER
+    sub-block are factored about I's first row r: exp(gamma_i - gamma_r)
+    and exp(gamma_r - gamma_j), both exponents at or below 0 because gamma
+    only falls, their product the pair's decay or an underflow where the
+    pair's decay is one. Inside a sub-block no row lies between every pair,
+    so the SUB x SUB pairs on the diagonal are written out pair by pair,
+    [SUB, SUB, d_k] a sub-block, summed over d_k where they are made."""
+    f32, dtype = jnp.float32, k.dtype
+    *lead, chunk, dk = k.shape
+    n_sub = chunk // SUB
+
+    def subs(x):  # [..., C, d_k] -> [..., C / SUB, SUB, d_k]
+        return x.reshape(*lead, n_sub, SUB, dk)
+
+    k32 = k.astype(f32)
+    qs, ks, gs = subs(q.astype(f32)), subs(k32), subs(gamma)
+    # the pair's decay does not depend on r: no gradient needs to pass it
+    ref = jax.lax.stop_gradient(gs[..., :1, :])  # [..., I, 1, d_k]
+    rows = jnp.exp(gs - ref)
+    rows = jnp.concatenate([ks * rows, qs * rows], axis=-2).astype(dtype)
+    # column j as sub-block I sees it, [..., I, C, d_k]; nothing at or past
+    # I's first row (those pairs are the diagonal's, or above it)
+    earlier = (jnp.arange(chunk)[None, :]
+               < SUB * jnp.arange(n_sub)[:, None])[..., None]
+    cols = (k32[..., None, :, :] * jnp.exp(jnp.where(
+        earlier, ref - gamma[..., None, :, :], -jnp.inf))).astype(dtype)
+    off = jnp.einsum("...Iid,...Ijd->...Iij", rows, cols,
+                     preferred_element_type=f32)  # [..., I, 2 SUB, C]
+    # the diagonal: i and j of one sub-block, i >= j
+    lower = jnp.tril(jnp.ones((SUB, SUB), bool))[..., None]
+    pair = ks[..., None, :, :] * jnp.exp(jnp.where(
+        lower, gs[..., :, None, :] - gs[..., None, :, :], -jnp.inf))
+    own = jnp.stack([jnp.sum(ks[..., :, None, :] * pair, -1),
+                     jnp.sum(qs[..., :, None, :] * pair, -1)], axis=-4)
+    # [..., 2, I, SUB, SUB] into its place among the columns
+    at = jnp.eye(n_sub, dtype=f32)
+    own = jnp.einsum("...Iij,IJ->...IiJj", own, at).reshape(
+        *lead, 2, chunk, chunk)
+    off = jnp.moveaxis(off.reshape(*lead, n_sub, 2, SUB, chunk), -3, -4)
+    both = own + off.reshape(*lead, 2, chunk, chunk)
+    fall, total = jnp.exp(gamma), gamma[..., -1:, :]  # total: gamma_C
+    return (lambda: beta[..., None] * both[..., 0, :, :],
+            lambda: both[..., 1, :, :], lambda: beta[..., None] * fall,
+            lambda: fall,
+            lambda: (jnp.exp(total - gamma),
+                     jnp.swapaxes(fall[..., -1:, :], -1, -2)))
+
+
+def _chunked_rule(q, k, v, g, beta, decay, scope: str):
+    """What the two rules share: the chunks, the solve, the loop. `decay`
+    makes a chunk's two products under the decay from the running sums of
+    g; `scope` names the two phases."""
     b, s, h, dk = q.shape
     dv, chunk = v.shape[-1], CHUNK
     if s % chunk:
         raise ValueError(
-            f"a gated-delta-net layer computes in chunks of {chunk} tokens "
+            f"a linear layer computes in chunks of {chunk} tokens "
             f"and the sequence holds {s}: not a whole number of chunks")
     n, f32, dtype = s // chunk, jnp.float32, v.dtype
 
@@ -122,29 +291,22 @@ def chunked_delta_rule(q, k, v, g, beta):
         x = x.reshape(b, n, chunk, h, *x.shape[3:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
 
-    with jax.named_scope("gdn.chunk_prepare"):
+    with jax.named_scope(f"{scope}.chunk_prepare"):
         q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
-        gamma = jnp.cumsum(g, axis=-1)  # [N, B, H, C]
-        lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-        # D_ij for i >= j only: above the diagonal the exponent is positive
-        decay = jnp.exp(jnp.where(
-            lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
-        kk = jnp.einsum("nbhik,nbhjk->nbhij", k, k, preferred_element_type=f32)
-        qk = jnp.einsum("nbhik,nbhjk->nbhij", q, k, preferred_element_type=f32)
-        system = jnp.eye(chunk, dtype=f32) + jnp.tril(
-            beta[..., None] * kk * decay, -1)
+        # the running sums: [N, B, H, C] a head, [N, B, H, C, d_k] a channel
+        kk, qk, into_w, into_q, leaving = decay(
+            q, k, jnp.cumsum(g, axis=3), beta)
+        system = jnp.eye(chunk, dtype=f32) + jnp.tril(kk(), -1)
         rhs = jnp.concatenate(
-            [k.astype(f32) * (beta * jnp.exp(gamma))[..., None],
-             v.astype(f32) * beta[..., None]], axis=-1)
+            [k.astype(f32) * into_w(), v.astype(f32) * beta[..., None]],
+            axis=-1)
         solved = jax.scipy.linalg.solve_triangular(
             system, rhs, lower=True, unit_diagonal=True)
         w, u = solved[..., :dk].astype(dtype), solved[..., dk:]
-        within = (qk * decay).astype(dtype)  # lower(Q K^T * D)
-        q_in = (q.astype(f32) * jnp.exp(gamma)[..., None]).astype(dtype)
-        total = gamma[..., -1]  # gamma_C, [N, B, H]
-        k_out = (k.astype(f32) * jnp.exp(
-            total[..., None] - gamma)[..., None]).astype(dtype)
-        carry_decay = jnp.exp(total)[..., None, None]
+        within = qk().astype(dtype)  # lower(Q K^T under the decay)
+        q_in = (q.astype(f32) * into_q()).astype(dtype)
+        into_next, carry_decay = leaving()
+        k_out = (k.astype(f32) * into_next).astype(dtype)
 
     def body(state, xs):
         w, u, within, q_in, k_out, carry_decay = xs
@@ -159,13 +321,27 @@ def chunked_delta_rule(q, k, v, g, beta):
             "bhck,bhcv->bhkv", k_out, fresh, preferred_element_type=f32)
         return state, out.astype(dtype)
 
-    with jax.named_scope("gdn.scan"):
+    with jax.named_scope(f"{scope}.scan"):
         state, out = jax.lax.scan(
             body, jnp.zeros((b, h, dk, dv), f32),
             (w, u, within, q_in, k_out, carry_decay))
     # [N, B, H, C, d_v] -> [B, S, H, d_v]
     out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3).reshape(b, s, h, dv)
     return out, state
+
+
+def chunked_delta_rule(q, k, v, g, beta):
+    """The gated delta net's rule. q, k [B, S, H, d_k] (normalised and
+    scaled), v [B, S, H, d_v], g and beta [B, S, H] float32 -> o [B, S, H,
+    d_v] in v's type and the final state [B, H, d_k, d_v] float32. S has to
+    be a multiple of CHUNK."""
+    return _chunked_rule(q, k, v, g, beta, _scalar_decay, "gdn")
+
+
+def chunked_kda_rule(q, k, v, g, beta):
+    """Kimi Delta Attention's rule: as `chunked_delta_rule` with g [B, S, H,
+    d_k] float32, a number a channel."""
+    return _chunked_rule(q, k, v, g, beta, _channel_decay, "kda")
 
 
 def gated_delta_net(layer, x, cfg):
@@ -197,3 +373,53 @@ def gated_delta_net(layer, x, cfg):
         out = _rmsnorm(out, layer["gdn_norm_scale"], cfg.norm_eps)
         out = out * jax.nn.silu(gate.reshape(b, s, h, dv))
         return out.reshape(b, s, h * dv) @ layer["gdn_o"]
+
+
+def _kda_from_projections(small, q, k, v, raw, beta, *, heads: int):
+    """The projections of a KDA layer (q, k, v [B, S, H d], the decay's
+    `raw` [B, S, H d_k] as its bottleneck gave it, beta [B, S, H] float32)
+    and the layer's `small` weights (the taps, A_log, dt_bias) -> the rule's
+    output [B, S, H, d_v]. Everything in here is computed again in the
+    backward pass (`kimi_delta_attention`)."""
+    b, s, _ = q.shape
+    dk, dv, f32 = q.shape[-1] // heads, v.shape[-1] // heads, jnp.float32
+    with jax.named_scope("kda.conv"):
+        q = jax.nn.silu(_causal_conv(q, small["kda_conv_q"]))
+        k = jax.nn.silu(_causal_conv(k, small["kda_conv_k"]))
+        v = jax.nn.silu(_causal_conv(v, small["kda_conv_v"]))
+        q = _l2norm(q.reshape(b, s, heads, dk)) * jnp.asarray(
+            dk ** -0.5, q.dtype)
+        k = _l2norm(k.reshape(b, s, heads, dk))
+        v = v.reshape(b, s, heads, dv)
+        g = -jnp.exp(small["kda_a_log"].astype(f32))[:, None] * (
+            jax.nn.softplus(
+                raw.astype(f32) + small["kda_dt_bias"].astype(f32))
+        ).reshape(b, s, heads, dk)
+    return chunked_kda_rule(q, k, v, g, beta)[0]
+
+
+def kimi_delta_attention(layer, x, cfg):
+    """x [B, S, d] (normalised) -> the mixer's output [B, S, d]."""
+    b, s, _ = x.shape
+    h, dv = cfg.n_heads, cfg.linear_value_head_dim
+    with jax.named_scope("kda.project"):
+        q, k, v = x @ layer["kda_q"], x @ layer["kda_k"], x @ layer["kda_v"]
+        gate = (x @ layer["kda_g_down"]) @ layer["kda_g_up"]
+        beta = jax.nn.sigmoid((x @ layer["kda_b"]).astype(jnp.float32))
+        raw = (x @ layer["kda_f_down"]) @ layer["kda_f_up"]
+    # Kept for the backward pass: the five projections as they leave their
+    # products, in the model's type. The convolution, SiLU, the norms of q
+    # and k, the decay (float32, a number a channel) and the rule with a
+    # chunk's matrices and the states are computed again there: kept, the
+    # float32 copies the norms and the softplus leave behind alone are 0.5
+    # GB a layer at 8192 tokens of Kimi-Linear's widths, beside the 2.9 GB
+    # of the rule's own. (The gated delta net's checkpoint is round its
+    # rule alone.)
+    small = {name: layer[name] for name in (
+        "kda_conv_q", "kda_conv_k", "kda_conv_v", "kda_a_log", "kda_dt_bias")}
+    out = jax.checkpoint(functools.partial(_kda_from_projections, heads=h))(
+        small, q, k, v, raw, beta)
+    with jax.named_scope("kda.out"):
+        out = _rmsnorm(out, layer["kda_norm_scale"], cfg.norm_eps)
+        out = out * jax.nn.sigmoid(gate.reshape(b, s, h, dv))
+        return out.reshape(b, s, h * dv) @ layer["kda_o"]

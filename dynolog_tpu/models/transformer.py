@@ -23,7 +23,11 @@ import jax
 import jax.numpy as jnp
 
 
-LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
+LAYER_TYPES = ("full_attention", "linear_attention", "kda",
+               "sliding_attention")
+# the layer kinds whose mixer is a recurrence of
+# dynolog_tpu.models.linear_attention, which is also the mixer's kind
+LINEAR_TYPES = ("linear_attention", "kda")
 BLOCK_TYPES = ("mamba2", "moe", "attention", "mlp")
 # A mixer's kind -> (its norm's scale in the layer, the scope that norm runs
 # under: a mixer's norm goes with the phase it feeds).
@@ -31,6 +35,7 @@ MIXER_NORMS = {
     "attention": ("attn_scale", "attn"),
     "mla": ("attn_scale", "mla.project"),
     "linear_attention": ("attn_scale", "gdn.project"),
+    "kda": ("attn_scale", "kda.project"),
     "mamba2": ("ssm_scale", "ssm.project"),
     "mlp": ("mlp_scale", "mlp"),
     "moe": ("mlp_scale", "moe.route"),
@@ -113,11 +118,12 @@ class TransformerConfig:
     # theta's and theta's over `factor`, and the softmax scale times
     # (0.1 mscale_all_dim ln factor + 1)^2. None: plain RoPE.
     rope_scaling: tuple | None = None
-    # A hybrid model: each layer's kind, "full_attention" or
-    # "linear_attention" (a gated-delta-net layer,
-    # dynolog_tpu.models.linear_attention, over n_heads heads of
-    # linear_key_head_dim / linear_value_head_dim), as a published config
-    # lists them. None: every layer is full attention.
+    # A hybrid model: each layer's kind, "full_attention" (latent attention
+    # where attn_type is "mla"), "linear_attention" (a gated-delta-net
+    # layer) or "kda" (a Kimi Delta Attention layer: the same rule with a
+    # decay a channel; both dynolog_tpu.models.linear_attention, over
+    # n_heads heads of linear_key_head_dim / linear_value_head_dim), as a
+    # published config lists them. None: every layer is full attention.
     layer_types: tuple | None = None
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
@@ -261,7 +267,21 @@ class TransformerConfig:
         if self.post_norm and self.has_linear_layers:
             raise ValueError(
                 f"post_norm follows the mixers of {tuple(POST_NORMS)}: not a "
-                "linear_attention layer")
+                f"layer of {LINEAR_TYPES}")
+        if self.has_linear_layers and (
+                min(self.linear_key_head_dim, self.linear_value_head_dim,
+                    self.linear_conv_kernel) < 1):
+            raise ValueError(
+                f"layer_types names a layer of {LINEAR_TYPES}: heads of "
+                f"linear_key_head_dim {self.linear_key_head_dim} and "
+                f"linear_value_head_dim {self.linear_value_head_dim} under "
+                f"a convolution of {self.linear_conv_kernel} taps have to "
+                "be 1 or more each")
+        if self.attn_type == "mla" and self.rope_layer_types is not None:
+            raise ValueError(
+                "rope_layer_types says which layers' multi-head attention "
+                "is rotated: latent attention rotates its rotary part "
+                "wherever rope_theta is stated and nowhere where it is None")
 
     def layer_type(self, i: int) -> str:
         return "full_attention" if self.layer_types is None else (
@@ -281,7 +301,7 @@ class TransformerConfig:
 
     def is_linear(self, i: int) -> bool:
         return (self.layer_types is not None
-                and self.layer_types[i] == "linear_attention")
+                and self.layer_types[i] in LINEAR_TYPES)
 
     @property
     def has_linear_layers(self) -> bool:
@@ -294,10 +314,11 @@ class TransformerConfig:
     def mixers(self, i: int) -> tuple:
         """The mixers of layer i in order, each x + mixer(norm(x)): the one
         `block_types` states, else the layer's attention-kind mixer
-        ("attention", "mla" or "linear_attention") and its "mlp" or "moe"."""
+        ("attention", "mla", "linear_attention" or "kda") and its "mlp" or
+        "moe"."""
         if self.block_types is not None:
             return (self.block_types[i],)
-        first = ("linear_attention" if self.is_linear(i)
+        first = (self.layer_types[i] if self.is_linear(i)
                  else "mla" if self.attn_type == "mla" else "attention")
         return first, "moe" if self.is_sparse(i) else "mlp"
 
@@ -367,6 +388,10 @@ def init_params(rng, cfg: TransformerConfig):
                     init_linear_layer)
 
                 layer.update(init_linear_layer(k[0], cfg))
+            elif kind == "kda":
+                from dynolog_tpu.models.linear_attention import init_kda_layer
+
+                layer.update(init_kda_layer(k[0], cfg))
             elif kind == "mla":
                 from dynolog_tpu.models.mla import init_mla_layer
 
@@ -572,6 +597,10 @@ def _mix(kind, i, layer, x, positions, cfg: TransformerConfig, mesh):
         from dynolog_tpu.models.linear_attention import gated_delta_net
 
         return gated_delta_net(layer, h, cfg), None
+    if kind == "kda":
+        from dynolog_tpu.models.linear_attention import kimi_delta_attention
+
+        return kimi_delta_attention(layer, h, cfg), None
     if kind == "mla":
         from dynolog_tpu.models.mla import latent_attention
 

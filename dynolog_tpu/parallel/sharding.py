@@ -158,6 +158,24 @@ PARAM_RULES = {
     "gdn_a": P(),
     "gdn_a_log": P(),
     "gdn_dt_bias": P(),
+    # A Kimi Delta Attention layer (the same module): as the gated delta
+    # net's, by heads on `model`; the two bottlenecks go in replicated (a
+    # head's width) and come out by heads (columns are heads x head size);
+    # kda_norm_scale by "scale" above.
+    "kda_q": P(None, "model"),
+    "kda_k": P(None, "model"),
+    "kda_v": P(None, "model"),
+    "kda_f_up": P(None, "model"),
+    "kda_g_up": P(None, "model"),
+    "kda_o": P("model", None),
+    "kda_f_down": P(),
+    "kda_g_down": P(),
+    "kda_conv_q": P(),
+    "kda_conv_k": P(),
+    "kda_conv_v": P(),
+    "kda_b": P(),
+    "kda_a_log": P(),
+    "kda_dt_bias": P(),
 }
 
 

@@ -262,6 +262,41 @@ def test_the_kernels_compile_for_the_chip_at_the_published_widths(
                        and "bf16[2,4096,128]" not in line for line in calls)
 
 
+def test_the_kda_rule_compiles_for_the_chip_at_the_published_widths(one_chip):
+    """Kimi Delta Attention's rule, forward and backward under its
+    checkpoint, at Kimi-Linear's 32 heads of 128 over the cell's 4096
+    positions (tests/test_kimi_linear.py holds its numbers; here with the
+    kernels' cases, one file a worker describes the topology in). What it
+    guards: the [16, 16, 128] pairs of a diagonal sub-block are summed where
+    they are made; stored, they are 1.07 GB a layer a product at 4096
+    positions and the job no longer fits its chip."""
+    from dynolog_tpu.models import linear_attention as la
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(jax.checkpoint(la.chunked_kda_rule)(
+            q, k, v, g, beta)[0].astype(jnp.float32))
+
+    wide = shape(1, 4096, 32, 128)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            wide, wide, wide, shape(1, 4096, 32, 128, dtype=jnp.float32),
+            shape(1, 4096, 32, dtype=jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2  # the loop, forward and backward
+    # a fusion is an op of the step itself: one whose RESULT is the pairs
+    stored = [line for line in text.splitlines() if " fusion(" in line
+              and re.match(r"\s*(ROOT )?%\S+ = f32\[64,1,32,4,16,16,128\]",
+                           line)]
+    assert not stored, stored[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
 # -- the model whole -----------------------------------------------------
 
 
