@@ -75,10 +75,28 @@ In a chunk gamma is then [C, d_k] and the decay sits INSIDE the contraction,
 in the place of K K^T * D and Q K^T * D; W, the carried state's decay
 (Diag(exp gamma_C) S) and the other terms take gamma a channel where they
 took it a head, and nothing else changes. A and B are made by sub-blocks of
-SUB rows (`_channel_decay`): written out whole they are [C, C, d_k] a chunk
-a head (8.6 GB a layer at 8192 tokens), and factored once over the chunk
-they overflow float32. Five phases under `kda.project`, `kda.conv`,
-`kda.chunk_prepare`, `kda.scan`, `kda.out`; the backward pass computes again
+SUB rows: written out whole they are [C, C, d_k] a chunk a head (8.6 GB a
+layer at 8192 tokens), and factored once over the chunk they overflow
+float32. WHAT MAKES THEM is read from what is being compiled
+(`_channel_decay`, `jax.lax.platform_dependent`; no field, no option): for a
+TPU two Pallas kernels, `kda_pairs_fwd` and its own backward pass
+`kda_pairs_bwd` (dynolog_tpu/ops/kda_pairs.py), which keep a sub-block's
+pairs, columns and products in fast memory; anywhere else `_plain_pairs`,
+the same mathematics in plain ops, which is also the kernels' plain
+reference (tests/test_kda_pairs.py), as `reference_attention` stands beside
+the flash kernels. The kernels are taken at the sizes the chip's compiler
+has taken them at (a chunk of 64, d_k of 64, 128 or 256:
+`kda_pairs.compiles`; any other keeps `_plain_pairs` on a TPU too), and over
+a mesh (`kimi_delta_attention`'s `mesh`, as the flash kernels' in
+transformer.py) they run in a `jax.shard_map`, a device on its own batch
+rows and heads: the partitioner cannot split a Mosaic kernel. Five phases under `kda.project`, `kda.conv`,
+`kda.chunk_prepare`, `kda.scan`, `kda.out`; a kernel's name is its op's
+name, so on a TPU a capture shows two `kda_pairs_fwd` and one
+`kda_pairs_bwd` a layer a step as ops of their own, and because they are
+called under the scope their time stays in `kda.chunk_prepare`, beside the
+running sums, the solve, `W`, `U` and the rows scaled for the loop (the
+outermost name the program wrote is an op's scope: the flash kernels of a
+latent layer are `mla.attend`'s the same way). The backward pass computes again
 everything from the projections on (`kimi_delta_attention`), where the
 gated delta net's computes again its rule alone.
 """
@@ -216,13 +234,12 @@ def _scalar_decay(q, k, gamma, beta):
             lambda: jnp.exp(gamma)[..., None], leaving)
 
 
-def _channel_decay(q, k, gamma, beta):
-    """Kimi Delta Attention's: a decay a channel, so it sits inside the
-    contraction. q, k [..., C, d_k], gamma [..., C, d_k], beta [..., C] ->
-    (diag(beta) A, B) float32, A_ij = sum_d k_id k_jd exp(gamma_id -
-    gamma_jd) and B the same with q_i, for i >= j (zeros above), and the
-    four factors `_scalar_decay` returns, a channel each: [..., C, d_k]
-    thrice and exp(gamma_C) [..., d_k, 1]; as functions, as it hands them.
+def _plain_pairs(q, k, gamma):
+    """q, k [..., C, d_k], gamma [..., C, d_k] float32 -> [..., 2, C, C]
+    float32: A_ij = sum_d k_id k_jd exp(gamma_id - gamma_jd) and B the same
+    with q_i, for i >= j (zeros above). In plain ops: what runs wherever the
+    program is not compiled for a TPU, and the plain reference of the two
+    kernels that make the same there (`dynolog_tpu/ops/kda_pairs.py`).
 
     (K * exp(gamma)) (K * exp(-gamma))^T would be one product and overflows
     float32 inside a chunk (gamma passes -88 within 64 tokens at the
@@ -266,7 +283,47 @@ def _channel_decay(q, k, gamma, beta):
     own = jnp.einsum("...Iij,IJ->...IiJj", own, at).reshape(
         *lead, 2, chunk, chunk)
     off = jnp.moveaxis(off.reshape(*lead, n_sub, 2, SUB, chunk), -3, -4)
-    both = own + off.reshape(*lead, 2, chunk, chunk)
+    return own + off.reshape(*lead, 2, chunk, chunk)
+
+
+def _channel_decay(q, k, gamma, beta, mesh=None):
+    """Kimi Delta Attention's: a decay a channel, so it sits inside the
+    contraction. q, k [N, B, H, C, d_k], gamma [N, B, H, C, d_k], beta [N,
+    B, H, C] -> (diag(beta) A, B) float32, A_ij = sum_d k_id k_jd
+    exp(gamma_id - gamma_jd) and B the same with q_i, for i >= j (zeros
+    above), and the four factors `_scalar_decay` returns, a channel each:
+    [..., C, d_k] thrice and exp(gamma_C) [..., d_k, 1]; as functions, as it
+    hands them.
+
+    A and B are made by what is being compiled, not by a field: for a TPU
+    the two Pallas kernels `kda_pairs_fwd` and `kda_pairs_bwd`, which hold
+    the pairs in fast memory, everywhere else `_plain_pairs`, op for op what
+    it was (the compiler sees the one or the other, never a conditional).
+    At a chunk or a head width the chip's compiler has not taken the kernels
+    at (`kda_pairs.compiles`) it is `_plain_pairs` on a TPU too."""
+    from dynolog_tpu.ops.kda_pairs import compiles, kda_pairs
+
+    if not compiles(*k.shape[-2:]):
+        both = _plain_pairs(q, k, gamma)
+    else:
+        kernels = kda_pairs
+        if mesh is not None:
+            # A Mosaic kernel is opaque to the SPMD partitioner (as the
+            # flash kernels, transformer.py `_softmax_attention`): each
+            # device runs it on its own batch rows and heads; a chunk-head
+            # needs no other's. The plain ops the partitioner splits
+            # itself, as it did.
+            from jax.sharding import PartitionSpec as P
+
+            from dynolog_tpu.parallel.sharding import BATCH_AXES
+
+            rows = P(None, BATCH_AXES, "model", None, None)
+            kernels = jax.shard_map(
+                kda_pairs, mesh=mesh, in_specs=(rows, rows, rows),
+                out_specs=P(None, BATCH_AXES, "model", None, None, None),
+                check_vma=False)
+        both = jax.lax.platform_dependent(
+            q, k, gamma, tpu=kernels, default=_plain_pairs)
     fall, total = jnp.exp(gamma), gamma[..., -1:, :]  # total: gamma_C
     return (lambda: beta[..., None] * both[..., 0, :, :],
             lambda: both[..., 1, :, :], lambda: beta[..., None] * fall,
@@ -338,10 +395,12 @@ def chunked_delta_rule(q, k, v, g, beta):
     return _chunked_rule(q, k, v, g, beta, _scalar_decay, "gdn")
 
 
-def chunked_kda_rule(q, k, v, g, beta):
+def chunked_kda_rule(q, k, v, g, beta, mesh=None):
     """Kimi Delta Attention's rule: as `chunked_delta_rule` with g [B, S, H,
-    d_k] float32, a number a channel."""
-    return _chunked_rule(q, k, v, g, beta, _channel_decay, "kda")
+    d_k] float32, a number a channel. `mesh`: the mesh the program is
+    partitioned over, if any (`_channel_decay`'s kernels are not)."""
+    return _chunked_rule(
+        q, k, v, g, beta, functools.partial(_channel_decay, mesh=mesh), "kda")
 
 
 def gated_delta_net(layer, x, cfg):
@@ -375,7 +434,7 @@ def gated_delta_net(layer, x, cfg):
         return out.reshape(b, s, h * dv) @ layer["gdn_o"]
 
 
-def _kda_from_projections(small, q, k, v, raw, beta, *, heads: int):
+def _kda_from_projections(small, q, k, v, raw, beta, *, heads: int, mesh):
     """The projections of a KDA layer (q, k, v [B, S, H d], the decay's
     `raw` [B, S, H d_k] as its bottleneck gave it, beta [B, S, H] float32)
     and the layer's `small` weights (the taps, A_log, dt_bias) -> the rule's
@@ -395,11 +454,12 @@ def _kda_from_projections(small, q, k, v, raw, beta, *, heads: int):
             jax.nn.softplus(
                 raw.astype(f32) + small["kda_dt_bias"].astype(f32))
         ).reshape(b, s, heads, dk)
-    return chunked_kda_rule(q, k, v, g, beta)[0]
+    return chunked_kda_rule(q, k, v, g, beta, mesh)[0]
 
 
-def kimi_delta_attention(layer, x, cfg):
-    """x [B, S, d] (normalised) -> the mixer's output [B, S, d]."""
+def kimi_delta_attention(layer, x, cfg, mesh=None):
+    """x [B, S, d] (normalised) -> the mixer's output [B, S, d]. `mesh`: the
+    mesh the program is partitioned over, if any."""
     b, s, _ = x.shape
     h, dv = cfg.n_heads, cfg.linear_value_head_dim
     with jax.named_scope("kda.project"):
@@ -417,8 +477,8 @@ def kimi_delta_attention(layer, x, cfg):
     # rule alone.)
     small = {name: layer[name] for name in (
         "kda_conv_q", "kda_conv_k", "kda_conv_v", "kda_a_log", "kda_dt_bias")}
-    out = jax.checkpoint(functools.partial(_kda_from_projections, heads=h))(
-        small, q, k, v, raw, beta)
+    out = jax.checkpoint(functools.partial(
+        _kda_from_projections, heads=h, mesh=mesh))(small, q, k, v, raw, beta)
     with jax.named_scope("kda.out"):
         out = _rmsnorm(out, layer["kda_norm_scale"], cfg.norm_eps)
         out = out * jax.nn.sigmoid(gate.reshape(b, s, h, dv))

@@ -600,7 +600,7 @@ def _mix(kind, i, layer, x, positions, cfg: TransformerConfig, mesh):
     if kind == "kda":
         from dynolog_tpu.models.linear_attention import kimi_delta_attention
 
-        return kimi_delta_attention(layer, h, cfg), None
+        return kimi_delta_attention(layer, h, cfg, mesh), None
     if kind == "mla":
         from dynolog_tpu.models.mla import latent_attention
 

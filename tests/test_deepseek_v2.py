@@ -267,8 +267,11 @@ def test_the_kda_rule_compiles_for_the_chip_at_the_published_widths(one_chip):
     checkpoint, at Kimi-Linear's 32 heads of 128 over the cell's 4096
     positions (tests/test_kimi_linear.py holds its numbers; here with the
     kernels' cases, one file a worker describes the topology in). What it
-    guards: the [16, 16, 128] pairs of a diagonal sub-block are summed where
-    they are made; stored, they are 1.07 GB a layer a product at 4096
+    guards: compiled for the chip, a chunk's two products under the decay
+    are the two Pallas kernels (tests/test_kda_pairs.py holds their numbers)
+    and Mosaic takes them at these widths; the program keeps no conditional
+    on the platform and none of the plain body's [16, 16, 128] pairs of a
+    diagonal sub-block: stored, they are 1.07 GB a layer a product at 4096
     positions and the job no longer fits its chip."""
     from dynolog_tpu.models import linear_attention as la
 
@@ -289,12 +292,93 @@ def test_the_kda_rule_compiles_for_the_chip_at_the_published_widths(one_chip):
         jax.config.update("jax_enable_compilation_cache", True)
     text = compiled.as_text()
     assert text.count(" while(") >= 2  # the loop, forward and backward
+    calls = [line for line in text.splitlines()
+             if "custom_call_target" in line and "kda_pairs" in line]
+    # computed again under the checkpoint, then backward (the first pass
+    # is dead here: the gradient alone is asked for)
+    assert sum("kda_pairs_fwd" in line for line in calls) == 1
+    assert sum("kda_pairs_bwd" in line for line in calls) == 1
+    assert " conditional(" not in text
     # a fusion is an op of the step itself: one whose RESULT is the pairs
     stored = [line for line in text.splitlines() if " fusion(" in line
               and re.match(r"\s*(ROOT )?%\S+ = f32\[64,1,32,4,16,16,128\]",
                            line)]
     assert not stored, stored[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
+@pytest.mark.parametrize("dtype, precision", [
+    ("bfloat16", None), ("float32", "highest")])
+@pytest.mark.parametrize("dk", [64, 256])
+def test_the_kda_kernels_compile_at_every_width_they_are_taken_at(
+        one_chip, dk, dtype, precision):
+    """`kda_pairs.WIDTHS` beside Kimi-Linear's 128 (the rule whole, above):
+    Mosaic takes the turned layout, forward and backward, in the model's
+    type and in float32 under a caller's `highest`, which reaches the
+    kernels' products as it reaches the plain body's. Any other width keeps
+    the plain body (tests/test_kda_pairs.py)."""
+    from dynolog_tpu.ops import kda_pairs as kernels
+
+    assert kernels.WIDTHS == (64, 128, 256)
+    rows = jax.ShapeDtypeStruct((16, 64, dk), jnp.dtype(dtype),
+                                sharding=one_chip)
+    gamma = jax.ShapeDtypeStruct((16, 64, dk), jnp.float32, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision(precision or "default"):
+            text = jax.jit(jax.grad(
+                lambda *a: jnp.sum(kernels.kda_pairs(*a) ** 2), (0, 1, 2))
+            ).lower(rows, rows, gamma).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "kda_pairs_fwd" in text and "kda_pairs_bwd" in text
+
+
+def test_the_kda_rule_compiles_for_four_chips_over_a_mesh():
+    """The rule's gradient over data 2 x model 2 of a described v5e host,
+    batch rows and heads a device: the kernels sit in a `shard_map` (a
+    Mosaic kernel cannot be partitioned, and without the mesh handed down
+    the chip's compiler is never reached), each device runs them on its own
+    [N, 1, 2] chunk-heads, and nothing is gathered for them."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from dynolog_tpu.models import linear_attention as la
+    from dynolog_tpu.parallel.sharding import BATCH_AXES, MeshSpec, make_mesh
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - as `one_chip`
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = make_mesh(MeshSpec(data=2, model=2), topo.devices)
+
+    def shape(*tail, dtype=jnp.bfloat16):
+        spec = P(BATCH_AXES, None, "model", *(None,) * len(tail))
+        return jax.ShapeDtypeStruct(
+            (2, 512, 4, *tail), dtype, sharding=NamedSharding(mesh, spec))
+
+    def loss(*args):
+        return jnp.sum(jax.checkpoint(
+            lambda *a: la.chunked_kda_rule(*a, mesh))(*args)[0].astype(
+                jnp.float32))
+
+    wide = shape(128)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            wide, wide, wide, shape(128, dtype=jnp.float32),
+            shape(dtype=jnp.float32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    calls = [line for line in text.splitlines()
+             if "custom_call_target" in line and "kda_pairs" in line]
+    assert sum("kda_pairs_fwd" in line for line in calls) == 1
+    assert sum("kda_pairs_bwd" in line for line in calls) == 1
+    # a device's own share: 8 chunks of 1 batch row and 2 heads
+    assert all("[16,64,128]" in line for line in calls), calls
+    assert " all-gather(" not in text
 
 
 # -- the model whole -----------------------------------------------------
